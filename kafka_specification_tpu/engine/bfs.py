@@ -51,6 +51,7 @@ from ..resilience.resources import (
     is_disk_full,
 )
 from ..resilience.retry import ChunkRetryHandler
+from ..utils.platform_guard import device_stamp
 from .pipeline import (
     grow_visited as _grow_visited,
     make_pipeline,
@@ -270,9 +271,20 @@ class _Step:
         self.C = model.total_fanout
         # opt-in Pallas fingerprint kernel (hashed mode only; bit-identical
         # to the jnp path — see ops/pallas_fingerprint.py)
-        self.use_pallas = (
-            os.environ.get("KSPEC_USE_PALLAS") == "1" and not self.spec.exact64
-        )
+        want_pallas = os.environ.get("KSPEC_USE_PALLAS") == "1"
+        self.use_pallas = want_pallas and not self.spec.exact64
+        if (
+            want_pallas
+            and self.spec.exact64
+            and jax.default_backend() != "cpu"
+        ):
+            # on an accelerator the opt-in either runs the kernels or
+            # fails: it never lands on the jnp path unannounced
+            raise RuntimeError(
+                "KSPEC_USE_PALLAS=1: the Pallas kernels serve hashed specs "
+                f"only and {model.name} packs into an exact 64-bit "
+                "fingerprint; unset KSPEC_USE_PALLAS for this model"
+            )
         # global action id per flattened choice column
         act_ids = np.concatenate(
             [np.full(a.n_choices, i, np.int32) for i, a in enumerate(model.actions)]
@@ -514,7 +526,7 @@ class _Step:
     ):
         # use_pallas is in the key because the cache outlives this _Step
         # (it is shared per Model) and KSPEC_USE_PALLAS can toggle between
-        # check() calls (scripts/tpu_window.py does exactly that).
+        # check() calls.
         # squeeze_full only changes the program on the uniform-shift
         # compact path (per-action and full paths already run T = T_exp) —
         # normalize it so the sticky flag can't force recompiles of
@@ -1474,7 +1486,7 @@ def check(
         mem_budget=mem_budget,
         chunk_size=chunk_size,
         checkpoint_dir=checkpoint_dir,
-        platform=jax.default_backend(),
+        **device_stamp(),
     )
 
     # identity stamp: a checkpoint may only resume the same model, constants,
@@ -2205,23 +2217,30 @@ def check(
                     use_p_hbm = not use_p and (
                         os.environ.get("KSPEC_PALLAS_HBM") == "1"
                     )
-                if (
-                    step_builder.use_pallas
-                    and not use_p
-                    and not use_p_hbm
-                    and not pallas_vmem_noted
-                ):
-                    pallas_vmem_noted = True
-                    print(
-                        "[kspec] KSPEC_USE_PALLAS: table capacity "
+                if step_builder.use_pallas and not use_p and not use_p_hbm:
+                    gate = (
+                        "KSPEC_USE_PALLAS: table capacity "
                         f"{ht_hi.shape[0]} exceeds the VMEM-staged "
                         f"kernel's limit ({pallas_hs.MAX_VMEM_CAP}); "
-                        "falling back to the jnp HBM probe path "
-                        "(KSPEC_PALLAS_HBM=1 selects the HBM-resident "
-                        "DMA kernel instead)",
-                        file=sys.stderr,
-                        flush=True,
                     )
+                    if jax.default_backend() != "cpu":
+                        # on an accelerator the opt-in either runs a
+                        # Pallas kernel or fails: the jnp probe is never
+                        # substituted (the loud fallback below is the
+                        # interpret-mode test venue's)
+                        raise RuntimeError(
+                            gate + "set KSPEC_PALLAS_HBM=1 for the "
+                            "HBM-resident kernel or unset KSPEC_USE_PALLAS"
+                        )
+                    if not pallas_vmem_noted:
+                        pallas_vmem_noted = True
+                        print(
+                            "[kspec] " + gate + "falling back to the jnp "
+                            "HBM probe path (KSPEC_PALLAS_HBM=1 selects "
+                            "the HBM-resident DMA kernel instead)",
+                            file=sys.stderr,
+                            flush=True,
+                        )
                 if use_p_hbm:
                     ht_hi, ht_lo, m, _ni, ovf = (
                         pallas_hs.probe_insert_pallas_hbm(

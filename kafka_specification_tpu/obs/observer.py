@@ -193,6 +193,14 @@ class RunObserver:
             seconds=round(result.seconds, 3),
             states_per_sec=round(result.states_per_sec, 1),
         )
+        # the run directory's own record of which path produced the
+        # answer: whole-level programs run + why (if ever) the run left
+        # them (`--pipeline device`); mesh size and what the exchange
+        # carried (sharded engine)
+        for key in ("device", "devices", "exchange_compressed",
+                    "exchange_bytes_total"):
+            if key in s:
+                summary[key] = s[key]
         if result.violation is not None:
             summary["violation"] = {
                 "invariant": result.violation.invariant,

@@ -3,7 +3,7 @@
 One definition of "same winners as the jnp path" for every consumer that
 validates a Pallas probe kernel against hashset.probe_insert: the
 interpret-mode bit-identity tests (tests/test_pallas.py) and the on-chip
-smoke tool (scripts/tpu_probe_smoke.py).  The fixture bakes in the
+ladder (scripts/tpu_mosaic_ladder.py).  The fixture bakes in the
 awkward cases — in-batch duplicates (winner identity matters: the lowest
 -index row carries parent/action attribution for traces), rows colliding
 with pre-seeded table entries, and invalid rows.

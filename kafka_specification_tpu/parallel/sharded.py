@@ -34,27 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax >= 0.5 promotes shard_map to the top level; the replicated-value
-# checking flag was separately renamed check_rep -> check_vma.  Feature-
-# detect BOTH independently (there are versions with a top-level shard_map
-# that still takes check_rep), so the engine runs across the whole window.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_sm_params = _inspect.signature(_shard_map).parameters
-_SHARD_MAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in _sm_params
-    else {"check_rep": False}
-    if "check_rep" in _sm_params
-    else {}
-)
-del _inspect, _sm_params
-
 from ..engine.bfs import (
     AdaptiveCompact,
     CheckResult,
@@ -81,6 +60,7 @@ from ..resilience.resources import (
 )
 from ..resilience.retry import ChunkRetryHandler
 from ..storage.parent_log import ShardedParentLog
+from ..utils.platform_guard import device_stamp
 from .multihost import (
     fetch_global,
     is_coordinator,
@@ -565,7 +545,7 @@ def _make_sharded_step(
     # name which dim rides the mesh axis instead of the old implicit
     # P("d")-for-everything (same placement, now spelled out and
     # asserted in tests so a real-ICI mesh inherits it unchanged)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(
@@ -597,7 +577,7 @@ def _make_sharded_step(
             P("d", None),  # sent framing digests [D, 5]
             P("d", None),  # recv framing digests [D, 5]
         ),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -882,7 +862,7 @@ def _make_sharded_level(
             nclean[None],
         )
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         level_body,
         mesh=mesh,
         in_specs=(
@@ -911,7 +891,7 @@ def _make_sharded_level(
             P("d"),        # replicated overflow flag
             P("d"),        # clean (counted) chunks
         ),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -1117,7 +1097,7 @@ def _make_sharded_level_host(
             nclean[None],
         )
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         level_body,
         mesh=mesh,
         in_specs=(
@@ -1140,7 +1120,7 @@ def _make_sharded_level_host(
             P("d"),        # replicated overflow flag
             P("d"),        # clean (counted) chunks
         ),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -1675,7 +1655,7 @@ def check_sharded(
         store="disk" if use_disk else "ram",
         mem_budget=mem_budget,
         checkpoint_dir=checkpoint_dir,
-        platform=jax.default_backend(),
+        **device_stamp(),
     )
     host_sets = None
     spill_base = None

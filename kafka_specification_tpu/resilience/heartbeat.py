@@ -1,16 +1,16 @@
 """Shared JSONL heartbeat envelope.
 
 Every record written by the observability/liveness streams — the engines'
-per-level stats lines, the TPU-window sentry's per-attempt lines, and the
-supervisor's own event log — carries the same envelope so one consumer
+per-level stats lines and the supervisor's own event log — carries the
+same envelope so one consumer
 (the supervisor's stall detector, or a human with `tail -f | jq`) can read
 any of them:
 
     {"kind": "<stream>", "ts": "<UTC ISO-8601>", "unix": <float seconds>, ...}
 
-`kind` values in use: "level" (engine per-level stats), "sentry" (TPU
-sentry attempts), "supervisor" (resilient_run events).  Stream-specific
-fields ride alongside.
+`kind` values in use: "level" (engine per-level stats), "supervisor"
+(resilient_run events), "service" (daemon events).  Stream-specific fields
+ride alongside.
 
 Must stay jax-free: imported by parents that never touch the accelerator.
 """

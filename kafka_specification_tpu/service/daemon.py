@@ -40,8 +40,10 @@ from typing import Optional
 
 from .. import durable_io as _dio
 from ..utils import clock as _clk
+from ..utils.platform_guard import device_label, device_stamp
 from ..engine.bfs import check
 from ..obs import RunContext, fleettrace
+from ..obs.atomicio import atomic_write_text
 from ..obs.metrics import MetricsRegistry
 from ..resilience.faults import FaultPlan, InjectedCrash, injected_skew_s
 from ..resilience.heartbeat import append_jsonl, heartbeat_record
@@ -56,7 +58,9 @@ from .kernel_cache import (
 )
 from .queue import JobQueue
 from .scheduler import TenantPolicy, plan_groups, union_invariants
+from ..utils.pretty import render_trace
 from .verdict import (
+    COUNTEREXAMPLE,
     EXIT_RESOURCE,
     error_verdict,
     verdict_from_result,
@@ -230,9 +234,15 @@ class Daemon:
         old_term = signal.signal(signal.SIGTERM, self.request_stop)
         old_int = signal.signal(signal.SIGINT, self.request_stop)
         orphans = self.queue.requeue_orphans()
-        self._event("daemon-start", pid=os.getpid(), requeued=len(orphans))
+        # initializes the backend: a daemon that cannot get its platform
+        # (a second daemon on a one-chip host) dies here, before it
+        # claims a job
+        dev = device_stamp()
+        self._event("daemon-start", pid=os.getpid(), requeued=len(orphans),
+                    **dev)
         print(
-            f"[serve] daemon up: dir={self.queue.dir} pid={os.getpid()}"
+            f"[serve] daemon up: dir={self.queue.dir} pid={os.getpid()} "
+            f"on {device_label(dev)}"
             + (f" (requeued {len(orphans)} orphaned claims)" if orphans
                else ""),
             file=sys.stderr,
@@ -655,7 +665,14 @@ class Daemon:
         # fixed point: re-compile them now — verdicts are already
         # published, the busy-heartbeat window is still open, and no job
         # is waiting on this — so the SECOND job of the shape shows zero
-        # compile spans even when the first had to grow
+        # compile spans even when the first had to grow.  A daemon that is
+        # about to exit (stop requested, --max-jobs reached) has no second
+        # job to warm for: on the chip each re-compiled step costs 15-40 s
+        if self._stop or (
+            self.cfg.max_jobs is not None
+            and self.jobs_done >= self.cfg.max_jobs
+        ):
+            return n
         try:
             warmed = entry["prepared"].rewarm()
             if warmed:
@@ -727,6 +744,7 @@ class Daemon:
                     # summary with the member's own derived verdict +
                     # service metadata
                     leader_ctx.finish(rec["status"], **_summary(rec))
+                    run_dir = leader_ctx.dir
                 else:
                     ctx = RunContext(
                         self.queue.run_dir(spec["job_id"]), durable=False
@@ -744,6 +762,16 @@ class Daemon:
                     )
                     rec["run_id"] = ctx.run_id
                     ctx.finish(rec["status"], **_summary(rec))
+                    run_dir = ctx.dir
+                if res.violation is not None and res.violation.trace:
+                    # the verdict carries only trace_len: the rendered
+                    # counterexample is the run directory's record
+                    atomic_write_text(
+                        os.path.join(run_dir, COUNTEREXAMPLE),
+                        render_trace(
+                            cache_entry["model"].meta, res.violation.trace
+                        ) + "\n",
+                    )
                 self._finish_job(spec, rec)
                 fleettrace.emit_span(
                     self.queue.dir, spec.get("trace"), "svc-run",

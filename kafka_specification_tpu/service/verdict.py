@@ -33,6 +33,9 @@ from typing import Optional
 from ..resilience.resources import EXIT_RESOURCE_EXHAUSTED as EXIT_RESOURCE
 
 VERDICT_SCHEMA = "kspec-verdict/1"
+# the verdict carries trace_len only; the daemon leaves the rendered
+# counterexample under this name in the job's run directory
+COUNTEREXAMPLE = "counterexample.txt"
 
 EXIT_OK = 0
 EXIT_VIOLATION = 1
@@ -114,7 +117,7 @@ def render_verdict(rec: dict) -> str:
         lines.append(
             f"  Invariant {v['invariant']} is VIOLATED at depth "
             f"{v['depth']} (trace of {v['trace_len']} states in the run "
-            f"report)"
+            f"directory's {COUNTEREXAMPLE})"
         )
     elif rec.get("model") is not None:
         lines.append("  No invariant violations. Exhaustive check complete.")

@@ -109,6 +109,10 @@ def parse_cfg(path_or_text) -> TlcConfig:
 
 KAFKA_VARIANTS = ("KafkaTruncateToHighWatermark", "Kip101", "Kip279")
 
+# shipped .cfg files whose stem is not a module name (TLC pairs Model.cfg
+# with Model.tla; these document their explicit `--module`)
+CFG_MODULE_ALIASES = {"Kip320Stretch": "Kip320", "Kip320FiveBroker": "Kip320"}
+
 
 def _setlen(v) -> int:
     return len(v) if isinstance(v, list) else int(v)

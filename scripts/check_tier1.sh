@@ -20,14 +20,14 @@ set -u
 cd "$(dirname "$0")/.."
 
 echo "[tier1] stage 1: static gate (compileall + pyflakes)"
-python -m compileall -q kafka_specification_tpu tests scripts bench.py || {
+python -m compileall -q kafka_specification_tpu tests scripts bench.py chip_smoke.py || {
     echo "[tier1] FAIL: compileall found syntax errors" >&2
     exit 1
 }
 if python -c "import pyflakes" 2>/dev/null; then
     # F821 undefined-name class of bugs; pyflakes is advisory-strict:
     # any finding fails the gate (the tree is kept pyflakes-clean)
-    python -m pyflakes kafka_specification_tpu scripts bench.py || {
+    python -m pyflakes kafka_specification_tpu scripts bench.py chip_smoke.py || {
         echo "[tier1] FAIL: pyflakes findings (fix or # noqa them)" >&2
         exit 1
     }

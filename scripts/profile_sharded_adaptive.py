@@ -20,17 +20,13 @@ import time
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from kafka_specification_tpu.utils.platform_guard import pin_cpu_in_process  # noqa: E402
+from kafka_specification_tpu.utils.platform_guard import (  # noqa: E402
+    enable_compile_cache,
+    pin_cpu_in_process,
+)
 
 pin_cpu_in_process()
-import jax  # noqa: E402
-
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
-    ),
-)
+enable_compile_cache()
 
 from kafka_specification_tpu.models import kip320  # noqa: E402
 from kafka_specification_tpu.models.kafka_replication import Config  # noqa: E402

@@ -10,15 +10,14 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from kafka_specification_tpu.utils.platform_guard import pin_cpu_in_process  # noqa: E402
+from kafka_specification_tpu.utils.platform_guard import (  # noqa: E402
+    enable_compile_cache,
+    pin_cpu_in_process,
+)
 
 pin_cpu_in_process()
 import jax  # noqa: E402
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+enable_compile_cache()
 import jax.numpy as jnp
 import numpy as np
 

@@ -17,15 +17,13 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from kafka_specification_tpu.utils.platform_guard import pin_cpu_in_process  # noqa: E402
+from kafka_specification_tpu.utils.platform_guard import (  # noqa: E402
+    enable_compile_cache,
+    pin_cpu_in_process,
+)
 
 pin_cpu_in_process()
-import jax  # noqa: E402
-
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"),
-)
+enable_compile_cache()
 
 from kafka_specification_tpu.engine import check  # noqa: E402
 from kafka_specification_tpu.models import kip320  # noqa: E402

@@ -3,7 +3,11 @@
 import numpy as np
 import pytest
 
-from kafka_specification_tpu.utils.cfg import parse_cfg, build_model
+from kafka_specification_tpu.utils.cfg import (
+    CFG_MODULE_ALIASES,
+    build_model,
+    parse_cfg,
+)
 from kafka_specification_tpu.utils.cli import main as cli_main
 from kafka_specification_tpu.engine.bfs import check
 
@@ -36,9 +40,8 @@ CHECK_DEADLOCK FALSE
 def test_build_model_registry_covers_all_modules():
     import pathlib
 
-    aliases = {"Kip320Stretch": "Kip320"}  # cfg files not named after a module
     for cfg_file in pathlib.Path("configs").glob("*.cfg"):
-        module = aliases.get(cfg_file.stem, cfg_file.stem)
+        module = CFG_MODULE_ALIASES.get(cfg_file.stem, cfg_file.stem)
         cfg = parse_cfg(cfg_file)
         model = build_model(module, cfg)
         oracle = build_model(module, cfg, oracle=True)

@@ -448,6 +448,20 @@ def test_batched_group_bit_identical_to_solo(tmp_path):
             assert rec["violation"]["invariant"] == s.violation.invariant
             assert rec["violation"]["depth"] == s.violation.depth
             assert rec["violation"]["trace_len"] == len(s.violation.trace)
+            # the verdict carries trace_len only: the rendered trace is
+            # the run directory's record, equal to the solo rendering
+            # under the .cfg's own replica names
+            from kafka_specification_tpu.utils.cfg import build_model, parse_cfg
+            from kafka_specification_tpu.utils.pretty import render_trace
+
+            meta = build_model(
+                "KafkaTruncateToHighWatermark", parse_cfg(TTW_CFG_WEAK)
+            ).meta
+            cex = os.path.join(q.run_dir(job["job_id"]), "counterexample.txt")
+            with open(cex) as fh:
+                assert fh.read() == render_trace(
+                    meta, s.violation.trace
+                ) + "\n", name
     # trace VALUES: replay the batched runner directly against solo
     from kafka_specification_tpu.engine.bfs import prepare
     from kafka_specification_tpu.service.batch import Member, run_group

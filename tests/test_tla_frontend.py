@@ -109,9 +109,10 @@ def test_validate_cfg_constants():
     # every shipped config assigns the full constant set of its module
     import pathlib
 
-    aliases = {"Kip320Stretch": "Kip320"}
+    from kafka_specification_tpu.utils.cfg import CFG_MODULE_ALIASES
+
     for cfg_file in pathlib.Path("configs").glob("*.cfg"):
-        module = aliases.get(cfg_file.stem, cfg_file.stem)
+        module = CFG_MODULE_ALIASES.get(cfg_file.stem, cfg_file.stem)
         problems = tf.validate_cfg_constants(parse_cfg(cfg_file), REF, module)
         assert not problems, (cfg_file, problems)
 
