@@ -1,0 +1,21 @@
+from metriclib import has, median_over_passes
+
+META = {
+    "name": "host_share", "unit": "%", "better": "lower",
+    "source": "program_span", "layer": "level loop on the host",
+    "moves": "states_per_s",
+    "what": "sum of host_ms over sum of level_ms of a pass's level records "
+            "(the engine's own host clocks; step_ms ends in a blocking fetch, "
+            "host_ms is the commit work after it), median over the passes",
+}
+
+
+def read(ctx):
+    def one(p):
+        recs = p["level_records"]
+        if not has(recs, "host_ms") or not has(recs, "level_ms"):
+            return None
+        total = sum(r["level_ms"] for r in recs)
+        return 100.0 * sum(r["host_ms"] for r in recs) / total if total else None
+
+    return median_over_passes(ctx, one)
