@@ -39,7 +39,8 @@ import numpy as np
 from ..models.base import Model
 from ..obs import metrics as _met
 from ..obs.observer import RunObserver
-from ..ops import dedup, hashset
+from ..obs.tracer import now as _now
+from ..ops import hashset
 from ..ops.fingerprint import fingerprint_lanes
 from ..resilience import integrity as _integ
 from ..resilience.checkpoints import CheckpointStore
@@ -52,10 +53,17 @@ from ..resilience.resources import (
 )
 from ..resilience.retry import ChunkRetryHandler
 from ..utils.platform_guard import device_stamp
+from .hostio import HostIO
 from .pipeline import (
+    fp_stage,
     grow_visited as _grow_visited,
+    invariant_stage,
     make_pipeline,
+    program_name,
     resolve_pipeline,
+    sorted_dedup_stage,
+    squeeze_stage,
+    stage,
 )
 
 # insert-or-find on the device hash table; table + claim lattice donated so
@@ -93,11 +101,11 @@ class _CompileOnFirstCall:
     def __call__(self, *args):
         from ..obs import tracer as _tr
 
-        t0 = time.time()
+        t0 = _tr.now()
         out = self.fn(*args)
         cur = _tr.current_tracer()
         if cur is not None:
-            cur.emit_span("compile", t0, time.time(), **self._attrs)
+            cur.emit_span("compile", t0, _tr.now(), **self._attrs)
         # swap in the bare jitted fn iff this entry is still current (a
         # capacity-growth eviction may already have dropped the key)
         if self._cache.get(self._key) is self:
@@ -384,6 +392,7 @@ class _Step:
         B = bucket
         M = B * C
 
+        @stage("expand")
         def _expand_full(states, fvalid):
             en_pre, en, packed = jax.vmap(self._expand_one)(states)  # [B,C]x2, [B,C,K]
             en = en & fvalid[:, None]
@@ -426,45 +435,51 @@ class _Step:
                     parts.append(ok)
                 return jnp.concatenate(parts)
 
-            en_pre = jax.vmap(_guards_one)(states)  # [B, C] pre-constraint
+            with stage("guard"):
+                # [B, C] pre-constraint
+                en_pre = jax.vmap(_guards_one)(states)
             cand_parts, valid_parts, parent_parts, act_parts = [], [], [], []
             act_en_parts, act_guard_parts, ovf_parts = [], [], []
             for ai, a in enumerate(model.actions):
                 na = a.n_choices
                 W = widths[ai]
-                ga = (en_pre[:, bounds[ai] : bounds[ai + 1]] & fvalid[:, None]).reshape(
-                    B * na
+                with stage("compact"):
+                    ga = (
+                        en_pre[:, bounds[ai] : bounds[ai + 1]]
+                        & fvalid[:, None]
+                    ).reshape(B * na)
+                    n_en = jnp.sum(ga, dtype=jnp.int32)
+                    act_guard_parts.append(n_en)
+                    ovf_parts.append(n_en > W)
+                    cpos = jnp.where(ga, jnp.cumsum(ga) - 1, W)
+                    cidx = jnp.zeros((W,), jnp.int32).at[cpos].set(
+                        jnp.arange(B * na, dtype=jnp.int32)
+                    )
+                    rowvalid = jnp.arange(W) < n_en
+                with stage("expand"):
+                    sidx = cidx // na
+                    ch = cidx % na
+                    gstate = jax.tree.map(lambda x: x[sidx], states)
+                    ok, nxt = jax.vmap(a.kernel)(gstate, ch)
+                    ok = ok & rowvalid
+                    if model.constraint is not None:
+                        ok = ok & jax.vmap(model.constraint)(nxt)
+                    cand_parts.append(jax.vmap(spec.pack)(nxt))
+                    valid_parts.append(ok)
+                    parent_parts.append(sidx)
+                    act_parts.append(jnp.full((W,), ai, jnp.int32))
+                    act_en_parts.append(jnp.sum(ok, dtype=jnp.int32))
+            with stage("expand"):
+                return (
+                    en_pre,
+                    jnp.concatenate(cand_parts, axis=0),
+                    jnp.concatenate(valid_parts),
+                    jnp.concatenate(parent_parts),
+                    jnp.concatenate(act_parts),
+                    jnp.stack(act_en_parts),
+                    jnp.stack(act_guard_parts),
+                    jnp.stack(ovf_parts),
                 )
-                n_en = jnp.sum(ga, dtype=jnp.int32)
-                act_guard_parts.append(n_en)
-                ovf_parts.append(n_en > W)
-                cpos = jnp.where(ga, jnp.cumsum(ga) - 1, W)
-                cidx = jnp.zeros((W,), jnp.int32).at[cpos].set(
-                    jnp.arange(B * na, dtype=jnp.int32)
-                )
-                rowvalid = jnp.arange(W) < n_en
-                sidx = cidx // na
-                ch = cidx % na
-                gstate = jax.tree.map(lambda x: x[sidx], states)
-                ok, nxt = jax.vmap(a.kernel)(gstate, ch)
-                ok = ok & rowvalid
-                if model.constraint is not None:
-                    ok = ok & jax.vmap(model.constraint)(nxt)
-                cand_parts.append(jax.vmap(spec.pack)(nxt))
-                valid_parts.append(ok)
-                parent_parts.append(sidx)
-                act_parts.append(jnp.full((W,), ai, jnp.int32))
-                act_en_parts.append(jnp.sum(ok, dtype=jnp.int32))
-            return (
-                en_pre,
-                jnp.concatenate(cand_parts, axis=0),
-                jnp.concatenate(valid_parts),
-                jnp.concatenate(parent_parts),
-                jnp.concatenate(act_parts),
-                jnp.stack(act_en_parts),
-                jnp.stack(act_guard_parts),
-                jnp.stack(ovf_parts),
-            )
 
         return _expand_compact if widths is not None else _expand_full
 
@@ -504,14 +519,19 @@ class _Step:
         )
 
     def cached(self, key, build, **attrs):
-        """Compile-cache insert-or-get: `build()` must return the jitted
-        callable; the first call of a fresh entry is wrapped in a
-        ``compile`` span (_CompileOnFirstCall) and the key is appended to
-        the compiled log PreparedKernels.rewarm replays."""
+        """Compile-cache insert-or-get: `build()` returns the un-jitted
+        program, which is jitted here under its cache tag and the naming
+        version (``dvl_n1``; pipeline.program_name), so the HLO module
+        and the profiler's module line say which program ran.  The first
+        call of a fresh entry is wrapped in a ``compile`` span
+        (_CompileOnFirstCall) and the key is appended to the compiled
+        log PreparedKernels.rewarm replays."""
         if key not in self._cache:
             self._compiled_log.add(key)
+            fn = build()
+            fn.__name__ = fn.__qualname__ = program_name(key[0])
             self._cache[key] = _CompileOnFirstCall(
-                build(), self._cache, key, **attrs
+                jax.jit(fn), self._cache, key, **attrs
             )
         return self._cache[key]
 
@@ -551,11 +571,9 @@ class _Step:
         )
         return self.cached(
             key,
-            lambda: jax.jit(
-                self.build_raw(
-                    bucket, vcap, with_invariants, with_merge, compact,
-                    squeeze_full,
-                )
+            lambda: self.build_raw(
+                bucket, vcap, with_invariants, with_merge, compact,
+                squeeze_full,
             ),
             bucket=bucket,
             vcap=vcap,
@@ -608,7 +626,7 @@ class _Step:
         squeeze_full: bool = False,
     ):
         spec, model = self.spec, self.model
-        C, K = self.C, self.K
+        K = self.K
         widths = self.norm_widths(bucket, compact)
         per_action = isinstance(compact, (list, tuple))
         shift = widths is not None  # truthy iff the compact path is on
@@ -639,62 +657,14 @@ class _Step:
         # squeezes the enabled candidates to the front, fingerprints them,
         # and hands (rows, fps) straight to the host.
         host_dedup = not with_merge
-        sent = jnp.uint32(dedup.SENT)
-
-        def squeeze(cand, parent, actid, valid, width):
-            """Compact enabled candidate rows to the front of a `width`
-            buffer; overflow=True iff more than `width` rows are enabled."""
-            n_en = jnp.sum(valid, dtype=jnp.int32)
-            spos = jnp.where(valid, jnp.cumsum(valid) - 1, width)
-            out = jnp.zeros((width, K), jnp.uint32).at[spos].set(cand)
-            out_parent = jnp.full((width,), -1, jnp.int32).at[spos].set(parent)
-            out_act = jnp.full((width,), -1, jnp.int32).at[spos].set(actid)
-            rowvalid = jnp.arange(width) < n_en
-            return out, out_parent, out_act, rowvalid, n_en, n_en > width
-
-        def fp_masked(cand, valid):
-            """Masked (hi, lo) fingerprints (Pallas opt-in or jnp path)."""
-            if self.use_pallas:
-                import math
-
-                from ..ops.pallas_fingerprint import fingerprint_pallas
-
-                interp = jax.default_backend() == "cpu"
-                # block_rows must divide the buffer width (the largest
-                # power-of-two divisor, capped at 8k rows/block): every
-                # buffer here is 1024-aligned or a power-of-two multiple
-                # of C, so blocks stay >= 256 rows
-                rows = cand.shape[0]
-                block = math.gcd(rows, 1 << 13)
-                return fingerprint_pallas(
-                    cand, valid, block_rows=block, interpret=interp
-                )
-            hi, lo = fingerprint_lanes(cand, spec.exact64)
-            return jnp.where(valid, hi, sent), jnp.where(valid, lo, sent)
-
-        def frontier_invariants(states, fvalid):
-            """Per-invariant (any-violated, first-index) on the frontier
-            being expanded (each state is checked exactly once, at
-            expansion; BFS order: states before successors)."""
-            if not (with_invariants and model.invariants):
-                return jnp.stack([jnp.bool_(False)]), jnp.stack([jnp.int32(0)])
-            if model.invariants_fused is not None:
-                # one trace for all predicates: shared subtrees (e.g. the
-                # WeakIsr/StrongIsr quantifier core in emitted models)
-                # evaluate once
-                ok = jax.vmap(model.invariants_fused)(states)  # [B, n_inv]
-                bad = fvalid[:, None] & ~ok
-                return jnp.any(bad, axis=0), jnp.argmax(bad, axis=0)
-            viol_any, viol_idx = [], []
-            for inv in model.invariants:
-                ok = jax.vmap(inv.pred)(states)
-                bad = fvalid & ~ok
-                viol_any.append(jnp.any(bad))
-                viol_idx.append(jnp.argmax(bad))
-            return jnp.stack(viol_any), jnp.stack(viol_idx)
+        use_pallas = self.use_pallas
 
         def step(frontier, fvalid, vhi, vlo, vn):
-            states = jax.vmap(spec.unpack)(frontier)
+            # the frontier unpack belongs to whichever stage reads the
+            # states first: the guard sweep on the compact path, the
+            # full-lattice expansion otherwise
+            with stage("guard" if shift else "expand"):
+                states = jax.vmap(spec.unpack)(frontier)
             (
                 en_pre,
                 cand,
@@ -705,9 +675,10 @@ class _Step:
                 act_guard,
                 exp_ovf,
             ) = expand(states, fvalid)
-            deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
-            dl_any = jnp.any(deadlocked)
-            dl_idx = jnp.argmax(deadlocked)
+            with stage("guard" if shift else "expand"):
+                deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
+                dl_any = jnp.any(deadlocked)
+                dl_idx = jnp.argmax(deadlocked)
 
             # overflow contract: bool[n_actions + 1] — per-action compact-
             # buffer overflow plus one trailing squeeze-overflow flag
@@ -720,84 +691,42 @@ class _Step:
                 return jnp.concatenate([exp_ovf, tail])
 
             if host_dedup:
-                out, out_parent, out_act, rowvalid, n_en, sq_ovf = squeeze(
-                    cand, parent, actid, valid, T
+                out, out_parent, out_act, rowvalid, n_en, sq_ovf = (
+                    squeeze_stage(cand, parent, actid, valid, T, K)
                 )
-                overflow = ovf_vec(sq_ovf)
-                out_hi, out_lo = fp_masked(out, rowvalid)
-                viol_any, viol_idx = frontier_invariants(states, fvalid)
+                out_hi, out_lo = fp_stage(out, rowvalid, spec, use_pallas)
+                viol_any, viol_idx = invariant_stage(
+                    model, states, fvalid, with_invariants
+                )
                 return (
-                    out,
-                    out_parent,
-                    out_act,
-                    n_en,
-                    vhi,
-                    vlo,
-                    vn,
-                    viol_any,
-                    viol_idx,
-                    dl_any,
-                    dl_idx,
-                    act_en,
-                    out_hi,
-                    out_lo,
-                    overflow,
-                    act_guard,
+                    out, out_parent, out_act, n_en, vhi, vlo, vn,
+                    viol_any, viol_idx, dl_any, dl_idx, act_en,
+                    out_hi, out_lo, ovf_vec(sq_ovf), act_guard,
                 )
 
             if shift:
-                cand, parent, actid, valid, _, sq_ovf = squeeze(
-                    cand, parent, actid, valid, T
+                cand, parent, actid, valid, _, sq_ovf = squeeze_stage(
+                    cand, parent, actid, valid, T, K
                 )
                 overflow = ovf_vec(sq_ovf)
             else:
                 overflow = ovf_vec()
 
-            hi, lo = fp_masked(cand, valid)
-            # minimal-payload sort: only the original index rides through the
-            # sort network; state rows/parents are gathered once afterwards
-            order = jnp.lexsort((lo, hi))
-            hi_s, lo_s = hi[order], lo[order]
-            invalid_s = (hi_s == sent) & (lo_s == sent)
-            first = dedup.first_occurrence_mask(hi_s, lo_s, invalid_s)
-            seen, rank = dedup.rank_sorted(vhi, vlo, vn, hi_s, lo_s)
-            is_new = first & ~seen
-
-            # compact new states to the front (OOB scatter indices are dropped)
-            pos = jnp.where(is_new, jnp.cumsum(is_new) - 1, T)
-            out = jnp.zeros((T, K), jnp.uint32).at[pos].set(cand[order])
-            out_parent = jnp.full((T,), -1, jnp.int32).at[pos].set(parent[order])
-            out_act = jnp.full((T,), -1, jnp.int32).at[pos].set(actid[order])
-            out_hi = jnp.full((T,), sent).at[pos].set(hi_s)
-            out_lo = jnp.full((T,), sent).at[pos].set(lo_s)
-            out_rank = jnp.zeros((T,), jnp.int32).at[pos].set(rank)
-            new_n = jnp.sum(is_new, dtype=jnp.int32)
-
-            if with_merge:
-                vhi2, vlo2, vn2 = dedup.merge_ranked(
-                    vhi, vlo, vn, out_hi, out_lo, out_rank, new_n, vcap
-                )
-            else:
-                vhi2, vlo2, vn2 = vhi, vlo, vn
-
-            viol_any, viol_idx = frontier_invariants(states, fvalid)
+            hi, lo = fp_stage(cand, valid, spec, use_pallas)
+            # the shared winner-selection sequence (sort, first
+            # occurrence, visited rank, compaction, rank-scatter merge)
+            (out, out_parent, out_act, new_n, out_hi, out_lo,
+             vhi2, vlo2, vn2, _rank) = sorted_dedup_stage(
+                cand, parent, actid, valid, hi, lo,
+                vhi, vlo, vn, vcap, T, K, True,
+            )
+            viol_any, viol_idx = invariant_stage(
+                model, states, fvalid, with_invariants
+            )
             return (
-                out,
-                out_parent,
-                out_act,
-                new_n,
-                vhi2,
-                vlo2,
-                vn2,
-                viol_any,
-                viol_idx,
-                dl_any,
-                dl_idx,
-                act_en,
-                out_hi,
-                out_lo,
-                overflow,
-                act_guard,
+                out, out_parent, out_act, new_n, vhi2, vlo2, vn2,
+                viol_any, viol_idx, dl_any, dl_idx, act_en,
+                out_hi, out_lo, overflow, act_guard,
             )
 
         return step
@@ -1216,6 +1145,7 @@ def check(
     verify-checkpoint`, and resuming after the operator frees space is
     bit-identical to an uninterrupted run (tests/test_resources.py).
     """
+    t_check = _now()
     spec = model.spec
     # encoding-soundness gate (analysis; KSPEC_ANALYZE=0 disables): an
     # action that can write outside its declared field ranges would be
@@ -1231,7 +1161,12 @@ def check(
 
     # unified telemetry: run_id-stamped stats/spans/metrics when a run
     # context is given; the exact historical stats_path stream otherwise
-    obs_ = RunObserver(run, stats_path, engine="bfs")
+    # (root span `check` from this function's first line; `check-open`
+    # until the first level begins, `check-close` after the last)
+    obs_ = RunObserver(run, stats_path, engine="bfs",
+                       annotate=jax.profiler.TraceAnnotation)
+    obs_.check_begin(t_check, model=model.name)
+    io = HostIO(obs_)  # counted transfers + named dispatches
 
     from ..storage import resolve_store
 
@@ -1294,6 +1229,9 @@ def check(
         # seed do not exist, so traces cannot be reconstructed
         store_trace = False
 
+    # (child span: the eager pack + fingerprint of the initial states is
+    # 55-73 ms on the chip, PERF.md section 5)
+    sp_ = obs_.open_span("init-states")
     inits = [
         {k: np.asarray(v, np.int32) for k, v in s.items()} for s in model.init_states()
     ]
@@ -1322,6 +1260,7 @@ def check(
 
     t0 = time.perf_counter()
     hi0, lo0 = fingerprint_lanes(jnp.asarray(init_packed), spec.exact64)
+    sp_.finish()
     disk = None
     ephemeral_spill = None
     if visited_backend == "host":
@@ -1414,7 +1353,7 @@ def check(
         vlo = np.full(vcap, 0xFFFFFFFF, np.uint32)
         vhi[:n0] = np.asarray(hi0)[order]
         vlo[:n0] = np.asarray(lo0)[order]
-        vhi, vlo = jnp.asarray(vhi), jnp.asarray(vlo)
+        vhi, vlo = io.put(vhi), io.put(vlo)
         vn = jnp.int32(n0)
 
     levels = [n0]
@@ -1453,6 +1392,8 @@ def check(
 
     # invariants on init states
     if check_invariants and model.invariants:
+        # (child span: op-by-op dispatch, ~120 ms on the chip for one row)
+        sp_ = obs_.open_span("host-invariants", rows=n0)
         st0 = jax.vmap(spec.unpack)(jnp.asarray(init_packed))
         for inv in model.invariants:
             ok = np.asarray(jax.vmap(inv.pred)(st0))
@@ -1473,6 +1414,7 @@ def check(
                 obs_.finish(res)
                 obs_.close()
                 return res
+        sp_.finish()
 
     frontier_np = init_packed
     depth = 0
@@ -1951,6 +1893,7 @@ def check(
         compact_shift=compact_shift,
         compact_gate=compact_gate,
         check_deadlock=check_deadlock,
+        io=io,
     )
     if getattr(pipe, "name", "") == "device" and shadow_rate > 0 and \
             pipe.device_fallback is None:
@@ -1979,7 +1922,7 @@ def check(
           PR 7 bit-identity contract, used as a runtime oracle)."""
         from ..obs import metrics as _met
 
-        t0 = time.perf_counter()
+        t0 = _now()
         main_fps = _integ.pair_u64(
             np.asarray(out_hi[:nn]), np.asarray(out_lo[:nn])
         )
@@ -2039,7 +1982,7 @@ def check(
         _met.inc("kspec_integrity_shadow_total")
         _integ.count_check()
         obs_.chunk_span(
-            "shadow", time.perf_counter() - t0,
+            "shadow", t0,
             depth=depth, start=start, rows=int(fp_n), mode=mode,
         )
 
@@ -2075,8 +2018,9 @@ def check(
         nonlocal lvl_launches, lvl_launches_max, run_launches_max
         nonlocal lvl_act_en, a_w  # arena buffers grow via _grow_arena
         nonlocal ht_hi, ht_lo, ht_claim, hash_n, pallas_vmem_noted
+        nonlocal lvl_store_s
         (start, fp_n, bucket, finalize, pre_v, shadow, dispatch_s,
-         t_staged, piece, pre_vcap) = st
+         t_staged, piece, pre_vcap, t_dispatch) = st
         queued_s = time.perf_counter() - t_staged
         t_wait = time.perf_counter()
         (
@@ -2097,19 +2041,20 @@ def check(
             act_guard,
             launches,
         ) = finalize()
-        act_en_np = np.asarray(act_en, np.int64)
+        act_en_np = io.fetch(act_en, np.int64)
         # frontier-level verdicts (states being expanded = level `depth`)
         if check_invariants:
-            viol_any_np = np.asarray(viol_any)
+            viol_any_np = io.fetch(viol_any)
             if viol_any_np.any():
                 inv_i = int(np.argmax(viol_any_np))
-                idx = start + int(np.asarray(viol_idx)[inv_i])
+                idx = start + int(io.fetch(viol_idx)[inv_i])
                 verdict = ("invariant", idx, model.invariants[inv_i].name)
                 return True
-        if check_deadlock and bool(dl_any):
-            verdict = ("deadlock", start + int(dl_idx), "Deadlock")
+        if check_deadlock and bool(io.fetch(dl_any)):
+            verdict = ("deadlock", start + int(io.fetch(dl_idx)),
+                       "Deadlock")
             return True
-        nn = int(new_n)
+        nn = int(io.fetch(new_n))
         if shadow:
             # pre_vcap: the visited capacity AT DISPATCH — the next
             # chunk's dispatch may have grown `vcap` before this commit,
@@ -2129,14 +2074,17 @@ def check(
         # overlap on, queued_ms is how long the chunk sat staged while
         # the previous chunk committed — device time hidden behind host
         # work; wait_ms is the residual block on the outputs at commit
+        # the span is the true interval, dispatch to outputs in hand
+        # (dispatch + queued + wait); step_ms stays dispatch + wait
         obs_.chunk_span(
-            "step", step_s, depth=depth, start=start, rows=fp_n,
+            "step", t_dispatch, depth=depth, start=start, rows=fp_n,
             bucket=bucket, launches=launches,
             dispatch_ms=round(dispatch_s * 1e3, 2),
             wait_ms=round(wait_s * 1e3, 2),
             queued_ms=round(queued_s * 1e3, 2),
         )
         t_host = time.perf_counter()
+        t_host_wall = _now()
         if host_set is not None and nn:
             if use_arena:
                 _grow_arena(nn)
@@ -2173,11 +2121,13 @@ def check(
                     # frontier + parent log in discovery order (int64
                     # parents: level-global indices can pass 2^31 at
                     # the scales this tier exists for)
+                    t_st = time.perf_counter()
                     disk.append(
                         rows[mask],
                         np.asarray(out_parent[:nn], np.int64)[mask] + start,
                         np.asarray(out_act[:nn])[mask],
                     )
+                    lvl_store_s += time.perf_counter() - t_st
                 else:
                     lvl_rows.append(rows[mask])
                     lvl_parent.append(
@@ -2277,8 +2227,8 @@ def check(
                     ht_hi, ht_lo, ht_claim, m, _ni, ovf = _hash_insert(
                         ht_hi, ht_lo, ht_claim, out_hi, out_lo, valid
                     )
-                isnew |= np.asarray(m)
-                if not bool(ovf):
+                isnew |= io.fetch(m)
+                if not bool(io.fetch(ovf)):
                     break
                 ht_hi, ht_lo = hashset.rehash_into(
                     ht_hi, ht_lo, 2 * ht_hi.shape[0]
@@ -2286,35 +2236,35 @@ def check(
                 ht_claim = None
             mask = isnew[:nn]
             hash_n += int(mask.sum())
-            lvl_rows.append(np.asarray(out[:nn])[mask])
-            lvl_parent.append(np.asarray(out_parent[:nn])[mask] + start)
-            lvl_act.append(np.asarray(out_act[:nn])[mask])
+            lvl_rows.append(io.fetch(out[:nn])[mask])
+            lvl_parent.append(io.fetch(out_parent[:nn])[mask] + start)
+            lvl_act.append(io.fetch(out_act[:nn])[mask])
             lvl_new += int(mask.sum())
             if chain is not None:
                 chain.fold(
                     _integ.pair_u64(
-                        np.asarray(out_hi[:nn])[mask],
-                        np.asarray(out_lo[:nn])[mask],
+                        io.fetch(out_hi[:nn])[mask],
+                        io.fetch(out_lo[:nn])[mask],
                     )
                 )
         elif nn:
-            lvl_rows.append(np.asarray(out[:nn]))
-            lvl_parent.append(np.asarray(out_parent[:nn]) + start)
-            lvl_act.append(np.asarray(out_act[:nn]))
+            lvl_rows.append(io.fetch(out[:nn]))
+            lvl_parent.append(io.fetch(out_parent[:nn]) + start)
+            lvl_act.append(io.fetch(out_act[:nn]))
             lvl_new += nn
             if chain is not None:
                 # device backend: the in-jit dedup already
                 # compacted exactly the new states to the front
                 chain.fold(
                     _integ.pair_u64(
-                        np.asarray(out_hi[:nn]),
-                        np.asarray(out_lo[:nn]),
+                        io.fetch(out_hi[:nn]),
+                        io.fetch(out_lo[:nn]),
                     )
                 )
         host_s = time.perf_counter() - t_host
         prof_host_s += host_s
         obs_.chunk_span(
-            "host-assembly", host_s, depth=depth, start=start, new=nn,
+            "host-assembly", t_host_wall, depth=depth, start=start, new=nn,
             backend=visited_backend,
         )
         if collect_stats:
@@ -2322,7 +2272,8 @@ def check(
 
         return False
 
-    def _commit_device_level(fin, dispatch_s: float, plan) -> bool:
+    def _commit_device_level(fin, dispatch_s: float, t_dispatch: float,
+                             plan) -> bool:
         """Commit a whole device-resident level (DevicePipeline.run_level):
         block on the level program's outputs, apply the serial commit
         loop's verdict rule, then the host bookkeeping.
@@ -2348,7 +2299,7 @@ def check(
         never dispatched — the serial break)."""
         nonlocal verdict, lvl_new, prof_step, prof_host_s
         nonlocal lvl_launches, lvl_launches_max, run_launches_max
-        nonlocal lvl_act_en, lvl_probe_ms, a_w
+        nonlocal lvl_act_en, lvl_probe_ms, a_w, lvl_store_s
         t_wait = time.perf_counter()
         out = fin()
         wait_s = time.perf_counter() - t_wait
@@ -2363,7 +2314,7 @@ def check(
         # whole blocked wall is device-wait — there is no in-flight
         # dispatch window like the per-chunk staged contract has
         obs_.chunk_span(
-            "step", step_s, depth=depth, start=0, rows=plan[2],
+            "step", t_dispatch, depth=depth, start=0, rows=plan[2],
             bucket=plan[0], launches=launches, chunks=plan[1],
             pipeline="device",
             dispatch_ms=0.0,
@@ -2380,10 +2331,12 @@ def check(
             )
             return True
         t_host = time.perf_counter()
+        t_host_wall = _now()
         nn = out["new_n"]
         if host_set is not None:
             # the deferred batched probe — ONE host call for the level
             t_probe = time.perf_counter()
+            t_probe_wall = _now()
             committed = 0
             if nn:
                 if use_arena:
@@ -2423,7 +2376,9 @@ def check(
                     par = out["parent"].astype(np.int64)[mask]
                     acts = out["act"][mask]
                     if disk is not None:
+                        t_st = time.perf_counter()
                         disk.append(rows, par, acts)
+                        lvl_store_s += time.perf_counter() - t_st
                     else:
                         lvl_rows.append(rows)
                         lvl_parent.append(par)
@@ -2435,7 +2390,7 @@ def check(
             probe_s = time.perf_counter() - t_probe
             lvl_probe_ms += probe_s * 1e3
             obs_.chunk_span(
-                "host-probe", probe_s, depth=depth, rows=nn,
+                "host-probe", t_probe_wall, depth=depth, rows=nn,
                 new=committed, backend=visited_backend,
                 batched="level",
             )
@@ -2449,7 +2404,7 @@ def check(
         host_s = time.perf_counter() - t_host
         prof_host_s += host_s
         obs_.chunk_span(
-            "host-assembly", host_s, depth=depth, start=0, new=nn,
+            "host-assembly", t_host_wall, depth=depth, start=0, new=nn,
             backend=visited_backend,
         )
         if collect_stats:
@@ -2540,6 +2495,7 @@ def check(
             lvl_launches = 0  # successor-kernel launches this level
             lvl_launches_max = 0  # ... and the per-chunk maximum
             lvl_probe_ms = 0.0  # deferred batched host-probe wall
+            lvl_store_s = 0.0  # trace store / parent log wall (`store_ms`)
             verdict = None  # (kind, global_frontier_idx, inv_name)
             # Host-native backend: assemble the next level in a preallocated
             # arena via the fused C pass (native.FpSet.insert_compact) — one
@@ -2629,6 +2585,7 @@ def check(
                         dev_plan = None
             if dev_plan is not None:
                 t_attempt = time.perf_counter()
+                t_dispatch = _now()
                 dres = pipe.run_level(
                     dev_rows, f_total, depth, vhi, vlo, vn, vcap,
                     dev_plan,
@@ -2638,7 +2595,7 @@ def check(
                     dispatch_s = time.perf_counter() - t_attempt
                     dev_handled = dev_plan[2]
                     if _commit_device_level(dev_fin, dispatch_s,
-                                            dev_plan):
+                                            t_dispatch, dev_plan):
                         dev_handled = f_total  # verdict: skip the tail
             # Tail iteration after a device-resident span: a fully-
             # handled level skips it entirely, and a disk-tier tail
@@ -2664,7 +2621,7 @@ def check(
                 bucket = _next_pow2(max(fp_n, min_bucket))
                 M = bucket * C
                 if visited_backend == "device":
-                    need = int(vn) + M
+                    need = int(io.fetch(vn)) + M
                     if need > vcap:
                         # one shared growth policy with the device level
                         # path (pipeline.grow_visited); growth is
@@ -2694,13 +2651,14 @@ def check(
                 # arrays are immutable, so holding them is free)
                 pre_v = (vhi, vlo, vn) if shadow else None
                 t_attempt = time.perf_counter()
+                t_dispatch = _now()
                 vhi, vlo, vn, finalize = pipe.run_chunk_staged(
                     piece, fp_n, bucket, depth, vhi, vlo, vn, vcap
                 )
                 cur = (
                     start, fp_n, bucket, finalize, pre_v, shadow,
                     time.perf_counter() - t_attempt, time.perf_counter(),
-                    piece, vcap,
+                    piece, vcap, t_dispatch,
                 )
                 if overlap_on:
                     overlap_staged_peak = max(
@@ -2737,37 +2695,61 @@ def check(
                 break
 
             new_n = lvl_new
+            # the next frontier (every run needs it) ...
             if use_arena:
                 next_frontier = a_rows[:a_w]
-                level_parent = a_parent[:a_w]
-                level_act = a_act[:a_w]
-                if (store_trace or collect_levels is not None) and a_w < int(
-                    0.95 * a_cap
-                ):
-                    # retained levels: shrink-copy so the trace store doesn't
-                    # hold the arena's growth headroom for the whole run
-                    next_frontier = next_frontier.copy()
-                    level_parent = level_parent.copy()
-                    level_act = level_act.copy()
-            elif disk is not None:
-                # publish the level: segments + parent-log frame become the
-                # pending frontier; the consumed level's segments go behind
-                # the checkpoint-generation deletion barrier
-                next_frontier = disk.end_level()
-                level_parent = level_act = None  # trace lives in the log
-            else:
+            elif disk is None:
                 next_frontier = (
                     np.concatenate(lvl_rows)
                     if lvl_rows
                     else np.empty((0, K), np.uint32)
                 )
-                level_parent = (
-                    np.concatenate(lvl_parent)
-                    if lvl_parent
-                    else np.empty(0, np.int64)
-                )
-                level_act = (
-                    np.concatenate(lvl_act) if lvl_act else np.empty(0, np.int64)
+            level_parent = level_act = None
+            # ... and what only the trace store and the parent log need:
+            # parents and action ids, the retained copy, the published
+            # disk level.  One `store` span a level; `store_ms` adds the
+            # parent-log appends the commits made (lvl_store_s)
+            if store_trace or collect_levels is not None or disk is not None:
+                st_span = obs_.open_span("store", depth=depth + 1)
+                t_st = time.perf_counter()
+                if use_arena:
+                    level_parent = a_parent[:a_w]
+                    level_act = a_act[:a_w]
+                    if a_w < int(0.95 * a_cap):
+                        # retained levels: shrink-copy so the trace store
+                        # doesn't hold the arena's growth headroom for the
+                        # whole run
+                        next_frontier = next_frontier.copy()
+                        level_parent = level_parent.copy()
+                        level_act = level_act.copy()
+                elif disk is not None:
+                    # publish the level: segments + parent-log frame become
+                    # the pending frontier; the consumed level's segments go
+                    # behind the checkpoint-generation deletion barrier
+                    # (the trace lives in the log)
+                    next_frontier = disk.end_level()
+                else:
+                    level_parent = (
+                        np.concatenate(lvl_parent)
+                        if lvl_parent
+                        else np.empty(0, np.int64)
+                    )
+                    level_act = (
+                        np.concatenate(lvl_act)
+                        if lvl_act
+                        else np.empty(0, np.int64)
+                    )
+                if store_trace:
+                    trace_store.append(
+                        (next_frontier, level_parent, level_act)
+                    )
+                lvl_store_s += time.perf_counter() - t_st
+                st_span.finish(
+                    rows=new_n,
+                    bytes=new_n * 4 * K + sum(
+                        a.nbytes for a in (level_parent, level_act)
+                        if a is not None
+                    ),
                 )
             depth += 1
             if new_n:
@@ -2810,6 +2792,10 @@ def check(
                         **rec,
                         "successor_launches": lvl_launches,
                         "launches_per_chunk_max": lvl_launches_max,
+                        # what the host launched, moved and stored this
+                        # level (engine/hostio.py; docs/observability.md)
+                        **io.take(),
+                        "store_ms": round(lvl_store_s * 1e3, 3),
                         # deferred batched host-probe attribution (the
                         # host-backend device path): in-memory records
                         # + the gauge/span side channels only — the
@@ -2836,8 +2822,6 @@ def check(
                     )
             if collect_levels is not None and new_n:
                 collect_levels.append(_f_all(next_frontier))
-            if store_trace:
-                trace_store.append((next_frontier, level_parent, level_act))
             if progress:
                 progress(depth, new_n, total)
 
@@ -2891,6 +2875,7 @@ def check(
         # injected paths: same typed clean exit (every writer cleans
         # up its tmp on failure, so the promoted state is intact)
         exhausted = ResourceExhausted("enospc", str(e), depth=depth)
+    obs_.check_closing()
     if integrity_fail is not None:
         # typed terminal (resilience.integrity): stamp the manifest so
         # `cli report` renders the integrity beat, then propagate for the
@@ -2944,6 +2929,7 @@ def check(
     if violation is None and check_invariants and model.invariants and _f_rows(frontier_np):
         # the loop was cut (max_depth/max_states) before the remaining
         # frontier was expanded — its states still need their invariant pass
+        sp_ = obs_.open_span("host-invariants", rows=_f_rows(frontier_np))
         st = jax.vmap(spec.unpack)(jnp.asarray(_f_all(frontier_np)))
         for inv in model.invariants:
             ok = np.asarray(jax.vmap(inv.pred)(st))
@@ -2960,6 +2946,7 @@ def check(
                     )
                 )
                 break
+        sp_.finish()
 
     dt = time.perf_counter() - t0
     result_stats.update(

@@ -1,0 +1,100 @@
+"""The hot path's one door between host and device: counted transfers and
+named dispatches.
+
+Both per-chunk pipelines, the whole-level pipeline and the level loop
+fetch device outputs and upload host arrays through :class:`HostIO`, so a
+level record can say what crossed (``d2h_bytes``, ``d2h_fetches``,
+``h2d_bytes``, ``h2d_puts``) and what was launched (``dispatches``,
+``discarded_dispatches``, ``discarded_ms``).  Counting is two integer
+additions a call and there is no span per transfer; a ``dispatch`` span
+(and, inside a profile, a ``kspec.dispatch <program>`` annotation) is
+written per program launched.  Lives in the engine, not in ``obs/``: it
+touches JAX arrays, and ``obs/`` stays jax-free.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..obs.tracer import now
+
+#: the level-record fields this module owns, in record order
+LEVEL_COUNTERS = (
+    "dispatches", "discarded_dispatches", "discarded_ms",
+    "d2h_bytes", "d2h_fetches", "h2d_bytes", "h2d_puts",
+)
+
+
+class _Dispatch:
+    """One launched program, open until the host blocks on its outputs."""
+
+    def __init__(self, io: "HostIO", handle):
+        self.io, self.handle, self.t0 = io, handle, now()
+
+    def finish(self, discarded: bool = False) -> None:
+        """The host has the outputs in hand (or an error): close the span.
+        `discarded`: the outputs are thrown away and the work re-run.
+        A second call does nothing."""
+        if self.io is None:
+            return
+        attrs = {}
+        if discarded:
+            self.io.discarded_dispatches += 1
+            self.io.discarded_ms += (now() - self.t0) * 1e3
+            attrs["discarded"] = True
+        if self.handle is not None:
+            self.handle.finish(**attrs)
+        self.io = None
+
+
+class HostIO:
+    def __init__(self, obs=None):
+        self.obs = obs  # a RunObserver, or None: count only
+        self.reset()
+
+    def reset(self) -> None:
+        self.dispatches = self.discarded_dispatches = 0
+        self.discarded_ms = 0.0
+        self.d2h_bytes = self.d2h_fetches = 0
+        self.h2d_bytes = self.h2d_puts = 0
+
+    def take(self) -> dict:
+        """This level's counters (LEVEL_COUNTERS), then zero them."""
+        rec = {k: getattr(self, k) for k in LEVEL_COUNTERS}
+        rec["discarded_ms"] = round(rec["discarded_ms"], 3)
+        self.reset()
+        return rec
+
+    # --- transfers ----------------------------------------------------------
+    def fetch(self, x, dtype=None) -> np.ndarray:
+        """``np.asarray(x, dtype)``; a device array is counted as one
+        fetch of its bytes (blocks until the value is computed)."""
+        if isinstance(x, jax.Array):
+            self.d2h_fetches += 1
+            self.d2h_bytes += x.nbytes
+        return np.asarray(x, dtype)
+
+    def put(self, x) -> jax.Array:
+        """``jnp.asarray(x)``; a host array is counted as one upload."""
+        if isinstance(x, np.ndarray):
+            self.h2d_puts += 1
+            self.h2d_bytes += x.nbytes
+        return jnp.asarray(x)
+
+    # --- spans and dispatches -----------------------------------------------
+    def span(self, kind: str, t0: float, **attrs) -> None:
+        """A completed host span that started at `t0` and ends now."""
+        if self.obs is not None:
+            self.obs.chunk_span(kind, t0, **attrs)
+
+    def dispatch(self, program: str, **attrs) -> _Dispatch:
+        """Call just before launching `program`; ``finish()`` the result
+        where the host next blocks on the launch's outputs."""
+        self.dispatches += 1
+        return _Dispatch(
+            self,
+            self.obs.dispatch(program, **attrs)
+            if self.obs is not None else None,
+        )

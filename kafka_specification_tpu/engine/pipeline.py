@@ -117,6 +117,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.tracer import now as _now
 from ..ops import dedup, devlevel
 from ..ops.fingerprint import fingerprint_lanes
 from ..pipeline_registry import (  # noqa: F401 — re-exported API
@@ -129,6 +130,39 @@ from ..pipeline_registry import (  # noqa: F401 — re-exported API
 #: registered pipeline names (resolve_pipeline validates against the
 #: jax-free registry; kept as a tuple for the pre-registry callers)
 PIPELINES = pipeline_names()
+
+#: the ONE stage vocabulary of the level programs.  Every stage body is
+#: wrapped, where the stage is DEFINED, in ``jax.named_scope("kspec." +
+#: name)``: the scope lands in each HLO instruction's ``op_name`` metadata,
+#: which is what a profile's device events carry, so device time reads by
+#: stage instead of by XLA's fusion numbers (docs/observability.md
+#: § Stage vocabulary).  ops/ modules use the literal ``kspec.<stage>``
+#: strings (they cannot import the engine); tests/test_stage_names.py
+#: holds every scope found in a lowered program to this tuple.
+STAGES = (
+    "guard", "expand", "compact", "fingerprint", "dedup_sort",
+    "dedup_probe", "dedup_merge", "invariants", "digest",
+)
+STAGE_PREFIX = "kspec."
+
+#: part of every step program's module name (``dvl_n1``).  JAX strips
+#: debug metadata before it hashes a program for the persistent compile
+#: cache but DOES hash the module name, so a program compiled before a
+#: vocabulary change would be a cache hit that carries the old scopes.
+#: Bump this whenever STAGES or a scope's placement changes: the renamed
+#: programs recompile once and bake the new names in.
+NAMING_VERSION = 1
+
+
+def stage(name: str):
+    """``jax.named_scope`` of one vocabulary stage."""
+    assert name in STAGES, name
+    return jax.named_scope(STAGE_PREFIX + name)
+
+
+def program_name(tag: str) -> str:
+    """Module name of the step-cache program with cache tag `tag`."""
+    return f"{tag}_n{NAMING_VERSION}"
 
 
 def key_vcap(key: tuple) -> Optional[int]:
@@ -170,9 +204,10 @@ def grow_visited(vhi, vlo, vcap: int, need: int, cache: Optional[dict]
     from .bfs import _next_pow2
 
     new_cap = _next_pow2(need)
-    pad = jnp.full(new_cap - vcap, 0xFFFFFFFF, jnp.uint32)
-    vhi = jnp.concatenate([vhi, pad])
-    vlo = jnp.concatenate([vlo, pad])
+    with stage("dedup_merge"):
+        pad = jnp.full(new_cap - vcap, 0xFFFFFFFF, jnp.uint32)
+        vhi = jnp.concatenate([vhi, pad])
+        vlo = jnp.concatenate([vlo, pad])
     if cache is not None:
         evict_vcap(cache, vcap)
     return vhi, vlo, new_cap
@@ -186,48 +221,74 @@ def grow_visited(vhi, vlo, vcap: int, need: int, cache: Optional[dict]
 def squeeze_stage(cand, parent, actid, valid, width, K):  # kspec: traced
     """Stage 2: compact enabled candidate rows to the front of a `width`
     buffer; overflow=True iff more than `width` rows are enabled."""
-    n_en = jnp.sum(valid, dtype=jnp.int32)
-    spos = jnp.where(valid, jnp.cumsum(valid) - 1, width)
-    out = jnp.zeros((width, K), jnp.uint32).at[spos].set(cand)
-    out_parent = jnp.full((width,), -1, jnp.int32).at[spos].set(parent)
-    out_act = jnp.full((width,), -1, jnp.int32).at[spos].set(actid)
-    rowvalid = jnp.arange(width) < n_en
-    return out, out_parent, out_act, rowvalid, n_en, n_en > width
+    with stage("compact"):
+        n_en = jnp.sum(valid, dtype=jnp.int32)
+        spos = jnp.where(valid, jnp.cumsum(valid) - 1, width)
+        out = jnp.zeros((width, K), jnp.uint32).at[spos].set(cand)
+        out_parent = jnp.full((width,), -1, jnp.int32).at[spos].set(parent)
+        out_act = jnp.full((width,), -1, jnp.int32).at[spos].set(actid)
+        rowvalid = jnp.arange(width) < n_en
+        return out, out_parent, out_act, rowvalid, n_en, n_en > width
 
 
 def fp_stage(cand, valid, spec, use_pallas: bool):  # kspec: traced
     """Stage 3: masked (hi, lo) fingerprints (Pallas opt-in or jnp)."""
     sent = jnp.uint32(dedup.SENT)
-    if use_pallas:
-        import math
+    with stage("fingerprint"):
+        if use_pallas:
+            import math
 
-        from ..ops.pallas_fingerprint import fingerprint_pallas
+            from ..ops.pallas_fingerprint import fingerprint_pallas
 
-        interp = jax.default_backend() == "cpu"
-        rows = cand.shape[0]
-        block = math.gcd(rows, 1 << 13)
-        return fingerprint_pallas(cand, valid, block_rows=block,
-                                  interpret=interp)
-    hi, lo = fingerprint_lanes(cand, spec.exact64)
-    return jnp.where(valid, hi, sent), jnp.where(valid, lo, sent)
+            interp = jax.default_backend() == "cpu"
+            # block_rows must divide the buffer width (the largest
+            # power-of-two divisor, capped at 8k rows/block): every
+            # buffer here is 1024-aligned or a power-of-two multiple of
+            # C, so blocks stay >= 256 rows
+            rows = cand.shape[0]
+            block = math.gcd(rows, 1 << 13)
+            return fingerprint_pallas(cand, valid, block_rows=block,
+                                      interpret=interp)
+        hi, lo = fingerprint_lanes(cand, spec.exact64)
+        return jnp.where(valid, hi, sent), jnp.where(valid, lo, sent)
 
 
 def invariant_stage(model, states, fvalid, with_invariants: bool):  # kspec: traced
     """Stage 5: per-invariant (any-violated, first-index) on the frontier
-    being expanded (each state checked exactly once, at expansion)."""
-    if not (with_invariants and model.invariants):
-        return jnp.stack([jnp.bool_(False)]), jnp.stack([jnp.int32(0)])
-    if model.invariants_fused is not None:
-        ok = jax.vmap(model.invariants_fused)(states)  # [B, n_inv]
-        bad = fvalid[:, None] & ~ok
-        return jnp.any(bad, axis=0), jnp.argmax(bad, axis=0)
-    viol_any, viol_idx = [], []
-    for inv in model.invariants:
-        ok = jax.vmap(inv.pred)(states)
-        bad = fvalid & ~ok
-        viol_any.append(jnp.any(bad))
-        viol_idx.append(jnp.argmax(bad))
-    return jnp.stack(viol_any), jnp.stack(viol_idx)
+    being expanded (each state checked exactly once, at expansion; BFS
+    order: states before successors)."""
+    with stage("invariants"):
+        if not (with_invariants and model.invariants):
+            return (jnp.stack([jnp.bool_(False)]),
+                    jnp.stack([jnp.int32(0)]))
+        if model.invariants_fused is not None:
+            # one trace for all predicates: shared subtrees (e.g. the
+            # WeakIsr/StrongIsr quantifier core in emitted models)
+            # evaluate once
+            ok = jax.vmap(model.invariants_fused)(states)  # [B, n_inv]
+            bad = fvalid[:, None] & ~ok
+            return jnp.any(bad, axis=0), jnp.argmax(bad, axis=0)
+        viol_any, viol_idx = [], []
+        for inv in model.invariants:
+            ok = jax.vmap(inv.pred)(states)
+            bad = fvalid & ~ok
+            viol_any.append(jnp.any(bad))
+            viol_idx.append(jnp.argmax(bad))
+        return jnp.stack(viol_any), jnp.stack(viol_idx)
+
+
+def _sort_first(hi, lo):  # kspec: traced
+    """The sort half of stage 4: stable lexsort of the fingerprint pairs
+    and the first-occurrence mask over the sorted order -> (hi_s, lo_s,
+    order, first).  ONE source of the winner-selection order for both
+    dedup stages."""
+    sent = jnp.uint32(dedup.SENT)
+    with stage("dedup_sort"):
+        order = jnp.lexsort((lo, hi))
+        hi_s, lo_s = hi[order], lo[order]
+        invalid_s = (hi_s == sent) & (lo_s == sent)
+        first = dedup.first_occurrence_mask(hi_s, lo_s, invalid_s)
+    return hi_s, lo_s, order, first
 
 
 def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
@@ -250,24 +311,26 @@ def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
     the PRIMARY set) lets with_merge=False callers run their own gated
     merge_ranked."""
     sent = jnp.uint32(dedup.SENT)
-    order = jnp.lexsort((lo, hi))
-    hi_s, lo_s = hi[order], lo[order]
-    invalid_s = (hi_s == sent) & (lo_s == sent)
-    first = dedup.first_occurrence_mask(hi_s, lo_s, invalid_s)
+    # minimal-payload sort: only the original index rides through the
+    # sort network; state rows/parents are gathered once afterwards
+    hi_s, lo_s, order, first = _sort_first(hi, lo)
     seen, rank = dedup.rank_sorted(vhi, vlo, vn, hi_s, lo_s)
     is_new = first & ~seen
     if also_seen_in is not None:
         a_hi, a_lo, a_n = also_seen_in
         a_seen, _ar = dedup.rank_sorted(a_hi, a_lo, a_n, hi_s, lo_s)
         is_new = is_new & ~a_seen
-    pos = jnp.where(is_new, jnp.cumsum(is_new) - 1, T)
-    out = jnp.zeros((T, K), jnp.uint32).at[pos].set(cand[order])
-    out_parent = jnp.full((T,), -1, jnp.int32).at[pos].set(parent[order])
-    out_act = jnp.full((T,), -1, jnp.int32).at[pos].set(actid[order])
-    out_hi = jnp.full((T,), sent).at[pos].set(hi_s)
-    out_lo = jnp.full((T,), sent).at[pos].set(lo_s)
-    out_rank = jnp.zeros((T,), jnp.int32).at[pos].set(rank)
-    new_n = jnp.sum(is_new, dtype=jnp.int32)
+    # compact new states to the front (OOB scatter indices are dropped)
+    with stage("compact"):
+        pos = jnp.where(is_new, jnp.cumsum(is_new) - 1, T)
+        out = jnp.zeros((T, K), jnp.uint32).at[pos].set(cand[order])
+        out_parent = jnp.full((T,), -1, jnp.int32).at[pos].set(
+            parent[order])
+        out_act = jnp.full((T,), -1, jnp.int32).at[pos].set(actid[order])
+        out_hi = jnp.full((T,), sent).at[pos].set(hi_s)
+        out_lo = jnp.full((T,), sent).at[pos].set(lo_s)
+        out_rank = jnp.zeros((T,), jnp.int32).at[pos].set(rank)
+        new_n = jnp.sum(is_new, dtype=jnp.int32)
     if with_merge:
         vhi, vlo, vn = dedup.merge_ranked(
             vhi, vlo, vn, out_hi, out_lo, out_rank, new_n, vcap
@@ -302,27 +365,25 @@ def candidate_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
     Returns (out, out_parent, out_act, out_hi, out_lo, new_n,
     n_hi, n_lo, n_rank)."""
     sent = jnp.uint32(dedup.SENT)
-    order = jnp.lexsort((lo, hi))
-    hi_s, lo_s = hi[order], lo[order]
-    invalid_s = (hi_s == sent) & (lo_s == sent)
-    first = dedup.first_occurrence_mask(hi_s, lo_s, invalid_s)
+    hi_s, lo_s, order, first = _sort_first(hi, lo)
     seen, rank = dedup.rank_sorted(lhi, llo, ln, hi_s, lo_s)
     is_new = first & ~seen
-    # sorted-order compaction: what the level-new merge consumes
-    pos_s = jnp.where(is_new, jnp.cumsum(is_new) - 1, T)
-    n_hi = jnp.full((T,), sent).at[pos_s].set(hi_s)
-    n_lo = jnp.full((T,), sent).at[pos_s].set(lo_s)
-    n_rank = jnp.zeros((T,), jnp.int32).at[pos_s].set(rank)
-    new_n = jnp.sum(is_new, dtype=jnp.int32)
-    # candidate-order compaction: scatter the sorted novelty decisions
-    # back to candidate positions, then compact without re-sorting
-    isnew_c = jnp.zeros((T,), bool).at[order].set(is_new)
-    pos_c = jnp.where(isnew_c, jnp.cumsum(isnew_c) - 1, T)
-    out = jnp.zeros((T, K), jnp.uint32).at[pos_c].set(cand)
-    out_parent = jnp.full((T,), -1, jnp.int32).at[pos_c].set(parent)
-    out_act = jnp.full((T,), -1, jnp.int32).at[pos_c].set(actid)
-    out_hi = jnp.full((T,), sent).at[pos_c].set(hi)
-    out_lo = jnp.full((T,), sent).at[pos_c].set(lo)
+    with stage("compact"):
+        # sorted-order compaction: what the level-new merge consumes
+        pos_s = jnp.where(is_new, jnp.cumsum(is_new) - 1, T)
+        n_hi = jnp.full((T,), sent).at[pos_s].set(hi_s)
+        n_lo = jnp.full((T,), sent).at[pos_s].set(lo_s)
+        n_rank = jnp.zeros((T,), jnp.int32).at[pos_s].set(rank)
+        new_n = jnp.sum(is_new, dtype=jnp.int32)
+        # candidate-order compaction: scatter the sorted novelty decisions
+        # back to candidate positions, then compact without re-sorting
+        isnew_c = jnp.zeros((T,), bool).at[order].set(is_new)
+        pos_c = jnp.where(isnew_c, jnp.cumsum(isnew_c) - 1, T)
+        out = jnp.zeros((T, K), jnp.uint32).at[pos_c].set(cand)
+        out_parent = jnp.full((T,), -1, jnp.int32).at[pos_c].set(parent)
+        out_act = jnp.full((T,), -1, jnp.int32).at[pos_c].set(actid)
+        out_hi = jnp.full((T,), sent).at[pos_c].set(hi)
+        out_lo = jnp.full((T,), sent).at[pos_c].set(lo)
     return (out, out_parent, out_act, out_hi, out_lo, new_n,
             n_hi, n_lo, n_rank)
 
@@ -343,7 +404,9 @@ class LegacyPipeline:
 
     def __init__(self, step_builder, model, adapt, chunk_retry, fault,
                  check_invariants: bool, visited_backend: str,
-                 on_degrade_chunk):
+                 on_degrade_chunk, io=None):
+        from .hostio import HostIO
+
         self.step = step_builder
         self.model = model
         self.adapt = adapt
@@ -352,6 +415,8 @@ class LegacyPipeline:
         self.check_invariants = check_invariants
         self.visited_backend = visited_backend
         self.on_degrade_chunk = on_degrade_chunk
+        #: counted transfers + named dispatches (engine/hostio.py)
+        self.io = io if io is not None else HostIO()
         self.squeeze_full = False  # sticky pre-sort-squeeze overflow relief
         self.compile_fallback = False
 
@@ -365,13 +430,15 @@ class LegacyPipeline:
     def run_chunk(self, piece, fp_n, bucket, depth, vhi, vlo, vn, vcap):
         from .bfs import _pad_rows  # cycle-free: bfs imports us lazily
 
-        adapt = self.adapt
+        adapt, io = self.adapt, self.io
         compact_arg = adapt.widths_for(bucket)
         attempt_sq_full = self.squeeze_full
         self.chunk_retry.reset_chunk()
         dispatched = 0  # successor-kernel passes actually dispatched,
         # overflow/retry re-dispatches included
+        attempt = 0
         while True:
+            launch = None
             try:
                 injected = self.fault.chunk_error(
                     escalated=isinstance(compact_arg, (list, tuple))
@@ -386,19 +453,27 @@ class LegacyPipeline:
                     compact=compact_arg,
                     squeeze_full=attempt_sq_full,
                 )
+                frontier = io.put(_pad_rows(piece, bucket))
+                launch = io.dispatch("step", attempt=attempt, depth=depth,
+                                     bucket=bucket, vcap=vcap)
+                attempt += 1
                 (
                     out, out_parent, out_act, new_n, vhi_n, vlo_n, vn_n,
                     viol_any, viol_idx, dl_any, dl_idx, act_en,
                     out_hi, out_lo, overflow, act_guard,
                 ) = step(
-                    jnp.asarray(_pad_rows(piece, bucket)),
+                    frontier,
                     jnp.arange(bucket) < fp_n,
                     vhi,
                     vlo,
                     vn,
                 )
                 dispatched += self.launches_per_chunk
+                # the overflow read forces the whole program
+                ovf = io.fetch(overflow)
             except Exception as e:  # noqa: BLE001 — XLA compile/run
+                if launch is not None:
+                    launch.finish(discarded=True)
                 # known failure ladder — one policy for both engines
                 # (resilience.retry.ChunkRetryHandler); see check()'s
                 # docstring for the degradation contract
@@ -414,10 +489,11 @@ class LegacyPipeline:
                 compact_arg = adapt.compile_fallback(bucket)
                 self.compile_fallback = True
                 continue
-            ovf = np.asarray(overflow)
             if compact_arg is None or not ovf.any():
+                launch.finish()
                 vhi, vlo, vn = vhi_n, vlo_n, vn_n
                 break
+            launch.finish(discarded=True)  # outputs incomplete: re-run
             # retry this chunk with the offending buffers widened: a
             # per-action compact overflow doubles that action's width
             # (floored for the rest of the run); a squeeze overflow
@@ -430,12 +506,12 @@ class LegacyPipeline:
                     compact_arg,
                     ovf[:-1],
                     bucket,
-                    np.asarray(act_guard, np.int64) / max(fp_n, 1),
+                    io.fetch(act_guard, np.int64) / max(fp_n, 1),
                 )
         # adapt buffer sizing from the committed attempt's PRE-constraint
         # guard counts (what the buffers actually hold; act_en is
         # post-constraint and undercounts on pruning models)
-        adapt.observe(np.asarray(act_guard, np.int64) / max(fp_n, 1))
+        adapt.observe(io.fetch(act_guard, np.int64) / max(fp_n, 1))
         return (
             out, out_parent, out_act, new_n, vhi, vlo, vn,
             viol_any, viol_idx, dl_any, dl_idx, act_en,
@@ -520,7 +596,8 @@ class FusedPipeline:
 
     def __init__(self, step_builder, model, adapt, chunk_retry, fault,
                  check_invariants: bool, visited_backend: str,
-                 on_degrade_chunk, compact_shift: int, compact_gate: int):
+                 on_degrade_chunk, compact_shift: int, compact_gate: int,
+                 io=None):
         self.step = step_builder
         self.model = model
         self.spec = model.spec
@@ -534,8 +611,9 @@ class FusedPipeline:
         self.fallback = False  # sticky: a failed fused compile pins legacy
         self.legacy = LegacyPipeline(
             step_builder, model, adapt, chunk_retry, fault,
-            check_invariants, visited_backend, on_degrade_chunk,
+            check_invariants, visited_backend, on_degrade_chunk, io=io,
         )
+        self.io = self.legacy.io
         self.adapt = adapt
         self._bounds = np.cumsum(
             [0] + [a.n_choices for a in model.actions]
@@ -562,7 +640,7 @@ class FusedPipeline:
         shared per-base step cache (service/kernel_cache.py)."""
         key = ("fgd", bucket, self.step.inv_sig(self.check_invariants))
         return self.step.cached(
-            key, lambda: jax.jit(self._build_guard(bucket)),
+            key, lambda: self._build_guard(bucket),
             bucket=bucket, program="fused-guards",
         )
 
@@ -574,8 +652,8 @@ class FusedPipeline:
                self.step.use_pallas)
         return self.step.cached(
             key,
-            lambda: jax.jit(self._build_succ(
-                bucket, widths, vcap, with_merge, device_out)),
+            lambda: self._build_succ(
+                bucket, widths, vcap, with_merge, device_out),
             bucket=bucket, vcap=vcap, widths=repr(widths),
             program="fused-successors",
         )
@@ -597,22 +675,24 @@ class FusedPipeline:
             return jnp.concatenate(parts)
 
         def step(frontier, fvalid):  # kspec: traced
-            states = jax.vmap(spec.unpack)(frontier)
-            en_pre = jax.vmap(guards_one)(states)  # [B, C] predicate matrix
-            ga = en_pre & fvalid[:, None]
-            act_guard = jnp.stack(
-                [
-                    jnp.sum(ga[:, bounds[i]: bounds[i + 1]],
-                            dtype=jnp.int32)
-                    for i in range(n_actions)
-                ]
-            )
-            deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
+            with stage("guard"):
+                states = jax.vmap(spec.unpack)(frontier)
+                # [B, C] predicate matrix
+                en_pre = jax.vmap(guards_one)(states)
+                ga = en_pre & fvalid[:, None]
+                act_guard = jnp.stack(
+                    [
+                        jnp.sum(ga[:, bounds[i]: bounds[i + 1]],
+                                dtype=jnp.int32)
+                        for i in range(n_actions)
+                    ]
+                )
+                deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
+                dl_any, dl_idx = jnp.any(deadlocked), jnp.argmax(deadlocked)
             viol_any, viol_idx = invariant_stage(
                 model, states, fvalid, check_invariants
             )
-            return (ga, act_guard, viol_any, viol_idx,
-                    jnp.any(deadlocked), jnp.argmax(deadlocked))
+            return ga, act_guard, viol_any, viol_idx, dl_any, dl_idx
 
         return step
 
@@ -632,42 +712,44 @@ class FusedPipeline:
         )
 
         def step(frontier, sidx, chloc, rowvalid, vhi, vlo, vn):  # kspec: traced
-            states = jax.vmap(spec.unpack)(frontier)
-            gstate = jax.tree.map(lambda x: x[sidx], states)
-            cand_parts, ok_parts = [], []
-            for i, a in enumerate(model.actions):
-                # kspec: allow(host-materialization) offs is the static
-                # trace-time width table (np cumsum of Python ints), not
-                # a traced value
-                sl = slice(int(offs[i]), int(offs[i + 1]))
-                ga = jax.tree.map(lambda x: x[sl], gstate)
-                # guards are NOT re-evaluated: launch 1 proved every
-                # pooled row enabled, so the kernel's own ok bit is
-                # redundant here (same pure function, same inputs)
-                _, nxt_a = jax.vmap(a.kernel)(ga, chloc[sl])
-                ok_a = rowvalid[sl]
-                if model.constraint is not None:
-                    ok_a = ok_a & jax.vmap(model.constraint)(nxt_a)
-                # pack per segment: only the K packed lanes are ever
-                # concatenated, never the full unpacked state tree
-                cand_parts.append(jax.vmap(spec.pack)(nxt_a))
-                ok_parts.append(ok_a)
-            ok = jnp.concatenate(ok_parts)
-            cand = jnp.concatenate(cand_parts, axis=0)
+            with stage("expand"):
+                states = jax.vmap(spec.unpack)(frontier)
+                gstate = jax.tree.map(lambda x: x[sidx], states)
+                cand_parts, ok_parts = [], []
+                for i, a in enumerate(model.actions):
+                    # kspec: allow(host-materialization) offs is the
+                    # static trace-time width table (np cumsum of Python
+                    # ints), not a traced value
+                    sl = slice(int(offs[i]), int(offs[i + 1]))
+                    ga = jax.tree.map(lambda x: x[sl], gstate)
+                    # guards are NOT re-evaluated: launch 1 proved every
+                    # pooled row enabled, so the kernel's own ok bit is
+                    # redundant here (same pure function, same inputs)
+                    _, nxt_a = jax.vmap(a.kernel)(ga, chloc[sl])
+                    ok_a = rowvalid[sl]
+                    if model.constraint is not None:
+                        ok_a = ok_a & jax.vmap(model.constraint)(nxt_a)
+                    # pack per segment: only the K packed lanes are ever
+                    # concatenated, never the full unpacked state tree
+                    cand_parts.append(jax.vmap(spec.pack)(nxt_a))
+                    ok_parts.append(ok_a)
+                ok = jnp.concatenate(ok_parts)
+                cand = jnp.concatenate(cand_parts, axis=0)
             if not device_out:
                 # host backend: validity is resolved at C speed on the
                 # host (run_chunk compacts by the ok mask), so no device
                 # squeeze scatter is needed at all
                 hi, lo = fp_stage(cand, ok, spec, use_pallas)
                 return cand, ok, hi, lo
-            act_en = jnp.stack(
-                [
-                    # kspec: allow(host-materialization) static width table
-                    jnp.sum(ok[int(offs[i]): int(offs[i + 1])],
-                            dtype=jnp.int32)
-                    for i in range(len(model.actions))
-                ]
-            )
+            with stage("expand"):
+                act_en = jnp.stack(
+                    [
+                        # kspec: allow(host-materialization) static table
+                        jnp.sum(ok[int(offs[i]): int(offs[i + 1])],
+                                dtype=jnp.int32)
+                        for i in range(len(model.actions))
+                    ]
+                )
             out, out_parent, out_act, rowvalid2, n_en, _ovf = squeeze_stage(
                 cand, sidx, actid_f, ok, W, K
             )
@@ -686,13 +768,18 @@ class FusedPipeline:
         return step
 
     # --- host glue --------------------------------------------------------
-    def _compact(self, ga_np: np.ndarray, widths: tuple):
+    def _compact(self, ga, widths: tuple, depth: int):
         """Stage 2, host half: C-speed stream compaction of the guard
         matrix into the pooled (state-index, choice) layout — replaces
         the legacy path's O(lattice) in-jit cumsum+scatter (measured
         ~13x cheaper on the flagship chunk) and preserves the legacy
         compact path's candidate order exactly (action-major, row-major
-        within an action's [B, n_choices] slice)."""
+        within an action's [B, n_choices] slice).  Fetches the predicate
+        matrix, uploads the index vectors, and is one ``compact-host``
+        span: -> (sidx host, sidx, chloc, rowvalid on the device)."""
+        t0 = _now()
+        io = self.io
+        ga_np = io.fetch(ga)
         bounds = self._bounds
         W = int(sum(widths))
         sidx = np.zeros(W, np.int32)
@@ -711,7 +798,10 @@ class FusedPipeline:
             chloc[off: off + n] = idx % na
             rowvalid[off: off + n] = True
             off += w
-        return sidx, chloc, rowvalid, counts
+        on_device = (io.put(sidx), io.put(chloc), io.put(rowvalid))
+        io.span("compact-host", t0, depth=depth, rows=int(sum(counts)),
+                width=W)
+        return (sidx,) + on_device
 
     # --- the chunk driver -------------------------------------------------
     def run_chunk(self, piece, fp_n, bucket, depth, vhi, vlo, vn, vcap):
@@ -744,9 +834,12 @@ class FusedPipeline:
 
         if reset:
             self.chunk_retry.reset_chunk()
+        io = self.io
         dispatched = 0  # successor programs actually dispatched,
         # retries included — what "launches" honestly means
+        attempt = 0
         while True:
+            launch = None
             try:
                 # escalated=True on BOTH inject and handle: the fused
                 # programs are the adaptive (escalated-shape) family, so
@@ -755,27 +848,37 @@ class FusedPipeline:
                 injected = self.fault.chunk_error(escalated=True)
                 if injected is not None:
                     raise injected
-                frontier = jnp.asarray(_pad_rows(piece, bucket))
+                frontier = io.put(_pad_rows(piece, bucket))
                 fvalid = jnp.arange(bucket) < fp_n
+                launch = io.dispatch("fgd", attempt=attempt, depth=depth,
+                                     bucket=bucket)
                 (ga, act_guard, viol_any, viol_idx, dl_any, dl_idx
                  ) = self.guard_step(bucket)(frontier, fvalid)
                 dispatched += 1  # launch 1: the guard matrix
-                act_guard_np = np.asarray(act_guard, np.int64)
+                # the counts shape launch 2: this read forces launch 1
+                act_guard_np = io.fetch(act_guard, np.int64)
+                launch.finish()
                 widths = self.pool.widths_for(
                     bucket, act_guard_np.astype(np.float64), fp_n
                 )
-                sidx, chloc, rowvalid, _counts = self._compact(
-                    np.asarray(ga), widths
+                sidx, sidx_d, chloc_d, rowvalid_d = self._compact(
+                    ga, widths, depth
                 )
+                # launch 2 stays in flight: finalize() closes its span
+                # where the host first blocks on its outputs
+                launch = io.dispatch("fsc", attempt=attempt, depth=depth,
+                                     bucket=bucket, vcap=vcap)
+                attempt += 1
                 outs = self.succ_step(bucket, widths, vcap)(
-                    frontier, jnp.asarray(sidx), jnp.asarray(chloc),
-                    jnp.asarray(rowvalid), vhi, vlo, vn,
+                    frontier, sidx_d, chloc_d, rowvalid_d, vhi, vlo, vn,
                 )
                 dispatched += 1  # launch 2: the update skeleton
                 if self.visited_backend != "host":
                     (out, out_parent, out_act, new_n, out_hi, out_lo,
                      vhi, vlo, vn, act_en) = outs
             except Exception as e:  # noqa: BLE001 — XLA compile/run
+                if launch is not None:
+                    launch.finish(discarded=True)
                 # escalated=True: the fused programs are the adaptive
                 # (escalated-shape) family, so a compile/alloc failure
                 # degrades to the always-compilable legacy uniform path
@@ -803,7 +906,7 @@ class FusedPipeline:
                              act_guard_np=act_guard_np,
                              verdicts=(viol_any, viol_idx, dl_any,
                                        dl_idx),
-                             dispatched=dispatched):
+                             dispatched=dispatched, launch=launch):
                     cand, ok, hi, lo = outs
                     viol_any, viol_idx, dl_any, dl_idx = verdicts
                     try:
@@ -814,8 +917,10 @@ class FusedPipeline:
                         # whole chunk synchronously; anything else
                         # degrades the run to legacy (the documented
                         # fused failure contract)
-                        ok_np = np.asarray(ok)
+                        ok_np = io.fetch(ok)
+                        launch.finish()
                     except Exception as e:  # noqa: BLE001 — XLA runtime
+                        launch.finish(discarded=True)
                         action = self.chunk_retry.handle(
                             e, escalated=True, depth=depth
                         )
@@ -840,11 +945,11 @@ class FusedPipeline:
                         )
                         return fin2()
                     nn = int(ok_np.sum())
-                    out = np.asarray(cand)[ok_np]
+                    out = io.fetch(cand)[ok_np]
                     out_parent = sidx[ok_np]
                     out_act = self._actid_np(widths)[ok_np]
-                    out_hi = np.asarray(hi)[ok_np]
-                    out_lo = np.asarray(lo)[ok_np]
+                    out_hi = io.fetch(hi)[ok_np]
+                    out_lo = io.fetch(lo)[ok_np]
                     offs = np.cumsum([0] + list(widths))
                     act_en = np.asarray(
                         [
@@ -860,12 +965,17 @@ class FusedPipeline:
                     )
 
                 return vhi, vlo, vn, finalize
-            committed = (
-                out, out_parent, out_act, new_n, vhi, vlo, vn,
-                viol_any, viol_idx, dl_any, dl_idx, act_en,
-                out_hi, out_lo, act_guard_np, dispatched,
-            )
-            return vhi, vlo, vn, lambda: committed
+            def finalize(launch=launch, act_en=act_en, committed=(
+                    out, out_parent, out_act, new_n, vhi, vlo, vn,
+                    viol_any, viol_idx, dl_any, dl_idx, None,
+                    out_hi, out_lo, act_guard_np, dispatched)):
+                # the first read of launch 2's outputs: the host blocks
+                # here until the update skeleton has run
+                act_en_np = io.fetch(act_en, np.int64)
+                launch.finish()
+                return committed[:11] + (act_en_np,) + committed[12:]
+
+            return vhi, vlo, vn, finalize
 
     def _actid_np(self, widths: tuple) -> np.ndarray:
         return np.concatenate(
@@ -944,7 +1054,7 @@ class DevicePipeline:
     def __init__(self, step_builder, model, adapt, chunk_retry, fault,
                  check_invariants: bool, visited_backend: str,
                  on_degrade_chunk, compact_shift: int, compact_gate: int,
-                 check_deadlock: bool = False):
+                 check_deadlock: bool = False, io=None):
         self.step = step_builder
         self.model = model
         self.spec = model.spec
@@ -956,8 +1066,9 @@ class DevicePipeline:
         self.fused = FusedPipeline(
             step_builder, model, adapt, chunk_retry, fault,
             check_invariants, visited_backend, on_degrade_chunk,
-            compact_shift, compact_gate,
+            compact_shift, compact_gate, io=io,
         )
+        self.io = self.fused.io
         self.pool = PooledWidths(model.actions)
         self._ln_hw = 0  # per-level new-state high water (LN ladder)
         #: sticky fallback reason; None while the level path is live
@@ -1050,9 +1161,7 @@ class DevicePipeline:
                    self.check_deadlock, self.step.use_pallas)
             return self.step.cached(
                 key,
-                lambda: jax.jit(
-                    self._build_level_host(B, NCp, widths, LN)
-                ),
+                lambda: self._build_level_host(B, NCp, widths, LN),
                 bucket=B, chunks=NCp, widths=repr(widths),
                 level_new_cap=LN, program="device-level-host",
             )
@@ -1061,9 +1170,7 @@ class DevicePipeline:
                self.check_deadlock, self.step.use_pallas)
         return self.step.cached(
             key,
-            lambda: jax.jit(
-                self._build_level(B, NCp, vcap, widths, LN)
-            ),
+            lambda: self._build_level(B, NCp, vcap, widths, LN),
             bucket=B, vcap=vcap, chunks=NCp, widths=repr(widths),
             level_new_cap=LN, program="device-level",
         )
@@ -1114,15 +1221,17 @@ class DevicePipeline:
             def body(carry):  # kspec: traced
                 (i, orows, opar, oact, on, lhi, llo, ln,
                  vkind, vinv, vidx, act_en, agmax, dig, ovf) = carry
-                start = i * B
-                rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
-                fvalid = (
-                    start + jnp.arange(B, dtype=jnp.int32)
-                ) < f_total
-                states = jax.vmap(spec.unpack)(rows)
+                with stage("guard"):
+                    start = i * B
+                    rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
+                    fvalid = (
+                        start + jnp.arange(B, dtype=jnp.int32)
+                    ) < f_total
+                    states = jax.vmap(spec.unpack)(rows)
                 (en_pre, cand, valid, parent, actid, a_en, a_guard,
                  exp_ovf) = expand(states, fvalid)
-                deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
+                with stage("guard"):
+                    deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
                 viol_any, viol_idx = invariant_stage(
                     model, states, fvalid, check_invariants
                 )
@@ -1141,31 +1250,35 @@ class DevicePipeline:
                     also_seen_in=(vhi, vlo, vn),
                 )
                 # verdicts, serial-commit priority
-                inv_any = jnp.any(viol_any)
-                inv_i = jnp.argmax(viol_any).astype(jnp.int32)
-                dl_any = jnp.bool_(check_deadlock) & jnp.any(deadlocked)
-                kind = jnp.where(
-                    inv_any, jnp.int32(1),
-                    jnp.where(dl_any, jnp.int32(2), jnp.int32(0)),
-                )
-                g_idx = jnp.where(
-                    inv_any, viol_idx[inv_i],
-                    jnp.argmax(deadlocked).astype(jnp.int32),
-                ).astype(jnp.int32) + start
-                take = (vkind == 0) & (kind != 0)
-                commit = kind == 0  # a verdict chunk commits nothing
+                with stage("invariants"):
+                    inv_any = jnp.any(viol_any)
+                    inv_i = jnp.argmax(viol_any).astype(jnp.int32)
+                    dl_any = (jnp.bool_(check_deadlock)
+                              & jnp.any(deadlocked))
+                    kind = jnp.where(
+                        inv_any, jnp.int32(1),
+                        jnp.where(dl_any, jnp.int32(2), jnp.int32(0)),
+                    )
+                    g_idx = jnp.where(
+                        inv_any, viol_idx[inv_i],
+                        jnp.argmax(deadlocked).astype(jnp.int32),
+                    ).astype(jnp.int32) + start
+                    take = (vkind == 0) & (kind != 0)
+                    commit = kind == 0  # a verdict chunk commits nothing
                 # LN overflow: this level's new states outgrew the
                 # ladder-sized level-new set — dropped merge scatters
                 # would corrupt later chunks' novelty, so stop
                 # committing (commit_ok) and flag for the exact-bound
                 # re-dispatch.  Width/squeeze overflows flag the same
                 # way (the whole level re-runs either way).
-                ln_ovf = commit & ((ln + new_n) > LN)
-                commit_ok = commit & ~ovf & ~ln_ovf
-                app_n = jnp.where(commit_ok, new_n, 0)
-                orows = devlevel.append_rows(orows, n_out, on)
-                opar = devlevel.append_vec(opar, n_par + start, on)
-                oact = devlevel.append_vec(oact, n_act, on)
+                with stage("dedup_merge"):
+                    ln_ovf = commit & ((ln + new_n) > LN)
+                    commit_ok = commit & ~ovf & ~ln_ovf
+                    app_n = jnp.where(commit_ok, new_n, 0)
+                with stage("compact"):
+                    orows = devlevel.append_rows(orows, n_out, on)
+                    opar = devlevel.append_vec(opar, n_par + start, on)
+                    oact = devlevel.append_vec(oact, n_act, on)
                 lhi, llo, ln = dedup.merge_ranked(
                     lhi, llo, ln, n_hi, n_lo, n_rank, app_n, LN
                 )
@@ -1175,34 +1288,38 @@ class DevicePipeline:
                         n_hi, n_lo, jnp.arange(T) < app_n
                     ),
                 )
-                act_en = act_en + jnp.where(commit_ok, a_en, 0)
-                agmax = jnp.maximum(agmax, a_guard)
-                ovf = ovf | jnp.any(exp_ovf) | sq_ovf | ln_ovf
+                with stage("expand"):  # its counters and overflow flags
+                    act_en = act_en + jnp.where(commit_ok, a_en, 0)
+                    agmax = jnp.maximum(agmax, a_guard)
+                    ovf = ovf | jnp.any(exp_ovf) | sq_ovf | ln_ovf
+                with stage("invariants"):  # the first verdict wins
+                    vkind = jnp.where(take, kind, vkind)
+                    vinv = jnp.where(take, inv_i, vinv)
+                    vidx = jnp.where(take, g_idx, vidx)
                 return (i + 1, orows, opar, oact, on + app_n,
-                        lhi, llo, ln,
-                        jnp.where(take, kind, vkind),
-                        jnp.where(take, inv_i, vinv),
-                        jnp.where(take, g_idx, vidx),
+                        lhi, llo, ln, vkind, vinv, vidx,
                         act_en, agmax, dig, ovf)
 
             def cond(carry):  # kspec: traced
                 return (carry[0] < n_chunks) & (carry[8] == 0)
 
-            init = (
-                jnp.int32(0),
-                jnp.zeros((OC, K), jnp.uint32),
-                jnp.zeros((OC,), jnp.int32),
-                jnp.zeros((OC,), jnp.int32),
-                jnp.int32(0),
-                jnp.full((LN,), sent),
-                jnp.full((LN,), sent),
-                jnp.int32(0),
-                jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                jnp.zeros((n_actions,), jnp.int32),
-                jnp.zeros((n_actions,), jnp.int32),
-                devlevel.zero_digest(),
-                jnp.bool_(False),
-            )
+            # the level's output and level-new buffers
+            with stage("compact"):
+                init = (
+                    jnp.int32(0),
+                    jnp.zeros((OC, K), jnp.uint32),
+                    jnp.zeros((OC,), jnp.int32),
+                    jnp.zeros((OC,), jnp.int32),
+                    jnp.int32(0),
+                    jnp.full((LN,), sent),
+                    jnp.full((LN,), sent),
+                    jnp.int32(0),
+                    jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                    jnp.zeros((n_actions,), jnp.int32),
+                    jnp.zeros((n_actions,), jnp.int32),
+                    devlevel.zero_digest(),
+                    jnp.bool_(False),
+                )
             (_i, orows, opar, oact, on, lhi, llo, _ln, vkind, vinv,
              vidx, act_en, agmax, dig, ovf) = jax.lax.while_loop(
                 cond, body, init
@@ -1262,15 +1379,17 @@ class DevicePipeline:
             def body(carry):  # kspec: traced
                 (i, orows, opar, oact, ohi, olo, on, lhi, llo, ln,
                  vkind, vinv, vidx, act_en, agmax, ovf) = carry
-                start = i * B
-                rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
-                fvalid = (
-                    start + jnp.arange(B, dtype=jnp.int32)
-                ) < f_total
-                states = jax.vmap(spec.unpack)(rows)
+                with stage("guard"):
+                    start = i * B
+                    rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
+                    fvalid = (
+                        start + jnp.arange(B, dtype=jnp.int32)
+                    ) < f_total
+                    states = jax.vmap(spec.unpack)(rows)
                 (en_pre, cand, valid, parent, actid, a_en, a_guard,
                  exp_ovf) = expand(states, fvalid)
-                deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
+                with stage("guard"):
+                    deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
                 viol_any, viol_idx = invariant_stage(
                     model, states, fvalid, check_invariants
                 )
@@ -1284,59 +1403,67 @@ class DevicePipeline:
                     lhi, llo, ln, T, K,
                 )
                 # verdicts, serial-commit priority (same as _build_level)
-                inv_any = jnp.any(viol_any)
-                inv_i = jnp.argmax(viol_any).astype(jnp.int32)
-                dl_any = jnp.bool_(check_deadlock) & jnp.any(deadlocked)
-                kind = jnp.where(
-                    inv_any, jnp.int32(1),
-                    jnp.where(dl_any, jnp.int32(2), jnp.int32(0)),
-                )
-                g_idx = jnp.where(
-                    inv_any, viol_idx[inv_i],
-                    jnp.argmax(deadlocked).astype(jnp.int32),
-                ).astype(jnp.int32) + start
-                take = (vkind == 0) & (kind != 0)
-                commit = kind == 0  # a verdict chunk commits nothing
-                ln_ovf = commit & ((ln + new_n) > LN)
-                commit_ok = commit & ~ovf & ~ln_ovf
-                app_n = jnp.where(commit_ok, new_n, 0)
-                orows = devlevel.append_rows(orows, n_out, on)
-                opar = devlevel.append_vec(opar, n_par + start, on)
-                oact = devlevel.append_vec(oact, n_act, on)
-                ohi = devlevel.append_vec(ohi, n_ohi, on)
-                olo = devlevel.append_vec(olo, n_olo, on)
+                with stage("invariants"):
+                    inv_any = jnp.any(viol_any)
+                    inv_i = jnp.argmax(viol_any).astype(jnp.int32)
+                    dl_any = (jnp.bool_(check_deadlock)
+                              & jnp.any(deadlocked))
+                    kind = jnp.where(
+                        inv_any, jnp.int32(1),
+                        jnp.where(dl_any, jnp.int32(2), jnp.int32(0)),
+                    )
+                    g_idx = jnp.where(
+                        inv_any, viol_idx[inv_i],
+                        jnp.argmax(deadlocked).astype(jnp.int32),
+                    ).astype(jnp.int32) + start
+                    take = (vkind == 0) & (kind != 0)
+                    commit = kind == 0  # a verdict chunk commits nothing
+                with stage("dedup_merge"):
+                    ln_ovf = commit & ((ln + new_n) > LN)
+                    commit_ok = commit & ~ovf & ~ln_ovf
+                    app_n = jnp.where(commit_ok, new_n, 0)
+                with stage("compact"):
+                    orows = devlevel.append_rows(orows, n_out, on)
+                    opar = devlevel.append_vec(opar, n_par + start, on)
+                    oact = devlevel.append_vec(oact, n_act, on)
+                    ohi = devlevel.append_vec(ohi, n_ohi, on)
+                    olo = devlevel.append_vec(olo, n_olo, on)
                 lhi, llo, ln = dedup.merge_ranked(
                     lhi, llo, ln, s_hi, s_lo, s_rank, app_n, LN
                 )
-                act_en = act_en + jnp.where(commit_ok, a_en, 0)
-                agmax = jnp.maximum(agmax, a_guard)
-                ovf = ovf | jnp.any(exp_ovf) | sq_ovf | ln_ovf
+                with stage("expand"):  # its counters and overflow flags
+                    act_en = act_en + jnp.where(commit_ok, a_en, 0)
+                    agmax = jnp.maximum(agmax, a_guard)
+                    ovf = ovf | jnp.any(exp_ovf) | sq_ovf | ln_ovf
+                with stage("invariants"):  # the first verdict wins
+                    vkind = jnp.where(take, kind, vkind)
+                    vinv = jnp.where(take, inv_i, vinv)
+                    vidx = jnp.where(take, g_idx, vidx)
                 return (i + 1, orows, opar, oact, ohi, olo,
-                        on + app_n, lhi, llo, ln,
-                        jnp.where(take, kind, vkind),
-                        jnp.where(take, inv_i, vinv),
-                        jnp.where(take, g_idx, vidx),
+                        on + app_n, lhi, llo, ln, vkind, vinv, vidx,
                         act_en, agmax, ovf)
 
             def cond(carry):  # kspec: traced
                 return (carry[0] < n_chunks) & (carry[10] == 0)
 
-            init = (
-                jnp.int32(0),
-                jnp.zeros((OC, K), jnp.uint32),
-                jnp.full((OC,), -1, jnp.int32),
-                jnp.full((OC,), -1, jnp.int32),
-                jnp.full((OC,), sent),
-                jnp.full((OC,), sent),
-                jnp.int32(0),
-                jnp.full((LN,), sent),
-                jnp.full((LN,), sent),
-                jnp.int32(0),
-                jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                jnp.zeros((n_actions,), jnp.int32),
-                jnp.zeros((n_actions,), jnp.int32),
-                jnp.bool_(False),
-            )
+            # the level's output and level-new buffers
+            with stage("compact"):
+                init = (
+                    jnp.int32(0),
+                    jnp.zeros((OC, K), jnp.uint32),
+                    jnp.full((OC,), -1, jnp.int32),
+                    jnp.full((OC,), -1, jnp.int32),
+                    jnp.full((OC,), sent),
+                    jnp.full((OC,), sent),
+                    jnp.int32(0),
+                    jnp.full((LN,), sent),
+                    jnp.full((LN,), sent),
+                    jnp.int32(0),
+                    jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                    jnp.zeros((n_actions,), jnp.int32),
+                    jnp.zeros((n_actions,), jnp.int32),
+                    jnp.bool_(False),
+                )
             (_i, orows, opar, oact, ohi, olo, on, _lh, _ll, _ln,
              vkind, vinv, vidx, act_en, agmax, ovf) = jax.lax.while_loop(
                 cond, body, init
@@ -1364,6 +1491,8 @@ class DevicePipeline:
 
         B, nc, handled = plan
         NCp = _next_pow2(nc)
+        io = self.io
+        program = "dvh" if self.host_mode else "dvl"
         self.chunk_retry.reset_chunk()
         n_actions = len(self.model.actions)
         widths = self.step.norm_widths(
@@ -1391,13 +1520,15 @@ class DevicePipeline:
         i_vkind, i_agmax, i_ovf = (
             (6, 10, 11) if self.host_mode else (7, 11, 13)
         )
+        attempt = 0
         while True:
+            launch = None
             try:
                 injected = self.fault.chunk_error(escalated=True)
                 if injected is not None:
                     raise injected
                 if not self.host_mode:
-                    need = int(vn) + min(NCp * T, LN + T)
+                    need = int(io.fetch(vn)) + min(NCp * T, LN + T)
                     if need > vcap:
                         # eviction of the outgrown capacity's programs
                         # is DEFERRED until this level dispatches
@@ -1414,10 +1545,14 @@ class DevicePipeline:
                     # un-gated tail chunk (handled < f_total) runs through
                     # the per-chunk ladder afterwards, and NCp*B can be
                     # smaller than the full frontier in that case
-                    fbuf = jnp.asarray(
+                    fbuf = io.put(
                         _pad_rows(frontier_np[:handled], NCp * B)
                     )
                 fn = self._level_program(B, NCp, vcap, widths, LN)
+                launch = io.dispatch(program, attempt=attempt, depth=depth,
+                                     bucket=B, vcap=vcap, chunks=nc,
+                                     level_new_cap=LN)
+                attempt += 1
                 if self.host_mode:
                     outs = fn(fbuf, jnp.int32(handled), jnp.int32(nc))
                 else:
@@ -1425,8 +1560,10 @@ class DevicePipeline:
                               *pre_v)
                 dispatched += 1
                 # forces the level program (the ONE device sync/level)
-                overflow = bool(outs[i_ovf])
+                overflow = bool(io.fetch(outs[i_ovf]))
             except Exception as e:  # noqa: BLE001 — XLA compile/run
+                if launch is not None:
+                    launch.finish(discarded=True)
                 action = self.chunk_retry.handle(
                     e, escalated=True, depth=depth
                 )
@@ -1436,8 +1573,11 @@ class DevicePipeline:
                     f"{type(e).__name__}: {e}"[:200], depth
                 )
                 return None
-            agmax_np = np.asarray(outs[i_agmax], np.int64)
-            if overflow and int(outs[i_vkind]) == 0 and not exact:
+            agmax_np = io.fetch(outs[i_agmax], np.int64)
+            redo = (overflow and int(io.fetch(outs[i_vkind])) == 0
+                    and not exact)
+            launch.finish(discarded=redo)
+            if redo:
                 # a segment (or the level-new set) overflowed: outputs
                 # are incomplete — discard and re-dispatch ONCE from the
                 # pre-level visited state at widths sized from the
@@ -1469,58 +1609,60 @@ class DevicePipeline:
             # LN high water tracks the PRE-probe level-new count here
             # (the level-new set is what it sizes, and that set holds
             # the not-yet-probed candidates)
-            self._ln_hw = max(self._ln_hw, int(outs[5]))
+            self._ln_hw = max(self._ln_hw, int(io.fetch(outs[5])))
 
             def finalize(outs=outs, dispatched=dispatched):
-                on = int(outs[5])
-                vk = int(outs[6])
+                on = int(io.fetch(outs[5]))
+                vk = int(io.fetch(outs[6]))
                 verdict = None
                 if vk:
                     verdict = (
                         "invariant" if vk == 1 else "deadlock",
-                        int(outs[8]),
-                        int(outs[7]),
+                        int(io.fetch(outs[8])),
+                        int(io.fetch(outs[7])),
                     )
                 return dict(
-                    rows=np.asarray(outs[0][:on]),
-                    parent=np.asarray(outs[1][:on], np.int32),
-                    act=np.asarray(outs[2][:on], np.int32),
+                    rows=io.fetch(outs[0][:on]),
+                    parent=io.fetch(outs[1][:on], np.int32),
+                    act=io.fetch(outs[2][:on], np.int32),
                     hi=np.ascontiguousarray(
-                        np.asarray(outs[3][:on]), np.uint32
+                        io.fetch(outs[3][:on]), np.uint32
                     ),
                     lo=np.ascontiguousarray(
-                        np.asarray(outs[4][:on]), np.uint32
+                        io.fetch(outs[4][:on]), np.uint32
                     ),
                     new_n=on,
                     verdict=verdict,
-                    act_en=np.asarray(outs[9], np.int64),
+                    act_en=io.fetch(outs[9], np.int64),
                     digest=None,  # host folds the probe survivors
                     launches=dispatched,
                 )
 
             # visited refs unchanged: the host set is the visited state
             return vhi, vlo, vn, vcap, finalize
-        self._ln_hw = max(self._ln_hw, int(outs[3]))
+        self._ln_hw = max(self._ln_hw, int(io.fetch(outs[3])))
         new_vhi, new_vlo, new_vn = outs[4], outs[5], outs[6]
 
         def finalize(outs=outs, dispatched=dispatched):
-            on = int(outs[3])
-            vk = int(outs[7])
+            on = int(io.fetch(outs[3]))
+            vk = int(io.fetch(outs[7]))
             verdict = None
             if vk:
                 verdict = (
                     "invariant" if vk == 1 else "deadlock",
-                    int(outs[9]),
-                    int(outs[8]),
+                    int(io.fetch(outs[9])),
+                    int(io.fetch(outs[8])),
                 )
             return dict(
-                rows=np.asarray(outs[0][:on]),
-                parent=np.asarray(outs[1][:on], np.int64),
-                act=np.asarray(outs[2][:on]),
+                rows=io.fetch(outs[0][:on]),
+                parent=io.fetch(outs[1][:on], np.int64),
+                act=io.fetch(outs[2][:on]),
                 new_n=on,
                 verdict=verdict,
-                act_en=np.asarray(outs[10], np.int64),
-                digest=devlevel.digest_ints(outs[12]),
+                act_en=io.fetch(outs[10], np.int64),
+                digest=devlevel.digest_ints(
+                    tuple(io.fetch(a) for a in outs[12])
+                ),
                 launches=dispatched,
             )
 
@@ -1537,23 +1679,26 @@ class DevicePipeline:
 def make_pipeline(name: str, *, step_builder, model, adapt, chunk_retry,
                   fault, check_invariants, visited_backend,
                   on_degrade_chunk, compact_shift, compact_gate,
-                  check_deadlock: bool = False):
-    """Pipeline factory (the one interface check() builds against)."""
+                  check_deadlock: bool = False, io=None):
+    """Pipeline factory (the one interface check() builds against).
+    io: the run's :class:`..hostio.HostIO` (counted transfers, named
+    dispatches); None gives the pipeline a private one."""
     if name == "legacy":
         return LegacyPipeline(
             step_builder, model, adapt, chunk_retry, fault,
-            check_invariants, visited_backend, on_degrade_chunk,
+            check_invariants, visited_backend, on_degrade_chunk, io=io,
         )
     if name == "device":
         return DevicePipeline(
             step_builder, model, adapt, chunk_retry, fault,
             check_invariants, visited_backend, on_degrade_chunk,
             compact_shift, compact_gate, check_deadlock=check_deadlock,
+            io=io,
         )
     return FusedPipeline(
         step_builder, model, adapt, chunk_retry, fault,
         check_invariants, visited_backend, on_degrade_chunk,
-        compact_shift, compact_gate,
+        compact_shift, compact_gate, io=io,
     )
 
 

@@ -11,8 +11,8 @@ This package makes observability a subsystem instead of a side effect:
   knobs, checkpoint lineage across resumes, terminal status) and all the
   artifacts that previously scattered across the repo root;
 - :class:`SpanTracer` (obs/tracer) — nested run_id-stamped spans to an
-  append-only untearable JSONL, with optional ``jax.profiler`` windows
-  attachable to a span kind via ``KSPEC_OBS_XPROF=<kind>:<lo>-<hi>``;
+  append-only untearable JSONL, each with the start it really had and
+  the span that caused it;
 - :class:`MetricsRegistry` (obs/metrics) — counters/gauges/histograms
   exported as JSONL snapshots and an atomically-replaced Prometheus
   textfile for scraping during multi-day runs;

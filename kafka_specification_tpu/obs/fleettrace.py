@@ -104,12 +104,14 @@ EVENT_KINDS = {
 #: per-run engine tracer vocabulary (obs/tracer.py emitters) — the other
 #: half of the registry the lint holds against docs/observability.md.
 ENGINE_SPAN_KINDS = {
-    "level", "compile", "step", "shadow", "host-assembly", "host-probe",
+    "check", "check-open", "check-close", "run-open", "init-states",
+    "host-invariants", "level", "compile", "step", "dispatch",
+    "compact-host", "store", "shadow", "host-assembly", "host-probe",
     "exchange", "exchange-level", "spill-run-write", "spill-merge",
     "checkpoint-write", "checkpoint-verify",
 }
 ENGINE_EVENT_KINDS = {
-    "pipeline-fallback", "xprof-start", "xprof-stop",
+    "pipeline-fallback",
     "retry", "chunk-degrade", "compile-fallback", "checkpoint-fallback",
     "integrity-violation", "elastic-reshard",
 }

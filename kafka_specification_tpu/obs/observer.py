@@ -13,7 +13,14 @@ shim over this class:
   run_id-stamped, routed to the run directory's ``stats.jsonl``, folded
   into the metrics registry (states/sec, duplicate ratio, per-shard
   imbalance, wall-share counters), snapshotted to ``metrics.jsonl`` +
-  ``metrics.prom`` every level, and bracketed by level spans.
+  ``metrics.prom`` every level, and bracketed by spans: a root ``check``
+  span over the whole engine call, ``level`` spans under it, and the
+  chunk-phase and dispatch spans under the level that caused them.  While
+  a ``jax.profiler`` trace is being recorded, ``check``, ``level`` and
+  ``dispatch`` are also written as profiler annotations (``kspec.check``,
+  ``kspec.level d=<depth>``, ``kspec.dispatch <program>``), so they sit on
+  the profiler's own clock above the device operations; the engine hands
+  in the annotation factory, this package never imports jax.
 
 Constructing an observer also (de)activates the module-global tracer and
 metrics registry: a ``run=None`` engine call always *clears* them, so a
@@ -30,7 +37,7 @@ from typing import Optional
 
 from ..resilience.heartbeat import append_jsonl, heartbeat_record
 from .metrics import set_registry
-from .tracer import set_tracer
+from .tracer import now, set_tracer
 
 
 # metrics export cadence: toy models run thousands of millisecond-scale
@@ -40,11 +47,55 @@ from .tracer import set_tracer
 _SNAPSHOT_MIN_INTERVAL_S = 5.0
 
 
+class _Both:
+    """A span handle and a profiler annotation closed together."""
+
+    def __init__(self, span, annotation):
+        self.span, self.annotation = span, annotation
+
+    def finish(self, **attrs) -> None:
+        if self.span is not None:
+            self.span.finish(**attrs)
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
+
+    def abandon(self) -> None:
+        """Close with no completed record (a begin marker then stays
+        unmatched, as a level cut by a verdict always has)."""
+        if self.span is not None:
+            self.span.abandon()
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
+
+
+class _Emitted:
+    """A span recorded when it ends, from the start it really had, and
+    never a parent: for work that overlaps other spans without nesting
+    (a dispatch still in flight while the next chunk is staged)."""
+
+    def __init__(self, tracer, kind: str, attrs: dict):
+        self.tracer, self.kind, self.attrs = tracer, kind, attrs
+        self.t0 = now()
+
+    def finish(self, **attrs) -> None:
+        self.tracer.emit_span(self.kind, self.t0, now(),
+                              **self.attrs, **attrs)
+
+
 class RunObserver:
     def __init__(self, run=None, stats_path: Optional[str] = None,
-                 engine: str = "bfs"):
+                 engine: str = "bfs", annotate=None):
+        """annotate: the engine's profiler-annotation factory
+        (``jax.profiler.TraceAnnotation``) or None.  An annotation made
+        while no profile is being recorded costs the profiler's own flag
+        test."""
         self.run = run
         self.engine = engine
+        self._annotate = annotate
+        self._check = None  # the open root span of this engine call
+        self._phase = None  # the open check-open / check-close span
+        self._level = None  # the open level span
+        self._t_begin = now()
         self._last_snapshot = 0.0
         # legacy stream: exactly where the caller pointed it; the run
         # directory's stats.jsonl is the default only when a run is active
@@ -64,12 +115,77 @@ class RunObserver:
         if self.run is not None:
             self.run.record_config(engine=self.engine, **fields)
 
+    # --- spans -------------------------------------------------------------
+    def _open(self, kind: str, name: Optional[str], marker: bool,
+              t0=None, nested: bool = True, **attrs) -> _Both:
+        """A span (begin-marked or not; not `nested`: recorded at its end
+        and never a parent) and, where `name` is given and the engine
+        handed in a factory, the profiler annotation beside it."""
+        span = annotation = None
+        # the annotation first and closed last, so it encloses the span by
+        # microseconds and the two clocks can be compared start to start
+        if name is not None and self._annotate is not None:
+            annotation = self._annotate(name)
+            annotation.__enter__()
+        if self.run is not None:
+            tr = self.run.tracer
+            if not nested:
+                span = _Emitted(tr, kind, attrs)
+            elif marker:
+                span = tr.begin(kind, t0, **attrs)
+            else:
+                span = tr.span(kind, **attrs).start(t0)
+        return _Both(span, annotation)
+
+    def check_begin(self, t0: float, **attrs) -> None:
+        """Root span `check` over the whole engine call, and under it
+        `check-open`, which lasts until the first level begins.  `t0` is
+        the engine's first line (the observer is built a little later)."""
+        self._t_begin = t0
+        self._check = self._open("check", "kspec.check", True, t0, **attrs)
+        self._phase = self.open_span("check-open", t0)
+
+    def check_closing(self) -> None:
+        """The level loop is over: `check-close` lasts until close()."""
+        self.level_abandon()
+        self._end_phase()
+        if self._check is not None:
+            self._phase = self.open_span("check-close")
+
+    def _end_phase(self) -> None:
+        if self._phase is not None:
+            self._phase.finish()
+            self._phase = None
+
+    def open_span(self, kind: str, t0=None, **attrs) -> _Both:
+        """A span closed by hand (``.finish(**attrs)``) that is the parent
+        of what is recorded meanwhile: ``check-open``, ``check-close``,
+        ``store``.  No-op handle without a run."""
+        return self._open(kind, None, False, t0, **attrs)
+
+    def dispatch(self, program: str, **attrs) -> _Both:
+        """One device program launched: open at the call, ``finish()`` at
+        the point the host next blocks on its outputs."""
+        return self._open("dispatch", "kspec.dispatch " + program, False,
+                          nested=False, program=program, **attrs)
+
     # --- per-level emission -----------------------------------------------
     def level_begin(self, depth: int, frontier: int) -> None:
         """Begin marker for the level span (crash forensics: a 'B' with no
-        matching 'E' pins the level the run died in)."""
-        if self.run is not None:
-            self.run.tracer.begin("level", depth=depth, frontier=frontier)
+        matching 'E' pins the level the run died in).  Until its end the
+        level is the parent of every span recorded on this thread."""
+        self._end_phase()
+        self._level = self._open(
+            "level", f"kspec.level d={depth}", True,
+            depth=depth, frontier=frontier,
+        )
+
+    def level_abandon(self) -> None:
+        """A verdict cut the level: its begin marker stays unmatched (as
+        it always has) and it stops being the current parent."""
+        if self._level is not None:
+            self._level.abandon()
+            self._level = None
 
     def level(self, **fields) -> dict:
         """Build + route the per-level heartbeat record.
@@ -83,18 +199,15 @@ class RunObserver:
             rec = heartbeat_record("level", **fields)
         if self.stats_path is not None:
             append_jsonl(self.stats_path, rec)
+        if self._level is not None:
+            self._level.finish(new=fields.get("new"),
+                               total=fields.get("total"))
+            self._level = None
         if self.run is not None:
-            # span t0 back-computed from the record's own wall time (the
-            # engines time levels with perf_counter, a different clock)
-            t0 = time.time() - fields.get("level_ms", 0.0) / 1e3
-            self.run.tracer.end(
-                "level", t0, depth=fields.get("depth"),
-                new=fields.get("new"), total=fields.get("total"),
-            )
             self._fold_metrics(fields)
-            now = time.time()
-            if now - self._last_snapshot >= _SNAPSHOT_MIN_INTERVAL_S:
-                self._last_snapshot = now
+            t = time.time()
+            if t - self._last_snapshot >= _SNAPSHOT_MIN_INTERVAL_S:
+                self._last_snapshot = t
                 self.run.snapshot_metrics()
         return rec
 
@@ -113,8 +226,12 @@ class RunObserver:
         m.set_gauge("kspec_states_distinct", f.get("total", 0))
         m.set_gauge("kspec_duplicate_ratio",
                     round(dup / en, 4) if en else 0.0)
+        # the run's rate so far: distinct states over the seconds since
+        # the check began (not the last level's rate)
+        elapsed = now() - self._t_begin
         m.set_gauge("kspec_states_per_sec",
-                    round(new / (lvl_ms / 1e3), 1) if lvl_ms else 0.0)
+                    round(f.get("total", 0) / elapsed, 1)
+                    if elapsed > 0 else 0.0)
         m.observe("kspec_level_ms", lvl_ms)
         # host-vs-step wall share (single-device engine records both)
         if "step_ms" in f:
@@ -141,13 +258,13 @@ class RunObserver:
                     m.set_gauge(name, v, shard=d)
 
     # --- sub-level spans ---------------------------------------------------
-    def chunk_span(self, kind: str, seconds: float, **attrs) -> None:
-        """Record a completed chunk-phase span (step / host-assembly /
-        dedup-insert / exchange) from the engine's own duration timer —
-        no-op without a run."""
+    def chunk_span(self, kind: str, t0: float, **attrs) -> None:
+        """Record a chunk-phase span (step / host-assembly / host-probe /
+        exchange) that ends now, given the start it really had
+        (``tracer.now()`` taken when the work began) — no-op without a
+        run."""
         if self.run is not None:
-            t1 = time.time()
-            self.run.tracer.emit_span(kind, t1 - seconds, t1, **attrs)
+            self.run.tracer.emit_span(kind, t0, now(), **attrs)
 
     # --- terminal ----------------------------------------------------------
     def abort(self, status: str, **detail) -> None:
@@ -210,5 +327,10 @@ class RunObserver:
         self.run.finish(status, **summary)
 
     def close(self) -> None:
+        self.level_abandon()
+        self._end_phase()
+        if self._check is not None:
+            self._check.finish()
+            self._check = None
         if self.run is not None:
             self.run.deactivate()

@@ -44,8 +44,6 @@ _EVENT_KINDS = (
     "resource-exhausted",
     "integrity-violation",
     "pipeline-fallback",
-    "xprof-start",
-    "xprof-stop",
 )
 
 

@@ -14,7 +14,6 @@ caller pointed it:
       events.jsonl     supervisor events (resilient runs)
       logs/            per-attempt child logs (resilient runs)
       spill/           disk-tier default when --mem-budget is set
-      xprof/           jax.profiler windows (KSPEC_OBS_XPROF)
 
 The manifest is written atomically at open (status "running"), updated
 with a resume-lineage entry every time an existing run directory is
@@ -40,7 +39,7 @@ from typing import Optional
 from ..resilience.heartbeat import heartbeat_record
 from .atomicio import atomic_write_json
 from .metrics import MetricsRegistry, set_registry
-from .tracer import SpanTracer, set_tracer
+from .tracer import SpanTracer, now, set_tracer
 
 MANIFEST = "manifest.json"
 
@@ -108,6 +107,7 @@ class RunContext:
         that are pure observability because the durable record lives
         elsewhere (the serving daemon's per-job dirs, whose contract is
         the queue's verdict file).  Writes stay atomic either way."""
+        t_open = now()
         self.durable = durable
         existing = None
         if run_dir is not None and os.path.isfile(
@@ -143,6 +143,7 @@ class RunContext:
             )
             self.manifest["status"] = "running"
             self.manifest["pid"] = os.getpid()
+            self.manifest["dir"] = os.path.abspath(self.dir)
         else:
             self.manifest = {
                 "run_id": self.run_id,
@@ -150,6 +151,7 @@ class RunContext:
                 "pid": os.getpid(),
                 "argv": list(sys.argv),
                 "cwd": os.getcwd(),
+                "dir": os.path.abspath(self.dir),
                 "git": git_describe(),
                 "lineage": [
                     {"event": "open", "pid": os.getpid(), **_ts_fields()}
@@ -157,6 +159,9 @@ class RunContext:
                 **_ts_fields("created", "created_unix"),
             }
         self.write_manifest()
+        # what opening the run directory cost (directory, git describe,
+        # the first fsync'd manifest): engine start is otherwise invisible
+        self.tracer.emit_span("run-open", t_open, now())
 
     # --- manifest ---------------------------------------------------------
     def write_manifest(self) -> None:
@@ -181,8 +186,7 @@ class RunContext:
         set_registry(self.metrics)
 
     def deactivate(self) -> None:
-        self.tracer.xprof_force_stop()  # windows must flush even when a
-        set_tracer(None)                # verdict cut the level loop early
+        set_tracer(None)
         set_registry(None)
         self.tracer.close()
 

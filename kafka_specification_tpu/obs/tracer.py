@@ -14,80 +14,91 @@ Record shapes (all carry the shared heartbeat envelope kind/ts/unix from
     {"kind": "event", "event": "<event kind>", <attrs>}     point-in-time
 
 Begin markers are emitted only for the long-lived kinds the engines mark
-explicitly (``level``) so a crash mid-level is visible in the log; every
+explicitly (``check``, ``level``) so a crash mid-level is visible in the
+log; a begin marker and its completed span share one ``span_id``.  Every
 other span lands as one "E" record at exit (span bodies that crash emit
 nothing — the surrounding begin marker and the heartbeat stream carry the
 forensics).
+
+One clock: every ``t0`` is the start the span really had, taken from
+``time.time_ns()`` when the work starts (:func:`now`), recorded to the
+microsecond; ``ms`` is recorded to the microsecond too.  The idle gaps
+these spans must explain on a device trace are about a millisecond each.
+An open span (context manager or begin marker) is the current parent of
+everything recorded on its thread until it closes, so each record names
+its cause in ``parent_id``.
 
 Deep call sites (storage spills, checkpoint writes, retry backoff) use the
 module-level :func:`span` / :func:`event` helpers, which no-op unless a
 run context is active — so the storage and resilience layers need no
 plumbing and stay usable without the obs subsystem.
 
-Optional ``jax.profiler`` windows: ``KSPEC_OBS_XPROF=<span_kind>[:<lo>[-<hi>]]``
-arms a profiler trace (TensorBoard format, written under the run
-directory's ``xprof/``) around spans of that kind whose ``depth`` attr
-falls in the range — e.g. ``KSPEC_OBS_XPROF=level:3-5`` profiles BFS
-levels 3..5.  jax is imported lazily and only when a window arms; the
-tracer itself must stay jax-free (it is imported by supervisor parents,
+Must stay jax-free at import time (it is imported by supervisor parents,
 which leave the accelerator to their child).
-
-Must stay jax-free at import time.
 """
 
 from __future__ import annotations
 
 import json
 import os
-import sys
 import threading
 import time
 from typing import Optional
 
 from ..resilience.heartbeat import heartbeat_record
 
-XPROF_ENV = "KSPEC_OBS_XPROF"
 
-
-def parse_xprof(spec: Optional[str]):
-    """``"level:3-5"`` -> ("level", 3, 5); ``"level:3"`` -> ("level", 3, 3);
-    ``"level"`` -> ("level", 0, inf).  None/empty -> None."""
-    if not spec:
-        return None
-    kind, _, rng = spec.partition(":")
-    kind = kind.strip()
-    if not kind:
-        raise ValueError(f"{XPROF_ENV}={spec!r}: empty span kind")
-    if not rng:
-        return kind, 0, float("inf")
-    lo, sep, hi = rng.partition("-")
-    try:
-        lo_i = int(lo)
-        hi_i = int(hi) if sep else lo_i
-    except ValueError:
-        raise ValueError(
-            f"{XPROF_ENV}={spec!r}: range must be '<lo>[-<hi>]'"
-        )
-    return kind, lo_i, hi_i
+def now() -> float:
+    """Unix seconds on the tracer's one clock (``time.time_ns()``)."""
+    return time.time_ns() / 1e9
 
 
 class _SpanCM:
-    """Context manager for one span (returned by SpanTracer.span)."""
+    """One open span: a context manager (``with tracer.span(...)``), or
+    opened and closed by hand (``start()`` ... ``finish(**attrs)``) where
+    wrapping the work in a ``with`` block is not practical."""
 
-    def __init__(self, tracer: "SpanTracer", kind: str, attrs: dict):
+    def __init__(self, tracer: "SpanTracer", kind: str, attrs: dict,
+                 marker: bool = False):
         self.tracer = tracer
         self.kind = kind
         self.attrs = attrs
+        self.marker = marker
         self.span_id = None
         self.t0 = None
 
-    def __enter__(self):
-        self.span_id, self.t0 = self.tracer._enter(self.kind, self.attrs)
+    def start(self, t0: Optional[float] = None) -> "_SpanCM":
+        """Open the span (at `t0` where the work began before the tracer
+        could be told) and make it this thread's current parent."""
+        tr = self.tracer
+        self.span_id = tr._next_id()
+        self.t0 = now() if t0 is None else t0
+        stack = tr._stack
+        if self.marker:
+            tr._write(heartbeat_record(
+                "span", run_id=tr.run_id, ph="B", span=self.kind,
+                span_id=self.span_id,
+                parent_id=stack[-1] if stack else None, **self.attrs,
+            ))
+        stack.append(self.span_id)
         return self
 
+    def abandon(self):
+        """Stop being the current parent with no completed record (a
+        begin marker then stays unmatched).  -> the parent's id."""
+        stack = self.tracer._stack
+        if self.span_id in stack:  # drops any child left open by a raise
+            del stack[stack.index(self.span_id):]
+        return stack[-1] if stack else None
+
+    def finish(self, error: Optional[str] = None, **attrs) -> None:
+        self.tracer._record(self.kind, self.span_id, self.abandon(),
+                            self.t0, now(), {**self.attrs, **attrs}, error)
+
+    __enter__ = start
+
     def __exit__(self, exc_type, exc, tb):
-        self.tracer._exit(self.kind, self.span_id, self.t0, self.attrs,
-                          error=exc_type.__name__ if exc_type else None)
+        self.finish(error=exc_type.__name__ if exc_type else None)
         return False
 
 
@@ -117,9 +128,6 @@ class SpanTracer:
         # which is nesting that never happened
         self._lock = threading.Lock()
         self._tls = threading.local()
-        self._xprof = parse_xprof(os.environ.get(XPROF_ENV))
-        self._xprof_dir = os.path.join(os.path.dirname(path), "xprof")
-        self._xprof_live = False
 
     # --- untearable append ------------------------------------------------
     def _write(self, rec: dict) -> None:
@@ -151,18 +159,7 @@ class SpanTracer:
             self._seq += 1
             return self._seq
 
-    def _enter(self, kind: str, attrs: dict):
-        span_id = self._next_id()
-        self._stack.append(span_id)
-        self.xprof_maybe_start(kind, attrs.get("depth"))
-        return span_id, time.time()
-
-    def _exit(self, kind, span_id, t0, attrs, error=None):
-        self.xprof_maybe_stop(kind)
-        if self._stack and self._stack[-1] == span_id:
-            self._stack.pop()
-        parent = self._stack[-1] if self._stack else None
-        t1 = time.time()
+    def _record(self, kind, span_id, parent, t0, t1, attrs, error=None):
         rec = heartbeat_record(
             "span",
             t=t1,
@@ -171,8 +168,8 @@ class SpanTracer:
             span=kind,
             span_id=span_id,
             parent_id=parent,
-            t0=round(t0, 3),
-            ms=round((t1 - t0) * 1e3, 1),
+            t0=round(t0, 6),
+            ms=round((t1 - t0) * 1e3, 3),
             **attrs,
         )
         if error is not None:
@@ -182,97 +179,25 @@ class SpanTracer:
     def span(self, kind: str, **attrs) -> _SpanCM:
         return _SpanCM(self, kind, attrs)
 
+    def begin(self, kind: str, t0: Optional[float] = None,
+              **attrs) -> _SpanCM:
+        """Open a long-lived span with a begin marker (ph=B) — crash
+        forensics: a 'B' with no matching 'E' pins where the run died.
+        Close it with the returned handle's ``finish(**attrs)``."""
+        return _SpanCM(self, kind, attrs, marker=True).start(t0)
+
     def emit_span(self, kind: str, t0: float, t1: float, **attrs) -> None:
-        """Record an already-completed span from explicit timestamps — the
-        zero-intrusion form for engine hot loops that already keep their
-        own timers (no reindentation, no context manager overhead)."""
-        parent = self._stack[-1] if self._stack else None
-        self._write(
-            heartbeat_record(
-                "span",
-                t=t1,
-                run_id=self.run_id,
-                ph="E",
-                span=kind,
-                span_id=self._next_id(),
-                parent_id=parent,
-                t0=round(t0, 3),
-                ms=round((t1 - t0) * 1e3, 1),
-                **attrs,
-            )
-        )
-
-    def begin(self, kind: str, **attrs) -> None:
-        """Emit a begin marker (ph=B) — crash forensics for long-lived
-        spans: a 'B' with no matching 'E' pins where the run died."""
-        self._write(
-            heartbeat_record(
-                "span",
-                run_id=self.run_id,
-                ph="B",
-                span=kind,
-                span_id=self._next_id(),
-                **attrs,
-            )
-        )
-        self.xprof_maybe_start(kind, attrs.get("depth"))
-
-    def end(self, kind: str, t0: float, **attrs) -> None:
-        """Close a begin-marked span by explicit start time (pairs with
-        `begin`; the engines' level loop uses begin/end because wrapping
-        the whole level body in a context manager is not practical)."""
-        self.xprof_maybe_stop(kind)
-        self.emit_span(kind, t0, time.time(), **attrs)
+        """Record an already-completed span from explicit timestamps
+        (:func:`now` at its true start and end) — the zero-intrusion form
+        for engine hot loops (no reindentation, no context manager)."""
+        stack = self._stack
+        self._record(kind, self._next_id(), stack[-1] if stack else None,
+                     t0, t1, attrs)
 
     def event(self, kind: str, **attrs) -> None:
         self._write(
             heartbeat_record("event", run_id=self.run_id, event=kind, **attrs)
         )
-
-    # --- optional jax.profiler windows -------------------------------------
-    def xprof_maybe_start(self, kind: str, depth) -> None:
-        if self._xprof is None or self._xprof_live:
-            return
-        want_kind, lo, hi = self._xprof
-        if kind != want_kind:
-            return
-        if depth is not None and not (lo <= depth <= hi):
-            return
-        try:
-            import jax
-
-            os.makedirs(self._xprof_dir, exist_ok=True)
-            jax.profiler.start_trace(self._xprof_dir)
-            self._xprof_live = True
-            self.event("xprof-start", span=kind, depth=depth,
-                       dir=self._xprof_dir)
-        except Exception as e:  # profiling is best-effort, never a failure
-            self._xprof = None  # don't retry every span
-            print(f"[obs] {XPROF_ENV} window failed to start: {e}",
-                  file=sys.stderr)
-
-    def xprof_maybe_stop(self, kind: str) -> None:
-        if not self._xprof_live or self._xprof is None:
-            return
-        if kind != self._xprof[0]:
-            return
-        self._xprof_stop(kind)
-
-    def xprof_force_stop(self) -> None:
-        """Flush any still-open window — a verdict/cutoff `break` exits
-        the level loop without the span end that would close it."""
-        if self._xprof_live and self._xprof is not None:
-            self._xprof_stop(self._xprof[0])
-
-    def _xprof_stop(self, kind: str) -> None:
-        try:
-            import jax
-
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
-        self._xprof_live = False
-        self.event("xprof-stop", span=kind)
 
 
 # --- module-level current tracer (deep call sites, zero plumbing) ---------

@@ -13,6 +13,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# Stage scopes of this module (engine/pipeline.py STAGES holds the
+# vocabulary; ops cannot import the engine, so the names are literal here).
+_PROBE = "kspec.dedup_probe"
+_MERGE = "kspec.dedup_merge"
+
 # Sentinel (all-ones) sorts to the end; used to pad invalid slots.
 # (kept as a Python int: a module-level jnp constant would initialize the
 # default JAX backend at import time, which must not happen on TPU hosts
@@ -29,13 +34,21 @@ def first_occurrence_mask(hi_s, lo_s, invalid_s):
 
 
 def rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
-    """Vectorized lower-bound rank of queries in a sorted pair set.
+    """Vectorized lower-bound rank of queries in a sorted pair set (the
+    ``dedup_probe`` stage).
 
     set_hi/set_lo: uint32[cap] sorted ascending on (hi, lo) for the first
     set_n entries (the rest is sentinel padding).  Fixed-iteration binary
     search — static trip count, fully vectorized over queries.  Returns
     (found_mask, rank) where rank is the insertion index (bisect_left).
     """
+    with jax.named_scope(_PROBE):
+        return _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo)
+
+
+def _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
+    """rank_sorted's body, in no stage scope of its own: merge_ranked runs
+    it inside ``dedup_merge`` (a merge's own search is merge time)."""
     cap = set_hi.shape[0]
     n_q = q_hi.shape[0]
     lo_i = jnp.zeros((n_q,), jnp.int32)
@@ -79,20 +92,22 @@ def merge_ranked(set_hi, set_lo, set_n, new_hi, new_lo, new_rank, new_n, out_cap
       target(visited[i]) = i + (# new entries below visited[i])
     Out-of-range targets (sentinel tails) drop or overwrite padding with
     sentinels — both harmless.  Returns (hi[out_cap], lo[out_cap], n).
+    The ``dedup_merge`` stage, its own rank search included.
     """
-    cap = set_hi.shape[0]
-    M = new_hi.shape[0]
-    j = jnp.arange(M, dtype=jnp.int32)
-    valid_new = j < new_n
-    tgt_new = jnp.where(valid_new, new_rank + j, out_cap)
+    with jax.named_scope(_MERGE):
+        cap = set_hi.shape[0]
+        M = new_hi.shape[0]
+        j = jnp.arange(M, dtype=jnp.int32)
+        valid_new = j < new_n
+        tgt_new = jnp.where(valid_new, new_rank + j, out_cap)
 
-    # rank of each visited entry within the new list
-    _, cnt_before = rank_sorted(new_hi, new_lo, new_n, set_hi, set_lo)
-    tgt_old = jnp.arange(cap, dtype=jnp.int32) + cnt_before
+        # rank of each visited entry within the new list
+        _, cnt_before = _rank_sorted(new_hi, new_lo, new_n, set_hi, set_lo)
+        tgt_old = jnp.arange(cap, dtype=jnp.int32) + cnt_before
 
-    sent = jnp.uint32(SENT)
-    out_hi = jnp.full((out_cap,), sent)
-    out_lo = jnp.full((out_cap,), sent)
-    out_hi = out_hi.at[tgt_old].set(set_hi).at[tgt_new].set(new_hi)
-    out_lo = out_lo.at[tgt_old].set(set_lo).at[tgt_new].set(new_lo)
-    return out_hi, out_lo, set_n + new_n
+        sent = jnp.uint32(SENT)
+        out_hi = jnp.full((out_cap,), sent)
+        out_lo = jnp.full((out_cap,), sent)
+        out_hi = out_hi.at[tgt_old].set(set_hi).at[tgt_new].set(new_hi)
+        out_lo = out_lo.at[tgt_old].set(set_lo).at[tgt_new].set(new_lo)
+        return out_hi, out_lo, set_n + new_n

@@ -42,6 +42,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+#: the digest folds' stage scope (engine/pipeline.py STAGES)
+_DIGEST = "kspec.digest"
+
 #: 16-bit limb block: column sums of uint16 limbs over <= 2^16 rows fit
 #: uint32 exactly ((2^16-1) * 2^16 < 2^32), so digests of arbitrarily
 #: wide buffers reduce block-wise with no 64-bit ALU
@@ -80,6 +83,7 @@ def _add_limbs(acc, add):  # kspec: traced
     return jnp.stack(out)
 
 
+@jax.named_scope(_DIGEST)
 def masked_digest(hi, lo, valid):  # kspec: traced
     """(count, xor, sum) over the fingerprint pairs selected by `valid`.
 
@@ -111,6 +115,7 @@ def masked_digest(hi, lo, valid):  # kspec: traced
     return count, xor_hi, xor_lo, limbs
 
 
+@jax.named_scope(_DIGEST)
 def combine_digest(acc, new):  # kspec: traced
     """Fold one chunk digest into the running level accumulator."""
     c0, xh0, xl0, l0 = acc

@@ -64,8 +64,10 @@ def new_table(cap: int):
 CLAIM_FREE = 0x7FFFFFFF  # int32 max: "this slot was never claimed"
 
 
+@jax.named_scope("kspec.dedup_probe")  # engine/pipeline.py STAGES
 def probe_insert(t_hi, t_lo, q_hi, q_lo, valid, max_probes: int = 32, claim=None):
-    """Insert-or-find a batch of fingerprints.
+    """Insert-or-find a batch of fingerprints (the hash backend's
+    ``dedup_probe`` stage).
 
     t_hi/t_lo: uint32[cap] table (cap power of two).
     q_hi/q_lo: uint32[M] batch; `valid` masks live rows.

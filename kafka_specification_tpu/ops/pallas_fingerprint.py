@@ -63,4 +63,5 @@ def fingerprint_pallas(lanes, valid, block_rows: int = 1024, interpret: bool = F
             jax.ShapeDtypeStruct((m,), jnp.uint32),
         ],
         interpret=interpret,
+        name="kspec_fingerprint",
     )(lanes, valid)

@@ -321,6 +321,7 @@ def _kernel_hbm(max_probes, q_hi_ref, q_lo_ref, valid_ref, _ti, _tl,
     jax.jit,
     static_argnames=("max_probes", "block_rows", "interpret"),
 )
+@jax.named_scope("kspec.dedup_probe")  # engine/pipeline.py STAGES
 def probe_insert_pallas_hbm(
     t_hi,
     t_lo,
@@ -373,6 +374,7 @@ def probe_insert_pallas_hbm(
         ],
         input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
+        name="kspec_dedup_probe",
     )(q_hi, q_lo, jnp.asarray(valid, jnp.int32), t_hi, t_lo)
     is_new = is_new3 == 1
     return (
@@ -388,6 +390,7 @@ def probe_insert_pallas_hbm(
     jax.jit,
     static_argnames=("max_probes", "block_rows", "interpret", "group"),
 )
+@jax.named_scope("kspec.dedup_probe")  # engine/pipeline.py STAGES
 def probe_insert_pallas(
     t_hi,
     t_lo,
@@ -451,6 +454,7 @@ def probe_insert_pallas(
         ],
         input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
+        name="kspec_dedup_probe",
     )(q_hi, q_lo, jnp.asarray(valid, jnp.int32), t_hi, t_lo)
     is_new = is_new3 == 1
     return (

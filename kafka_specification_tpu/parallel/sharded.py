@@ -47,6 +47,7 @@ from ..pipeline_registry import resolve_pipeline
 from ..models.base import Model
 from ..obs import metrics as _met
 from ..obs.observer import RunObserver
+from ..obs.tracer import now as _now
 from ..ops import dedup, hashset
 from ..resilience import integrity as _integ
 from ..resilience.checkpoints import CheckpointStore
@@ -2911,9 +2912,10 @@ def check_sharded(
                         sent_b = raw_b
                     lvl_exch_bytes += sent_b
                     lvl_exch_raw_bytes += raw_b
+                # (the span ends here, so its start is now minus the timer)
                 obs_.chunk_span(
                     "exchange",
-                    time.perf_counter() - t_chunk,
+                    _now() - (time.perf_counter() - t_chunk),
                     depth=depth,
                     bucket=bucket,
                     exchange=exchange,
@@ -3199,7 +3201,7 @@ def check_sharded(
                         )
                 obs_.chunk_span(
                     "exchange-level",
-                    time.perf_counter() - t0l,
+                    _now() - (time.perf_counter() - t0l),
                     depth=depth,
                     bucket=B,
                     chunks=nc,
@@ -3322,7 +3324,7 @@ def check_sharded(
                     ) * 1e3
                     obs_.chunk_span(
                         "host-probe",
-                        time.perf_counter() - t_probe,
+                        _now() - (time.perf_counter() - t_probe),
                         depth=depth, rows=int(counts.sum()),
                         new=int(newc.sum()), batched="level",
                     )
