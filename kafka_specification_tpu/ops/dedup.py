@@ -5,7 +5,9 @@ visited set is a sorted array of fingerprint pairs living in HBM; each BFS
 level sorts the candidate fingerprints (XLA sort on TPU), drops in-batch
 duplicates by adjacent comparison, and probes the visited set with a
 fixed-iteration vectorized binary search (jit-friendly: no data-dependent
-control flow).
+control flow).  The probe's insertion ranks are all the merge needs: it
+places the new entries by them and counts the visited entries' shifts from
+them (histogram + prefix sum), with no search of its own.
 """
 
 from __future__ import annotations
@@ -47,8 +49,7 @@ def rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
 
 
 def _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
-    """rank_sorted's body, in no stage scope of its own: merge_ranked runs
-    it inside ``dedup_merge`` (a merge's own search is merge time)."""
+    """rank_sorted's body, in no stage scope of its own."""
     cap = set_hi.shape[0]
     n_q = q_hi.shape[0]
     lo_i = jnp.zeros((n_q,), jnp.int32)
@@ -90,9 +91,12 @@ def merge_ranked(set_hi, set_lo, set_n, new_hi, new_lo, new_rank, new_n, out_cap
     with two scatters instead of re-sorting V+M keys:
       target(new[j])     = rank[j] + j
       target(visited[i]) = i + (# new entries below visited[i])
-    Out-of-range targets (sentinel tails) drop or overwrite padding with
-    sentinels — both harmless.  Returns (hi[out_cap], lo[out_cap], n).
-    The ``dedup_merge`` stage, its own rank search included.
+    New and visited are disjoint, so new[j] < visited[i] exactly when
+    rank[j] <= i: the count is the inclusive prefix sum of a histogram of
+    the live ranks, and nothing is searched.  Lanes j >= new_n may hold any
+    rank and are masked out of it.  Out-of-range targets (sentinel tails)
+    drop or overwrite padding with sentinels — both harmless.  Returns
+    (hi[out_cap], lo[out_cap], n).  The ``dedup_merge`` stage.
     """
     with jax.named_scope(_MERGE):
         cap = set_hi.shape[0]
@@ -101,9 +105,11 @@ def merge_ranked(set_hi, set_lo, set_n, new_hi, new_lo, new_rank, new_n, out_cap
         valid_new = j < new_n
         tgt_new = jnp.where(valid_new, new_rank + j, out_cap)
 
-        # rank of each visited entry within the new list
-        _, cnt_before = _rank_sorted(new_hi, new_lo, new_n, set_hi, set_lo)
-        tgt_old = jnp.arange(cap, dtype=jnp.int32) + cnt_before
+        # new entries below each visited slot (a rank of cap, above every
+        # slot, drops with the dead lanes)
+        hist = jnp.zeros((cap,), jnp.int32)
+        hist = hist.at[jnp.where(valid_new, new_rank, cap)].add(1, mode="drop")
+        tgt_old = jnp.arange(cap, dtype=jnp.int32) + jnp.cumsum(hist)
 
         sent = jnp.uint32(SENT)
         out_hi = jnp.full((out_cap,), sent)
