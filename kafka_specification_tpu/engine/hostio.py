@@ -1,7 +1,8 @@
 """The hot path's one door between host and device: counted transfers and
 named dispatches.
 
-Both per-chunk pipelines, the whole-level pipeline and the level loop
+Both per-chunk pipelines, the whole-level pipeline, the level loop and the
+sharded engine (``parallel/sharded.py``, through its own transfer functions)
 fetch device outputs and upload host arrays through :class:`HostIO`, so a
 level record can say what crossed (``d2h_bytes``, ``d2h_fetches``,
 ``h2d_bytes``, ``h2d_puts``) and what was launched (``dispatches``,
@@ -50,8 +51,14 @@ class _Dispatch:
 
 
 class HostIO:
-    def __init__(self, obs=None):
+    def __init__(self, obs=None, fetch=None, put=None):
+        """`fetch` / `put`: the engine's own transfer functions where a
+        plain ``np.asarray`` / ``jnp.asarray`` will not do: the sharded
+        engine hands in ``fetch_global`` / ``put_global`` (a mesh layout
+        rides ``put``'s second argument; on a multi-process mesh a
+        replicated fetch counts once in each process)."""
         self.obs = obs  # a RunObserver, or None: count only
+        self._fetch, self._put = fetch, put
         self.reset()
 
     def reset(self) -> None:
@@ -74,14 +81,17 @@ class HostIO:
         if isinstance(x, jax.Array):
             self.d2h_fetches += 1
             self.d2h_bytes += x.nbytes
+            if self._fetch is not None:
+                x = self._fetch(x)
         return np.asarray(x, dtype)
 
-    def put(self, x) -> jax.Array:
-        """``jnp.asarray(x)``; a host array is counted as one upload."""
+    def put(self, x, *where) -> jax.Array:
+        """``jnp.asarray(x)`` (or the engine's own ``put(x, *where)``); a
+        host array is counted as one upload."""
         if isinstance(x, np.ndarray):
             self.h2d_puts += 1
             self.h2d_bytes += x.nbytes
-        return jnp.asarray(x)
+        return jnp.asarray(x) if self._put is None else self._put(x, *where)
 
     # --- spans and dispatches -----------------------------------------------
     def span(self, kind: str, t0: float, **attrs) -> None:

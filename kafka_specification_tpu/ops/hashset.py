@@ -145,6 +145,15 @@ def new_claim(cap: int):
     return jnp.full((cap,), CLAIM_FREE, jnp.int32)
 
 
+@jax.jit
+def _insert_all(t_hi, t_lo, q_hi, q_lo):
+    """probe_insert of an all-live batch, jitted once per shape: the eager
+    call would trace and compile its probe loop anew every time (a fresh
+    loop body each call), in every run that builds or grows a table."""
+    return probe_insert(t_hi, t_lo, q_hi, q_lo,
+                        jnp.ones(q_hi.shape[0], bool))
+
+
 def table_from_pairs(hi, lo, min_cap: int = 1 << 10, chunk: int = 1 << 20):
     """Build a table containing exactly the given (assumed-distinct) pairs.
 
@@ -164,9 +173,7 @@ def table_from_pairs(hi, lo, min_cap: int = 1 << 10, chunk: int = 1 << 20):
         for start in range(0, n, chunk):
             h = jnp.asarray(hi[start : start + chunk])
             lo_c = jnp.asarray(lo[start : start + chunk])
-            nh, nl, _c, _m, _n2, ovf = probe_insert(
-                nh, nl, h, lo_c, jnp.ones(h.shape[0], bool)
-            )
+            nh, nl, _c, _m, _n2, ovf = _insert_all(nh, nl, h, lo_c)
             if bool(ovf):  # pragma: no cover - improbable at 1/4 load
                 ok = False
                 break

@@ -42,6 +42,8 @@ from ..engine.bfs import (
     _Step,
     walk_trace,
 )
+from ..engine.hostio import HostIO
+from ..engine.pipeline import stage
 from ..ops import devlevel
 from ..pipeline_registry import resolve_pipeline
 from ..models.base import Model
@@ -76,6 +78,20 @@ from ..ops.fingerprint import fingerprint_lanes
 # exercise growth at small state counts)
 _HASH_MIN_CAP = 1 << 14
 
+#: stage scope of the exchange body (routing, codec encode, the collective,
+#: decode): the one stage of the device vocabulary (engine/pipeline.py
+#: STAGES) that only the sharded programs have.  The framing digests on
+#: either side of it carry the `digest` scope.
+_EXCHANGE = "kspec.exchange"
+
+#: cache tags (and, through pipeline.program_name, module names) of the
+#: three sharded program kinds: the per-chunk step, the whole-level
+#: program, and its deferred-probe twin for the host visited backend
+STEP_TAG, LEVEL_TAG, LEVEL_HOST_TAG = "shs", "shl", "shh"
+#: ... and of the invariant pass over host-held rows (the initial states;
+#: the last frontier when a bound cut the search)
+INVARIANT_TAG = "shi"
+
 
 def _shard_tables_from_pairs(per_shard, min_cap: int):
     """Uniform-capacity per-shard tables from per-shard (hi, lo) pairs.
@@ -100,21 +116,22 @@ def _shard_tables_from_pairs(per_shard, min_cap: int):
             return np.stack(ths), np.stack(tls), cap
 
 
-def _grow_hash_tables(dev_vhi, dev_vlo, new_cap: int, shard1):
+def _grow_hash_tables(dev_vhi, dev_vlo, new_cap: int, shard1, io):
     """Rehash every shard's HBM hash table into (>=) `new_cap` slots.
 
     Host-driven (runs between chunk attempts, amortized O(n) per
-    doubling); fetch_global/put_global keep it multi-process-correct —
-    every process computes the identical grown tables.  Returns
-    (dev_vhi, dev_vlo, cap)."""
-    old_hi = fetch_global(dev_vhi)  # [D, cap]
-    old_lo = fetch_global(dev_vlo)
+    doubling); fetch_global/put_global (counted through `io`, the
+    engine's HostIO) keep it multi-process-correct — every process
+    computes the identical grown tables.  Returns (dev_vhi, dev_vlo,
+    cap)."""
+    old_hi = io.fetch(dev_vhi)  # [D, cap]
+    old_lo = io.fetch(dev_vlo)
     live = ~((old_hi == hashset.SENT) & (old_lo == hashset.SENT))
     per_shard = [
         (old_hi[d][live[d]], old_lo[d][live[d]]) for d in range(old_hi.shape[0])
     ]
     nh, nl, cap = _shard_tables_from_pairs(per_shard, new_cap)
-    return put_global(nh, shard1), put_global(nl, shard1), cap
+    return io.put(nh, shard1), io.put(nl, shard1), cap
 
 
 def _norm_shift(bucket: int, shift: int) -> int:
@@ -165,15 +182,16 @@ def _fp_digest(dhi, dlo, mask):  # kspec: traced
     64-bit ALU, and wrapping 32-bit sums/xors combine across shards
     just as commutatively."""
     z = jnp.uint32(0)
-    mh = jnp.where(mask, dhi, z)
-    ml = jnp.where(mask, dlo, z)
-    return jnp.stack([
-        jnp.sum(mask, dtype=jnp.uint32),
-        jax.lax.reduce(mh, z, jax.lax.bitwise_xor, [0]),
-        jax.lax.reduce(ml, z, jax.lax.bitwise_xor, [0]),
-        jnp.sum(mh, dtype=jnp.uint32),
-        jnp.sum(ml, dtype=jnp.uint32),
-    ])
+    with stage("digest"):
+        mh = jnp.where(mask, dhi, z)
+        ml = jnp.where(mask, dlo, z)
+        return jnp.stack([
+            jnp.sum(mask, dtype=jnp.uint32),
+            jax.lax.reduce(mh, z, jax.lax.bitwise_xor, [0]),
+            jax.lax.reduce(ml, z, jax.lax.bitwise_xor, [0]),
+            jnp.sum(mh, dtype=jnp.uint32),
+            jnp.sum(ml, dtype=jnp.uint32),
+        ])
 
 
 def _acc_digest(acc, dig, enabled):  # kspec: traced
@@ -182,15 +200,16 @@ def _acc_digest(acc, dig, enabled):  # kspec: traced
     shards: counts and wrapping sums add, xors xor.  `enabled` masks
     out chunks the serial path would have discarded (overflowed
     attempts)."""
-    z = jnp.zeros((5,), jnp.uint32)
-    d = jnp.where(enabled, dig, z)
-    return jnp.stack([
-        acc[0] + d[0],
-        acc[1] ^ d[1],
-        acc[2] ^ d[2],
-        acc[3] + d[3],
-        acc[4] + d[4],
-    ])
+    with stage("digest"):
+        z = jnp.zeros((5,), jnp.uint32)
+        d = jnp.where(enabled, dig, z)
+        return jnp.stack([
+            acc[0] + d[0],
+            acc[1] ^ d[1],
+            acc[2] ^ d[2],
+            acc[3] + d[3],
+            acc[4] + d[4],
+        ])
 
 
 def _combine_digs(dig: np.ndarray) -> tuple:
@@ -337,7 +356,11 @@ def _make_exchange(D: int, W: int, R: int, K: int, exchange: str,
             r_lo = jnp.where(mine, r_lo, sent)
             return r_hi, r_lo, r_cand, r_parent, r_act, ovf_dest
 
-    return route
+    def scoped(*operands):  # kspec: traced
+        with jax.named_scope(_EXCHANGE):
+            return route(*operands)
+
+    return scoped
 
 
 def _make_sharded_step(
@@ -352,7 +375,8 @@ def _make_sharded_step(
     hash_table: bool = False,
     compress: bool = False,
 ):
-    """Jitted sharded level step.
+    """The sharded level step, un-jitted: check_sharded jits it under its
+    cache tag through the model's step cache (engine.bfs._Step.cached).
 
     Global shapes (D = mesh size):
       frontier [D*bucket, K], fvalid [D*bucket]
@@ -428,10 +452,11 @@ def _make_sharded_step(
         )
         deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
 
-        hi, lo = fingerprint_lanes(cand, spec.exact64)
         sent = jnp.uint32(dedup.SENT)
-        hi = jnp.where(valid, hi, sent)
-        lo = jnp.where(valid, lo, sent)
+        with stage("fingerprint"):
+            hi, lo = fingerprint_lanes(cand, spec.exact64)
+            hi = jnp.where(valid, hi, sent)
+            lo = jnp.where(valid, lo, sent)
         # parent as a mesh-global frontier row id (survives the exchange)
         parent_g = me.astype(jnp.int32) * bucket + parent
 
@@ -454,10 +479,11 @@ def _make_sharded_step(
         # minimal-payload sort over the received (owned) candidates: the
         # sort both dedups the batch (first-occurrence) and fixes the
         # shard's discovery order deterministically
-        order = jnp.lexsort((r_lo, r_hi))
-        hi_s, lo_s = r_hi[order], r_lo[order]
-        invalid_s = (hi_s == sent) & (lo_s == sent)
-        first = dedup.first_occurrence_mask(hi_s, lo_s, invalid_s)
+        with stage("dedup_sort"):
+            order = jnp.lexsort((r_lo, r_hi))
+            hi_s, lo_s = r_hi[order], r_lo[order]
+            invalid_s = (hi_s == sent) & (lo_s == sent)
+            first = dedup.first_occurrence_mask(hi_s, lo_s, invalid_s)
         ovf_probe = jnp.bool_(False)
         if hash_table:
             # per-shard HBM open-addressing table (ops/hashset): vhi/vlo
@@ -481,14 +507,16 @@ def _make_sharded_step(
             seen, rank = dedup.rank_sorted(vhi, vlo, vn, hi_s, lo_s)
             is_new = first & ~seen
 
-        pos = jnp.where(is_new, jnp.cumsum(is_new) - 1, R)
-        out = jnp.zeros((R, K), jnp.uint32).at[pos].set(r_cand[order])
-        out_parent = jnp.full((R,), -1, jnp.int32).at[pos].set(r_parent[order])
-        out_act = jnp.full((R,), -1, jnp.int32).at[pos].set(r_act[order])
-        out_hi = jnp.full((R,), sent).at[pos].set(hi_s)
-        out_lo = jnp.full((R,), sent).at[pos].set(lo_s)
-        out_rank = jnp.zeros((R,), jnp.int32).at[pos].set(rank)
-        new_n = jnp.sum(is_new, dtype=jnp.int32)
+        with stage("compact"):
+            pos = jnp.where(is_new, jnp.cumsum(is_new) - 1, R)
+            out = jnp.zeros((R, K), jnp.uint32).at[pos].set(r_cand[order])
+            out_parent = jnp.full((R,), -1, jnp.int32).at[pos].set(
+                r_parent[order])
+            out_act = jnp.full((R,), -1, jnp.int32).at[pos].set(r_act[order])
+            out_hi = jnp.full((R,), sent).at[pos].set(hi_s)
+            out_lo = jnp.full((R,), sent).at[pos].set(lo_s)
+            out_rank = jnp.zeros((R,), jnp.int32).at[pos].set(rank)
+            new_n = jnp.sum(is_new, dtype=jnp.int32)
 
         if hash_table:
             pass  # vhi2/vlo2 already hold the updated table
@@ -506,11 +534,12 @@ def _make_sharded_step(
         # state, at expansion; `states` is already unpacked)
         viol_any, viol_idx = [], []
         if model.invariants:
-            for inv in model.invariants:
-                ok = jax.vmap(inv.pred)(states)
-                bad = fvalid & ~ok
-                viol_any.append(jnp.any(bad))
-                viol_idx.append(jnp.argmax(bad))
+            with stage("invariants"):
+                for inv in model.invariants:
+                    ok = jax.vmap(inv.pred)(states)
+                    bad = fvalid & ~ok
+                    viol_any.append(jnp.any(bad))
+                    viol_idx.append(jnp.argmax(bad))
         else:
             viol_any, viol_idx = [jnp.bool_(False)], [jnp.int32(0)]
 
@@ -580,11 +609,11 @@ def _make_sharded_step(
         ),
         check_vma=False,
     )
-    return jax.jit(sharded)
+    return sharded
 
 
 def _grow_sorted_shards(dev_vhi, dev_vlo, vcap: int, new_cap: int,
-                        layout):
+                        layout, io):
     """Grow every shard's sorted visited pair set to `new_cap` slots
     (sentinel-padded) — the one growth path for the per-chunk loop and
     the device-resident level driver.  Multi-process takes the host
@@ -592,15 +621,15 @@ def _grow_sorted_shards(dev_vhi, dev_vlo, vcap: int, new_cap: int,
     process grows on device with no host copy."""
     D = dev_vhi.shape[0]
     if is_multiprocess():
-        grown_hi = fetch_global(dev_vhi)
-        grown_lo = fetch_global(dev_vlo)
+        grown_hi = io.fetch(dev_vhi)
+        grown_lo = io.fetch(dev_vlo)
         pad = np.full(
             (D, new_cap - grown_hi.shape[1]), 0xFFFFFFFF, np.uint32
         )
-        dev_vhi = put_global(
+        dev_vhi = io.put(
             np.concatenate([grown_hi, pad], axis=1), layout
         )
-        dev_vlo = put_global(
+        dev_vlo = io.put(
             np.concatenate([grown_lo, pad], axis=1), layout
         )
     else:
@@ -668,7 +697,7 @@ def _make_sharded_level(
     host re-dispatches ONCE from the pre-level visited state at exact
     measured widths — <=2 launches per level per shard even then.
 
-    Returns the jitted program over global operands
+    Returns the (un-jitted) program over global operands
     (fbuf [D*NCp*B, K], flen [D], ncs [D], vhi/vlo [D, vcap], vn [D])
     laid out per :func:`mesh_layouts`.
     """
@@ -705,9 +734,10 @@ def _make_sharded_level(
             (en_pre, cand, valid, parent, actid, a_en, a_guard,
              exp_ovf) = expand(states, fvalid)
             deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
-            hi, lo = fingerprint_lanes(cand, spec.exact64)
-            hi = jnp.where(valid, hi, sent)
-            lo = jnp.where(valid, lo, sent)
+            with stage("fingerprint"):
+                hi, lo = fingerprint_lanes(cand, spec.exact64)
+                hi = jnp.where(valid, hi, sent)
+                lo = jnp.where(valid, lo, sent)
             # parent as a mesh-global LEVEL row id: src shard * F +
             # (chunk offset + row) — the host decodes src_d = pg // F,
             # level row = pg % F (chunk offsets are i*B by plan)
@@ -734,13 +764,14 @@ def _make_sharded_level(
             # the per-chunk step's exact semantics)
             if model.invariants:
                 v_any, v_idx = [], []
-                for inv in model.invariants:
-                    ok = jax.vmap(inv.pred)(states)
-                    bad = fvalid & ~ok
-                    v_any.append(jnp.any(bad))
-                    v_idx.append(jnp.argmax(bad).astype(jnp.int32))
-                viol_any = jnp.stack(v_any)
-                viol_idx = jnp.stack(v_idx)
+                with stage("invariants"):
+                    for inv in model.invariants:
+                        ok = jax.vmap(inv.pred)(states)
+                        bad = fvalid & ~ok
+                        v_any.append(jnp.any(bad))
+                        v_idx.append(jnp.argmax(bad).astype(jnp.int32))
+                    viol_any = jnp.stack(v_any)
+                    viol_idx = jnp.stack(v_idx)
             else:
                 viol_any = jnp.zeros((1,), bool)
                 viol_idx = jnp.zeros((1,), jnp.int32)
@@ -894,7 +925,7 @@ def _make_sharded_level(
         ),
         check_vma=False,
     )
-    return jax.jit(sharded)
+    return sharded
 
 
 def _make_sharded_level_host(
@@ -968,9 +999,10 @@ def _make_sharded_level_host(
             (en_pre, cand, valid, parent, actid, a_en, a_guard,
              exp_ovf) = expand(states, fvalid)
             deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
-            hi, lo = fingerprint_lanes(cand, spec.exact64)
-            hi = jnp.where(valid, hi, sent)
-            lo = jnp.where(valid, lo, sent)
+            with stage("fingerprint"):
+                hi, lo = fingerprint_lanes(cand, spec.exact64)
+                hi = jnp.where(valid, hi, sent)
+                lo = jnp.where(valid, lo, sent)
             parent_g = me.astype(jnp.int32) * F + (start + parent)
             sent_dig = _fp_digest(hi, lo, valid)
             (r_hi, r_lo, r_cand, r_parent, r_act, ovf_dest) = route(
@@ -993,13 +1025,14 @@ def _make_sharded_level_host(
             # states only, so the deferred probe cannot change them)
             if model.invariants:
                 v_any, v_idx = [], []
-                for inv in model.invariants:
-                    ok = jax.vmap(inv.pred)(states)
-                    bad = fvalid & ~ok
-                    v_any.append(jnp.any(bad))
-                    v_idx.append(jnp.argmax(bad).astype(jnp.int32))
-                viol_any = jnp.stack(v_any)
-                viol_idx = jnp.stack(v_idx)
+                with stage("invariants"):
+                    for inv in model.invariants:
+                        ok = jax.vmap(inv.pred)(states)
+                        bad = fvalid & ~ok
+                        v_any.append(jnp.any(bad))
+                        v_idx.append(jnp.argmax(bad).astype(jnp.int32))
+                    viol_any = jnp.stack(v_any)
+                    viol_idx = jnp.stack(v_idx)
             else:
                 viol_any = jnp.zeros((1,), bool)
                 viol_idx = jnp.zeros((1,), jnp.int32)
@@ -1123,7 +1156,7 @@ def _make_sharded_level_host(
         ),
         check_vma=False,
     )
-    return jax.jit(sharded)
+    return sharded
 
 
 class ShardedDeviceLevel:
@@ -1574,7 +1607,33 @@ def check_sharded(
     "legacy" (and "fused", which has no sharded variant) run the
     per-chunk path — the bit-identity oracle.  Unknown names are
     rejected loudly (pipeline_registry.resolve_pipeline).
+
+    What is kept across calls, and what is not.  Every compiled program
+    of this engine — the per-chunk step (``shs``), the whole-level
+    program (``shl``) and its host-backend twin (``shh``), the invariant
+    pass over host-held rows (``shi``) — lives in the MODEL's step cache
+    (``engine.bfs._Step.cached``, the cache ``PreparedKernels`` wraps),
+    found by this function itself: no handle is passed.  A program is
+    keyed by everything that shapes it: its tag, the mesh (devices and
+    axis names), bucket, visited capacity, expansion widths, exchange
+    kind, per-destination width, codec on/off, visited backend, level-new
+    capacity and chunk count (level programs), the ordered invariant
+    names and the deadlock flag.  A later call on the same model object,
+    mesh and options therefore traces, lowers, compiles and loads nothing
+    (no ``compile`` span in its run directory); a call with another mesh
+    or option builds what it lacks beside the rest.  Programs at an
+    outgrown visited capacity are NOT evicted (unlike engine.check's):
+    every call replays the capacity ladder from its start and asks for
+    them again.  Everything else starts fresh in every call, exactly as
+    before: the visited shards and their capacity ladder, the frontier,
+    the adaptive compact widths (``AdaptiveCompact``), the
+    per-destination width floor (``w_extra``), the level-new and pooled
+    width high waters of the device path (``_ln_hw``, ``pool.hw``), the
+    digest chain, checkpoints and workers — so two calls with the same
+    arguments walk the same sequence of program shapes, and a resumed or
+    elastic call may build programs the cache lacks.
     """
+    t_check = _now()  # the root `check` span starts at the first line
     # encoding-soundness gate (analysis; KSPEC_ANALYZE=0 disables) —
     # same refusal contract as engine.check, memoized per model name
     from ..analysis import require_encoding_sound
@@ -1592,12 +1651,71 @@ def check_sharded(
         hb_dir = os.path.join(run.dir, "shards")
     if run is not None and not is_coordinator():
         run = None
-    obs_ = RunObserver(run, stats_path, engine="sharded")
+    # (root span `check` from this function's first line; `check-open`
+    # until the first level begins, `check-close` after the last)
+    obs_ = RunObserver(run, stats_path, engine="sharded",
+                       annotate=jax.profiler.TraceAnnotation)
+    obs_.check_begin(t_check, model=model.name)
+    # counted transfers + named dispatches (engine/hostio.py)
+    io = HostIO(obs_, fetch=fetch_global, put=put_global)
     spec = model.spec
-    expander = _Step(model)  # width bookkeeping only; steps build their own
+    # width bookkeeping, and the OWNER of every compiled program of this
+    # engine: `expander.cached` keeps them in the model's step cache
+    # (the one PreparedKernels wraps), so a later call on the same model,
+    # mesh and program-shaping options finds what this call built
+    expander = _Step(model)
     C = expander.C
     K = spec.num_lanes
+    # explicit per-tensor mesh layouts (mesh_layouts; asserted in
+    # tests/test_sharded_device.py)
+    layouts = mesh_layouts(mesh)
 
+    def _first_violation(rows: np.ndarray):
+        """The invariant pass over host-held rows -> (invariant, row
+        index) of the first invariant, in declaration order, that some
+        row violates, or None.  One jitted program per padded row count
+        (rows sharded over the mesh; XLA partitions it), kept with the
+        level programs — so it costs a launch, not an eager dispatch per
+        operation of every predicate."""
+        n = rows.shape[0]
+        N = D * _next_pow2(max(-(-n // D), 8))
+
+        def build():
+            def invariant_rows(padded, n_valid):  # kspec: traced
+                with stage("invariants"):
+                    live = jnp.arange(N) < n_valid
+                    states = jax.vmap(spec.unpack)(padded)
+                    bad = [
+                        live & ~jax.vmap(inv.pred)(states)
+                        for inv in model.invariants
+                    ]
+                    return (
+                        jnp.stack([jnp.any(b) for b in bad]),
+                        jnp.stack([jnp.argmax(b) for b in bad]),
+                    )
+
+            return invariant_rows
+
+        fn = expander.cached(
+            (INVARIANT_TAG, mesh, N, expander.inv_sig(True)), build,
+            program=INVARIANT_TAG, bucket=N,
+        )
+        padded = np.zeros((N, K), np.uint32)
+        padded[:n] = rows
+        # (a span and a profiler annotation, not a level's dispatch: it
+        # runs before the first level and after the last)
+        launch = obs_.dispatch(INVARIANT_TAG, bucket=N)
+        any_bad, first = fn(
+            io.put(padded, layouts["frontier"]), np.int32(n)
+        )
+        any_bad = io.fetch(any_bad)
+        launch.finish()
+        if not any_bad.any():
+            return None
+        i = int(np.argmax(any_bad))
+        return model.invariants[i], int(io.fetch(first)[i])
+
+    sp_ = obs_.open_span("init-states")
     inits = [
         {k: np.asarray(v, np.int32) for k, v in s.items()} for s in model.init_states()
     ]
@@ -1605,38 +1723,40 @@ def check_sharded(
         np.stack([np.asarray(spec.pack(s)) for s in inits]), axis=0
     )
     n0 = init_packed.shape[0]
+    sp_.finish()
 
     t0 = time.perf_counter()
     # invariants on the init states (semantics must match engine.check)
     if model.invariants:
-        st0 = jax.vmap(spec.unpack)(jnp.asarray(init_packed))
-        for inv in model.invariants:
-            ok = np.asarray(jax.vmap(inv.pred)(st0))
-            if not ok.all():
-                idx = int(np.argmax(~ok))
-                st = {
-                    k: np.asarray(v)
-                    for k, v in spec.unpack(jnp.asarray(init_packed[idx])).items()
-                }
-                dec = model.decode(st) if model.decode else st
-                res = CheckResult(
-                    model.name,
-                    [n0],
-                    n0,
-                    0,
-                    Violation(
-                        invariant=inv.name,
-                        depth=0,
-                        state=dec,
-                        trace=[("<init>", dec)],
-                    ),
-                    time.perf_counter() - t0,
-                    0.0,
-                    stats={"devices": D},
-                )
-                obs_.finish(res)
-                obs_.close()
-                return res
+        sp_ = obs_.open_span("host-invariants", rows=n0)
+        bad0 = _first_violation(init_packed)
+        if bad0 is not None:
+            inv, idx = bad0
+            st = {
+                k: np.asarray(v)
+                for k, v in spec.unpack(jnp.asarray(init_packed[idx])).items()
+            }
+            dec = model.decode(st) if model.decode else st
+            res = CheckResult(
+                model.name,
+                [n0],
+                n0,
+                0,
+                Violation(
+                    invariant=inv.name,
+                    depth=0,
+                    state=dec,
+                    trace=[("<init>", dec)],
+                ),
+                time.perf_counter() - t0,
+                0.0,
+                stats={"devices": D},
+            )
+            sp_.finish()
+            obs_.finish(res)
+            obs_.close()
+            return res
+        sp_.finish()
     from ..storage import resolve_store
 
     use_disk = resolve_store(store, mem_budget)
@@ -1771,7 +1891,6 @@ def check_sharded(
     depth = 0
     violation = None
     result_levels: list = []  # per-level stats records (mirrors engine.check)
-    steps = {}
     w_extra = 0  # extra doublings of the all_to_all per-destination width
     exch_bytes_total = 0  # exchange wire bytes actually moved (all_to_all)
     exch_raw_bytes_total = 0  # ... and the raw-layout bytes at same widths
@@ -2175,14 +2294,12 @@ def check_sharded(
         chain.fold(_integ.pair_u64(hi0, lo0))
         chain.seal(0, n0)
 
-    # explicit per-tensor mesh layouts (mesh_layouts; asserted in
-    # tests/test_sharded_device.py): shard1 keeps its historical name as
-    # the [D, cap] per-shard-table layout for the growth helpers
-    layouts = mesh_layouts(mesh)
+    # shard1 keeps its historical name as the [D, cap] per-shard-table
+    # layout for the growth helpers
     shard1 = layouts["fpset"]
-    dev_vhi = put_global(vhi, layouts["fpset"])
-    dev_vlo = put_global(vlo, layouts["fpset"])
-    dev_vn = put_global(vn, layouts["pershard"])
+    dev_vhi = io.put(vhi, layouts["fpset"])
+    dev_vlo = io.put(vlo, layouts["fpset"])
+    dev_vn = io.put(vn, layouts["pershard"])
 
     # async-checkpoint bookkeeping (KSPEC_OVERLAP; mirrors engine.bfs):
     # `last_ckpt_depth` = submitted, `ckpt_durable_depth` = promoted.
@@ -2378,8 +2495,8 @@ def check_sharded(
         elif visited_backend == "device-hash":
             # dump each shard's live pairs (slot order is rebuilt on
             # resume by reinsertion)
-            th = fetch_global(dev_vhi)
-            tl = fetch_global(dev_vlo)
+            th = io.fetch(dev_vhi)
+            tl = io.fetch(dev_vlo)
             live = ~((th == hashset.SENT) & (tl == hashset.SENT))
             extra = {
                 "hash_hi": th[live],
@@ -2388,10 +2505,10 @@ def check_sharded(
             }
         else:
             # trim the common sentinel tail (rebuilt on resume from vcap)
-            vn_np = fetch_global(dev_vn)
+            vn_np = io.fetch(dev_vn)
             extra = {
-                "vhi": fetch_global(dev_vhi)[:, : int(vn_np.max())],
-                "vlo": fetch_global(dev_vlo)[:, : int(vn_np.max())],
+                "vhi": io.fetch(dev_vhi)[:, : int(vn_np.max())],
+                "vlo": io.fetch(dev_vlo)[:, : int(vn_np.max())],
                 "vn": vn_np,
             }
         if chain is not None and chain.anchored:
@@ -2640,6 +2757,10 @@ def check_sharded(
             # gauge and the device path's O(1)/level contract)
             lvl_dispatches = 0
             lvl_probe_ms = 0.0  # deferred batched host-probe wall
+            # step_ms / host_ms of the level record, as engine.check's:
+            # step = dispatch + the blocking wait on a program's flags;
+            # host = frontier assembly before it and the commit after it
+            prof_step = prof_host_s = 0.0
             offs = [0] * D
             # base offset of each shard's rows in this level's shard-major order
             prev_base = np.concatenate([[0], np.cumsum([p.shape[0] for p in pending])])
@@ -2648,9 +2769,11 @@ def check_sharded(
             def _build_chunk():
                 """Assemble the next chunk's per-shard frontier slice, or
                 None when the level is exhausted."""
+                nonlocal prof_host_s
                 rem = max(p.shape[0] - o for p, o in zip(pending, offs))
                 if rem <= 0:
                     return None
+                t_build = time.perf_counter()
                 governor.poll(depth)  # deadline watchdog (cheap)
                 bucket = min(_next_pow2(max(rem, min_bucket // D, 32)), chunk)
                 frontier = np.zeros((D, bucket, K), np.uint32)
@@ -2662,8 +2785,11 @@ def check_sharded(
                     took[d] = rows.shape[0]
                     offs[d] += rows.shape[0]
                 fvalid = np.arange(bucket)[None, :] < took[:, None]
+                t_chunk = time.perf_counter()
+                prof_host_s += t_chunk - t_build
+                # (the last slot counts this chunk's dispatch attempts)
                 return [bucket, frontier, took, chunk_off, fvalid,
-                        time.perf_counter()]
+                        t_chunk, 0]
 
             def _attempt_once(ctx, attempt, w_try, compress=None):
                 """Dispatch ONE attempt of a chunk (no flag fetches) with
@@ -2681,11 +2807,13 @@ def check_sharded(
                 results stay exact at every width.  Width retries are
                 CHUNK-LOCAL (learned floors persist)."""
                 nonlocal vcap, dev_vhi, dev_vlo, chunk, adaptive_fallback
-                nonlocal lvl_dispatches
+                nonlocal lvl_dispatches, prof_step
                 if compress is None:
                     compress = compress_on
                 bucket = ctx[0]
+                t_att = time.perf_counter()
                 while True:
+                    launch = None
                     if isinstance(attempt, int):
                         ca = _norm_shift(bucket, attempt) or None
                     else:
@@ -2698,19 +2826,23 @@ def check_sharded(
                         # probing stays short (shard_visited is host-tracked)
                         if 2 * int(shard_visited.max()) > vcap:
                             dev_vhi, dev_vlo, vcap = _grow_hash_tables(
-                                dev_vhi, dev_vlo, 2 * vcap, shard1
+                                dev_vhi, dev_vlo, 2 * vcap, shard1, io
                             )
                     if visited_backend == "device":
                         # grow per-shard visited capacity for the worst-case merge
                         # (one shared growth path with the device level driver)
-                        need = int(fetch_global(dev_vn).max()) + R
+                        need = int(io.fetch(dev_vn).max()) + R
                         if need > vcap:
                             dev_vhi, dev_vlo, vcap = _grow_sorted_shards(
                                 dev_vhi, dev_vlo, vcap, _next_pow2(need),
-                                layouts["fpset"],
+                                layouts["fpset"], io,
                             )
 
-                    key = (bucket, vcap, ca, exchange, W, compress)
+                    # everything that shapes the program: the cache
+                    # outlives this call (it is the model's)
+                    key = (STEP_TAG, mesh, bucket, vcap, ca, exchange, W,
+                           compress, visited_backend,
+                           expander.inv_sig(True))
                     try:
                         # exchange-step fault injection point (the jitted step
                         # below carries the all_to_all/all_gather exchange)
@@ -2719,8 +2851,9 @@ def check_sharded(
                         )
                         if injected is not None:
                             raise injected
-                        if key not in steps:
-                            steps[key] = _make_sharded_step(
+                        fn = expander.cached(
+                            key,
+                            lambda: _make_sharded_step(
                                 model,
                                 mesh,
                                 bucket,
@@ -2731,22 +2864,26 @@ def check_sharded(
                                 with_merge=visited_backend == "device",
                                 hash_table=visited_backend == "device-hash",
                                 compress=compress,
-                            )
-                        outs = steps[key](
-                            put_global(
-                                ctx[1].reshape(D * bucket, K),
-                                layouts["frontier"],
                             ),
-                            put_global(
-                                ctx[4].reshape(D * bucket),
-                                layouts["fvalid"],
-                            ),
-                            dev_vhi,
-                            dev_vlo,
-                            dev_vn,
+                            program=STEP_TAG, bucket=bucket, vcap=vcap,
                         )
+                        rows_d = io.put(
+                            ctx[1].reshape(D * bucket, K),
+                            layouts["frontier"],
+                        )
+                        valid_d = io.put(
+                            ctx[4].reshape(D * bucket), layouts["fvalid"]
+                        )
+                        launch = io.dispatch(
+                            STEP_TAG, attempt=ctx[6], depth=depth,
+                            bucket=bucket, vcap=vcap,
+                        )
+                        ctx[6] += 1
+                        outs = fn(rows_d, valid_d, dev_vhi, dev_vlo, dev_vn)
                         lvl_dispatches += 1
                     except Exception as e:  # noqa: BLE001 — XLA compile/run
+                        if launch is not None:
+                            launch.finish(discarded=True)
                         # one failure policy for both engines (resilience
                         # .retry.ChunkRetryHandler): transient -> bounded-
                         # backoff re-run of the same attempt (the functional
@@ -2773,11 +2910,13 @@ def check_sharded(
                             # lone process shrinking would desync the fleet)
                             chunk = max(_next_pow2(max(32, min_bucket // D)),
                                         chunk >> 1)
-                        steps.pop(key, None)
+                        expander._cache.pop(key, None)
                         attempt = adapt.compile_fallback(bucket)
                         adaptive_fallback = True
                         continue
-                    return outs, (attempt, w_try, ca, T, W, R, compress)
+                    prof_step += time.perf_counter() - t_att
+                    return outs, (attempt, w_try, ca, T, W, R, compress,
+                                  launch)
 
             def _flags_retry(ctx, outs, meta):
                 """Fetch the attempt's overflow flags; -> None when it
@@ -2785,11 +2924,11 @@ def check_sharded(
                 with (applying the escalation/widening/table-growth
                 policy — see _attempt_once's docstring)."""
                 nonlocal vcap, dev_vhi, dev_vlo
-                attempt, w_try, ca, T, W, R, compress = meta
+                attempt, w_try, ca, T, W, R, compress, _launch = meta
                 ovf_expand, act_guard = outs[12], outs[13]
                 ovf_dest, ovf_probe = outs[14], outs[15]
                 if ca is not None:
-                    ovf_np = fetch_global(ovf_expand)  # [D, n_actions]
+                    ovf_np = io.fetch(ovf_expand)  # [D, n_actions]
                     if ovf_np.any():
                         return (
                             adapt.escalate(
@@ -2797,13 +2936,13 @@ def check_sharded(
                                 ovf_np.any(axis=0),
                                 ctx[0],
                                 _shard_density(
-                                    fetch_global(act_guard), ctx[2]
+                                    io.fetch(act_guard), ctx[2]
                                 ),
                             ),
                             w_try,
                             compress,
                         )
-                if exchange == "all_to_all" and fetch_global(
+                if exchange == "all_to_all" and io.fetch(
                     ovf_dest
                 ).any():
                     if W < T:
@@ -2817,14 +2956,14 @@ def check_sharded(
                         # the wire layout changes)
                         return (attempt, w_try, False)
                 if visited_backend == "device-hash" and bool(
-                    fetch_global(ovf_probe).any()
+                    io.fetch(ovf_probe).any()
                 ):
                     # a shard exhausted its probe budget: grow every
                     # shard's table and re-run the chunk (the attempt's
                     # returned tables are discarded — the step is
                     # functional, so nothing was committed)
                     dev_vhi, dev_vlo, vcap = _grow_hash_tables(
-                        dev_vhi, dev_vlo, 2 * vcap, shard1
+                        dev_vhi, dev_vlo, 2 * vcap, shard1, io
                     )
                     return (attempt, w_try, compress)
                 return None
@@ -2833,10 +2972,15 @@ def check_sharded(
                 """Flag-check a dispatched chunk, re-running the ladder
                 synchronously on any overflow, then install the committed
                 attempt's visited arrays."""
-                nonlocal dev_vhi, dev_vlo, dev_vn
+                nonlocal dev_vhi, dev_vlo, dev_vn, prof_step
                 ctx, outs, meta = st
                 while True:
+                    t_wait = time.perf_counter()
                     nxt = _flags_retry(ctx, outs, meta)
+                    prof_step += time.perf_counter() - t_wait
+                    # the flag reads blocked on the program: its outputs
+                    # are in hand, to keep or (an overflow) to throw away
+                    meta[-1].finish(discarded=nxt is not None)
                     if nxt is None:
                         break
                     outs, meta = _attempt_once(
@@ -2854,8 +2998,8 @@ def check_sharded(
                 nonlocal lvl_en_per_shard, lvl_recv_per_shard
                 nonlocal shard_visited, lvl_exch_bytes, lvl_exch_raw_bytes
                 ctx, outs, meta = st
-                bucket, frontier, took, chunk_off, _fv, t_chunk = ctx
-                _attempt, _wt, _ca, T, W, R, compress = meta
+                bucket, frontier, took, chunk_off, _fv, t_chunk, _n = ctx
+                _attempt, _wt, _ca, T, W, R, compress, _launch = meta
                 (
                     out, out_parent, out_act, new_n, _vh, _vl, _vn,
                     viol_any, viol_idx, dl_any, dl_idx, act_en,
@@ -2873,8 +3017,8 @@ def check_sharded(
                 # over the DECODED payload, so the codec + headers are
                 # inside the protection boundary.
                 if chain is not None:
-                    sd = np.asarray(fetch_global(sent_dig), np.uint32)
-                    rd = np.array(fetch_global(recv_dig), np.uint32)
+                    sd = np.asarray(io.fetch(sent_dig), np.uint32)
+                    rd = np.array(io.fetch(recv_dig), np.uint32)
                     sp = fault.flip(
                         "exchange", depth + 1, ckpt_depth=ckpt_durable_depth
                     )
@@ -2893,7 +3037,7 @@ def check_sharded(
                         )
                 # adapt buffer sizing from the committed attempt's guard counts
                 # (mirrors engine.check; no-op until escalation activates)
-                adapt.observe(_shard_density(fetch_global(act_guard), took))
+                adapt.observe(_shard_density(io.fetch(act_guard), took))
                 # exchange wire accounting (ROADMAP item 5's measure):
                 # bytes this chunk's all_to_all actually moved vs the raw
                 # (uncompressed) layout's bytes at the same widths
@@ -2922,21 +3066,21 @@ def check_sharded(
                     compressed=compress,
                 )
                 # frontier-level verdicts (states being expanded = level `depth`)
-                viol_any_np = fetch_global(viol_any)  # [D, n_inv]
+                viol_any_np = io.fetch(viol_any)  # [D, n_inv]
                 if viol_any_np.any():
                     inv_i = int(np.argmax(viol_any_np.any(axis=0)))
                     d = int(np.argmax(viol_any_np[:, inv_i]))
-                    idx = int(fetch_global(viol_idx)[d, inv_i])
+                    idx = int(io.fetch(viol_idx)[d, inv_i])
                     gidx = int(prev_base[d] + chunk_off[d] + idx)
                     verdict = (model.invariants[inv_i].name, frontier[d, idx], gidx)
                     return True
-                if check_deadlock and fetch_global(dl_any).any():
-                    d = int(np.argmax(fetch_global(dl_any)))
-                    idx = int(fetch_global(dl_idx)[d])
+                if check_deadlock and io.fetch(dl_any).any():
+                    d = int(np.argmax(io.fetch(dl_any)))
+                    idx = int(io.fetch(dl_idx)[d])
                     gidx = int(prev_base[d] + chunk_off[d] + idx)
                     verdict = ("Deadlock", frontier[d, idx], gidx)
                     return True
-                counts = fetch_global(new_n)
+                counts = io.fetch(new_n)
                 # received candidates per OWNER shard (post-exchange, pre-host-
                 # dedup on the host backend; == novel on device backends)
                 lvl_recv_per_shard += counts.astype(np.int64)
@@ -2944,13 +3088,13 @@ def check_sharded(
                 # device-side slice to the widest shard before the host copy —
                 # the padded buffer is mostly empty
                 cmax = int(counts.max())
-                out3 = fetch_global(out.reshape(D, M_per, K)[:, :cmax])
+                out3 = io.fetch(out.reshape(D, M_per, K)[:, :cmax])
                 if collect_trace:
-                    parent_np = fetch_global(out_parent.reshape(D, M_per)[:, :cmax])
-                    act_np = fetch_global(out_act.reshape(D, M_per)[:, :cmax])
+                    parent_np = io.fetch(out_parent.reshape(D, M_per)[:, :cmax])
+                    act_np = io.fetch(out_act.reshape(D, M_per)[:, :cmax])
                 if host_sets is not None and cmax:
-                    hi3 = fetch_global(out_hi.reshape(D, M_per)[:, :cmax])
-                    lo3 = fetch_global(out_lo.reshape(D, M_per)[:, :cmax])
+                    hi3 = io.fetch(out_hi.reshape(D, M_per)[:, :cmax])
+                    lo3 = io.fetch(out_lo.reshape(D, M_per)[:, :cmax])
                     # global dedup: each shard's OWNER process inserts into its
                     # FpSet (batch dedup already happened on device; insert()
                     # returns the first-time mask); the masks are OR-merged so
@@ -3003,10 +3147,19 @@ def check_sharded(
                 lvl_new_per_shard += newc
                 shard_visited += newc
                 if obs_.collect:
-                    act_en_np = fetch_global(act_en).astype(np.int64)
+                    act_en_np = io.fetch(act_en).astype(np.int64)
                     lvl_act_en += act_en_np.sum(axis=0)
                     lvl_en_per_shard += act_en_np.sum(axis=1)
                 return False
+
+            def _commit_timed(st):
+                """_commit_sharded, its wall booked to `host_ms`."""
+                nonlocal prof_host_s
+                t_c = time.perf_counter()
+                try:
+                    return _commit_sharded(st)
+                finally:
+                    prof_host_s += time.perf_counter() - t_c
 
             def _run_device_level():
                 """The sharded device-resident level path (--pipeline
@@ -3025,6 +3178,7 @@ def check_sharded(
                 nonlocal lvl_recv_per_shard, shard_visited
                 nonlocal lvl_exch_bytes, lvl_exch_raw_bytes
                 nonlocal lvl_dispatches, lvl_probe_ms
+                nonlocal prof_step, prof_host_s
                 lens = [p.shape[0] for p in pending]
                 plan = sdev.plan_level(lens, chunk, min_bucket)
                 if plan is None:
@@ -3063,67 +3217,69 @@ def check_sharded(
                     fbuf[d, :n] = pending[d][:n]
                     flen[d] = n
                 pre_v = (dev_vhi, dev_vlo, dev_vn)
+                program = LEVEL_HOST_TAG if host_mode else LEVEL_TAG
+                inv_sig = expander.inv_sig(True)
+                operands = None
+                prof_host_s += time.perf_counter() - t0l
                 while True:
+                    t_att = time.perf_counter()
+                    launch = None
                     try:
                         injected = fault.chunk_error(escalated=True)
                         if injected is not None:
                             raise injected
-                        if host_mode:
-                            key = ("lvlh", B, NCp, widths, LN, W,
-                                   exchange, compress)
-                            if key not in steps:
-                                steps[key] = _make_sharded_level_host(
-                                    model, mesh, expander, B, NCp,
-                                    widths, LN, exchange, W, compress,
-                                    check_deadlock,
-                                )
-                            outs = steps[key](
-                                put_global(
-                                    fbuf.reshape(D * F, K),
-                                    layouts["frontier"],
-                                ),
-                                put_global(flen, layouts["pershard"]),
-                                put_global(
-                                    np.full(D, nc, np.int32),
-                                    layouts["pershard"],
-                                ),
+                        if operands is None:
+                            # uploaded once: a re-dispatch reads the
+                            # same device arrays
+                            operands = (
+                                io.put(fbuf.reshape(D * F, K),
+                                       layouts["frontier"]),
+                                io.put(flen, layouts["pershard"]),
+                                io.put(np.full(D, nc, np.int32),
+                                       layouts["pershard"]),
                             )
+                        if host_mode:
+                            # (no visited shards ride the host program)
+                            make, at_vcap = _make_sharded_level_host, ()
                         else:
                             need = int(
-                                fetch_global(pre_v[2]).max()
+                                io.fetch(pre_v[2]).max()
                             ) + min(nc * R, LN + R)
                             if need > vcap:
                                 g_hi, g_lo, vcap = _grow_sorted_shards(
                                     pre_v[0], pre_v[1], vcap,
-                                    _next_pow2(need), layouts["fpset"],
+                                    _next_pow2(need), layouts["fpset"], io,
                                 )
                                 pre_v = (g_hi, g_lo, pre_v[2])
-                            key = ("lvl", B, NCp, vcap, widths, LN, W,
-                                   exchange, compress)
-                            if key not in steps:
-                                steps[key] = _make_sharded_level(
-                                    model, mesh, expander, B, NCp,
-                                    vcap, widths, LN, exchange, W,
-                                    compress, check_deadlock,
-                                )
-                            outs = steps[key](
-                                put_global(
-                                    fbuf.reshape(D * F, K),
-                                    layouts["frontier"],
-                                ),
-                                put_global(flen, layouts["pershard"]),
-                                put_global(
-                                    np.full(D, nc, np.int32),
-                                    layouts["pershard"],
-                                ),
-                                pre_v[0], pre_v[1], pre_v[2],
-                            )
+                            make, at_vcap = _make_sharded_level, (vcap,)
+                        # everything that shapes the program: the cache
+                        # outlives this call (it is the model's)
+                        key = (program, mesh, B, NCp, *at_vcap, widths, LN,
+                               W, exchange, compress, inv_sig,
+                               check_deadlock)
+                        shape = dict(bucket=B, **dict(zip(("vcap",), at_vcap)))
+                        fn = expander.cached(
+                            key,
+                            lambda: make(
+                                model, mesh, expander, B, NCp, *at_vcap,
+                                widths, LN, exchange, W, compress,
+                                check_deadlock,
+                            ),
+                            program=program, **shape,
+                        )
+                        launch = io.dispatch(
+                            program, attempt=dispatched, depth=depth,
+                            chunks=nc, level_new_cap=LN, **shape,
+                        )
+                        outs = fn(*operands, *(() if host_mode else pre_v))
                         dispatched += 1
                         lvl_dispatches += 1
                         # the one device sync per level: the overflow-
                         # flag read forces the whole level program
-                        overflow = bool(fetch_global(outs[i_ovf]).any())
+                        overflow = bool(io.fetch(outs[i_ovf]).any())
                     except Exception as e:  # noqa: BLE001 — XLA
+                        if launch is not None:
+                            launch.finish(discarded=True)
                         action = chunk_retry.handle(
                             e, escalated=True, depth=depth,
                             retry_transient=not is_multiprocess(),
@@ -3134,11 +3290,14 @@ def check_sharded(
                             f"{type(e).__name__}: {e}"[:200], depth
                         )
                         return
-                    agmax_np = fetch_global(outs[i_agm]).max(
+                    agmax_np = io.fetch(outs[i_agm]).max(
                         axis=0
                     ).astype(np.int64)
-                    vk = int(fetch_global(outs[i_vk])[0])
-                    if overflow and vk == 0 and not exact:
+                    vk = int(io.fetch(outs[i_vk])[0])
+                    redo = overflow and vk == 0 and not exact
+                    launch.finish(discarded=redo)
+                    prof_step += time.perf_counter() - t_att
+                    if redo:
                         # a segment / destination bucket / codec budget
                         # / the level-new set overflowed: outputs are
                         # incomplete — discard and re-dispatch ONCE from
@@ -3161,9 +3320,10 @@ def check_sharded(
                 # committed: install the merged visited arrays (the
                 # host-mode program carries no visited shards — the
                 # host sets below ARE the visited state)
+                t_commit = time.perf_counter()
                 if not host_mode:
                     dev_vhi, dev_vlo, dev_vn = outs[4], outs[5], outs[6]
-                counts = fetch_global(outs[i_cnt]).astype(np.int64)  # [D]
+                counts = io.fetch(outs[i_cnt]).astype(np.int64)  # [D]
                 sdev.observe(agmax_np, B, int(counts.max()))
                 sdev.launches_last = dispatched
                 adapt.observe(agmax_np.astype(np.float64) / max(B, 1))
@@ -3178,8 +3338,8 @@ def check_sharded(
                 # a corruption in those chunks must still alarm, it
                 # must never be laundered by a later verdict
                 if chain is not None:
-                    sd = np.asarray(fetch_global(outs[i_sd]), np.uint32)
-                    rd = np.array(fetch_global(outs[i_rd]), np.uint32)
+                    sd = np.asarray(io.fetch(outs[i_sd]), np.uint32)
+                    rd = np.array(io.fetch(outs[i_rd]), np.uint32)
                     sp = fault.flip(
                         "exchange", depth + 1,
                         ckpt_depth=ckpt_durable_depth,
@@ -3213,7 +3373,7 @@ def check_sharded(
                 # committed dispatch's widths (same per-chunk formulas
                 # as the per-chunk path)
                 if exchange == "all_to_all":
-                    ncl = int(fetch_global(outs[i_ncl])[0])
+                    ncl = int(io.fetch(outs[i_ncl])[0])
                     raw_b = D * D * W * (8 + 4 * K + 4 + 4)
                     if compress:
                         from ..ops import fpcompress as _fpc
@@ -3229,9 +3389,9 @@ def check_sharded(
                     lvl_exch_bytes += ncl * sent_b
                     lvl_exch_raw_bytes += ncl * raw_b
                 if vk:
-                    d = int(fetch_global(outs[i_vd])[0])
-                    inv_i = int(fetch_global(outs[i_vinv])[0])
-                    lidx = int(fetch_global(outs[i_vix])[0])
+                    d = int(io.fetch(outs[i_vd])[0])
+                    inv_i = int(io.fetch(outs[i_vinv])[0])
+                    lidx = int(io.fetch(outs[i_vix])[0])
                     gidx = int(prev_base[d] + lidx)
                     name = (
                         model.invariants[inv_i].name
@@ -3242,18 +3402,19 @@ def check_sharded(
                     for d2 in range(D):
                         # the serial break: the tail is never dispatched
                         offs[d2] = lens[d2]
+                    prof_host_s += time.perf_counter() - t_commit
                     return
                 OC = LN + R
                 cmax = int(counts.max())
                 if cmax:
-                    out3 = fetch_global(
+                    out3 = io.fetch(
                         outs[0].reshape(D, OC, K)[:, :cmax]
                     )
                     if collect_trace:
-                        par3 = fetch_global(
+                        par3 = io.fetch(
                             outs[1].reshape(D, OC)[:, :cmax]
                         )
-                        act3 = fetch_global(
+                        act3 = io.fetch(
                             outs[2].reshape(D, OC)[:, :cmax]
                         )
                 if host_mode:
@@ -3268,10 +3429,10 @@ def check_sharded(
                     t_probe = time.perf_counter()
                     masks = np.zeros((D, max(cmax, 1)), bool)
                     if cmax:
-                        hi3 = fetch_global(
+                        hi3 = io.fetch(
                             outs[3].reshape(D, OC)[:, :cmax]
                         )
-                        lo3 = fetch_global(
+                        lo3 = io.fetch(
                             outs[4].reshape(D, OC)[:, :cmax]
                         )
                         for d in range(D):
@@ -3355,22 +3516,23 @@ def check_sharded(
                         # over the same rows
                         _integ.fold_shard_device_digests(
                             chain,
-                            fetch_global(outs[13]),
-                            fetch_global(outs[14]),
-                            fetch_global(outs[15]),
-                            fetch_global(outs[16]),
+                            io.fetch(outs[13]),
+                            io.fetch(outs[14]),
+                            io.fetch(outs[15]),
+                            io.fetch(outs[16]),
                         )
                     lvl_new_per_shard += counts
                     lvl_recv_per_shard += counts
                     shard_visited += counts
                 if obs_.collect:
-                    act_en_np = fetch_global(outs[i_aen]).astype(
+                    act_en_np = io.fetch(outs[i_aen]).astype(
                         np.int64
                     )
                     lvl_act_en += act_en_np.sum(axis=0)
                     lvl_en_per_shard += act_en_np.sum(axis=1)
                 for d in range(D):
                     offs[d] = min(nc * B, lens[d])
+                prof_host_s += time.perf_counter() - t_commit
 
             if sdev is not None and sdev.fallback is None:
                 # Device-resident level path: one dispatched while_loop
@@ -3411,17 +3573,21 @@ def check_sharded(
                     )
                     if staged_sh is not None:
                         _resolve_chunk(staged_sh)
-                        if _commit_sharded(staged_sh):
+                        if _commit_timed(staged_sh):
                             staged_sh = None
                             break
                     staged_sh = cur
                 else:
                     _resolve_chunk(cur)
-                    if _commit_sharded(cur):
+                    if _commit_timed(cur):
                         break
             if staged_sh is not None and verdict is None:
                 _resolve_chunk(staged_sh)
-                _commit_sharded(staged_sh)
+                _commit_timed(staged_sh)
+            elif staged_sh is not None:
+                # a verdict cut the level with a chunk still staged: its
+                # outputs are never read
+                staged_sh[2][-1].finish(discarded=True)
             staged_sh = None
 
             if verdict is not None:
@@ -3489,6 +3655,12 @@ def check_sharded(
                     # (= launches PER SHARD; in-memory only, like the
                     # launch counters of the single-device engine)
                     "shard_launches": int(lvl_dispatches),
+                    # the single-device engine's host/device split and
+                    # what the host launched and moved this level
+                    # (engine/hostio.py; docs/observability.md)
+                    "step_ms": round(prof_step * 1e3, 1),
+                    "host_ms": round(prof_host_s * 1e3, 1),
+                    **io.take(),
                     # deferred batched host-probe attribution (host-
                     # backend device path; in-memory records + gauge/
                     # span side channels only)
@@ -3505,6 +3677,8 @@ def check_sharded(
                 _met.set_gauge(
                     "kspec_shard_launches_level", int(lvl_dispatches)
                 )
+                _met.inc("kspec_step_ms_total", round(prof_step * 1e3, 1))
+                _met.inc("kspec_host_ms_total", round(prof_host_s * 1e3, 1))
                 if lvl_probe_ms:
                     _met.set_gauge(
                         "kspec_host_probe_ms", round(lvl_probe_ms, 2)
@@ -3604,6 +3778,7 @@ def check_sharded(
         # injected paths: same typed clean exit (every writer cleans
         # up its tmp on failure, so the promoted state is intact)
         exhausted = ResourceExhausted("enospc", str(e), depth=depth)
+    obs_.check_closing()
     if integrity_fail is not None:
         # typed terminal (resilience.integrity): stamp the run manifest +
         # shard heartbeat, then propagate for the CLI's exit-76 mapping;
@@ -3664,20 +3839,19 @@ def check_sharded(
         # (shard-major order matches trace_store's level layout)
         rows = np.concatenate(pending) if pending else np.empty((0, K), np.uint32)
         if rows.shape[0]:
-            st = jax.vmap(spec.unpack)(jnp.asarray(rows))
-            for inv in model.invariants:
-                ok = np.asarray(jax.vmap(inv.pred)(st))
-                if not ok.all():
-                    idx = int(np.argmax(~ok))
-                    violation = build_violation(
-                        inv.name, depth, idx
-                    ) or Violation(
-                        invariant=inv.name,
-                        depth=depth,
-                        state=decode_row(rows[idx]),
-                        trace=[],
-                    )
-                    break
+            sp_ = obs_.open_span("host-invariants", rows=int(rows.shape[0]))
+            bad = _first_violation(rows)
+            if bad is not None:
+                inv, idx = bad
+                violation = build_violation(
+                    inv.name, depth, idx
+                ) or Violation(
+                    invariant=inv.name,
+                    depth=depth,
+                    state=decode_row(rows[idx]),
+                    trace=[],
+                )
+            sp_.finish()
 
     dt = time.perf_counter() - t0
     _shutdown_async(drain=True)

@@ -241,12 +241,16 @@ def test_sharded_per_shard_breakdowns_and_imbalance(tmp_path):
         assert sum(rec["shard_frontier"]) == rec["frontier"]
         assert sum(rec["shard_enabled"]) == rec["enabled_candidates"]
     # result.stats['levels'] additionally carries the PR 10 exchange/
-    # overlap accounting — in-memory only, never in the pinned stream
+    # overlap accounting and the host/device split and transfer counters
+    # of engine/hostio.py — in-memory only, never in the pinned stream
+    from kafka_specification_tpu.engine.hostio import LEVEL_COUNTERS
+
     assert [
         {k: v for k, v in r.items()
          if k not in ("exch_bytes", "exch_raw_bytes", "io_hidden_ms",
                       "io_exposed_ms", "shard_launches",
-                      "host_probe_ms")}
+                      "host_probe_ms", "step_ms", "host_ms")
+         + LEVEL_COUNTERS}
         for r in res.stats["levels"]
     ] == recs
     prom = open(run.metrics_prom).read()
