@@ -41,7 +41,6 @@ from ..obs import metrics as _met
 from ..obs.observer import RunObserver
 from ..obs.tracer import now as _now
 from ..ops import hashset
-from ..ops.fingerprint import fingerprint_lanes
 from ..resilience import integrity as _integ
 from ..resilience.checkpoints import CheckpointStore
 from ..resilience.faults import FaultPlan
@@ -57,6 +56,8 @@ from .hostio import HostIO
 from .pipeline import (
     fp_stage,
     grow_visited as _grow_visited,
+    init_rows_program,
+    invariant_rows_program,
     invariant_stage,
     make_pipeline,
     program_name,
@@ -534,6 +535,61 @@ class _Step:
                 jax.jit(fn), self._cache, key, **attrs
             )
         return self._cache[key]
+
+    def first_violation(self, key_head: tuple, N: int, rows: np.ndarray,
+                        io: HostIO, obs_: RunObserver, *where):
+        """The invariant pass over host-held `rows` -> (invariant, row
+        index) of the first invariant, in declaration order, that some
+        row violates (within it the lowest row), or None.  One jitted
+        program per padded row count `N` (pipeline.invariant_rows_program),
+        kept with the level programs under (*key_head, N, inv_sig) — so
+        it costs a launch, not an eager dispatch per operation of every
+        predicate.  `key_head`: the cache tag, then whatever else shapes
+        the program (the sharded engine's mesh); `where`: that engine's
+        placement, handed to ``io.put``."""
+        tag = key_head[0]
+        fn = self.cached(
+            (*key_head, N, self.inv_sig(True)),
+            lambda: invariant_rows_program(self.model, N),
+            program=tag, bucket=N,
+        )
+        # (a span and a profiler annotation, not a level's dispatch: it
+        # runs before the first level and after the last)
+        launch = obs_.dispatch(tag, bucket=N)
+        any_bad, first = fn(
+            io.put(_pad_rows(rows, N), *where), np.int32(rows.shape[0])
+        )
+        any_bad = io.fetch(any_bad)
+        launch.finish()
+        if not any_bad.any():
+            return None
+        i = int(np.argmax(any_bad))
+        return self.model.invariants[i], int(io.fetch(first)[i])
+
+    def init_rows(self, io: HostIO, obs_: RunObserver):
+        """The model's distinct initial states -> (rows u32[n0, K], hi,
+        lo), numpy, rows in ``np.unique`` order.  Pack and fingerprint
+        run as one cached program per padded state count; the padding
+        repeats the first state, which ``np.unique`` drops again."""
+        inits = [
+            {k: np.asarray(v, np.int32) for k, v in s.items()}
+            for s in self.model.init_states()
+        ]
+        N = _next_pow2(len(inits))
+        inits += inits[:1] * (N - len(inits))
+        fn = self.cached(
+            ("init", N), lambda: init_rows_program(self.spec),
+            program="init", bucket=N,
+        )
+        launch = obs_.dispatch("init", bucket=N)
+        rows, hi, lo = fn(
+            {k: io.put(np.stack([s[k] for s in inits])) for k in inits[0]}
+        )
+        rows = io.fetch(rows)
+        launch.finish()
+        # dedup inits (all corpus models have a single deterministic init)
+        rows, at = np.unique(rows, axis=0, return_index=True)
+        return rows, io.fetch(hi)[at], io.fetch(lo)[at]
 
     def get(
         self,
@@ -1229,15 +1285,10 @@ def check(
         # seed do not exist, so traces cannot be reconstructed
         store_trace = False
 
-    # (child span: the eager pack + fingerprint of the initial states is
-    # 55-73 ms on the chip, PERF.md section 5)
+    t0 = time.perf_counter()
     sp_ = obs_.open_span("init-states")
-    inits = [
-        {k: np.asarray(v, np.int32) for k, v in s.items()} for s in model.init_states()
-    ]
-    init_packed = np.stack([np.asarray(spec.pack(s)) for s in inits])
-    # dedup inits (all corpus models have a single deterministic init)
-    init_packed = np.unique(init_packed, axis=0)
+    init_packed, hi0, lo0 = step_builder.init_rows(io, obs_)
+    sp_.finish()
     n0 = init_packed.shape[0]
 
     if visited_backend not in ("device", "host", "device-hash"):
@@ -1258,9 +1309,6 @@ def check(
             lo
         ).astype(np.uint64)
 
-    t0 = time.perf_counter()
-    hi0, lo0 = fingerprint_lanes(jnp.asarray(init_packed), spec.exact64)
-    sp_.finish()
     disk = None
     ephemeral_spill = None
     if visited_backend == "host":
@@ -1390,31 +1438,38 @@ def check(
     def have_trace(depth) -> bool:
         return store_trace or (disk is not None and disk.has_trace(depth))
 
+    def first_violation(rows: np.ndarray):
+        """The invariant pass over host-held rows (the initial states; the
+        frontier a cut left unexpanded): one launch of a cached program
+        per power-of-two row bucket -> (invariant, row index) or None."""
+        sp_ = obs_.open_span("host-invariants", rows=rows.shape[0])
+        bad = step_builder.first_violation(
+            ("hinv",), _next_pow2(max(rows.shape[0], min_bucket)),
+            rows, io, obs_,
+        )
+        sp_.finish()
+        return bad
+
     # invariants on init states
     if check_invariants and model.invariants:
-        # (child span: op-by-op dispatch, ~120 ms on the chip for one row)
-        sp_ = obs_.open_span("host-invariants", rows=n0)
-        st0 = jax.vmap(spec.unpack)(jnp.asarray(init_packed))
-        for inv in model.invariants:
-            ok = np.asarray(jax.vmap(inv.pred)(st0))
-            if not ok.all():
-                idx = int(np.argmax(~ok))
-                dt = time.perf_counter() - t0
-                viol = Violation(
-                    invariant=inv.name,
-                    depth=0,
-                    state=decode_state(init_packed[idx]),
-                    trace=[("<init>", decode_state(init_packed[idx]))],
-                )
-                _drop_ephemeral_spill()
-                _shutdown_async(drain=True)
-                res = CheckResult(
-                    model.name, levels, total, 0, viol, dt, total / max(dt, 1e-9)
-                )
-                obs_.finish(res)
-                obs_.close()
-                return res
-        sp_.finish()
+        bad0 = first_violation(init_packed)
+        if bad0 is not None:
+            inv, idx = bad0
+            dt = time.perf_counter() - t0
+            viol = Violation(
+                invariant=inv.name,
+                depth=0,
+                state=decode_state(init_packed[idx]),
+                trace=[("<init>", decode_state(init_packed[idx]))],
+            )
+            _drop_ephemeral_spill()
+            _shutdown_async(drain=True)
+            res = CheckResult(
+                model.name, levels, total, 0, viol, dt, total / max(dt, 1e-9)
+            )
+            obs_.finish(res)
+            obs_.close()
+            return res
 
     frontier_np = init_packed
     depth = 0
@@ -2929,24 +2984,19 @@ def check(
     if violation is None and check_invariants and model.invariants and _f_rows(frontier_np):
         # the loop was cut (max_depth/max_states) before the remaining
         # frontier was expanded — its states still need their invariant pass
-        sp_ = obs_.open_span("host-invariants", rows=_f_rows(frontier_np))
-        st = jax.vmap(spec.unpack)(jnp.asarray(_f_all(frontier_np)))
-        for inv in model.invariants:
-            ok = np.asarray(jax.vmap(inv.pred)(st))
-            if not ok.all():
-                idx = int(np.argmax(~ok))
-                violation = (
-                    build_violation(inv.name, depth, idx)
-                    if have_trace(depth)
-                    else Violation(
-                        invariant=inv.name,
-                        depth=depth,
-                        state=decode_state(_f_row(frontier_np, idx)),
-                        trace=[],
-                    )
+        bad = first_violation(_f_all(frontier_np))
+        if bad is not None:
+            inv, idx = bad
+            violation = (
+                build_violation(inv.name, depth, idx)
+                if have_trace(depth)
+                else Violation(
+                    invariant=inv.name,
+                    depth=depth,
+                    state=decode_state(_f_row(frontier_np, idx)),
+                    trace=[],
                 )
-                break
-        sp_.finish()
+            )
 
     dt = time.perf_counter() - t0
     result_stats.update(

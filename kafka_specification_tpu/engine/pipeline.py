@@ -177,6 +177,12 @@ def key_vcap(key: tuple) -> Optional[int]:
       ("dvh",  bucket, ncp, widths, ln, inv_sig, deadlock, pallas)
                  — the host-backend (deferred-probe) level program:
                    no vcap component, the program embeds no visited set
+      ("hinv", bucket, inv_sig)   — the invariant pass over host-held rows
+      ("init", bucket)            — the initial states' pack + fingerprint
+                   (engine.bfs._Step.first_violation / init_rows; the
+                   sharded engine keys the former ("shi", mesh, N,
+                   inv_sig)): no vcap component, so rewarm skips them
+                   and growth never evicts them
     """
     tag = key[0]
     if tag in ("step", "fsc", "dvl"):
@@ -275,6 +281,39 @@ def invariant_stage(model, states, fvalid, with_invariants: bool):  # kspec: tra
             viol_any.append(jnp.any(bad))
             viol_idx.append(jnp.argmax(bad))
         return jnp.stack(viol_any), jnp.stack(viol_idx)
+
+
+def invariant_rows_program(model, N: int):
+    """The invariant pass over `N` HOST-HELD packed rows, un-jitted:
+    (rows u32[N, K], n_valid i32) -> (any_bad[n_inv], first[n_inv]), the
+    per-invariant verdict and lowest violating row index.  Rows at or
+    past `n_valid` are padding and masked OUT (an all-zero row may well
+    violate an invariant).  One body for both engines' start (the initial
+    states) and finish (the frontier a max_depth/max_states cut left
+    unexpanded): engine.bfs._Step.first_violation jits it once per row
+    bucket and launches it."""
+    spec = model.spec
+
+    def invariant_rows(rows, n_valid):  # kspec: traced
+        with stage("invariants"):
+            live = jnp.arange(N) < n_valid
+            states = jax.vmap(spec.unpack)(rows)
+        return invariant_stage(model, states, live, True)
+
+    return invariant_rows
+
+
+def init_rows_program(spec):
+    """Pack + fingerprint the stacked initial states, un-jitted:
+    {field: i32[N, ...]} -> (rows u32[N, K], hi u32[N], lo u32[N])."""
+
+    def init_rows(states):  # kspec: traced
+        with stage("expand"):
+            rows = jax.vmap(spec.pack)(states)
+        with stage("fingerprint"):
+            return (rows, *fingerprint_lanes(rows, spec.exact64))
+
+    return init_rows
 
 
 def _sort_first(hi, lo):  # kspec: traced

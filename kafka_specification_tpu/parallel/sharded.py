@@ -1672,48 +1672,13 @@ def check_sharded(
 
     def _first_violation(rows: np.ndarray):
         """The invariant pass over host-held rows -> (invariant, row
-        index) of the first invariant, in declaration order, that some
-        row violates, or None.  One jitted program per padded row count
-        (rows sharded over the mesh; XLA partitions it), kept with the
-        level programs — so it costs a launch, not an eager dispatch per
-        operation of every predicate."""
-        n = rows.shape[0]
-        N = D * _next_pow2(max(-(-n // D), 8))
-
-        def build():
-            def invariant_rows(padded, n_valid):  # kspec: traced
-                with stage("invariants"):
-                    live = jnp.arange(N) < n_valid
-                    states = jax.vmap(spec.unpack)(padded)
-                    bad = [
-                        live & ~jax.vmap(inv.pred)(states)
-                        for inv in model.invariants
-                    ]
-                    return (
-                        jnp.stack([jnp.any(b) for b in bad]),
-                        jnp.stack([jnp.argmax(b) for b in bad]),
-                    )
-
-            return invariant_rows
-
-        fn = expander.cached(
-            (INVARIANT_TAG, mesh, N, expander.inv_sig(True)), build,
-            program=INVARIANT_TAG, bucket=N,
+        index) or None: the single-device engine's program and launch
+        (``_Step.first_violation``), keyed by this mesh, with the rows
+        sharded over it (XLA partitions the program)."""
+        N = D * _next_pow2(max(-(-rows.shape[0] // D), 8))
+        return expander.first_violation(
+            (INVARIANT_TAG, mesh), N, rows, io, obs_, layouts["frontier"]
         )
-        padded = np.zeros((N, K), np.uint32)
-        padded[:n] = rows
-        # (a span and a profiler annotation, not a level's dispatch: it
-        # runs before the first level and after the last)
-        launch = obs_.dispatch(INVARIANT_TAG, bucket=N)
-        any_bad, first = fn(
-            io.put(padded, layouts["frontier"]), np.int32(n)
-        )
-        any_bad = io.fetch(any_bad)
-        launch.finish()
-        if not any_bad.any():
-            return None
-        i = int(np.argmax(any_bad))
-        return model.invariants[i], int(io.fetch(first)[i])
 
     sp_ = obs_.open_span("init-states")
     inits = [

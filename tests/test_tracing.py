@@ -8,6 +8,7 @@ import json
 import re
 
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from kafka_specification_tpu.engine import check
@@ -36,6 +37,9 @@ PROGRAMS = {
     "dvl": ("device", ALL),
     "dvh": ("host", ALL - {"digest"}),
     "step": ("device", ALL - {"digest"}),
+    # the engine's start and finish (ISSUE 26)
+    "hinv": ("device", {"invariants"}),
+    "init": ("device", {"expand", "fingerprint"}),
 }
 
 
@@ -51,6 +55,13 @@ def _lower(tag):
     if tag == "step":
         fn = sb.get(BUCKET, VCAP, True, with_merge=True, compact=2)
         args = (rows, jnp.zeros((BUCKET,), bool)) + visited
+    elif tag in ("hinv", "init"):
+        assert check(m, max_depth=0, min_bucket=BUCKET).ok
+        fn = sb._cache[{"hinv": ("hinv", BUCKET, sb.inv_sig(True)),
+                        "init": ("init", 1)}[tag]]
+        args = (rows, np.int32(1)) if tag == "hinv" else ({
+            k: np.asarray(v, np.int32)[None]
+            for k, v in m.init_states()[0].items()},)
     elif tag in ("fgd", "fsc"):
         fused = pl.FusedPipeline(sb, m, None, None, None, True, backend,
                                  None, 2, BUCKET)
@@ -72,7 +83,8 @@ def _lower(tag):
         args = (rows, jnp.int32(0), jnp.int32(0))
         if tag == "dvl":
             args += visited
-    return fn.fn.lower(*args).as_text(debug_info=True)
+    fn = getattr(fn, "fn", fn)  # a first-call wrapper, or the jit
+    return fn.lower(*args).as_text(debug_info=True)
 
 
 @pytest.mark.parametrize("tag", sorted(PROGRAMS))
@@ -137,7 +149,9 @@ def test_every_engine_span_reaches_check(tmp_path, pipeline):
     # microsecond records
     assert any(round(s["t0"], 3) != s["t0"] for s in done.values())
     programs = {s["program"] for s in done.values() if s["span"] == "dispatch"}
-    assert programs == ({"fgd", "fsc"} if pipeline == "fused" else {"dvl"})
+    # the engine's start and finish (ISSUE 26), then the level programs
+    assert programs == {"init", "hinv"} | (
+        {"fgd", "fsc"} if pipeline == "fused" else {"dvl"})
     man = json.load(open(run.manifest_path))
     assert man["dir"] == str(tmp_path / "run")
 
@@ -159,11 +173,18 @@ def test_level_records_carry_repeatable_counters(tmp_path, pipeline):
         assert rec["discarded_dispatches"] == 0 == rec["discarded_ms"]
     assert [[r[k] for k in exact] for r in runs[0]] == \
         [[r[k] for k in exact] for r in runs[1]]
-    # level 1 carries the visited set's first upload: two u32 lanes of the
-    # initial capacity, min_bucket x fanout rounded up to a power of two
-    # (levels 1 and 2 are otherwise one chunk of the same bucket)
-    vcap0 = 1 << (KW["min_bucket"] * _Step(_model()).C - 1).bit_length()
-    assert runs[0][0]["h2d_bytes"] - runs[0][1]["h2d_bytes"] == 2 * 4 * vcap0
+    # level 1 carries what crossed before it: the initial state's fields
+    # (`init`), its row padded to the bucket floor (`hinv`), and the visited
+    # set's first upload, two u32 lanes of the initial capacity, min_bucket
+    # x fanout rounded up to a power of two (levels 1 and 2 are otherwise
+    # one chunk of the same bucket)
+    m = _model()
+    vcap0 = 1 << (KW["min_bucket"] * _Step(m).C - 1).bit_length()
+    start = sum(np.asarray(v, np.int32).nbytes
+                for v in m.init_states()[0].values())
+    start += KW["min_bucket"] * m.spec.num_lanes * 4
+    assert (runs[0][0]["h2d_bytes"] - runs[0][1]["h2d_bytes"]
+            == start + 2 * 4 * vcap0)
     # the emitted stream stays historical: none of it reaches stats.jsonl
     emitted = read_jsonl_tolerant(str(tmp_path / "run0" / "stats.jsonl"))
     assert emitted and not any(
@@ -196,7 +217,7 @@ def test_forced_overflow_discards_one_dispatch(tmp_path, monkeypatch):
     assert len(thrown) == len(redone)
     for s in thrown:
         assert s["discarded"] is True and s["attempt"] == 0
-        again = [d for d in dispatches if d["depth"] == s["depth"]
+        again = [d for d in dispatches if d.get("depth") == s["depth"]
                  and d["attempt"] == 1]
         assert len(again) == 1 and "discarded" not in again[0]
         assert again[0]["level_new_cap"] > s["level_new_cap"] == 8
