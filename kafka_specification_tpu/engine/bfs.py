@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import json
 import os
-import sys
 import time
 from dataclasses import dataclass, field
 from typing import Optional
@@ -115,8 +114,8 @@ class _CompileOnFirstCall:
 
 
 def _round256(w: int) -> int:
-    """Round up to the fingerprint-block alignment (single source of
-    truth for widths_for and norm_widths — round-5 advisor item)."""
+    """Round up to the 256-row width alignment (single source of truth
+    for widths_for and norm_widths)."""
     return -(-w // 256) * 256
 
 
@@ -126,8 +125,8 @@ class AdaptiveCompact:
     hand-synced copies otherwise).
 
     Escalation: stay on the uniform legacy shift until a uniform attempt
-    actually overflows (the uniform path is cheaper when it fits —
-    docs/PROFILE_5R.md), then size each action's buffer at ~1.35x the
+    actually overflows (the uniform path is cheaper when it fits),
+    then size each action's buffer at ~1.35x the
     run's measured high-water per-state enablement, pow2-rounded with
     overflow-learned floors.  Callers supply the per-state guard density
     (single-device: act_guard / chunk rows; sharded: max over shards of
@@ -278,22 +277,6 @@ class _Step:
         self.spec = model.spec
         self.K = self.spec.num_lanes
         self.C = model.total_fanout
-        # opt-in Pallas fingerprint kernel (hashed mode only; bit-identical
-        # to the jnp path — see ops/pallas_fingerprint.py)
-        want_pallas = os.environ.get("KSPEC_USE_PALLAS") == "1"
-        self.use_pallas = want_pallas and not self.spec.exact64
-        if (
-            want_pallas
-            and self.spec.exact64
-            and jax.default_backend() != "cpu"
-        ):
-            # on an accelerator the opt-in either runs the kernels or
-            # fails: it never lands on the jnp path unannounced
-            raise RuntimeError(
-                "KSPEC_USE_PALLAS=1: the Pallas kernels serve hashed specs "
-                f"only and {model.name} packs into an exact 64-bit "
-                "fingerprint; unset KSPEC_USE_PALLAS for this model"
-            )
         # global action id per flattened choice column
         act_ids = np.concatenate(
             [np.full(a.n_choices, i, np.int32) for i, a in enumerate(model.actions)]
@@ -321,6 +304,7 @@ class _Step:
             except AttributeError:
                 pass
         self._compiled_log = log
+        self.last_key = None  # key of the latest cached() call
 
     def norm_widths(self, bucket: int, compact):
         """Normalize a compact spec to per-action buffer widths (rows).
@@ -339,10 +323,11 @@ class _Step:
         assert len(compact) == len(acts), (len(compact), len(acts))
         # Round caller-supplied widths up to a multiple of 256 (unless the
         # full lattice width — always a pow2 multiple of n_choices — is
-        # smaller): fp_masked blocks the candidate buffer by
-        # gcd(rows, 8192), so an odd width would give 1-row Pallas
-        # fingerprint blocks (round-5 advisor item).  The alignment
-        # invariant is enforced HERE, where the widths are created.
+        # smaller): the widths are shapes of the compiled programs, so
+        # the coarse 256-row ladder bounds how many distinct programs
+        # the measured maxima can ask for and keeps every buffer
+        # tile-aligned.  The alignment invariant is enforced HERE, where
+        # the widths are created.
         return tuple(
             min(_round256(max(1, int(w))), bucket * a.n_choices)
             for w, a in zip(compact, acts)
@@ -526,7 +511,10 @@ class _Step:
         and the profiler's module line say which program ran.  The first
         call of a fresh entry is wrapped in a ``compile`` span
         (_CompileOnFirstCall) and the key is appended to the compiled
-        log PreparedKernels.rewarm replays."""
+        log PreparedKernels.rewarm replays.  `last_key` hands the key back
+        to a caller of a builder (pipeline.warm_key), so each tag's layout
+        is written in its builder alone."""
+        self.last_key = key
         if key not in self._cache:
             self._compiled_log.add(key)
             fn = build()
@@ -600,9 +588,6 @@ class _Step:
         compact=None,
         squeeze_full: bool = False,
     ):
-        # use_pallas is in the key because the cache outlives this _Step
-        # (it is shared per Model) and KSPEC_USE_PALLAS can toggle between
-        # check() calls.
         # squeeze_full only changes the program on the uniform-shift
         # compact path (per-action and full paths already run T = T_exp) —
         # normalize it so the sticky flag can't force recompiles of
@@ -623,7 +608,6 @@ class _Step:
             with_merge,
             compact_key,
             squeeze_full,
-            self.use_pallas,
         )
         return self.cached(
             key,
@@ -713,7 +697,6 @@ class _Step:
         # squeezes the enabled candidates to the front, fingerprints them,
         # and hands (rows, fps) straight to the host.
         host_dedup = not with_merge
-        use_pallas = self.use_pallas
 
         def step(frontier, fvalid, vhi, vlo, vn):
             # the frontier unpack belongs to whichever stage reads the
@@ -750,7 +733,7 @@ class _Step:
                 out, out_parent, out_act, rowvalid, n_en, sq_ovf = (
                     squeeze_stage(cand, parent, actid, valid, T, K)
                 )
-                out_hi, out_lo = fp_stage(out, rowvalid, spec, use_pallas)
+                out_hi, out_lo = fp_stage(out, rowvalid, spec)
                 viol_any, viol_idx = invariant_stage(
                     model, states, fvalid, with_invariants
                 )
@@ -768,7 +751,7 @@ class _Step:
             else:
                 overflow = ovf_vec()
 
-            hi, lo = fp_stage(cand, valid, spec, use_pallas)
+            hi, lo = fp_stage(cand, valid, spec)
             # the shared winner-selection sequence (sort, first
             # occurrence, visited rank, compaction, rank-scatter merge)
             (out, out_parent, out_act, new_n, out_hi, out_lo,
@@ -1299,10 +1282,8 @@ def check(
     host_set = None
     ht_hi = ht_lo = ht_claim = None  # device-hash table (ops/hashset)
     hash_n = 0
-    # ht_claim is allocated LAZILY at the insert site (the jnp probe path
-    # needs it; the Pallas path does not), so table (re)builds just reset
-    # it to None.  pallas_vmem_noted: warn once per run on VMEM fallback.
-    pallas_vmem_noted = False
+    # ht_claim is allocated LAZILY at the insert site, so table (re)builds
+    # just reset it to None.
 
     def _u64(hi, lo):
         return (np.asarray(hi).astype(np.uint64) << np.uint64(32)) | np.asarray(
@@ -1920,7 +1901,7 @@ def check(
     # lattice vs fenced ISR mutations at <0.1%).  The policy — uniform
     # shift until a uniform attempt overflows, then measured high-water
     # widths with learned floors — lives in AdaptiveCompact, shared with
-    # the sharded engine (docs/PROFILE_5R.md has the measurements).
+    # the sharded engine.
     adapt = AdaptiveCompact(model.actions, compact_shift,
                             bucket_gate=compact_gate)
 
@@ -2072,7 +2053,7 @@ def check(
         nonlocal vhi, vlo, vn, verdict, lvl_new, prof_step, prof_host_s
         nonlocal lvl_launches, lvl_launches_max, run_launches_max
         nonlocal lvl_act_en, a_w  # arena buffers grow via _grow_arena
-        nonlocal ht_hi, ht_lo, ht_claim, hash_n, pallas_vmem_noted
+        nonlocal ht_hi, ht_lo, ht_claim, hash_n
         nonlocal lvl_store_s
         (start, fp_n, bucket, finalize, pre_v, shadow, dispatch_s,
          t_staged, piece, pre_vcap, t_dispatch) = st
@@ -2201,87 +2182,11 @@ def check(
             valid = jnp.arange(out_hi.shape[0]) < new_n
             isnew = np.zeros(out_hi.shape[0], bool)
             while True:
-                # Pallas probe kernel (ops/pallas_hashset) — the actual
-                # TPU dedup kernel a live hardware window profiles;
-                # interpret mode on CPU, bit-identical winners
-                # (tests/test_pallas.py).  It stages the whole table in
-                # VMEM, so beyond MAX_VMEM_CAP slots it cannot compile
-                # — fall back to the jnp HBM probe, loudly, and keep
-                # checking per iteration (a mid-run rehash can cross
-                # the threshold).
-                use_p = use_p_hbm = False
-                if step_builder.use_pallas:
-                    # lazy import: the default (non-pallas) path must
-                    # not depend on jax.experimental.pallas at all
-                    from ..ops import pallas_hashset as pallas_hs
-
-                    use_p = pallas_hs.fits_vmem(ht_hi.shape[0])
-                    # beyond the VMEM gate: the HBM-resident DMA
-                    # kernel (opt-in until a hardware window profiles
-                    # its per-slot descriptor overhead)
-                    use_p_hbm = not use_p and (
-                        os.environ.get("KSPEC_PALLAS_HBM") == "1"
-                    )
-                if step_builder.use_pallas and not use_p and not use_p_hbm:
-                    gate = (
-                        "KSPEC_USE_PALLAS: table capacity "
-                        f"{ht_hi.shape[0]} exceeds the VMEM-staged "
-                        f"kernel's limit ({pallas_hs.MAX_VMEM_CAP}); "
-                    )
-                    if jax.default_backend() != "cpu":
-                        # on an accelerator the opt-in either runs a
-                        # Pallas kernel or fails: the jnp probe is never
-                        # substituted (the loud fallback below is the
-                        # interpret-mode test venue's)
-                        raise RuntimeError(
-                            gate + "set KSPEC_PALLAS_HBM=1 for the "
-                            "HBM-resident kernel or unset KSPEC_USE_PALLAS"
-                        )
-                    if not pallas_vmem_noted:
-                        pallas_vmem_noted = True
-                        print(
-                            "[kspec] " + gate + "falling back to the jnp "
-                            "HBM probe path (KSPEC_PALLAS_HBM=1 selects "
-                            "the HBM-resident DMA kernel instead)",
-                            file=sys.stderr,
-                            flush=True,
-                        )
-                if use_p_hbm:
-                    ht_hi, ht_lo, m, _ni, ovf = (
-                        pallas_hs.probe_insert_pallas_hbm(
-                            ht_hi,
-                            ht_lo,
-                            out_hi,
-                            out_lo,
-                            valid,
-                            interpret=jax.default_backend() == "cpu",
-                        )
-                    )
-                    ht_claim = None
-                elif use_p:
-                    # KSPEC_PALLAS_GROUP: interleaved probe chains per
-                    # round (memory-level parallelism; winners
-                    # bit-identical — ops/pallas_hashset)
-                    ht_hi, ht_lo, m, _ni, ovf = (
-                        pallas_hs.probe_insert_pallas(
-                            ht_hi,
-                            ht_lo,
-                            out_hi,
-                            out_lo,
-                            valid,
-                            interpret=jax.default_backend() == "cpu",
-                            group=int(
-                                os.environ.get("KSPEC_PALLAS_GROUP", "8")
-                            ),
-                        )
-                    )
-                    ht_claim = None
-                else:
-                    if ht_claim is None:
-                        ht_claim = hashset.new_claim(ht_hi.shape[0])
-                    ht_hi, ht_lo, ht_claim, m, _ni, ovf = _hash_insert(
-                        ht_hi, ht_lo, ht_claim, out_hi, out_lo, valid
-                    )
+                if ht_claim is None:
+                    ht_claim = hashset.new_claim(ht_hi.shape[0])
+                ht_hi, ht_lo, ht_claim, m, _ni, ovf = _hash_insert(
+                    ht_hi, ht_lo, ht_claim, out_hi, out_lo, valid
+                )
                 isnew |= io.fetch(m)
                 if not bool(io.fetch(ovf)):
                     break

@@ -170,11 +170,11 @@ def key_vcap(key: tuple) -> Optional[int]:
     programs that don't embed the visited set (guard kernels).  Key
     shapes (engine.bfs._Step.get / FusedPipeline / DevicePipeline):
 
-      ("step", bucket, vcap, inv_sig, with_merge, compact, sq_full, pallas)
+      ("step", bucket, vcap, inv_sig, with_merge, compact, sq_full)
       ("fgd",  bucket, inv_sig)                     — fused launch 1
-      ("fsc",  bucket, vcap, widths, with_merge, device_out, pallas)
-      ("dvl",  bucket, vcap, ncp, widths, ln, inv_sig, deadlock, pallas)
-      ("dvh",  bucket, ncp, widths, ln, inv_sig, deadlock, pallas)
+      ("fsc",  bucket, vcap, widths, with_merge, device_out)
+      ("dvl",  bucket, vcap, ncp, widths, ln, inv_sig, deadlock)
+      ("dvh",  bucket, ncp, widths, ln, inv_sig, deadlock)
                  — the host-backend (deferred-probe) level program:
                    no vcap component, the program embeds no visited set
       ("hinv", bucket, inv_sig)   — the invariant pass over host-held rows
@@ -237,24 +237,10 @@ def squeeze_stage(cand, parent, actid, valid, width, K):  # kspec: traced
         return out, out_parent, out_act, rowvalid, n_en, n_en > width
 
 
-def fp_stage(cand, valid, spec, use_pallas: bool):  # kspec: traced
-    """Stage 3: masked (hi, lo) fingerprints (Pallas opt-in or jnp)."""
+def fp_stage(cand, valid, spec):  # kspec: traced
+    """Stage 3: masked (hi, lo) fingerprints."""
     sent = jnp.uint32(dedup.SENT)
     with stage("fingerprint"):
-        if use_pallas:
-            import math
-
-            from ..ops.pallas_fingerprint import fingerprint_pallas
-
-            interp = jax.default_backend() == "cpu"
-            # block_rows must divide the buffer width (the largest
-            # power-of-two divisor, capped at 8k rows/block): every
-            # buffer here is 1024-aligned or a power-of-two multiple of
-            # C, so blocks stay >= 256 rows
-            rows = cand.shape[0]
-            block = math.gcd(rows, 1 << 13)
-            return fingerprint_pallas(cand, valid, block_rows=block,
-                                      interpret=interp)
         hi, lo = fingerprint_lanes(cand, spec.exact64)
         return jnp.where(valid, hi, sent), jnp.where(valid, lo, sent)
 
@@ -462,8 +448,8 @@ class LegacyPipeline:
     @property
     def launches_per_chunk(self) -> int:
         """Successor-kernel passes dispatched per chunk: one per action
-        (the per-action phase-B evaluation; TODO.md's '12 DNF action
-        kernels vs hand's 9')."""
+        (the per-action phase-B evaluation: the emitted source's 12 DNF
+        action kernels against hand's 9)."""
         return len(self.model.actions)
 
     def run_chunk(self, piece, fp_n, bucket, depth, vhi, vlo, vn, vcap):
@@ -580,7 +566,7 @@ class PooledWidths:
     """Data-driven sizing of the fused path's shared candidate buffer.
 
     Each action owns one segment of the pooled buffer; its width rides a
-    power-of-two ladder (floor 256 for Pallas block alignment, capped at
+    power-of-two ladder (floor 256: bfs._round256's alignment, capped at
     the action's full lattice width) sized from max(this chunk's EXACT
     guard count, the run's high-water per-state density x bucket x 1.35
     headroom).  Exact counts are known before the successor program is
@@ -687,8 +673,7 @@ class FusedPipeline:
         """Launch 2: the pooled update skeleton (+ device dedup)."""
         with_merge = self.visited_backend == "device"
         device_out = self.visited_backend != "host"
-        key = ("fsc", bucket, vcap, widths, with_merge, device_out,
-               self.step.use_pallas)
+        key = ("fsc", bucket, vcap, widths, with_merge, device_out)
         return self.step.cached(
             key,
             lambda: self._build_succ(
@@ -741,7 +726,6 @@ class FusedPipeline:
         K = spec.num_lanes
         offs = np.cumsum([0] + list(widths))
         W = int(offs[-1])
-        use_pallas = self.step.use_pallas
         # static action-id column for the pooled layout
         actid_f = jnp.concatenate(
             [
@@ -778,7 +762,7 @@ class FusedPipeline:
                 # host backend: validity is resolved at C speed on the
                 # host (run_chunk compacts by the ok mask), so no device
                 # squeeze scatter is needed at all
-                hi, lo = fp_stage(cand, ok, spec, use_pallas)
+                hi, lo = fp_stage(cand, ok, spec)
                 return cand, ok, hi, lo
             with stage("expand"):
                 act_en = jnp.stack(
@@ -792,7 +776,7 @@ class FusedPipeline:
             out, out_parent, out_act, rowvalid2, n_en, _ovf = squeeze_stage(
                 cand, sidx, actid_f, ok, W, K
             )
-            hi, lo = fp_stage(out, rowvalid2, spec, use_pallas)
+            hi, lo = fp_stage(out, rowvalid2, spec)
             if with_merge:
                 (out, out_parent, out_act, new_n, out_hi, out_lo,
                  vhi, vlo, vn, _rank) = sorted_dedup_stage(
@@ -1197,7 +1181,7 @@ class DevicePipeline:
             # capacity growth can never evict it (key_vcap -> None)
             key = ("dvh", B, NCp, widths, LN,
                    self.step.inv_sig(self.check_invariants),
-                   self.check_deadlock, self.step.use_pallas)
+                   self.check_deadlock)
             return self.step.cached(
                 key,
                 lambda: self._build_level_host(B, NCp, widths, LN),
@@ -1206,7 +1190,7 @@ class DevicePipeline:
             )
         key = ("dvl", B, vcap, NCp, widths, LN,
                self.step.inv_sig(self.check_invariants),
-               self.check_deadlock, self.step.use_pallas)
+               self.check_deadlock)
         return self.step.cached(
             key,
             lambda: self._build_level(B, NCp, vcap, widths, LN),
@@ -1251,7 +1235,6 @@ class DevicePipeline:
         expand = self.step.make_expand(B, widths)
         check_invariants = self.check_invariants
         check_deadlock = self.check_deadlock
-        use_pallas = self.step.use_pallas
         n_actions = len(model.actions)
 
         def level(fbuf, f_total, n_chunks, vhi, vlo, vn):  # kspec: traced
@@ -1277,7 +1260,7 @@ class DevicePipeline:
                 (cand, parent, actid, rowvalid, _n_en,
                  sq_ovf) = squeeze_stage(cand, parent, actid, valid,
                                          T, K)
-                hi, lo = fp_stage(cand, rowvalid, spec, use_pallas)
+                hi, lo = fp_stage(cand, rowvalid, spec)
                 # the SHARED winner-selection sequence (one source of
                 # truth with the fused/legacy paths): primary set =
                 # level-new (its ranks drive the gated merge below),
@@ -1409,7 +1392,6 @@ class DevicePipeline:
         expand = self.step.make_expand(B, widths)
         check_invariants = self.check_invariants
         check_deadlock = self.check_deadlock
-        use_pallas = self.step.use_pallas
         n_actions = len(model.actions)
 
         def level(fbuf, f_total, n_chunks):  # kspec: traced
@@ -1435,7 +1417,7 @@ class DevicePipeline:
                 (cand, parent, actid, rowvalid, _n_en,
                  sq_ovf) = squeeze_stage(cand, parent, actid, valid,
                                          T, K)
-                hi, lo = fp_stage(cand, rowvalid, spec, use_pallas)
+                hi, lo = fp_stage(cand, rowvalid, spec)
                 (n_out, n_par, n_act, n_ohi, n_olo, new_n,
                  s_hi, s_lo, s_rank) = candidate_dedup_stage(
                     cand, parent, actid, rowvalid, hi, lo,
@@ -1743,38 +1725,34 @@ def make_pipeline(name: str, *, step_builder, model, adapt, chunk_retry,
 
 def warm_key(step_builder, model, key: tuple, vcap: int):
     """Re-compile one logged step-cache key at a new visited capacity —
-    PreparedKernels.rewarm's per-key worker.  Returns the rebuilt key,
-    or None when the key has no capacity component (guard kernels never
-    evict on growth)."""
+    PreparedKernels.rewarm's per-key worker.  Returns the rebuilt key as
+    the builder made it (``_Step.last_key``: each tag's layout is written
+    in its builder alone), or None when the key has no capacity component
+    (guard kernels never evict on growth)."""
     tag = key[0]
-    if tag == "step":
-        (_t, bucket, _vcap, inv_sig, with_merge, compact, sq_full,
-         _pallas) = key
-        if inv_sig and inv_sig != tuple(
+    K = model.spec.num_lanes
+
+    def sibling(inv_sig):  # the key of another invariant overlay's view
+        return inv_sig and inv_sig != tuple(
             i.name for i in model.invariants
-        ):
-            return None  # belongs to a sibling invariant overlay
-        step = step_builder.get(
+        )
+
+    if tag == "step":
+        (_t, bucket, _vcap, inv_sig, with_merge, compact, sq_full) = key
+        if sibling(inv_sig):
+            return None
+        fn = step_builder.get(
             bucket, vcap, bool(inv_sig),
             with_merge=with_merge, compact=compact, squeeze_full=sq_full,
         )
-        K = model.spec.num_lanes
-        out = step(
+        args = (
             jnp.zeros((bucket, K), jnp.uint32),
             jnp.zeros((bucket,), bool),
-            jnp.full(vcap, 0xFFFFFFFF, jnp.uint32),
-            jnp.full(vcap, 0xFFFFFFFF, jnp.uint32),
-            jnp.int32(0),
         )
-        jax.block_until_ready(out)
-        return ("step", bucket, vcap, inv_sig, with_merge, compact,
-                sq_full, step_builder.use_pallas)
-    if tag == "dvl":
-        (_t, bucket, _vcap, ncp, widths, ln, inv_sig, dl, _pallas) = key
-        if inv_sig and inv_sig != tuple(
-            i.name for i in model.invariants
-        ):
-            return None  # belongs to a sibling invariant overlay
+    elif tag == "dvl":
+        (_t, bucket, _vcap, ncp, widths, ln, inv_sig, dl) = key
+        if sibling(inv_sig):
+            return None
         pipe = DevicePipeline(
             step_builder, model, None, None, None,
             check_invariants=bool(inv_sig),
@@ -1783,20 +1761,13 @@ def warm_key(step_builder, model, key: tuple, vcap: int):
             check_deadlock=dl,
         )
         fn = pipe._level_program(bucket, ncp, vcap, widths, ln)
-        K = model.spec.num_lanes
-        out = fn(
+        args = (
             jnp.zeros((ncp * bucket, K), jnp.uint32),
             jnp.int32(0),
             jnp.int32(0),
-            jnp.full(vcap, 0xFFFFFFFF, jnp.uint32),
-            jnp.full(vcap, 0xFFFFFFFF, jnp.uint32),
-            jnp.int32(0),
         )
-        jax.block_until_ready(out)
-        return ("dvl", bucket, vcap, ncp, widths, ln, inv_sig, dl,
-                step_builder.use_pallas)
-    if tag == "fsc":
-        (_t, bucket, _vcap, widths, with_merge, device_out, _pallas) = key
+    elif tag == "fsc":
+        (_t, bucket, _vcap, widths, with_merge, device_out) = key
         pipe = FusedPipeline(
             step_builder, model, None, None, None,
             check_invariants=True,
@@ -1808,17 +1779,16 @@ def warm_key(step_builder, model, key: tuple, vcap: int):
         )
         fn = pipe.succ_step(bucket, widths, vcap)
         W = int(sum(widths))
-        K = model.spec.num_lanes
-        out = fn(
+        args = (
             jnp.zeros((bucket, K), jnp.uint32),
             jnp.zeros((W,), jnp.int32),
             jnp.zeros((W,), jnp.int32),
             jnp.zeros((W,), bool),
-            jnp.full(vcap, 0xFFFFFFFF, jnp.uint32),
-            jnp.full(vcap, 0xFFFFFFFF, jnp.uint32),
-            jnp.int32(0),
         )
-        jax.block_until_ready(out)
-        return ("fsc", bucket, vcap, widths, with_merge, device_out,
-                step_builder.use_pallas)
-    return None
+    else:
+        return None
+    made = step_builder.last_key
+    # every capacity-keyed program ends in (vhi, vlo, vn): an empty set
+    empty = jnp.full(vcap, 0xFFFFFFFF, jnp.uint32)
+    jax.block_until_ready(fn(*args, empty, empty, jnp.int32(0)))
+    return made

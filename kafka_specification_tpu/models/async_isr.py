@@ -68,7 +68,7 @@ def check_encoding_bounds(cfg: AsyncIsrConfig) -> None:
     oracle keeps calling it because a config the engine cannot encode
     must not be silently accepted by the cross-check path either.
     Spreading the bitset over multiple lanes is the documented extension
-    path (TODO.md)."""
+    path (ROADMAP.md R9)."""
     from ..analysis.encoding import EncodingUnsound, spec_fits_errors
 
     # bitset width 2^N, with N capped BEFORE the shift so a wild config

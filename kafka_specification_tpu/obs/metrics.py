@@ -34,7 +34,7 @@ from ..resilience.heartbeat import heartbeat_record
 from .atomicio import atomic_write_text
 
 # histogram default buckets: per-level wall times span 4ms toy levels to
-# multi-minute deep-product levels (RUNPROD464_r5.log)
+# multi-minute deep-product levels (the 463.8M-state product run)
 DEFAULT_MS_BUCKETS = (10, 50, 100, 500, 1000, 5000, 30_000, 120_000, 600_000)
 
 
@@ -168,7 +168,7 @@ class MetricsRegistry:
             lines.append(sample(f"{n}_sum", round(h["sum"], 3)))
             lines.append(sample(f"{n}_count", h["count"]))
         # no fsync — a scrape artifact needs no power-loss durability,
-        # and the serving daemon exports per verdict (bench.py --serve)
+        # and the serving daemon exports per verdict
         atomic_write_text(path, "\n".join(lines) + "\n", fsync=False)
 
 

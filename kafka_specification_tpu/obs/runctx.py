@@ -66,7 +66,7 @@ def git_describe(cwd: Optional[str] = None) -> Optional[str]:
     # memoized per (process, cwd): the checkout cannot change under a
     # live process, and the serving daemon opens a RunContext PER JOB —
     # 30ms of `git describe` per verdict was the warm path's single
-    # largest cost before the memo (bench.py --serve)
+    # largest cost before the memo
     key = cwd or os.path.dirname(os.path.abspath(__file__))
     if key in _GIT_DESCRIBE_CACHE:
         return _GIT_DESCRIBE_CACHE[key]

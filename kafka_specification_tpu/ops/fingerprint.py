@@ -52,7 +52,7 @@ def _murmur3_lanes(lanes: jnp.ndarray, seed: int) -> jnp.ndarray:
 
 
 def hash_pair(lanes: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Hashed-mode fingerprint pair (shared by the jnp and Pallas paths).
+    """Hashed-mode fingerprint pair.
 
     The all-ones pair is the dedup padding sentinel: a valid state hashing
     to it would be indistinguishable from padding and silently *dropped*

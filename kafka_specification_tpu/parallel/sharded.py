@@ -1919,8 +1919,8 @@ def check_sharded(
     # Compressed exchange default: ON where a real fabric carries the
     # all_to_all (the bytes are the scarce resource compression buys
     # back), OFF on the virtual CPU mesh (no wire — the codec's encode/
-    # decode compute is pure overhead there; BENCH_r10 measures the
-    # trade both ways).  KSPEC_EXCHANGE_COMPRESS=1/0 forces either.
+    # decode compute is pure overhead there; measured both ways on the
+    # CPU mesh, PR 10).  KSPEC_EXCHANGE_COMPRESS=1/0 forces either.
     _comp_env = os.environ.get("KSPEC_EXCHANGE_COMPRESS", "")
     compress_on = (
         overlap_on

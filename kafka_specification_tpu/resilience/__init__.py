@@ -1,7 +1,7 @@
 """Resilience subsystem: fault injection, hardened checkpoints, retries.
 
 Long-horizon exhaustive searches (the 10.7-hour half-billion-state product
-runs, RUNPROD464_r5.log) treat a crash as a restartable event, not a lost
+runs, RESULTS.md) treat a crash as a restartable event, not a lost
 run.  This package supplies the four pieces the engines and the supervisor
 share:
 

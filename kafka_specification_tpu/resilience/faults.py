@@ -26,7 +26,7 @@ Grammar (comma-separated specs in `KSPEC_FAULT` or `--fault`):
     compile_oom               the next escalated (per-action-tuple) chunk
                               step raises an LLVM-OOM-shaped error once
                               (the reproducible wide-product XLA:CPU
-                              failure, TODO.md)
+                              failure)
     transient_device_err:N    the next N chunk/exchange step executions
                               raise a transient-classified backend error
 

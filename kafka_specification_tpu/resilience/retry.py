@@ -1,6 +1,6 @@
 """Failure classification + bounded exponential backoff for chunk steps.
 
-The engines' known failure ladder (TODO.md, RUNPROD464_r5.log):
+The engines' known failure ladder (seen on the half-billion-state runs):
 
 - **transient**: the backend hiccuped (RPC drop, preempted device,
   transient DATA_LOSS/UNAVAILABLE status).  The chunk is side-effect-free

@@ -1,7 +1,7 @@
 """Checking-as-a-service: a warm multi-tenant serving daemon.
 
 Every check used to be a fresh CLI process — ~2 minutes cold, ~9 seconds
-warm (TODO.md) — which caps "heavy traffic from millions of users" at one
+warm — which caps "heavy traffic from millions of users" at one
 run per operator.  This package turns the checker into a service
 (ROADMAP item 3):
 

@@ -1,7 +1,7 @@
 """Shape-keyed compile cache: the warm heart of the serving daemon.
 
 A cold ``cli check`` pays ~2 minutes of jax import + reference parse +
-model build + trace/XLA-compile for seconds of actual checking (TODO.md).
+model build + trace/XLA-compile for seconds of actual checking.
 The daemon pays each of those exactly once per *schema shape* and then
 serves every later job of that shape warm, following the compiler-first
 portable-cache design of arXiv:2603.09555 (PAPERS.md): make compilation a

@@ -12,7 +12,7 @@ The checker's three dedup/state stores form a memory hierarchy:
   disk-spilled frontier queue (chunked segments consumed in discovery
   order) and an append-only on-disk parent log for counterexample traces.
   This is the tier that takes a run past RAM: the 463.8M-state product
-  (RUNPROD464_r5.log) filled the box; 2-5B states do not fit at
+  (RESULTS.md) filled the box; 2-5B states do not fit at
   ~16 B/fingerprint of host-set residency, which is exactly the wall TLC's
   disk-backed FPSet exists for.
 

@@ -1,5 +1,5 @@
 """Platform and compile-cache selection shared by every entry point
-(CLI, bench.py, scripts/, tests).
+(CLI, perfbench/, scripts/, tests).
 
 The program runs in-process on whatever platform JAX gives it; nothing
 here probes a platform or falls back to another.  An accelerator belongs
@@ -40,7 +40,7 @@ def compile_cache_dir():
     The one rule: where JAX_COMPILATION_CACHE_DIR is set, JAX reads it
     itself and the program sets no directory; otherwise the cache is
     `<checkout>/.jax_cache` — a fixed path, shared by the CLI, the tests
-    and bench.py, so every process of a run warms the same entries.
+    and the benchmark, so every process of a run warms the same entries.
     """
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return None

@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# THE blessed tier-1 entrypoint: builders, the bench harness, and CI all
+# THE blessed tier-1 entrypoint: builders and CI
 # invoke this one script instead of hand-copying the ROADMAP command (one
 # source of truth — a drifted copy silently weakens the gate).
 #
@@ -20,14 +20,14 @@ set -u
 cd "$(dirname "$0")/.."
 
 echo "[tier1] stage 1: static gate (compileall + pyflakes)"
-python -m compileall -q kafka_specification_tpu tests scripts bench.py chip_smoke.py || {
+python -m compileall -q kafka_specification_tpu tests scripts chip_smoke.py || {
     echo "[tier1] FAIL: compileall found syntax errors" >&2
     exit 1
 }
 if python -c "import pyflakes" 2>/dev/null; then
     # F821 undefined-name class of bugs; pyflakes is advisory-strict:
     # any finding fails the gate (the tree is kept pyflakes-clean)
-    python -m pyflakes kafka_specification_tpu scripts bench.py chip_smoke.py || {
+    python -m pyflakes kafka_specification_tpu scripts chip_smoke.py || {
         echo "[tier1] FAIL: pyflakes findings (fix or # noqa them)" >&2
         exit 1
     }

@@ -91,7 +91,7 @@ def test_static_gate():
         return  # advisory layer absent: compileall already ran
     out = subprocess.run(
         [sys.executable, "-m", "pyflakes",
-         "kafka_specification_tpu", "scripts", "bench.py"],
+         "kafka_specification_tpu", "scripts", "chip_smoke.py"],
         cwd=_REPO, capture_output=True, text=True, timeout=120,
     )
     assert out.returncode == 0, out.stdout + out.stderr

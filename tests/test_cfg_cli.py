@@ -2,6 +2,7 @@
 
 import numpy as np
 import pytest
+from conftest import needs_reference
 
 from kafka_specification_tpu.utils.cfg import (
     CFG_MODULE_ALIASES,
@@ -59,6 +60,7 @@ def test_cli_check_and_exit_codes(tmp_path, capsys):
     assert '"distinct_states": 12' in out
 
 
+@needs_reference
 def test_cli_simulate_emitted(capsys):
     # random walks over the mechanically emitted IdSequence model; TypeOk
     # holds on every walk -> exit 0
@@ -97,6 +99,7 @@ def test_stretch_config_builds_product_model():
     assert res.levels[:3] == [1, 30, 570]  # 3 partitions x 10 controller moves, etc.
 
 
+@needs_reference
 def test_validate_emitted_covers_reference_next():
     """`validate --emitted`: the mechanically emitted model's `Name~k` DNF
     branches map back to their source disjuncts and cover the reference

@@ -9,10 +9,9 @@ two paths compare as exact packed state sets per BFS level.  No
 hand-translated guard or update exists anywhere in the emitted path.
 """
 
-from pathlib import Path
-
 import numpy as np
 import pytest
+from conftest import REFERENCE as REF, needs_reference
 
 from kafka_specification_tpu.engine import check
 from kafka_specification_tpu.models import kafka_replication as kr
@@ -21,7 +20,6 @@ from kafka_specification_tpu.models.emitted import VARIANTS, make_emitted_model
 from kafka_specification_tpu.utils.tla_expr import parse_definition
 from kafka_specification_tpu.utils.tla_frontend import parse_tla
 
-REF = Path("/root/reference")
 TINY = kr.Config(2, 2, 1, 1)
 
 
@@ -46,6 +44,7 @@ def _assert_same_level_sets(m_emitted, m_hand):
     return r_e
 
 
+@needs_reference
 def test_every_definition_of_every_module_parses():
     """The expression front-end covers the corpus's whole syntax surface —
     ALL definitions of all 10 modules, including the Spec bodies
@@ -59,6 +58,7 @@ def test_every_definition_of_every_module_parses():
     assert count >= 108  # 10 modules, ~109 definitions incl. 8 Specs
 
 
+@needs_reference
 def test_spec_fairness_structure_and_no_liveness():
     """SURVEY.md §2.4 made two claims the front-end can now check in code:
     every Spec is `Init /\\ [][Next]_sub` plus only SF/WF fairness (which
@@ -87,6 +87,7 @@ def test_spec_fairness_structure_and_no_liveness():
     assert specs >= 7
 
 
+@needs_reference
 def test_emitted_truncate_to_hw_matches_hand_tiny():
     r = _assert_same_level_sets(
         make_emitted_model("KafkaTruncateToHighWatermark", TINY),
@@ -95,6 +96,7 @@ def test_emitted_truncate_to_hw_matches_hand_tiny():
     assert r.total == 353  # RESULTS.md tiny-config golden count
 
 
+@needs_reference
 def test_emitted_kip320_matches_hand_tiny():
     r = _assert_same_level_sets(
         make_emitted_model("Kip320", TINY), _hand("Kip320", TINY)
@@ -102,6 +104,7 @@ def test_emitted_kip320_matches_hand_tiny():
     assert r.total == 277
 
 
+@needs_reference
 @pytest.mark.slow
 @pytest.mark.parametrize("module", ["Kip101", "Kip279", "Kip320FirstTry"])
 def test_emitted_variant_matches_hand_tiny(module):
@@ -112,6 +115,7 @@ def test_emitted_variant_matches_hand_tiny(module):
     assert r.total == golden[module]
 
 
+@needs_reference
 def test_emitted_kip320_invariants_pass_tiny():
     """The THEOREM workload from emitted predicate kernels — all four
     invariants (Kip320.tla:168-171).  `LeaderInIsr` resolves to the
@@ -126,6 +130,7 @@ def test_emitted_kip320_invariants_pass_tiny():
     assert r.ok and r.total == 277
 
 
+@needs_reference
 def test_emitted_leader_in_isr_literal_false_at_init():
     """The literal KafkaReplication.tla:345 predicate fails at depth 0
     (leader = None at Init, :117-119) — same split the hand model pins in
@@ -140,6 +145,7 @@ def test_emitted_leader_in_isr_literal_false_at_init():
     assert r.violation.depth == 0
 
 
+@needs_reference
 def test_emitted_truncate_to_hw_weak_isr_violation_depth():
     """Known-bad variant: emitted WeakIsr kernel finds the violation at the
     same depth the hand model does (tests/test_variants.py)."""
@@ -152,6 +158,7 @@ def test_emitted_truncate_to_hw_weak_isr_violation_depth():
     assert r.violation.depth == 8
 
 
+@needs_reference
 @pytest.mark.slow
 def test_emitted_kip320_matches_hand_two_epochs():
     """Kip320 at (2r, L2, R2, E2) — 5,973 states (RESULTS.md)."""
@@ -174,6 +181,7 @@ def test_variant_list_is_complete():
 
 @pytest.mark.slow  # ~15s: 4,088-state set comparison; the literal-TypeOk
 # test below keeps the emitted AsyncIsr path in the fast suite
+@needs_reference
 def test_emitted_async_isr_matches_hand():
     """The standalone AsyncIsr emits end to end (SPairSet request encoding,
     emitted CONSTRAINT) and reproduces the hand model's 4,088-state space
@@ -194,6 +202,7 @@ def test_emitted_async_isr_matches_hand():
     assert rv.ok
 
 
+@needs_reference
 def test_emitted_async_isr_literal_type_ok_false_at_init():
     """The reference's literal TypeOk is violated at Init: pendingVersion
     is declared Nat (AsyncIsr.tla:45) but initialized to Nil (:145).  The
@@ -219,6 +228,7 @@ def test_emitted_async_isr_literal_type_ok_false_at_init():
     assert r2.ok
 
 
+@needs_reference
 def test_emitted_kip320_small_exhaustive():
     """Mechanically emitted Kip320 at (2r,L2,R2,E2) — the 5,973-state
     THEOREM workload — as a routine fast-suite run (VERDICT r2 item 6:
@@ -233,6 +243,7 @@ def test_emitted_kip320_small_exhaustive():
     assert res.total == 5973
 
 
+@needs_reference
 @pytest.mark.slow
 def test_emitted_kip320_3r_exhaustive():
     """Emitted Kip320 at the flagship 3-broker bench constants: exhaustive

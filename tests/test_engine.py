@@ -129,7 +129,7 @@ def test_adaptive_compile_fallback_exact(monkeypatch):
     """An escalated per-action compact program that fails to compile must
     not kill the run: the engine falls back loudly to the uniform path
     and stays exact (XLA:CPU's LLVM has been seen OOMing on the 27-action
-    mixed product's escalated step — TODO.md known gap, now handled).
+    mixed product's escalated step — a known gap, now handled).
 
     The escalated state is injected (widths_for returns a per-action
     tuple while adaptation is on) so the test doesn't depend on a model

@@ -25,10 +25,10 @@ from __future__ import annotations
 
 import json
 import os
-from pathlib import Path
 
 import numpy as np
 import pytest
+from conftest import needs_reference
 
 from kafka_specification_tpu.engine import check, prepare
 from kafka_specification_tpu.engine.pipeline import (
@@ -39,7 +39,6 @@ from kafka_specification_tpu.models import async_isr, kip320, variants
 from kafka_specification_tpu.models.kafka_replication import Config
 from kafka_specification_tpu.obs.runctx import RunContext
 
-REF = Path(os.environ.get("KSPEC_REFERENCE", "/root/reference"))
 TINY = Config(2, 2, 1, 1)
 
 # fused engages at bucket >= compact_gate; 32 puts every level of these
@@ -402,10 +401,7 @@ def test_fused_vs_legacy_backends(backend):
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not (REF / "Kip101.tla").exists(),
-    reason="no reference checkout: emitted kernels unavailable",
-)
+@needs_reference
 def test_fused_vs_legacy_emitted_kernels():
     """The same parity holds on the mechanically emitted kernels (the
     CLI default path when the reference corpus is present)."""
@@ -763,3 +759,27 @@ def test_pipeline_registry_is_the_single_source():
     assert entries["device"]["fallback"] == "fused"
     assert entries["fused"]["fallback"] == "legacy"
     assert entries["legacy"]["fallback"] is None
+
+
+@pytest.mark.parametrize("pipeline,tag", [
+    ("legacy", "step"), ("fused", "fsc"), ("device", "dvl"),
+])
+def test_warm_key_round_trips_every_capacity_key(pipeline, tag):
+    """Each step-cache key layout is written once, in its builder
+    (_Step.get, FusedPipeline.succ_step, DevicePipeline._level_program);
+    pipeline.warm_key reads a logged key apart and gets the rebuilt key
+    back from that builder.  So warming a key at ITS OWN capacity must
+    give the key itself: a builder whose layout drifts from what
+    warm_key takes apart (an element added, dropped or reordered) fails
+    here, not as a silent cache miss in a served job's warm pass."""
+    from kafka_specification_tpu.engine.pipeline import key_vcap, warm_key
+
+    model = variants.make_model("Kip101", TINY,
+                                invariants=("TypeOk", "WeakIsr"))
+    pk = prepare(model)
+    check(model, pipeline=pipeline, prepared=pk, visited_backend="device",
+          **{**KW, "store_trace": False})
+    keyed = [k for k in model._step_cache if key_vcap(k) is not None]
+    assert tag in {k[0] for k in keyed}, sorted(k[0] for k in keyed)
+    for key in keyed:
+        assert warm_key(pk.step, model, key, key_vcap(key)) == key

@@ -8,6 +8,7 @@ the reference module's own TypeOk conjuncts."""
 from pathlib import Path
 
 import pytest
+from conftest import needs_reference
 
 from kafka_specification_tpu.engine import check
 from kafka_specification_tpu.models.emitted import ref_path
@@ -27,6 +28,7 @@ from kafka_specification_tpu.utils.tla_emit import (
 from kafka_specification_tpu.utils.tla_frontend import parse_tla
 
 
+@needs_reference
 def test_id_sequence_schema_inferred_from_typeok():
     """nextId \\in IdSet \\union {MaxId+1} (IdSequence.tla:28,43) infers
     the exact scalar bounds the hand mapping used."""
@@ -35,6 +37,7 @@ def test_id_sequence_schema_inferred_from_typeok():
     assert sch == {"nextId": SInt("nextId", 0, 6)}
 
 
+@needs_reference
 def test_frl_schema_inferred_from_typeok():
     """FiniteReplicatedLog's \\A replica quantified record type
     (FiniteReplicatedLog.tla:90-95) infers the full nested schema:
@@ -57,6 +60,7 @@ def test_frl_schema_inferred_from_typeok():
     ]
 
 
+@needs_reference
 @pytest.mark.slow
 def test_inferred_emitted_models_reach_golden_counts():
     """The inferred schemas drive the emitted models to the exact golden
@@ -78,6 +82,7 @@ def test_inferred_emitted_models_reach_golden_counts():
     assert r.total == 29791  # 31^3
 
 
+@needs_reference
 def test_unsupported_shapes_fail_loudly():
     """L3's message-set state (SUBSET of a record set) is a representation
     choice, not an inferable bound — the inferencer must refuse it (the

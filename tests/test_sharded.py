@@ -254,7 +254,7 @@ def test_adaptive_compact_wide_model_hybrid_unit():
     cap, escalation widens only the actions whose measured need exceeds
     their uniform buffer and pins every other action at the 256-rounded
     uniform width, keeping the program's shapes close to the
-    known-compiling uniform one (round-5 LLVM-OOM finding, TODO.md)."""
+    known-compiling uniform one (round-5 LLVM-OOM finding)."""
     import numpy as np
 
     from kafka_specification_tpu.engine.bfs import AdaptiveCompact

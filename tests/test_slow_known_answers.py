@@ -30,7 +30,7 @@ def test_kip279_strong_isr_violated_at_three_replicas():
 def test_kip320_three_broker_exhaustive_pass():
     """The THEOREM workload (Kip320.tla:168-171) at 3 brokers: all four
     invariants hold across all 737,794 states (count pinned by the oracle —
-    also the bench.py workload)."""
+    also the job perfbench's kip320-3b cells cut to depth 10)."""
     m = kip320.make_model(THREE)
     res = check(
         m,

@@ -7,10 +7,9 @@ come out of the reference text itself (utils/tla_expr + utils/tla_emit),
 and must produce bit-identical per-level state sets to the hand models.
 """
 
-from pathlib import Path
-
 import numpy as np
 import pytest
+from conftest import REFERENCE as REF, needs_reference
 
 from kafka_specification_tpu.engine import check
 from kafka_specification_tpu.models import finite_replicated_log as frl
@@ -25,8 +24,6 @@ from kafka_specification_tpu.utils.tla_emit import (
 )
 from kafka_specification_tpu.utils.tla_expr import parse_definition, parse_expr
 from kafka_specification_tpu.utils.tla_frontend import parse_tla
-
-REF = Path("/root/reference")
 
 
 def _defs(module: str) -> dict:
@@ -45,6 +42,7 @@ def _defs(module: str) -> dict:
     return out
 
 
+@needs_reference
 def test_parser_covers_l1_l2_modules():
     """Every definition of Util/IdSequence/FiniteReplicatedLog parses."""
     for module, expect in (("Util", 3), ("IdSequence", 6), ("FiniteReplicatedLog", 25)):
@@ -52,6 +50,7 @@ def test_parser_covers_l1_l2_modules():
         assert len(defs) == expect, (module, sorted(defs))
 
 
+@needs_reference
 def test_util_min_max_range_from_choose_definitions():
     """Util's operators evaluated mechanically from their CHOOSE bodies
     (Util.tla:22-24) — no hand translation anywhere in the path."""
@@ -91,6 +90,7 @@ def _emit_frl(N: int, L: int, R: int):
     )
 
 
+@needs_reference
 def test_emitted_id_sequence_matches_hand_model():
     r = check(_emit_id_sequence(5))
     rh = check(id_sequence.make_model(5))
@@ -113,22 +113,26 @@ def _assert_same_level_sets(m_emitted, m_hand):
     return r_e
 
 
+@needs_reference
 def test_emitted_frl_matches_hand_model_small():
     r = _assert_same_level_sets(_emit_frl(2, 2, 2), frl.make_model(2, 2, 2))
     assert r.total == 49
 
 
+@needs_reference
 def test_emitted_frl_matches_hand_model_single_record():
     r = _assert_same_level_sets(_emit_frl(3, 4, 1), frl.make_model(3, 4, 1))
     assert r.total == 125
 
 
+@needs_reference
 @pytest.mark.slow
 def test_emitted_frl_matches_hand_model_golden():
     r = _assert_same_level_sets(_emit_frl(3, 4, 2), frl.make_model(3, 4, 2))
     assert r.total == 29791  # the closed-form golden count (RESULTS.md)
 
 
+@needs_reference
 def test_concrete_successors_match_hand_oracle():
     """Third path: IR-driven concrete successor enumeration (tla_concrete)
     vs the hand-written set-semantics oracle, from a nontrivial state."""

@@ -1,18 +1,13 @@
 """Structural front-end: parse the reference corpus and mechanically verify
 the hand-translated models' action inventories against each module's Next."""
 
-import os
-
 import pytest
+from conftest import REFERENCE as REF, needs_reference
 
 from kafka_specification_tpu.models import async_isr, finite_replicated_log, kip320, variants
 from kafka_specification_tpu.models.kafka_replication import Config
 from kafka_specification_tpu.utils import tla_frontend as tf
 
-REF = "/root/reference"
-needs_ref = pytest.mark.skipif(
-    not os.path.isdir(REF), reason="reference corpus not mounted"
-)
 
 TINY = Config(2, 2, 1, 1)
 
@@ -42,7 +37,7 @@ Next ==
     assert tf.next_disjuncts(mod) == ["Foo", "Bar"]
 
 
-@needs_ref
+@needs_reference
 def test_reference_chain_structure():
     chain = tf.load_chain(REF, "Kip320")
     assert set(chain) >= {"Kip320", "Kip279", "KafkaReplication", "Util"}
@@ -58,7 +53,7 @@ def test_reference_chain_structure():
     assert set(kr.instances) == {"LeaderEpochSeq", "RecordSeq", "ReplicaLog"}
 
 
-@needs_ref
+@needs_reference
 @pytest.mark.parametrize(
     "module,model",
     [
@@ -76,7 +71,7 @@ def test_model_actions_match_reference_next(module, model):
     assert not problems, problems
 
 
-@needs_ref
+@needs_reference
 def test_frl_standalone_next_actions():
     """FiniteReplicatedLog's Next nests its existentials, so disjunct names
     are the three mutators; our model matches them by construction."""
@@ -102,7 +97,7 @@ Next ==
     assert tf.next_disjuncts(mod) == ["Simple", "Quantified"]
 
 
-@needs_ref
+@needs_reference
 def test_validate_cfg_constants():
     from kafka_specification_tpu.utils.cfg import parse_cfg
 
