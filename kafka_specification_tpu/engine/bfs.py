@@ -784,6 +784,13 @@ class PreparedKernels:
     spans in its trace, the daemon's warm-path proof).  ``warmup``
     optionally pre-compiles the step for a given frontier bucket so even
     the FIRST job of a shape pays no compile inside its latency budget.
+
+    Two sizing facts of the last run ride along, each a fixed point of
+    the warm protocol ``note_result`` -> ``rewarm`` -> ``check``: the
+    final visited capacity (``capacity_hint``: no growth, no eviction,
+    every step-cache key matches) and, for the ``device`` pipeline, what
+    each whole-level program measured (``level_high_waters``: every level
+    program's first dispatch fits, none is discarded and re-run).
     """
 
     def __init__(self, model: Model):
@@ -804,9 +811,25 @@ class PreparedKernels:
         # res.total.
         self.capacity_hint = None
         self._hint_is_capacity = False  # True iff hint is a device vcap
+        # The second sizing fact a warm run starts from: what each
+        # whole-level program of the last run measured (the `device`
+        # pipeline's stats["device"]["high_waters"]: per depth, the
+        # exact per-action guard density and new-state count).  A level
+        # program's first dispatch is sized from high waters that a
+        # call would otherwise start at zero, so every growing level
+        # would overflow a buffer, throw the dispatch away and run
+        # again, in every call (a third of a 5-broker pass on the
+        # chip).  Fed back, each level's first dispatch takes the sizes
+        # its re-dispatch ran at, which are a fixed point for the same
+        # reason the capacity is: the records are counts, not shapes,
+        # so a seeded run reports what the run that seeded it did.
+        self.level_high_waters: dict = {}  # depth -> record
 
     def note_result(self, res: "CheckResult") -> None:
-        """Feed a finished run's visited sizing back into the hint."""
+        """Feed a finished run's sizing back: the final visited capacity
+        into the hint, and a device-pipeline run's per-level high waters
+        (unless it fell back) into `level_high_waters`, both
+        max-merged."""
         stats = res.stats or {}
         if stats.get("visited_backend") == "device":
             cap = stats.get("visited_capacity") or res.total
@@ -815,26 +838,44 @@ class PreparedKernels:
             cap = res.total
             self._hint_is_capacity = False
         self.capacity_hint = max(self.capacity_hint or 0, cap)
+        dev = stats.get("device") or {}
+        if dev.get("fallback") is None:
+            for rec in dev.get("high_waters", ()):
+                old = self.level_high_waters.get(rec["depth"])
+                if old is not None:
+                    rec = {
+                        **rec,
+                        "density": np.maximum(
+                            old["density"], rec["density"]).tolist(),
+                        "level_new": max(old["level_new"],
+                                         rec["level_new"]),
+                    }
+                self.level_high_waters[rec["depth"]] = rec
 
     def rewarm(self) -> int:
-        """Close the warm-capacity gap left by a run that GREW the device
-        visited set: growth evicts the steps compiled at every outgrown
-        capacity, but the buckets those steps served (the small early
-        levels) recur on the next run of this shape — which starts at the
-        new capacity fixed point and would pay one compile per missing
-        (bucket, final-capacity) variant.  Re-compile them now, off any
-        job's latency path, so the second job of a shape shows zero
-        compile spans even when the first had to grow (the serving
-        warm-path contract; the daemon calls this right after a run,
-        still inside its busy-heartbeat window).  Returns the number of
-        variants compiled."""
-        cap = self.capacity_hint
-        if not cap or not getattr(self, "_hint_is_capacity", False):
-            return 0  # non-device backends never evict on growth
-        from .pipeline import key_vcap, warm_key
+        """Build, off any job's latency path, the programs the next run
+        of this shape will ask for and the cache does not hold (the
+        serving warm-path contract: the second job of a shape shows
+        zero compile spans; the daemon calls this right after a run,
+        still inside its busy-heartbeat window).  Two gaps:
 
+        - a run that GREW the device visited set evicted the steps
+          compiled at every outgrown capacity, but the buckets those
+          steps served (the small early levels) recur on the next run,
+          which starts at the new capacity fixed point: re-compile them
+          there;
+        - a run seeded with `level_high_waters` sizes each level
+          program's first dispatch from them: build the ones that
+          differ from what the run itself compiled
+          (pipeline.warm_seeded_levels).
+
+        Returns the number of programs compiled."""
+        from .pipeline import key_vcap, warm_key, warm_seeded_levels
+
+        # non-device backends never evict on growth: no `cap`, no loop
+        cap = self.capacity_hint if self._hint_is_capacity else None
         done = 0
-        for key in list(self.step._compiled_log):
+        for key in list(self.step._compiled_log) if cap else ():
             vcap = key_vcap(key)
             if vcap is None or vcap == cap:
                 continue  # no capacity component, or already at the
@@ -846,6 +887,9 @@ class PreparedKernels:
                 continue
             if warm_key(self.step, self.model, key, cap) is not None:
                 done += 1
+        if self.level_high_waters:
+            done += warm_seeded_levels(
+                self.step, self.model, self.level_high_waters, cap)
         return done
 
     @property
@@ -1106,7 +1150,10 @@ def check(
     prepared: a :class:`PreparedKernels` for this model (``prepare``):
     the serving daemon's warm path — every compiled step is re-used, so a
     warm check pays zero trace/compile (its span trace shows zero
-    ``compile`` spans).  Must wrap the SAME model object.
+    ``compile`` spans), and on the ``device`` pipeline each whole-level
+    program's first dispatch is sized from what the last run fed to
+    ``note_result`` measured, so none is discarded and re-run.  Must
+    wrap the SAME model object.
 
     collect_trace: external list receiving the per-level trace store
     ``(rows, parent, act)`` tuples (filled only while store_trace is on) —
@@ -1931,6 +1978,11 @@ def check(
         check_deadlock=check_deadlock,
         io=io,
     )
+    if getattr(pipe, "name", "") == "device" and prepared is not None:
+        # the warm protocol's second fixed point: the level programs'
+        # first dispatches start where the last run of this prepared
+        # model ended (PreparedKernels.level_high_waters)
+        pipe.seed_high_waters(prepared.level_high_waters)
     if getattr(pipe, "name", "") == "device" and shadow_rate > 0 and \
             pipe.device_fallback is None:
         # shadow re-execution replays single chunks from their pre-chunk
@@ -2929,6 +2981,11 @@ def check(
                     "device": {
                         "levels": pipe.device_levels,
                         "fallback": pipe.device_fallback,
+                        # what the level programs measured, per level,
+                        # and whether a warm call's seed sized any
+                        # (PreparedKernels.level_high_waters)
+                        "high_waters": pipe.high_waters,
+                        "seeded": pipe.seeded,
                     }
                 }
                 if getattr(pipe, "name", "") == "device"

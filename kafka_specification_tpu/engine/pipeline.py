@@ -1094,6 +1094,14 @@ class DevicePipeline:
         self.io = self.fused.io
         self.pool = PooledWidths(model.actions)
         self._ln_hw = 0  # per-level new-state high water (LN ladder)
+        #: what an earlier call of the same prepared model measured, by
+        #: depth (seed_high_waters); empty on a cold call
+        self._seed: dict = {}
+        self.seeded = False  # True once a level took its sizes from it
+        #: what THIS call measured, one record per device level: exact
+        #: guard and new-state counts, whatever the buffers were sized
+        #: from (stats["device"]["high_waters"])
+        self.high_waters: list = []
         #: sticky fallback reason; None while the level path is live
         self.device_fallback: Optional[str] = None
         self.device_levels = 0  # levels actually run device-resident
@@ -1174,23 +1182,78 @@ class DevicePipeline:
             handled = f_total
         return (chunk, nc, handled)
 
-    def _level_program(self, B: int, NCp: int, vcap: int, widths: tuple,
-                       LN: int):
+    def seed_high_waters(self, records: dict) -> None:
+        """Start this call where the last call of the same prepared
+        model ended: `records` is PreparedKernels.level_high_waters,
+        depth -> a ``high_waters`` record of earlier runs (read, never
+        written).  They only choose the FIRST dispatch's buffer sizes;
+        a record that is too small (another chunk size, a level the
+        earlier run never reached) costs the one re-dispatch a cold
+        call pays."""
+        self._seed = records
+
+    def first_dispatch_sizes(self, depth: int, B: int, NCp: int):
+        """-> (widths, T, LN) of a level's first dispatch: the two
+        sizing policies (PooledWidths.widths_for, devlevel.
+        level_new_capacity) over the high waters as they stand, raised
+        first to what the seed holds for `depth`.  The high waters only
+        climb, so a seeded level sizes exactly as a cold call's
+        re-dispatch of it did."""
+        seed = self._seed.get(depth)
+        if seed is not None:
+            np.maximum(self.pool.hw, seed["density"], out=self.pool.hw)
+            self._ln_hw = max(self._ln_hw, seed["level_new"])
+            self.seeded = True
+        widths = self.step.norm_widths(
+            B,
+            self.pool.widths_for(
+                B, np.zeros(len(self.model.actions)), B
+            ),
+        )
+        T = self.step.expand_width(B, widths)
+        # level-new capacity ladder (ops/devlevel.level_new_capacity —
+        # ONE sizing policy shared with the sharded device-resident
+        # variant): the per-chunk merge costs O(LN), so size LN from the
+        # measured per-level new-state high water, NOT the NCp*T
+        # worst case — an overflow costs exactly one re-dispatch at the
+        # safe bound, steady state costs nothing.  This is where the
+        # device pipeline's merge win comes from: the serial path
+        # scatters O(visited capacity) per CHUNK, this path scatters
+        # O(level) per chunk and O(capacity) once.
+        LN = devlevel.level_new_capacity(T, self._ln_hw, NCp * T)
+        return widths, T, LN
+
+    def level_key(self, B: int, NCp: int, vcap: int, widths: tuple,
+                  LN: int) -> tuple:
+        """The step-cache key of a level program (layouts: key_vcap)."""
+        tail = (NCp, widths, LN,
+                self.step.inv_sig(self.check_invariants),
+                self.check_deadlock)
         if self.host_mode:
             # no vcap component: the program embeds no visited set, so
             # capacity growth can never evict it (key_vcap -> None)
-            key = ("dvh", B, NCp, widths, LN,
-                   self.step.inv_sig(self.check_invariants),
-                   self.check_deadlock)
+            return ("dvh", B) + tail
+        return ("dvl", B, vcap) + tail
+
+    def empty_level_args(self, B: int, NCp: int) -> tuple:
+        """A level program's arguments for a level of no rows, short of
+        the visited set (warm_key, warm_seeded_levels)."""
+        return (
+            jnp.zeros((NCp * B, self.spec.num_lanes), jnp.uint32),
+            jnp.int32(0),
+            jnp.int32(0),
+        )
+
+    def _level_program(self, B: int, NCp: int, vcap: int, widths: tuple,
+                       LN: int):
+        key = self.level_key(B, NCp, vcap, widths, LN)
+        if self.host_mode:
             return self.step.cached(
                 key,
                 lambda: self._build_level_host(B, NCp, widths, LN),
                 bucket=B, chunks=NCp, widths=repr(widths),
                 level_new_cap=LN, program="device-level-host",
             )
-        key = ("dvl", B, vcap, NCp, widths, LN,
-               self.step.inv_sig(self.check_invariants),
-               self.check_deadlock)
         return self.step.cached(
             key,
             lambda: self._build_level(B, NCp, vcap, widths, LN),
@@ -1496,9 +1559,16 @@ class DevicePipeline:
 
     def run_level(self, frontier_np, f_total: int, depth: int,
                   vhi, vlo, vn, vcap: int, plan):
-        """Run the whole-level program (with the <=1 exact-width
-        re-dispatch on segment overflow); -> (vhi, vlo, vn, vcap,
+        """Run the whole-level program; -> (vhi, vlo, vn, vcap,
         finalize) or None to fall back to the per-chunk ladder.
+
+        The first dispatch is sized from the high waters
+        (first_dispatch_sizes).  On a cold call they hold what the
+        earlier levels of this call measured, so a level that outgrows
+        them overflows a segment and pays the <=1 exact-width
+        re-dispatch; a call seeded from the last call of the same
+        prepared model (seed_high_waters) starts each level at the
+        sizes that re-dispatch ran at and dispatches once.
 
         The overflow-flag read is the one device sync per level, so
         this call BLOCKS until the level program completes (the
@@ -1515,21 +1585,7 @@ class DevicePipeline:
         io = self.io
         program = "dvh" if self.host_mode else "dvl"
         self.chunk_retry.reset_chunk()
-        n_actions = len(self.model.actions)
-        widths = self.step.norm_widths(
-            B, self.pool.widths_for(B, np.zeros(n_actions), B)
-        )
-        T = self.step.expand_width(B, widths)
-        # level-new capacity ladder (ops/devlevel.level_new_capacity —
-        # ONE sizing policy shared with the sharded device-resident
-        # variant): the per-chunk merge costs O(LN), so size LN from the
-        # run's measured per-level new-state high water, NOT the NCp*T
-        # worst case — an overflow costs exactly one re-dispatch at the
-        # safe bound, steady state costs nothing.  This is where the
-        # device pipeline's merge win comes from: the serial path
-        # scatters O(visited capacity) per CHUNK, this path scatters
-        # O(level) per chunk and O(capacity) once.
-        LN = devlevel.level_new_capacity(T, self._ln_hw, NCp * T)
+        widths, T, LN = self.first_dispatch_sizes(depth, B, NCp)
         exact = False  # True after an overflow re-dispatch (safe bounds)
         dispatched = 0
         fbuf = None
@@ -1620,17 +1676,21 @@ class DevicePipeline:
             break
         for oc in outgrown:
             evict_vcap(self.step._cache, oc)
-        # high waters for the next level's sizing
-        np.maximum(
-            self.pool.hw, agmax_np.astype(np.float64) / max(B, 1),
-            out=self.pool.hw,
-        )
+        # high waters for the next level's sizing, and the level's own
+        # record for the next CALL's (PreparedKernels.note_result).  In
+        # host mode the level-new count is the PRE-probe one (the
+        # level-new set is what it sizes, and that set holds the
+        # not-yet-probed candidates)
+        density = agmax_np.astype(np.float64) / max(B, 1)
+        level_new = int(io.fetch(outs[5 if self.host_mode else 3]))
+        np.maximum(self.pool.hw, density, out=self.pool.hw)
+        self._ln_hw = max(self._ln_hw, level_new)
+        self.high_waters.append({
+            "depth": depth, "bucket": B, "chunks": NCp,
+            "density": density.tolist(), "level_new": level_new,
+        })
         self.device_levels += 1
         if self.host_mode:
-            # LN high water tracks the PRE-probe level-new count here
-            # (the level-new set is what it sizes, and that set holds
-            # the not-yet-probed candidates)
-            self._ln_hw = max(self._ln_hw, int(io.fetch(outs[5])))
 
             def finalize(outs=outs, dispatched=dispatched):
                 on = int(io.fetch(outs[5]))
@@ -1661,7 +1721,6 @@ class DevicePipeline:
 
             # visited refs unchanged: the host set is the visited state
             return vhi, vlo, vn, vcap, finalize
-        self._ln_hw = max(self._ln_hw, int(io.fetch(outs[3])))
         new_vhi, new_vlo, new_vn = outs[4], outs[5], outs[6]
 
         def finalize(outs=outs, dispatched=dispatched):
@@ -1723,6 +1782,36 @@ def make_pipeline(name: str, *, step_builder, model, adapt, chunk_retry,
     )
 
 
+def _warm_pipeline(step_builder, model, tag: str, inv_sig, dl):
+    """A DevicePipeline that builds programs only (warm_key,
+    warm_seeded_levels): no run state, the production gate."""
+    return DevicePipeline(
+        step_builder, model, None, None, None,
+        check_invariants=bool(inv_sig),
+        visited_backend="host" if tag == "dvh" else "device",
+        on_degrade_chunk=None, compact_shift=2, compact_gate=4096,
+        check_deadlock=dl,
+    )
+
+
+def _sibling(model, inv_sig) -> bool:
+    """True for the key of another invariant overlay's view of the model
+    (service/kernel_cache.py shares one step cache per base model)."""
+    return bool(inv_sig) and inv_sig != tuple(
+        i.name for i in model.invariants
+    )
+
+
+def _run_empty(fn, args, vcap: Optional[int]):
+    """Compile `fn` by running it on nothing: zero frontier rows and,
+    where the program takes the visited set (`vcap`), an empty one."""
+    if vcap is not None:
+        # every capacity-keyed program ends in (vhi, vlo, vn)
+        empty = jnp.full(vcap, 0xFFFFFFFF, jnp.uint32)
+        args = (*args, empty, empty, jnp.int32(0))
+    jax.block_until_ready(fn(*args))
+
+
 def warm_key(step_builder, model, key: tuple, vcap: int):
     """Re-compile one logged step-cache key at a new visited capacity —
     PreparedKernels.rewarm's per-key worker.  Returns the rebuilt key as
@@ -1732,14 +1821,9 @@ def warm_key(step_builder, model, key: tuple, vcap: int):
     tag = key[0]
     K = model.spec.num_lanes
 
-    def sibling(inv_sig):  # the key of another invariant overlay's view
-        return inv_sig and inv_sig != tuple(
-            i.name for i in model.invariants
-        )
-
     if tag == "step":
         (_t, bucket, _vcap, inv_sig, with_merge, compact, sq_full) = key
-        if sibling(inv_sig):
+        if _sibling(model, inv_sig):
             return None
         fn = step_builder.get(
             bucket, vcap, bool(inv_sig),
@@ -1751,21 +1835,11 @@ def warm_key(step_builder, model, key: tuple, vcap: int):
         )
     elif tag == "dvl":
         (_t, bucket, _vcap, ncp, widths, ln, inv_sig, dl) = key
-        if sibling(inv_sig):
+        if _sibling(model, inv_sig):
             return None
-        pipe = DevicePipeline(
-            step_builder, model, None, None, None,
-            check_invariants=bool(inv_sig),
-            visited_backend="device",
-            on_degrade_chunk=None, compact_shift=2, compact_gate=4096,
-            check_deadlock=dl,
-        )
+        pipe = _warm_pipeline(step_builder, model, tag, inv_sig, dl)
         fn = pipe._level_program(bucket, ncp, vcap, widths, ln)
-        args = (
-            jnp.zeros((ncp * bucket, K), jnp.uint32),
-            jnp.int32(0),
-            jnp.int32(0),
-        )
+        args = pipe.empty_level_args(bucket, ncp)
     elif tag == "fsc":
         (_t, bucket, _vcap, widths, with_merge, device_out) = key
         pipe = FusedPipeline(
@@ -1788,7 +1862,43 @@ def warm_key(step_builder, model, key: tuple, vcap: int):
     else:
         return None
     made = step_builder.last_key
-    # every capacity-keyed program ends in (vhi, vlo, vn): an empty set
-    empty = jnp.full(vcap, 0xFFFFFFFF, jnp.uint32)
-    jax.block_until_ready(fn(*args, empty, empty, jnp.int32(0)))
+    _run_empty(fn, args, vcap)
     return made
+
+
+def warm_seeded_levels(step_builder, model, high_waters: dict,
+                       vcap: Optional[int]) -> int:
+    """Compile the first-dispatch level programs that a call seeded
+    with `high_waters` (PreparedKernels.level_high_waters: depth ->
+    record) will ask for and the cache does not hold —
+    PreparedKernels.rewarm's second half.  One pass over the records in
+    depth order through DevicePipeline.first_dispatch_sizes, the call
+    run_level itself makes, for every level-program variant the log
+    holds (tag, invariant view, deadlock flag); `vcap` is the capacity
+    the next call starts at (None: only the capacity-free host-backend
+    programs).  Returns the programs compiled.
+
+    Where a level fits one chunk the seeded sizes ARE the sizes the
+    cold call's re-dispatch ran at, so this finds everything in the
+    cache; a multi-chunk level's seeded level-new capacity lies under
+    the safe bound of that re-dispatch, and is built here."""
+    variants = {(k[0], k[-2], k[-1]) for k in step_builder._compiled_log
+                if k[0] in ("dvl", "dvh")}
+    done = 0
+    for tag, inv_sig, dl in sorted(variants):
+        if _sibling(model, inv_sig) or (tag == "dvl" and not vcap):
+            continue
+        pipe = _warm_pipeline(step_builder, model, tag, inv_sig, dl)
+        pipe.seed_high_waters(high_waters)
+        cap = None if tag == "dvh" else vcap
+        for depth in sorted(high_waters):
+            rec = high_waters[depth]
+            B, NCp = rec["bucket"], rec["chunks"]
+            widths, _T, LN = pipe.first_dispatch_sizes(depth, B, NCp)
+            if pipe.level_key(B, NCp, cap, widths, LN) in \
+                    step_builder._cache:
+                continue
+            _run_empty(pipe._level_program(B, NCp, cap, widths, LN),
+                       pipe.empty_level_args(B, NCp), cap)
+            done += 1
+    return done

@@ -783,3 +783,249 @@ def test_warm_key_round_trips_every_capacity_key(pipeline, tag):
     assert tag in {k[0] for k in keyed}, sorted(k[0] for k in keyed)
     for key in keyed:
         assert warm_key(pk.step, model, key, key_vcap(key)) == key
+
+
+# --- the level programs' high waters outlive a check call ------------------
+#
+# A whole-level program's first dispatch is sized from high waters that a
+# cold call starts at zero; PreparedKernels keeps what a device-pipeline
+# run measured (note_result) and the next check(prepared=pk) starts each
+# level there, so it dispatches every level program once.  Two scenarios,
+# each run once per session and asserted on by the cases below:
+#
+# - "natural": Kip320 at (2, 2, 2, 2) to depth 9 under the real sizing
+#   policy, sorted device backend.  The level of 510 rows overflows an
+#   action segment (floor 256) on a cold call: the density high water.
+# - "ladder": tiny Kip101 in 32-row chunks on the host backend (dvh), the
+#   level-new ladder scaled to test size (floor 8 for T, no
+#   LN_SAFE_SMALL shortcut; otherwise level_new_capacity's own formula):
+#   every level that grows by more than the headroom overflows the
+#   level-new set on a cold call, and a seeded multi-chunk level asks for
+#   a capacity under the re-dispatch's safe bound, a program only rewarm
+#   can have built: the level-new high water and rewarm's second half.
+
+_WARM: dict = {}
+
+
+def _level_dispatches(run):
+    """The level programs' dispatch spans of a run, in order, as
+    (depth, bucket, vcap, level_new_cap, attempt, discarded)."""
+    with open(os.path.join(run.dir, "spans.jsonl")) as fh:
+        spans = [json.loads(line) for line in fh]
+    compiles = [s for s in spans
+                if s.get("span") == "compile" and s.get("ph") != "B"]
+    keys = [(s["depth"], s["bucket"], s.get("vcap"), s["level_new_cap"],
+             s["attempt"], bool(s.get("discarded")))
+            for s in spans
+            if s.get("span") == "dispatch" and s.get("ph") != "B"
+            and s.get("program") in ("dvl", "dvh")]
+    return keys, compiles
+
+
+def _trace_digest(buf):
+    """sha256 over a run's trace store (rows, parents, action ids, level
+    by level, in discovery order): stricter than the digest chain, which
+    is order-free."""
+    import hashlib
+
+    h = hashlib.sha256()
+    for level in buf:
+        for a in level:  # the pipelines differ in index dtypes, not values
+            if a is not None:
+                h.update(np.ascontiguousarray(a, np.int64).tobytes())
+    return h.hexdigest()
+
+
+def _scaled_level_new_capacity(T, ln_hw, worst):
+    from kafka_specification_tpu.engine.bfs import _next_pow2
+    from kafka_specification_tpu.ops import devlevel
+
+    return min(_next_pow2(max(8, int(devlevel.LN_HEADROOM * ln_hw) + 1)),
+               _next_pow2(worst))
+
+
+def _warm_scenario(name, tmp_root):
+    if name in _WARM:
+        return _WARM[name]
+    from kafka_specification_tpu.ops import devlevel
+
+    if name == "natural":
+        model = kip320.make_model(Config(2, 2, 2, 2))
+        kw = {**KW, "chunk_size": 4096, "max_depth": 9,
+              "visited_backend": "device"}
+        patch = None
+    else:
+        model = variants.make_model("Kip101", TINY,
+                                    invariants=("TypeOk", "WeakIsr"))
+        kw = {**KW, "chunk_size": 32, "visited_backend": "host"}
+        patch = _scaled_level_new_capacity
+    kw.pop("stats_path")
+    out: dict = {}
+
+    def one(tag, pipeline="device", pk=None, vcap=None, **extra):
+        run = RunContext(os.path.join(tmp_root, f"{name}-{tag}"))
+        buf: list = []
+        res = check(
+            model, pipeline=pipeline, prepared=pk, run=run,
+            collect_trace=buf,
+            visited_capacity_exact=vcap or (pk and pk.capacity_hint),
+            **{**kw, **extra},
+        )
+        run.deactivate()
+        keys, compiles = _level_dispatches(run)
+        return {"res": res, "keys": keys, "compiles": compiles,
+                "digest": _trace_digest(buf)}
+
+    with pytest.MonkeyPatch.context() as mp:
+        if patch is not None:
+            mp.setattr(devlevel, "level_new_capacity", patch)
+        pk = prepare(model)
+        out["cold"] = one("cold", pk=pk)
+        pk.note_result(out["cold"]["res"])
+        out["rewarmed"] = pk.rewarm()
+        out["warm"] = one("warm", pk=pk)
+        pk.note_result(out["warm"]["res"])  # as the daemon does after
+        out["rewarmed_again"] = pk.rewarm()  # every job
+        out["third"] = one("third", pk=pk)
+        out["legacy"] = one("legacy", pipeline="legacy")
+        if name == "ladder":
+            # rewarm's second half alone: drop every level program and
+            # let it build what a seeded call asks for
+            cache = model._step_cache
+            for key in [k for k in cache if k[0] in ("dvl", "dvh")]:
+                del cache[key]
+            out["rebuilt"] = pk.rewarm()
+            out["fourth"] = one("fourth", pk=pk)
+        else:
+            # the calls below start at the capacity the cold call ended
+            # at, so they find its programs where rewarm put them
+            cap = pk.capacity_hint
+            # (c) a seed from a shallower run, then the deeper call
+            shallow_pk = prepare(model)
+            out["shallow"] = one("shallow", pk=shallow_pk, vcap=cap,
+                                 max_depth=8)
+            shallow_pk.note_result(out["shallow"]["res"])
+            out["deeper"] = one("deeper", pk=shallow_pk, vcap=cap)
+            # (e) no prepared; and a prepared fed by a fused result
+            out["bare"] = one("bare", vcap=cap)
+            fused_pk = prepare(model)
+            fused = one("fused", pipeline="fused", pk=fused_pk,
+                        max_depth=3)
+            fused_pk.note_result(fused["res"])
+            out["fused_pk"] = fused_pk
+            out["after_fused"] = one("after-fused", pk=fused_pk, vcap=cap)
+    _WARM[name] = out
+    return out
+
+
+@pytest.fixture(scope="module")
+def warm_root(tmp_path_factory):
+    return str(tmp_path_factory.mktemp("warm"))
+
+
+def _discarded_levels(res):
+    return [r["depth"] for r in res.stats["levels"]
+            if r["discarded_dispatches"]]
+
+
+@pytest.mark.perf
+@pytest.mark.parametrize("scenario", [
+    "natural",
+    pytest.param("ladder", marks=pytest.mark.device_host),
+])
+def test_warm_device_pass_dispatches_each_level_once(scenario, warm_root):
+    """(a), and (d) on the host backend: after note_result + rewarm the
+    second check(prepared=pk) discards nothing, launches each level the
+    first call re-dispatched once, compiles nothing, and equals the
+    first call and the legacy pipeline in levels, total and trace."""
+    sc = _warm_scenario(scenario, warm_root)
+    cold, warm, legacy = sc["cold"], sc["warm"], sc["legacy"]
+    redone = _discarded_levels(cold["res"])
+    assert redone, "the cold call never overflowed: nothing to show"
+    assert cold["res"].stats["device"]["seeded"] is False
+    assert warm["res"].stats["device"]["seeded"] is True
+    assert warm["res"].stats["device"]["fallback"] is None
+    assert _discarded_levels(warm["res"]) == []
+    for rec in warm["res"].stats["levels"]:
+        if rec["depth"] in redone:
+            assert rec["successor_launches"] <= 1, rec
+    assert not any(k[-1] for k in warm["keys"])  # no discarded span
+    assert all(k[4] == 0 for k in warm["keys"])  # every attempt the first
+    assert warm["compiles"] == []
+    for other in (cold, legacy):
+        assert warm["res"].levels == other["res"].levels
+        assert warm["res"].total == other["res"].total
+        assert warm["digest"] == other["digest"]
+    # the records are counts, not shapes: a seeded run reports what the
+    # run that seeded it did
+    assert (warm["res"].stats["device"]["high_waters"]
+            == cold["res"].stats["device"]["high_waters"])
+    if scenario == "ladder":
+        # a multi-chunk level's seeded level-new capacity is under the
+        # re-dispatch's safe bound: a program rewarm had to build
+        committed = {(k[0], k[3]) for k in cold["keys"] if not k[-1]}
+        assert {(k[0], k[3]) for k in warm["keys"]} - committed
+
+
+@pytest.mark.perf
+@pytest.mark.parametrize("scenario", [
+    "natural",
+    pytest.param("ladder", marks=pytest.mark.device_host),
+])
+def test_warm_device_pass_is_a_fixed_point(scenario, warm_root):
+    """(b): the third call dispatches the key sequence of the second
+    (bucket, vcap, level_new_cap per level), builds nothing, and leaves
+    rewarm nothing to do; the visited capacity stays where it was."""
+    sc = _warm_scenario(scenario, warm_root)
+    assert sc["third"]["keys"] == sc["warm"]["keys"]
+    assert sc["third"]["compiles"] == []
+    assert sc["rewarmed_again"] == 0
+    assert sc["third"]["digest"] == sc["cold"]["digest"]
+    if scenario == "ladder":
+        # rewarm builds the programs a seeded call asks for (here: after
+        # every level program was dropped) and the call then builds none
+        # (at least one per (bucket, level_new_cap) it dispatched; the
+        # padded chunk count, which no span holds, tells more apart)
+        assert sc["rebuilt"] >= len({k[1:] for k in sc["warm"]["keys"]})
+        assert sc["fourth"]["keys"] == sc["warm"]["keys"]
+        assert sc["fourth"]["compiles"] == []
+    caps = [sc[k]["res"].stats["visited_capacity"]
+            for k in ("cold", "warm", "third")]
+    assert caps[0] == caps[1] == caps[2]
+
+
+def test_seed_from_a_shallower_run_redispatches_once(warm_root):
+    """(c): a seed that is too small (fed by a max_depth 8 run) costs the
+    deeper call the one re-dispatch a cold call pays at the level the
+    seed never saw, and the counts are the golden's."""
+    sc = _warm_scenario("natural", warm_root)
+    cold, deeper = sc["cold"], sc["deeper"]
+    assert _discarded_levels(sc["shallow"]["res"]) == []
+    assert sc["shallow"]["res"].stats["device"]["seeded"] is False
+    assert max(r["depth"] for r in sc["shallow"]["res"].stats["levels"]) \
+        < min(_discarded_levels(cold["res"]))
+    assert deeper["res"].stats["device"]["seeded"] is True
+    assert _discarded_levels(deeper["res"]) == _discarded_levels(
+        cold["res"])
+    for rec in deeper["res"].stats["levels"]:
+        assert rec["discarded_dispatches"] <= 1
+        assert rec["successor_launches"] <= 2
+    assert deeper["res"].levels == cold["res"].levels
+    assert deeper["res"].total == cold["res"].total
+    assert deeper["digest"] == cold["digest"]
+
+
+@pytest.mark.parametrize("which", ["bare", "after_fused"])
+def test_unseeded_device_call_dispatches_as_before(which, warm_root):
+    """(e): a call without `prepared`, and a `prepared` fed by a
+    fused-pipeline result, dispatch exactly as a cold call does."""
+    sc = _warm_scenario("natural", warm_root)
+    cold, got = sc["cold"], sc[which]
+    assert sc["fused_pk"].level_high_waters == {}
+    assert got["res"].stats["device"]["seeded"] is False
+    # (its capacity is the one the cold call ended at, handed in)
+    assert [k[:2] + k[3:] for k in got["keys"]] == [
+        k[:2] + k[3:] for k in cold["keys"]]
+    assert _discarded_levels(got["res"]) == _discarded_levels(cold["res"])
+    assert got["res"].levels == cold["res"].levels
+    assert got["digest"] == cold["digest"]

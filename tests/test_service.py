@@ -396,6 +396,57 @@ def test_warm_zero_compiles_even_after_capacity_growth(tmp_path):
     assert q.result(j2)["violation"]["depth"] == 8
 
 
+KIP320_3B_CFG = """
+SPECIFICATION Spec
+CONSTANTS
+    Replicas = {b1, b2, b3}
+    LogSize = 2
+    MaxRecords = 2
+    MaxLeaderEpoch = 2
+INVARIANTS TypeOk LeaderInIsr WeakIsr StrongIsr
+CHECK_DEADLOCK FALSE
+"""
+
+
+def test_warm_device_pipeline_job_discards_no_dispatch(tmp_path,
+                                                       monkeypatch):
+    """The warm-path contract on the device pipeline: the first solo job
+    of a shape sizes its whole-level program from high waters that start
+    at zero, overflows and re-dispatches; the daemon feeds what the run
+    measured back (note_result) and rewarms, so the SECOND job of the
+    shape dispatches every level program once and still shows zero
+    compile spans.  The daemon's gate is the production one (4,096
+    rows), so the job is Kip320 at 3 brokers cut where its frontier
+    first passes 2,048 rows (depth 7: 2,715 rows)."""
+    monkeypatch.setenv("KSPEC_PIPELINE", "device")
+    svc = tmp_path / "svc"
+    q = JobQueue(str(svc))
+    d = _daemon(svc, min_bucket=256)
+
+    def job():
+        jid = q.submit(KIP320_3B_CFG, "Kip320", kernel_source="hand",
+                       max_depth=7, solo=True)["job_id"]
+        assert d.drain_once() == 1
+        with open(os.path.join(q.run_dir(jid), "spans.jsonl")) as fh:
+            spans = [json.loads(line) for line in fh]
+        levels = [s for s in spans if s.get("span") == "dispatch"
+                  and s.get("ph") != "B" and s.get("program") == "dvl"]
+        return jid, levels
+
+    j1, cold = job()
+    j2, warm = job()
+    assert [s for s in cold if s.get("discarded")]  # the premise
+    assert len(_compile_spans(q, j1)) > 0
+    assert warm and not [s for s in warm if s.get("discarded")]
+    assert [s["attempt"] for s in warm] == [0] * len(warm)
+    assert _compile_spans(q, j2) == []
+    for jid in (j1, j2):
+        rec = q.result(jid)
+        assert rec["status"] == "complete"
+        assert rec["distinct_states"] == 10099  # levels 0-7 of the golden
+    assert q.result(j1)["levels"] == q.result(j2)["levels"]
+
+
 def test_batched_group_bit_identical_to_solo(tmp_path):
     """Jobs sharing a schema shape but differing in invariant selection
     and depth bounds coalesce into ONE engine run; every member's verdict
