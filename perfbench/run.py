@@ -18,7 +18,8 @@ metric is a file this harness finds by name (BENCHMARK.json names them):
     perfbench/metrics/<metric>.py       META + read(ctx) for one per-layer metric
 
 `--rehearse` (never given by the driver) runs the same control flow on the
-CPU at a cut depth and prints counts only: no timing, rate or device metric.
+CPU, a depth-cut job at depth 4 and an uncut one (`max_depth` null) whole,
+and prints counts only: no timing, rate or device metric.
 """
 
 import argparse
@@ -145,19 +146,25 @@ class JaxEvents:
 
 
 def golden_for(golden, max_depth):
-    """What a pass cut at `max_depth` must return."""
-    levels = golden["levels"]
+    """What a pass cut at `max_depth` (None: not cut) must return.  A
+    golden ends where the search does: at the diameter of an exhaustive
+    space, at its violation's depth, or where its derivation stopped.  A
+    pass cut above the violation's depth never meets it."""
+    levels, violation = golden["levels"], golden["violation"]
+    ends = golden["exhaustive"] or violation is not None
+    if not ends and (max_depth is None or max_depth + 1 > len(levels)):
+        raise SystemExit(
+            f"perfbench: golden holds {len(levels) - 1} levels of a space "
+            f"it does not end, the job asks for depth {max_depth}")
     if max_depth is not None:
-        if max_depth + 1 > len(levels) and not golden["exhaustive"]:
-            raise SystemExit(
-                f"perfbench: golden holds {len(levels) - 1} levels, the job "
-                f"asks for depth {max_depth}")
         levels = levels[: max_depth + 1]
+        if violation is not None and violation["depth"] > max_depth:
+            violation = None
     return {"levels": levels, "total": sum(levels),
-            "diameter": len(levels) - 1, "violation": golden["violation"]}
+            "diameter": len(levels) - 1, "violation": violation}
 
 
-def judge_pass(rec, want, allow_retrace):
+def judge_pass(rec, want):
     """Why this pass fails, as (kind, text); empty when it passed.  Kinds:
     `answer` (differs from the golden), `degraded` (the recovery ladder
     ran), `compiled` (a program was built, loaded or re-traced)."""
@@ -182,21 +189,23 @@ def judge_pass(rec, want, allow_retrace):
     n_compile = sum(1 for s in rec["spans"]["spans"] if s[0] == "compile")
     if n_compile:
         why.append(("compiled", f"{n_compile} compile spans"))
-    if not allow_retrace and rec["jax"]["backend_compiles"]:
+    if rec["jax"]["backend_compiles"]:
         why.append(("compiled", f"JAX reports {rec['jax']['backend_compiles']}"
                     " backend compiles or cache loads"))
     return why
 
 
-def oracle_prefix(job, golden, seconds, max_depth=None):
+def oracle_prefix(job, golden, seconds, max_depth=None, key=None):
     """Plain breadth-first search over the oracle twin for `seconds` (or to
     `max_depth`): per-level counts of every level it finished, held to the
     golden.  The loop is the benchmark's own; only the transition relation
-    and the invariants are the program's oracle model."""
+    and the invariants are the program's oracle model.  `key` is what the
+    visited set holds of a state: the state itself, or (the control) less."""
+    key = key or (lambda s: s)
     om = job.oracle_model()
     deadline = time.perf_counter() + seconds
     frontier = list(dict.fromkeys(om.init_states()))
-    visited = set(frontier)
+    visited = {key(s) for s in frontier}
     levels = [len(frontier)]
     violation = None
     while frontier and violation is None and (
@@ -211,8 +220,8 @@ def oracle_prefix(job, golden, seconds, max_depth=None):
                 for t in a.successors(s):
                     if om.constraint is not None and not om.constraint(t):
                         continue
-                    if t not in visited:
-                        visited.add(t)
+                    if key(t) not in visited:
+                        visited.add(key(t))
                         nxt.append(t)
         if cut:
             break
@@ -223,8 +232,12 @@ def oracle_prefix(job, golden, seconds, max_depth=None):
             levels.append(len(nxt))
         frontier = nxt
     want = golden["levels"][: len(levels)]
-    ok = levels == want and (
-        violation is None or golden["violation"] is not None)
+    # a prefix may stop short of the golden's violation; one it does find
+    # is the golden's invariant, at the golden's depth
+    want_v = golden["violation"] or {}
+    ok = levels == want and (violation is None or (
+        violation == want_v.get("invariant")
+        and len(levels) - 1 == want_v.get("depth")))
     return {"levels": levels, "ok": ok, "violation": violation}
 
 
@@ -245,11 +258,15 @@ def memory_snapshot(jax):
 
 
 def pass_options(config, traffic, job_spec, depth_override):
+    """Engine keywords of one job: the configuration's, then the traffic's,
+    then the job's own.  `max_depth` null is the job a user runs with no
+    depth cut, and stays uncut under `depth_override` (a rehearsal) too:
+    a cut would keep a violating job from its violation."""
     opts = dict(config.get("options", {}))
     opts.update(traffic.get("options", {}))
     opts.update(job_spec.get("options", {}))
     opts.setdefault("max_depth", config["max_depth"])
-    if depth_override is not None:
+    if depth_override is not None and opts["max_depth"] is not None:
         opts["max_depth"] = min(depth_override, opts["max_depth"])
     return opts
 
@@ -276,10 +293,6 @@ class Cell:
         self.jobs = list(traffic["jobs"])
         if traffic.get("order", "as-listed") == "seeded-shuffle":
             random.Random(args.seed).shuffle(self.jobs)
-        # check_sharded keeps no program across calls, so each of its passes
-        # traces, lowers and reloads them: window_retrace_s prices that
-        # instead of failing the pass (PERF.md section 7)
-        self.allow_retrace = self.job.engine == "sharded"
 
     def one_pass(self, tag):
         """Every job of the traffic once, in the seed's order."""
@@ -295,8 +308,7 @@ class Cell:
             rec["jax"] = self.events.since(mark)
             rec["job"] = spec.get("name", str(j))
             rec["failed_because"] = judge_pass(
-                rec, golden_for(self.golden, opts["max_depth"]),
-                self.allow_retrace)
+                rec, golden_for(self.golden, opts["max_depth"]))
             recs.append(rec)
         if len(recs) == 1:
             return recs[0]
@@ -315,8 +327,8 @@ class Cell:
         }
 
     def set_up(self, t_start_unix):
-        """Step 1: untimed passes until one neither compiles nor loads a
-        program (the sharded engine: one pass), then the oracle prefix."""
+        """Step 1: untimed passes until one after the first neither compiles
+        nor loads a program (either engine), then the oracle prefix."""
         mark = self.events.mark()
         passes, problems, rewarmed = [], [], 0
         for i in range(MAX_SETUP_PASSES):
@@ -328,7 +340,7 @@ class Cell:
             if wrong:
                 problems += [w[1] for w in wrong]
                 break
-            if self.allow_retrace or (i > 0 and not rec["failed_because"]):
+            if i > 0 and not rec["failed_because"]:
                 break
             if i == MAX_SETUP_PASSES - 1:
                 problems.append(f"pass {i} of set-up still compiled: "
@@ -363,15 +375,20 @@ class Cell:
 
     def window(self, seconds):
         """Step 2: whole passes back to back; a pass is never cut.  With
-        `--trace 1`, one more pass under the profiler after the first."""
+        `--trace 1`, one more pass under the profiler after the first.
+        Returns the passes, the traced pass, and the window's seconds: first
+        pass's start to last pass's end, the profiler's stretch left out."""
         args = self.args
         passes, traced = [], None
         longest = 0.0  # of the window's own passes: the first always starts
+        traced_s = 0.0
         t_window = time.perf_counter()
         while True:
             if args.trace and len(passes) == 1 and traced is None:
-                # no sample of the window's medians
+                # no sample of the window's rate or medians
+                t_traced = time.perf_counter()
                 traced = self.traced_pass()
+                traced_s = time.perf_counter() - t_traced
                 continue
             elapsed = time.perf_counter() - t_window
             if args.rehearse:
@@ -385,7 +402,7 @@ class Cell:
             timing = "" if args.rehearse else f" wall_s={rec['wall_s']:.4f}"
             print(f"# pass {len(passes)}{timing} states={rec['total']} "
                   f"failed_because={rec['failed_because']}", flush=True)
-        return passes, traced, time.perf_counter() - t_window
+        return passes, traced, time.perf_counter() - t_window - traced_s
 
     def traced_pass(self):
         trace_dir = os.path.join(self.out_dir, "trace")
@@ -431,13 +448,14 @@ class Cell:
                 metrics[name] = {"value": value, "unit": entry["unit"]}
         return metrics
 
-    def end_to_end_metrics(self, passes, setup):
+    def end_to_end_metrics(self, passes, setup, window_s):
+        """The rate is all the window's states over all its seconds, so a
+        stall in any pass moves it; `verdict_s` is the median pass."""
         if not passes:
             return {}
         units = {m["name"]: m["unit"] for m in self.bench["end_to_end"]}
         values = {
-            "states_per_s": statistics.median(
-                p["total"] / p["wall_s"] for p in passes),
+            "states_per_s": sum(p["total"] for p in passes) / window_s,
             "verdict_s": statistics.median(p["wall_s"] for p in passes),
             "setup_s": setup.get("setup_s"),
         }
@@ -477,7 +495,8 @@ def main(argv=None):
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true",
-                    help="CPU, depth %d, counts only; never a measurement"
+                    help="CPU, depth %d where the job is cut in depth, counts "
+                    "only; never a measurement"
                     % REHEARSAL_DEPTH)
     args = ap.parse_args(argv)
     t_start_unix = process_start_unix()
@@ -503,13 +522,13 @@ def main(argv=None):
     memory["after_window"] = memory_snapshot(jax)
     attempted = len(passes)
     failed = sum(1 for p in passes if p["failed_because"])
-    correct = (not setup["problems"] and failed == 0
-               and attempted >= MIN_PASSES
-               and not (traced and traced["failed_because"]))
+    compared = compare(setup, passes, traced)
+    correct = all(c["value"] >= c["at_least"] if "at_least" in c
+                  else c["value"] <= c["limit"] for c in compared.values())
 
     memory_peak = max((m.get("peak_bytes_in_use", 0)
                        for m in memory["after_window"]["stats"]), default=0)
-    trace = None
+    trace = stages = None
     if traced is not None:
         try:
             trace = run.reduce_trace(traced)
@@ -517,22 +536,26 @@ def main(argv=None):
             # still be written; without busy_s the driver refuses the line
             log(f"perfbench: trace reduction failed: {type(e).__name__}: {e}")
     if args.trace:
-        metrics = run.per_layer_metrics({
+        import stagereduce
+
+        ctx = {
             "cell": cell, "config": config, "lanes": run.job.lanes,
             "setup": setup, "passes": passes, "traced": traced, "trace": trace,
             "device_kind": device["kind"], "peaks": peaks, "chips": chips,
             "memory_peak_bytes": memory_peak, "rehearsal": args.rehearse,
-        })
+        }
+        metrics = run.per_layer_metrics(ctx)
+        stages = stagereduce.for_ctx(ctx)
     else:
-        metrics = run.end_to_end_metrics(passes, setup)
+        metrics = run.end_to_end_metrics(passes, setup, window_s)
 
-    keep = ("wall_s", "total", "levels", "failed_because", "jax",
-            "level_records", "stats")
+    keep = ("wall_s", "total", "levels", "violation", "failed_because",
+            "jax", "level_records", "stats")
     with open(os.path.join(run.out_dir, "run.json"), "w") as fh:
         json.dump({
             "workload": cell["name"], "seed": args.seed, "trace": args.trace,
             "seconds": seconds, "window_s": window_s, "setup": setup,
-            "memory": memory,
+            "memory": memory, "compared": compared,
             "passes": [{k: p.get(k) for k in keep} for p in passes],
             "traced": traced and {k: traced.get(k) for k in keep},
             "trace": trace and {k: v for k, v in trace.items()
@@ -544,16 +567,45 @@ def main(argv=None):
         print(json.dumps({
             "rehearsal": True, "correct": correct, "attempted": attempted,
             "failed": failed, "metric_names": sorted(metrics),
-            "device": device, "problems": setup["problems"]}))
+            "device": device, "problems": setup["problems"],
+            "compared": compared}))
         return 0 if correct else 1
+    for why in setup["problems"] + [
+            w[1] for p in passes + [traced or {}]
+            for w in p.get("failed_because", [])][:8]:
+        log(f"perfbench: not correct: {why}"[:400])
+    for name, c in compared.items():
+        log(f"perfbench: compared {name} = {json.dumps(c)}")
     print(json.dumps(result_line(correct, attempted, failed, metrics, device,
-                                 memory_peak, trace)), flush=True)
+                                 memory_peak, trace, stages, compared)),
+          flush=True)
     return 0
 
 
+def compare(setup, passes, traced):
+    """Every number `correct` rests on, beside its limit.  The answers are
+    exact (per-level distinct-state counts, total, diameter and verdict
+    against the oracle-derived golden), so their limit is 0 passes that
+    differ; so is the limit on passes that degraded or built a program."""
+    judged = passes + ([traced] if traced else [])
+
+    def count(kind):
+        return sum(1 for p in judged
+                   if any(w[0] == kind for w in p["failed_because"]))
+
+    return {
+        "setup_problems": {"value": len(setup["problems"]), "limit": 0},
+        "passes_off_golden": {"value": count("answer"), "limit": 0},
+        "passes_degraded": {"value": count("degraded"), "limit": 0},
+        "passes_building_a_program": {"value": count("compiled"), "limit": 0},
+        "window_passes": {"value": len(passes), "at_least": MIN_PASSES},
+    }
+
+
 def result_line(correct, attempted, failed, metrics, device, memory_peak,
-                trace):
-    """The one object the driver reads, with exactly the contract's keys."""
+                trace, stages=None, compared=None):
+    """The one object the driver reads, with the contract's keys; the
+    numbers compared come last."""
     device = dict(device, memory_peak_bytes=memory_peak)
     line = {"correct": bool(correct), "attempted": attempted,
             "failed": failed, "metrics": metrics, "device": device}
@@ -563,9 +615,19 @@ def result_line(correct, attempted, failed, metrics, device, memory_peak,
         device["busy_s"] = trace["busy_s_mean"]
         device["window_s"] = trace["window_s"]
         line["breakdown"] = {
-            "device_ops": tracereduce.top(trace["op_seconds"]),
             "idle_gaps": tracereduce.top(trace["idle_by"]),
+            # the raw operation names, summed over the devices
+            "raw_ops": tracereduce.top(trace["op_seconds"]),
         }
+        if stages:
+            # device seconds by stage and program on the busiest device
+            # (`dedup_probe/fsc_n1`), readable without the HLO
+            line["breakdown"]["device_ops"] = tracereduce.top(
+                {f"{stage}/{prog}": secs
+                 for prog, by_stage in stages["by_program"].items()
+                 for stage, secs in by_stage.items() if secs})
+    if compared is not None:
+        line["compared"] = compared
     return line
 
 

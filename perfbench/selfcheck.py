@@ -16,12 +16,16 @@ chipless run that exits non-zero naming the TPU and prints no result.
 `--all` adds: a dry run in a scratch copy (under perfbench_out/) showing that
 a new configuration, traffic mix, per-layer metric and cell are picked up
 from new files and one new BENCHMARK.json entry each (one of them a sharded
-configuration on four virtual devices), with every existing file
-byte-identical; and `run.py --rehearse` for every cell, with and without
-`--trace 1` (CPU, depth 4, counts only).
+configuration on four virtual devices; one a violating job with no depth
+cut, derived there with the plain oracle, and the same job cut above its
+violation), with every existing file byte-identical; and `run.py
+--rehearse` for every cell, with and without `--trace 1` (CPU, depth 4,
+counts only).
 
 `--derive` writes perfbench/golden/<config>.derived.json: the per-level
-counts the oracle found, the command, and whether they equal the golden.
+counts the oracle found (to `depth`, or to the violation it meets first),
+the invariant that failed if one did, the command, and whether they equal
+the golden.
 The fast checks hold every golden to its derivation record.
 
 Not under tests/: tier-1's count does not move with the benchmark.
@@ -100,16 +104,7 @@ def check_benchmark_json():
                                    "chips", "max_depth", "assumed",
                                    "guarantees", "cut")),
               f"configuration {c['name']}: file states the job and its guarantees")
-        g = harness.load_json(os.path.join(HERE, "golden", c["name"] + ".json"))
-        check(len(g["levels"]) > f["max_depth"] and "provenance" in g,
-              f"configuration {c['name']}: golden reaches its depth, with provenance")
-        d = harness.load_json(os.path.join(HERE, "golden", c["name"] + ".derived.json"))
-        n = len(d["levels"])
-        check(d["levels"] == g["levels"][:n] and n > f["max_depth"]
-              and d["total"] == sum(d["levels"]) and d["violation"] is None
-              and sum(g["levels"]) == g.get("total", g.get("total_so_far")),
-              f"configuration {c['name']}: golden equals its oracle derivation "
-              f"({n - 1} levels, {d['total']:,} states)")
+        check_golden(c["name"], f["max_depth"])
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     check("setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25,
           "setup_s is end to end, bound at most 0.25")
@@ -133,6 +128,39 @@ def check_benchmark_json():
                                            "moves")),
               f"per-layer {m['name']}: its reader file says the same")
     return bench
+
+
+def check_golden(config_name, max_depth, hdir=HERE):
+    """A configuration's golden against its oracle derivation.  A golden
+    ends at the diameter of an exhaustive space, at its violation (the
+    derivation then names the golden's invariant, at its depth, and every
+    level and the total are equal), or where its derivation stopped; a job
+    cut in depth lies inside it, an uncut one (`max_depth` null) needs a
+    golden that ends."""
+    g = harness.load_json(os.path.join(hdir, "golden", config_name + ".json"))
+    d = harness.load_json(os.path.join(hdir, "golden",
+                                       config_name + ".derived.json"))
+    n = len(d["levels"])
+    violating = g["violation"] is not None
+    ends = g["exhaustive"] or violating
+    cut_inside = max_depth is not None and len(g["levels"]) > max_depth
+    check((ends or cut_inside) and "provenance" in g,
+          f"configuration {config_name}: golden reaches its depth "
+          f"({'uncut' if max_depth is None else max_depth}), with provenance")
+    total = g.get("total", g.get("total_so_far"))
+    same = (d["levels"] == g["levels"][:n] and d["total"] == sum(d["levels"])
+            and sum(g["levels"]) == total
+            and (ends or max_depth is None or n > max_depth))
+    if violating:
+        same = (same and d["levels"] == g["levels"] and d["total"] == total
+                and d["violation"] == g["violation"]["invariant"]
+                and n - 1 == g["violation"]["depth"])
+    else:
+        same = same and d["violation"] is None and (
+            not g["exhaustive"] or d["levels"] == g["levels"])
+    check(same, f"configuration {config_name}: golden equals its oracle "
+          f"derivation ({n - 1} levels, {d['total']:,} states"
+          + (f", {d['violation']} at depth {n - 1})" if violating else ")"))
 
 
 def check_trace_reduction():
@@ -179,8 +207,21 @@ def check_output_line():
                         "breakdown"}
           and set(line["device"]) == {"platform", "kind", "count",
                                       "memory_peak_bytes", "busy_s", "window_s"}
-          and set(line["breakdown"]) == {"device_ops", "idle_gaps"},
-          "output line: --trace 1 has the contract's keys")
+          and line["breakdown"] == {"idle_gaps": [["step", 0.5]],
+                                    "raw_ops": [["a", 1.0]]},
+          "output line: --trace 1 has the contract's keys (no stage "
+          "reduction: no device_ops, the raw operation names as raw_ops)")
+    stages = {"by_program": {"fsc_n1": {"dedup_probe": 0.75, "guard": 0.0},
+                             "step_n1": {"dedup_merge": 0.25}}}
+    compared = {"passes_off_golden": {"value": 0, "limit": 0}}
+    line = harness.result_line(True, 5, 0, metrics, device, 123, trace, stages,
+                               compared)
+    check(line["breakdown"]["device_ops"] == [["dedup_probe/fsc_n1", 0.75],
+                                              ["dedup_merge/step_n1", 0.25]]
+          and line["breakdown"]["raw_ops"] == [["a", 1.0]]
+          and list(line)[-1] == "compared",
+          "output line: device_ops grouped by stage and program, raw names "
+          "beside them, the numbers compared last")
     line = harness.result_line(True, 5, 0, metrics, device, 123, None)
     check(set(line) == {"correct", "attempted", "failed", "metrics", "device"}
           and set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"},
@@ -307,8 +348,63 @@ def check_dry_run_additions():
                                "better": "lower", "source": "program_counter",
                                "layer": "exchange", "moves": "states_per_s",
                                "workloads": ["dry-x4"]})
+    # a violating job with no depth cut, and the same job cut above its
+    # violation: what the PR that brings a counterexample cell adds
+    # (configs/Kip101.cfg: WeakIsr at depth 11 after 5,491 states, CPU-sized)
+    cex = dict(base, name="dry-kip101-cex", cfg="configs/Kip101.cfg",
+               module="Kip101", max_depth=None, reduced=[],
+               source="dry run: Kip101.tla under configs/Kip101.cfg")
+    cut = dict(cex, name="dry-kip101-d8", max_depth=8, reduced=["max_depth"])
+    for conf in (cex, cut):
+        with open(os.path.join(pb, "configs", conf["name"] + ".json"), "w") as fh:
+            json.dump(conf, fh)
+        with open(os.path.join(pb, "golden", conf["name"] + ".json"), "w") as fh:
+            json.dump({"config": conf["name"], "exhaustive": False,
+                       "violation": {"invariant": "WeakIsr", "depth": 11,
+                                     "trace_len": 12},
+                       "total": 5491, "provenance": "dry run: chip_smoke.py "
+                       "_KIP101_LEVELS, re-derived in this dry run",
+                       "levels": [1, 4, 14, 44, 100, 166, 268, 456, 684, 976,
+                                  1292, 1486]}, fh)
+        bench["configs"].append({"name": conf["name"], "source": conf["source"],
+                                 "file": f"perfbench/configs/{conf['name']}.json",
+                                 "reduced": conf["reduced"], "why": "dry run"})
+    bench["workloads"].append({"name": "dry-cex", "config": "dry-kip101-cex",
+                               "traffic": "exhaustive-trace", "chips": 1,
+                               "why": "dry run"})
+    bench["workloads"].append({"name": "dry-cex-d8", "config": "dry-kip101-d8",
+                               "traffic": "exhaustive-notrace", "chips": 1,
+                               "why": "dry run"})
     with open(os.path.join(copy, "BENCHMARK.json"), "w") as fh:
         json.dump(bench, fh)
+    for conf in (cex, cut):
+        p = subprocess.run([sys.executable, os.path.join(pb, "selfcheck.py"),
+                            "--derive", conf["name"], "99"], cwd=copy,
+                           capture_output=True, text=True, timeout=600)
+        check(p.returncode == 0, f"dry run: {conf['name']} derives with the "
+              "plain oracle to its violation, equal to its golden")
+        check_golden(conf["name"], conf["max_depth"], hdir=pb)
+    def rehearsed_passes(cell, trace):
+        return harness.load_json(os.path.join(
+            copy, "perfbench_out", cell, f"seed3-trace{trace}-rehearsal",
+            "run.json"))["passes"]
+
+    rc, last = rehearse("dry-cex", 1, cwd=copy)
+    seen = rehearsed_passes("dry-cex", 1)
+    want_v = {"invariant": "WeakIsr", "depth": 11, "trace_len": 12}
+    check(rc == 0 and last.get("correct") is True and len(seen) >= 3
+          and all(p["total"] == 5491 and p["violation"]["rendered_chars"] > 0
+                  and {k: p["violation"][k] for k in want_v} == want_v
+                  for p in seen),
+          "dry run: a violating, uncut configuration enters by files and "
+          "entries alone (every pass: WeakIsr at depth 11, 5,491 states, a "
+          "rendered 12-state trace)")
+    rc, last = rehearse("dry-cex-d8", 0, cwd=copy)
+    seen = rehearsed_passes("dry-cex-d8", 0)
+    check(rc == 0 and last.get("correct") is True
+          and all(p["violation"] is None and p["total"] == 163 for p in seen),
+          "dry run: the same job cut above its violation's depth owes no "
+          "violation (cut at 8, rehearsed at depth 4: 163 states)")
     rc, last = rehearse("dry-x4", 1, cwd=copy)
     check(rc == 0 and last.get("correct") is True
           and last.get("device", {}).get("count") == 4
@@ -328,6 +424,18 @@ def check_dry_run_additions():
     added = sorted(set(after) - set(before))
     print("     added files:", added)
     shutil.rmtree(copy, ignore_errors=True)
+
+
+def check_harness_tests():
+    """The harness's own tests (CPU): what a pass owes and why it fails, the
+    window's rate, the readers, the broken timed path and the control."""
+    p = subprocess.run([sys.executable, "-m", "pytest", "-q", "-p",
+                        "no:cacheprovider", os.path.join(HERE, "tests")],
+                       cwd=ROOT, capture_output=True, text=True, timeout=3600)
+    tail = (p.stdout.strip().splitlines() or [""])[-1]
+    if p.returncode != 0:
+        print(p.stdout[-4000:], p.stderr[-2000:])
+    check(p.returncode == 0, f"pytest perfbench/tests: {tail}")
 
 
 def derive_golden(config_name, depth):
@@ -376,6 +484,7 @@ def main():
     if "--all" in sys.argv[1:]:
         check_dry_run_additions()
         check_rehearsals(bench)
+        check_harness_tests()
     print(f"{len(FAILURES)} failed")
     return 1 if FAILURES else 0
 

@@ -9,11 +9,13 @@ trace (`selfcheck_data/stages_small.json`, the expectations and how they
 were worked beside it): containers left out, stage sums plus unnamed equal
 all leaf seconds, levels and programs split right, the clock tie measured
 right.  Holds the `.xplane.pb` wire reader to a tiny profile encoded here
-by hand, and the seven record-based readers to a hand-worked pass.
+by hand, the seven record-based readers to a hand-worked pass, and the
+`exchange` stage of a sharded program to a hand-worked two-device trace.
 
 Not under tests/: tier-1's count does not move with the benchmark.
 """
 
+import contextlib
 import json
 import os
 import sys
@@ -57,7 +59,7 @@ def check_reduction():
           and set(got["stage_s"]) == set(want["stage_ns"]),
           f"stages: seconds by stage = {want['stage_ns']} ns")
     check(close(sum(got["stage_s"].values()), got["leaf_s"]),
-          "stages: the nine stages plus unnamed equal all leaf seconds")
+          "stages: the ten stages plus unnamed equal all leaf seconds")
     check(close(got["leaf_s"] + got["container_only_s"], got["busy_s"]),
           "stages: leaf seconds plus container-only time equal busy time")
     check({str(d): v for d, v in got["by_level"].items()}.keys()
@@ -102,6 +104,10 @@ def check_names():
              "fsc_n1"),
             ("jit(dvl_n1)/while:", "unnamed", "dvl_n1"),
             ("jit(x)/kspec.not_a_stage/add:", "unnamed", "x"),
+            ("jit(shl_n1)/while/body/kspec.exchange/all_to_all:", "exchange",
+             "shl_n1"),
+            ("jit(shl_n1)/while/body/kspec.exchange/closed_call/kspec.digest/"
+             "xor:", "digest", "shl_n1"),
             ("", "unnamed", "")):
         check(stagereduce.stage_of(path) == stage
               and stagereduce.program_of(path) == prog,
@@ -221,10 +227,24 @@ def check_record_readers():
                      "discarded_dispatch_share", "d2h_bytes_per_state",
                      "h2d_bytes_per_state", "fetches_per_level",
                      "store_share", "pass_overhead_ms")]
-    check(len(new_names) == 16 and all(
+    check(len(new_names) == 17 and all(
         readers[n].read(old) is None for n in new_names),
         "a program without these records or a `dir` in its manifest: all "
-        "sixteen readers return nothing and do not raise")
+        "seventeen readers return nothing and do not raise")
+
+
+@contextlib.contextmanager
+def reduction_in_place(reduced):
+    """`stagereduce.for_ctx` finds `reduced` for a context whose traced pass
+    names a run directory, without a profile on disk."""
+    found, stagereduce.find_xplane = stagereduce.find_xplane, lambda d: "x"
+    stagereduce._CACHE["x"] = reduced
+    try:
+        yield {"traced": {"manifest": {"dir": "/nowhere/traced.0"}},
+               "rehearsal": False}
+    finally:
+        stagereduce.find_xplane = found
+        del stagereduce._CACHE["x"]
 
 
 def check_stage_readers(trace, want):
@@ -232,11 +252,7 @@ def check_stage_readers(trace, want):
     readers = harness.load_metric_readers()
     reduced = stagereduce.reduce_stages(trace)
     reduced["states"] = want["states"]
-    ctx = {"traced": {"manifest": {"dir": "/nowhere/traced.0"}},
-           "rehearsal": False}
-    found, stagereduce.find_xplane = stagereduce.find_xplane, lambda d: "x"
-    stagereduce._CACHE["x"] = reduced
-    try:
+    with reduction_in_place(reduced) as ctx:
         check(close(readers["stage_guard_us_per_state"].read(ctx),
                     want["stage_guard_us_per_state"], 1e-12)
               and readers["stage_digest_us_per_state"].read(ctx) == 0.0,
@@ -249,11 +265,66 @@ def check_stage_readers(trace, want):
                     for s in stagereduce.STAGES)
         check(close(total + reduced["stage_s"]["unnamed"] * 1e6 / 1000,
                     reduced["leaf_s"] * 1e6 / 1000, 1e-12),
-              "the nine stage metrics plus unnamed give the leaf time per "
+              "the ten stage metrics plus unnamed give the leaf time per "
               "state")
-    finally:
-        stagereduce.find_xplane = found
-        del stagereduce._CACHE["x"]
+
+
+def check_exchange_stage():
+    """A sharded level program on two devices: the operations under
+    `kspec.exchange` (routing, the collective) are the `exchange` stage on
+    the busiest device, and `stage_exchange_us_per_state` reads them.
+    Hand-worked: device 1 is busy 3000 ns (its while), device 0 600; on
+    device 1 the leaves are 600 + 400 ns of exchange and 900 of the probe,
+    so 1100 ns lie in the container alone; 1000 ns over 1000 states is
+    0.001 us a state."""
+    def op(name, kind, shape="u32[8]{0}"):
+        return f"%{name} = {shape} {kind}({shape} %p), calls=%c"
+
+    head = "jit(shl_n1)/while"
+    trace = {"planes": [
+        {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": [
+            [op("fusion.1", "fusion"), 1100, 600,
+             head + "/body/kspec.exchange/rem:"]]}]},
+        {"name": "/device:TPU:1", "lines": [{"name": "XLA Ops", "events": [
+            ["%while.1 = (s32[], u32[8]{0}) while((s32[], u32[8]{0}) %t), "
+             "condition=%c, body=%b", 1000, 3000, head + ":"],
+            [op("fusion.1", "fusion"), 1100, 600,
+             head + "/body/kspec.exchange/rem:"],
+            [op("all-to-all.1", "all-to-all", "(u32[4,8]{1,0})"), 1800, 400,
+             head + "/body/kspec.exchange/all_to_all:"],
+            [op("fusion.3", "fusion"), 2600, 900,
+             head + "/body/kspec.dedup_probe/while/body/gather:"]]}]},
+        {"name": "/host:CPU", "lines": [{"name": "python", "events": [
+            ["perfbench.pass", 1000, 5000, ""],
+            ["kspec.level d=7", 1050, 4000, ""]]}]}]}
+    got = stagereduce.reduce_stages(trace)
+    check(got["plane"] == "/device:TPU:1"
+          and same_ns(got["stage_s"], {"exchange": 1000, "dedup_probe": 900})
+          and same_ns(got["by_level"][7],
+                      {"exchange": 1000, "dedup_probe": 900})
+          and same_ns(got["by_program"]["shl_n1"],
+                      {"exchange": 1000, "dedup_probe": 900})
+          and close(got["container_only_s"], 1100e-9),
+          "exchange: routing and the collective under kspec.exchange are the "
+          "exchange stage, on the busiest device, by level and by program")
+    readers = harness.load_metric_readers()
+    got["states"] = 1000
+    with reduction_in_place(got) as ctx:
+        check(close(readers["stage_exchange_us_per_state"].read(ctx), 0.001,
+                    1e-15)
+              and readers["stage_unnamed_share"].read(ctx) == 0.0,
+              "reader stage_exchange_us_per_state: 1000 ns over 1000 states; "
+              "nothing of the exchange reads as unnamed")
+    trace = {"busy_s_mean": 1.0, "window_s": 2.0, "op_seconds": {"a": 1.0},
+             "idle_by": {}}
+    line = harness.result_line(True, 3, 0, {}, {}, 0, trace, got)
+    check(line["breakdown"]["device_ops"] == [
+        ["exchange/shl_n1", 1000e-9], ["dedup_probe/shl_n1", 900e-9]],
+        "breakdown: device seconds grouped as <stage>/<program>, "
+        "largest first")
+    line = harness.result_line(True, 3, 0, {}, {}, 0, trace, None)
+    check("device_ops" not in line["breakdown"],
+          "breakdown: no stage reduction, no grouping")
 
 
 def main():
@@ -262,6 +333,7 @@ def main():
     check_wire_reader()
     check_record_readers()
     check_stage_readers(trace, want)
+    check_exchange_stage()
     print(f"{len(FAILURES)} failed")
     return 1 if FAILURES else 0
 

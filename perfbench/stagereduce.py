@@ -34,8 +34,11 @@ import os
 
 from tracereduce import PASS_ANNOTATION, _clip, _merge, find_xplane, short_name
 
+# the program's vocabulary (engine/pipeline.py STAGES) and the one stage only
+# the sharded programs have (parallel/sharded.py: routing by owner, codec
+# encode, the collective, decode)
 STAGES = ("guard", "expand", "compact", "fingerprint", "dedup_sort",
-          "dedup_probe", "dedup_merge", "invariants", "digest")
+          "dedup_probe", "dedup_merge", "invariants", "digest", "exchange")
 STAGE_PREFIX = "kspec."
 UNNAMED = "unnamed"
 CONTAINERS = ("while", "conditional", "call")
