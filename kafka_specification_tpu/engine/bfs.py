@@ -963,23 +963,45 @@ def _f_all(f) -> np.ndarray:
     return f if isinstance(f, np.ndarray) else f.read_all()
 
 
-def walk_trace(trace_store, actions, decode_row, inv_name, depth, idx) -> Violation:
+def walk_trace(trace_store, actions, decode_row, inv_name, depth, idx,
+               obs=None, source="ram") -> Violation:
     """Parent-pointer counterexample reconstruction, shared by both engines.
 
     trace_store[level] = (rows, parent, act): the level's states in discovery
     order, each new state's parent index into the previous level, and the
     action id that produced it.  Walks level `depth` index `idx` back to an
     init state and returns the Violation with the root->violation trace.
+
+    With `obs` (a RunObserver) the walk is one `counterexample` span:
+    `source` says where the store lives (`ram` | `disk`), `decode_ms` is the
+    wall of the `decode_row` calls and `walk_ms` the rest (the pointer
+    reads).
     """
+    span = obs.open_span("counterexample", invariant=inv_name, depth=depth,
+                         source=source) if obs is not None else None
+    t_walk = time.perf_counter()
+    decode_s = 0.0
+
+    def decode(row):
+        nonlocal decode_s
+        t = time.perf_counter()
+        state = decode_row(row)
+        decode_s += time.perf_counter() - t
+        return state
+
     chain = []
     i = idx
     for d in range(depth, 0, -1):
         rows, parent, act = trace_store[d]
-        chain.append((actions[int(act[i])].name, decode_row(rows[i])))
+        chain.append((actions[int(act[i])].name, decode(rows[i])))
         i = int(parent[i])
     rows0, _, _ = trace_store[0]
-    chain.append(("<init>", decode_row(rows0[i])))
+    chain.append(("<init>", decode(rows0[i])))
     chain.reverse()
+    if span is not None:
+        walk_s = time.perf_counter() - t_walk - decode_s
+        span.finish(trace_len=len(chain), walk_ms=round(walk_s * 1e3, 3),
+                    decode_ms=round(decode_s * 1e3, 3))
     return Violation(invariant=inv_name, depth=depth, state=chain[-1][1], trace=chain)
 
 
@@ -1459,9 +1481,11 @@ def check(
             # record reads through the mmap'd level segments — this is
             # what makes traces survive checkpoint/resume
             return walk_trace(
-                disk.plog.view(), model.actions, decode_state, inv_name, depth, idx
+                disk.plog.view(), model.actions, decode_state, inv_name,
+                depth, idx, obs=obs_, source="disk",
             )
-        return walk_trace(trace_store, model.actions, decode_state, inv_name, depth, idx)
+        return walk_trace(trace_store, model.actions, decode_state, inv_name,
+                          depth, idx, obs=obs_)
 
     def have_trace(depth) -> bool:
         return store_trace or (disk is not None and disk.has_trace(depth))
@@ -2106,7 +2130,7 @@ def check(
         nonlocal lvl_launches, lvl_launches_max, run_launches_max
         nonlocal lvl_act_en, a_w  # arena buffers grow via _grow_arena
         nonlocal ht_hi, ht_lo, ht_claim, hash_n
-        nonlocal lvl_store_s
+        nonlocal lvl_store_s, lvl_chunks, lvl_rows_in
         (start, fp_n, bucket, finalize, pre_v, shadow, dispatch_s,
          t_staged, piece, pre_vcap, t_dispatch) = st
         queued_s = time.perf_counter() - t_staged
@@ -2130,6 +2154,8 @@ def check(
             launches,
         ) = finalize()
         act_en_np = io.fetch(act_en, np.int64)
+        lvl_chunks += 1
+        lvl_rows_in += fp_n
         # frontier-level verdicts (states being expanded = level `depth`)
         if check_invariants:
             viol_any_np = io.fetch(viol_any)
@@ -2137,21 +2163,24 @@ def check(
                 inv_i = int(np.argmax(viol_any_np))
                 idx = start + int(io.fetch(viol_idx)[inv_i])
                 verdict = ("invariant", idx, model.invariants[inv_i].name)
-                return True
-        if check_deadlock and bool(io.fetch(dl_any)):
+        if verdict is None and check_deadlock and bool(io.fetch(dl_any)):
             verdict = ("deadlock", start + int(io.fetch(dl_idx)),
                        "Deadlock")
-            return True
-        nn = int(io.fetch(new_n))
-        if shadow:
-            # pre_vcap: the visited capacity AT DISPATCH — the next
-            # chunk's dispatch may have grown `vcap` before this commit,
-            # and the shadow cross-exec replays against the pre-chunk
-            # visited refs, which are sized at the old capacity
-            _shadow_exec(
-                piece, fp_n, bucket, start, pre_v, pre_vcap,
-                out, out_hi, out_lo, nn, viol_any, dl_any,
-            )
+        nn = 0
+        if verdict is None:
+            nn = int(io.fetch(new_n))
+            if shadow:
+                # pre_vcap: the visited capacity AT DISPATCH — the next
+                # chunk's dispatch may have grown `vcap` before this
+                # commit, and the shadow cross-exec replays against the
+                # pre-chunk visited refs, which are sized at the old
+                # capacity
+                _shadow_exec(
+                    piece, fp_n, bucket, start, pre_v, pre_vcap,
+                    out, out_hi, out_lo, nn, viol_any, dl_any,
+                )
+        # a chunk that holds the verdict is booked like any other (its
+        # step time, launches and `step` span are the cut level's)
         wait_s = time.perf_counter() - t_wait
         step_s = dispatch_s + wait_s
         prof_step += step_s
@@ -2170,7 +2199,10 @@ def check(
             dispatch_ms=round(dispatch_s * 1e3, 2),
             wait_ms=round(wait_s * 1e3, 2),
             queued_ms=round(queued_s * 1e3, 2),
+            **({"verdict": verdict[0]} if verdict is not None else {}),
         )
+        if verdict is not None:
+            return True
         t_host = time.perf_counter()
         t_host_wall = _now()
         if host_set is not None and nn:
@@ -2312,9 +2344,17 @@ def check(
         nonlocal verdict, lvl_new, prof_step, prof_host_s
         nonlocal lvl_launches, lvl_launches_max, run_launches_max
         nonlocal lvl_act_en, lvl_probe_ms, a_w, lvl_store_s
+        nonlocal lvl_chunks, lvl_rows_in
         t_wait = time.perf_counter()
         out = fin()
         wait_s = time.perf_counter() - t_wait
+        # the one program ran all the plan's chunks, or stopped at the
+        # verdict's (its index is level-global, chunk i starts at i * B)
+        ran = plan[1]
+        if out["verdict"] is not None:
+            ran = out["verdict"][1] // plan[0] + 1
+        lvl_chunks += ran
+        lvl_rows_in += min(ran * plan[0], plan[2])
         step_s = dispatch_s + wait_s
         prof_step += step_s
         launches = out["launches"]
@@ -2508,6 +2548,8 @@ def check(
             lvl_launches_max = 0  # ... and the per-chunk maximum
             lvl_probe_ms = 0.0  # deferred batched host-probe wall
             lvl_store_s = 0.0  # trace store / parent log wall (`store_ms`)
+            lvl_chunks = lvl_rows_in = 0  # chunks committed, their rows
+            lvl_discarded = 0  # chunks dispatched and dropped at a verdict
             verdict = None  # (kind, global_frontier_idx, inv_name)
             # Host-native backend: assemble the next level in a preallocated
             # arena via the fused C pass (native.FpSet.insert_compact) — one
@@ -2680,8 +2722,15 @@ def check(
                         # a verdict in chunk k: the just-dispatched chunk
                         # k+1 is DISCARDED uncommitted — exactly what the
                         # serial path's break does (its device work is
-                        # pure and side-effect-free until commit)
+                        # pure and side-effect-free until commit); its
+                        # open launch is closed as discarded, so the
+                        # level's counters hold it (a legacy chunk has
+                        # none: its dispatch is complete)
+                        launch = getattr(finalize, "launch", None)
+                        if launch is not None:
+                            launch.finish(discarded=True)
                         staged = None
+                        lvl_discarded = 1
                         break
                     staged = cur
                 else:
@@ -2695,6 +2744,24 @@ def check(
                 kind, idx, inv_name = verdict
                 if disk is not None:
                     disk.abort_level()  # partial next-level writer: discard
+                if collect_stats:
+                    # the level a verdict cuts gets a completed record of
+                    # its own (never one of stats["levels"], whose length
+                    # is the number of committed levels) and its span ends
+                    # with cut=true; the counterexample is built after it
+                    cut = result_stats["cut_level"] = dict(
+                        depth=depth + 1,
+                        frontier=f_total,
+                        rows_committed=lvl_rows_in,
+                        chunks_committed=lvl_chunks,
+                        chunks_discarded=lvl_discarded,
+                        level_ms=round((time.perf_counter() - t_level) * 1e3, 1),
+                        step_ms=round(prof_step * 1e3, 1),
+                        host_ms=round(prof_host_s * 1e3, 1),
+                        successor_launches=lvl_launches,
+                        **io.take(),
+                    )
+                    obs_.level_cut(cut)
                 if have_trace(depth):
                     violation = build_violation(inv_name, depth, idx)
                 else:

@@ -36,8 +36,8 @@ class _Dispatch:
 
     def finish(self, discarded: bool = False) -> None:
         """The host has the outputs in hand (or an error): close the span.
-        `discarded`: the outputs are thrown away and the work re-run.
-        A second call does nothing."""
+        `discarded`: the outputs are thrown away (and the work re-run,
+        unless a verdict ended the level).  A second call does nothing."""
         if self.io is None:
             return
         attrs = {}

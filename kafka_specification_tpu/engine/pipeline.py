@@ -846,9 +846,12 @@ class FusedPipeline:
         staging queue; docs/engine.md § Async execution).  finalize()
         blocks on the outputs and returns run_chunk's exact tuple —
         with overlap off check() finalizes immediately, which IS the
-        historical serial behavior.  The returned visited refs chain the
-        next chunk's dispatch on the device backend (functional, still
-        in-flight — JAX async dispatch pipelines them)."""
+        historical serial behavior.  `finalize.launch` is launch 2's
+        open dispatch: a caller that drops the chunk uncommitted (a
+        verdict in the chunk before it) finishes it as discarded.  The
+        returned visited refs chain the next chunk's dispatch on the
+        device backend (functional, still in-flight — JAX async dispatch
+        pipelines them)."""
         if not self._gate(bucket):
             return self.legacy.run_chunk_staged(
                 piece, fp_n, bucket, depth, vhi, vlo, vn, vcap
@@ -987,6 +990,7 @@ class FusedPipeline:
                         out_hi, out_lo, act_guard_np, dispatched,
                     )
 
+                finalize.launch = launch
                 return vhi, vlo, vn, finalize
             def finalize(launch=launch, act_en=act_en, committed=(
                     out, out_parent, out_act, new_n, vhi, vlo, vn,
@@ -998,6 +1002,7 @@ class FusedPipeline:
                 launch.finish()
                 return committed[:11] + (act_en_np,) + committed[12:]
 
+            finalize.launch = launch
             return vhi, vlo, vn, finalize
 
     def _actid_np(self, widths: tuple) -> np.ndarray:

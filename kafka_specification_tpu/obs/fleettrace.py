@@ -108,7 +108,7 @@ ENGINE_SPAN_KINDS = {
     "host-invariants", "level", "compile", "step", "dispatch",
     "compact-host", "store", "shadow", "host-assembly", "host-probe",
     "exchange", "exchange-level", "spill-run-write", "spill-merge",
-    "checkpoint-write", "checkpoint-verify",
+    "checkpoint-write", "checkpoint-verify", "counterexample",
 }
 ENGINE_EVENT_KINDS = {
     "pipeline-fallback",

@@ -60,8 +60,8 @@ class _Both:
             self.annotation.__exit__(None, None, None)
 
     def abandon(self) -> None:
-        """Close with no completed record (a begin marker then stays
-        unmatched, as a level cut by a verdict always has)."""
+        """Close with no completed record (the begin marker then stays
+        unmatched: a level the run died or was stopped in)."""
         if self.span is not None:
             self.span.abandon()
         if self.annotation is not None:
@@ -181,10 +181,22 @@ class RunObserver:
         )
 
     def level_abandon(self) -> None:
-        """A verdict cut the level: its begin marker stays unmatched (as
-        it always has) and it stops being the current parent."""
+        """The level loop left a level open (a typed exit mid-level): its
+        begin marker stays unmatched and it stops being the current
+        parent.  A level a verdict cuts is completed by `level_cut`."""
         if self._level is not None:
             self._level.abandon()
+            self._level = None
+
+    def level_cut(self, record: dict) -> None:
+        """A verdict cut the level: its span ends here with `cut=true`, so
+        the begin marker is matched and what follows (the counterexample)
+        is no child of it.  `record` is result.stats["cut_level"]; nothing
+        goes to the stats stream, whose records are the committed levels."""
+        if self._level is not None:
+            self._level.finish(
+                cut=True, **{k: record[k] for k in (
+                    "rows_committed", "chunks_committed", "chunks_discarded")})
             self._level = None
 
     def level(self, **fields) -> dict:
@@ -313,9 +325,9 @@ class RunObserver:
         # the run directory's own record of which path produced the
         # answer: whole-level programs run + why (if ever) the run left
         # them (`--pipeline device`); mesh size and what the exchange
-        # carried (sharded engine)
+        # carried (sharded engine); the level a verdict cut
         for key in ("device", "devices", "exchange_compressed",
-                    "exchange_bytes_total"):
+                    "exchange_bytes_total", "cut_level"):
             if key in s:
                 summary[key] = s[key]
         if result.violation is not None:

@@ -2628,13 +2628,15 @@ def check_sharded(
         caller reports the violating state trace-less)."""
         if store_trace:
             return walk_trace(
-                trace_store, model.actions, decode_row, inv_name, d_level, idx
+                trace_store, model.actions, decode_row, inv_name, d_level,
+                idx, obs=obs_,
             )
         if plog is not None and plog.has_levels(d_level):
             # per-shard on-disk parent logs: O(depth) single-row reads —
             # this is what makes sharded traces survive checkpoint resume
             return walk_trace(
-                plog.view(), model.actions, decode_row, inv_name, d_level, idx
+                plog.view(), model.actions, decode_row, inv_name, d_level,
+                idx, obs=obs_, source="disk",
             )
         return None
 
