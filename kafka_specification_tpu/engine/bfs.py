@@ -53,6 +53,7 @@ from ..resilience.retry import ChunkRetryHandler
 from ..utils.platform_guard import device_stamp
 from .hostio import HostIO
 from .pipeline import (
+    counts_out,
     fp_stage,
     grow_visited as _grow_visited,
     init_rows_program,
@@ -62,6 +63,7 @@ from .pipeline import (
     program_name,
     resolve_pipeline,
     sorted_dedup_stage,
+    split_counts,
     squeeze_stage,
     stage,
 )
@@ -739,7 +741,7 @@ class _Step:
                 )
                 return (
                     out, out_parent, out_act, n_en, vhi, vlo, vn,
-                    viol_any, viol_idx, dl_any, dl_idx, act_en,
+                    viol_any, viol_idx, dl_any, dl_idx, counts_out(act_en),
                     out_hi, out_lo, ovf_vec(sq_ovf), act_guard,
                 )
 
@@ -755,7 +757,7 @@ class _Step:
             # the shared winner-selection sequence (sort, first
             # occurrence, visited rank, compaction, rank-scatter merge)
             (out, out_parent, out_act, new_n, out_hi, out_lo,
-             vhi2, vlo2, vn2, _rank) = sorted_dedup_stage(
+             vhi2, vlo2, vn2, _rank, probe) = sorted_dedup_stage(
                 cand, parent, actid, valid, hi, lo,
                 vhi, vlo, vn, vcap, T, K, True,
             )
@@ -764,7 +766,8 @@ class _Step:
             )
             return (
                 out, out_parent, out_act, new_n, vhi2, vlo2, vn2,
-                viol_any, viol_idx, dl_any, dl_idx, act_en,
+                viol_any, viol_idx, dl_any, dl_idx,
+                counts_out(act_en, probe),
                 out_hi, out_lo, overflow, act_guard,
             )
 
@@ -2147,13 +2150,16 @@ def check(
             viol_idx,
             dl_any,
             dl_idx,
-            act_en,
+            counts,
             out_hi,
             out_lo,
             act_guard,
             launches,
         ) = finalize()
-        act_en_np = io.fetch(act_en, np.int64)
+        # the program's counts vector (pipeline.counts_out): a chunk that
+        # holds the verdict ran its probe like any other
+        act_en_np, probe = split_counts(io.fetch(counts, np.int64))
+        lvl_rounds[:] += probe
         lvl_chunks += 1
         lvl_rows_in += fp_n
         # frontier-level verdicts (states being expanded = level `depth`)
@@ -2347,6 +2353,8 @@ def check(
         nonlocal lvl_chunks, lvl_rows_in
         t_wait = time.perf_counter()
         out = fin()
+        act_en_np, probe = split_counts(out["counts"])
+        lvl_rounds[:] += probe
         wait_s = time.perf_counter() - t_wait
         # the one program ran all the plan's chunks, or stopped at the
         # verdict's (its index is level-global, chunk i starts at i * B)
@@ -2460,7 +2468,7 @@ def check(
             backend=visited_backend,
         )
         if collect_stats:
-            lvl_act_en += out["act_en"]
+            lvl_act_en += act_en_np
         return False
 
     # storage read-side corruption (read-verified CRCs on spill runs /
@@ -2546,6 +2554,9 @@ def check(
             lvl_act_en = np.zeros(len(model.actions), np.int64)
             lvl_launches = 0  # successor-kernel launches this level
             lvl_launches_max = 0  # ... and the per-chunk maximum
+            # dedup_probe search rounds the committed dispatches ran, and
+            # what searches over the whole capacity would have run
+            lvl_rounds = np.zeros(2, np.int64)
             lvl_probe_ms = 0.0  # deferred batched host-probe wall
             lvl_store_s = 0.0  # trace store / parent log wall (`store_ms`)
             lvl_chunks = lvl_rows_in = 0  # chunks committed, their rows
@@ -2759,6 +2770,8 @@ def check(
                         step_ms=round(prof_step * 1e3, 1),
                         host_ms=round(prof_host_s * 1e3, 1),
                         successor_launches=lvl_launches,
+                        probe_rounds=int(lvl_rounds[0]),
+                        probe_rounds_plain=int(lvl_rounds[1]),
                         **io.take(),
                     )
                     obs_.level_cut(cut)
@@ -2871,6 +2884,8 @@ def check(
                         **rec,
                         "successor_launches": lvl_launches,
                         "launches_per_chunk_max": lvl_launches_max,
+                        "probe_rounds": int(lvl_rounds[0]),
+                        "probe_rounds_plain": int(lvl_rounds[1]),
                         # what the host launched, moved and stored this
                         # level (engine/hostio.py; docs/observability.md)
                         **io.take(),

@@ -316,6 +316,25 @@ def _sort_first(hi, lo):  # kspec: traced
     return hi_s, lo_s, order, first
 
 
+def counts_out(act_en, probe=None):  # kspec: traced
+    """The counts a level program hands the host, in the ONE vector it
+    already fetches: the per-action enabled counts, then the two probe
+    round counts of ``dedup.probe_sorted`` summed over the program's
+    probes (zeros where it probes nothing).  :func:`split_counts` is the
+    host's half."""
+    if probe is None:
+        probe = jnp.zeros((2,), jnp.int32)
+    return jnp.concatenate([act_en, probe])
+
+
+def split_counts(counts):
+    """A fetched :func:`counts_out` vector (or a [D, n] stack of them,
+    one a shard) -> (act_en, probe): the enabled counts as fetched, and
+    int64[2], the two probe round counts summed over the shards."""
+    counts = np.asarray(counts, np.int64)
+    return counts[..., :-2], counts[..., -2:].reshape(-1, 2).sum(axis=0)
+
+
 def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
                        vhi, vlo, vn, vcap, T, K, with_merge: bool,
                        also_seen_in=None):
@@ -334,17 +353,19 @@ def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
     device-resident level-new set the compacted rank indexes into.  The
     trailing out_rank return (insertion ranks of the compacted prefix in
     the PRIMARY set) lets with_merge=False callers run their own gated
-    merge_ranked."""
+    merge_ranked; the last return is the probes' round counts
+    (``dedup.probe_sorted``, summed over the one or two probes)."""
     sent = jnp.uint32(dedup.SENT)
     # minimal-payload sort: only the original index rides through the
     # sort network; state rows/parents are gathered once afterwards
     hi_s, lo_s, order, first = _sort_first(hi, lo)
-    seen, rank = dedup.rank_sorted(vhi, vlo, vn, hi_s, lo_s)
+    seen, rank, probe = dedup.probe_sorted(vhi, vlo, vn, hi_s, lo_s)
     is_new = first & ~seen
     if also_seen_in is not None:
         a_hi, a_lo, a_n = also_seen_in
-        a_seen, _ar = dedup.rank_sorted(a_hi, a_lo, a_n, hi_s, lo_s)
+        a_seen, _ar, a_probe = dedup.probe_sorted(a_hi, a_lo, a_n, hi_s, lo_s)
         is_new = is_new & ~a_seen
+        probe = probe + a_probe
     # compact new states to the front (OOB scatter indices are dropped)
     with stage("compact"):
         pos = jnp.where(is_new, jnp.cumsum(is_new) - 1, T)
@@ -361,7 +382,7 @@ def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
             vhi, vlo, vn, out_hi, out_lo, out_rank, new_n, vcap
         )
     return (out, out_parent, out_act, new_n, out_hi, out_lo,
-            vhi, vlo, vn, out_rank)
+            vhi, vlo, vn, out_rank, probe)
 
 
 def candidate_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
@@ -388,10 +409,11 @@ def candidate_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
     later in wall time and with O(1) host syncs instead of O(chunks).
 
     Returns (out, out_parent, out_act, out_hi, out_lo, new_n,
-    n_hi, n_lo, n_rank)."""
+    n_hi, n_lo, n_rank, probe): the last is the probe's round counts
+    (``dedup.probe_sorted``)."""
     sent = jnp.uint32(dedup.SENT)
     hi_s, lo_s, order, first = _sort_first(hi, lo)
-    seen, rank = dedup.rank_sorted(lhi, llo, ln, hi_s, lo_s)
+    seen, rank, probe = dedup.probe_sorted(lhi, llo, ln, hi_s, lo_s)
     is_new = first & ~seen
     with stage("compact"):
         # sorted-order compaction: what the level-new merge consumes
@@ -410,7 +432,7 @@ def candidate_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
         out_hi = jnp.full((T,), sent).at[pos_c].set(hi)
         out_lo = jnp.full((T,), sent).at[pos_c].set(lo)
     return (out, out_parent, out_act, out_hi, out_lo, new_n,
-            n_hi, n_lo, n_rank)
+            n_hi, n_lo, n_rank, probe)
 
 
 # --------------------------------------------------------------------------
@@ -779,14 +801,14 @@ class FusedPipeline:
             hi, lo = fp_stage(out, rowvalid2, spec)
             if with_merge:
                 (out, out_parent, out_act, new_n, out_hi, out_lo,
-                 vhi, vlo, vn, _rank) = sorted_dedup_stage(
+                 vhi, vlo, vn, _rank, probe) = sorted_dedup_stage(
                     out, out_parent, out_act, rowvalid2, hi, lo,
                     vhi, vlo, vn, vcap, W, K, with_merge,
                 )
                 return (out, out_parent, out_act, new_n, out_hi, out_lo,
-                        vhi, vlo, vn, act_en)
+                        vhi, vlo, vn, counts_out(act_en, probe))
             return (out, out_parent, out_act, n_en, hi, lo,
-                    vhi, vlo, vn, act_en)
+                    vhi, vlo, vn, counts_out(act_en))
 
         return step
 
@@ -981,7 +1003,7 @@ class FusedPipeline:
                         [
                             int(ok_np[offs[i]: offs[i + 1]].sum())
                             for i in range(len(widths))
-                        ],
+                        ] + [0, 0],  # counts_out's layout: no probe ran
                         np.int64,
                     )
                     return (
@@ -1310,7 +1332,7 @@ class DevicePipeline:
 
             def body(carry):  # kspec: traced
                 (i, orows, opar, oact, on, lhi, llo, ln,
-                 vkind, vinv, vidx, act_en, agmax, dig, ovf) = carry
+                 vkind, vinv, vidx, act_en, agmax, dig, ovf, probe) = carry
                 with stage("guard"):
                     start = i * B
                     rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
@@ -1334,7 +1356,7 @@ class DevicePipeline:
                 # level-new (its ranks drive the gated merge below),
                 # also_seen_in = the read-only visited set
                 (n_out, n_par, n_act, new_n, n_hi, n_lo, _l1, _l2,
-                 _l3, n_rank) = sorted_dedup_stage(
+                 _l3, n_rank, c_probe) = sorted_dedup_stage(
                     cand, parent, actid, rowvalid, hi, lo,
                     lhi, llo, ln, LN, T, K, False,
                     also_seen_in=(vhi, vlo, vn),
@@ -1388,7 +1410,7 @@ class DevicePipeline:
                     vidx = jnp.where(take, g_idx, vidx)
                 return (i + 1, orows, opar, oact, on + app_n,
                         lhi, llo, ln, vkind, vinv, vidx,
-                        act_en, agmax, dig, ovf)
+                        act_en, agmax, dig, ovf, probe + c_probe)
 
             def cond(carry):  # kspec: traced
                 return (carry[0] < n_chunks) & (carry[8] == 0)
@@ -1409,9 +1431,10 @@ class DevicePipeline:
                     jnp.zeros((n_actions,), jnp.int32),
                     devlevel.zero_digest(),
                     jnp.bool_(False),
+                    jnp.zeros((2,), jnp.int32),
                 )
             (_i, orows, opar, oact, on, lhi, llo, _ln, vkind, vinv,
-             vidx, act_en, agmax, dig, ovf) = jax.lax.while_loop(
+             vidx, act_en, agmax, dig, ovf, probe) = jax.lax.while_loop(
                 cond, body, init
             )
             # ONE O(capacity) merge per level (the serial path pays one
@@ -1419,12 +1442,13 @@ class DevicePipeline:
             # visited set by construction, so the rank-scatter merge of
             # the sorted level-new prefix lands the identical sorted
             # visited array
-            _f, rank_v = dedup.rank_sorted(vhi, vlo, vn, lhi, llo)
+            _f, rank_v, m_probe = dedup.probe_sorted(vhi, vlo, vn, lhi, llo)
             vhi, vlo, vn = dedup.merge_ranked(
                 vhi, vlo, vn, lhi, llo, rank_v, on, vcap
             )
             return (orows, opar, oact, on, vhi, vlo, vn, vkind, vinv,
-                    vidx, act_en, agmax, dig, ovf)
+                    vidx, counts_out(act_en, probe + m_probe), agmax, dig,
+                    ovf)
 
         return level
 
@@ -1467,7 +1491,7 @@ class DevicePipeline:
 
             def body(carry):  # kspec: traced
                 (i, orows, opar, oact, ohi, olo, on, lhi, llo, ln,
-                 vkind, vinv, vidx, act_en, agmax, ovf) = carry
+                 vkind, vinv, vidx, act_en, agmax, ovf, probe) = carry
                 with stage("guard"):
                     start = i * B
                     rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
@@ -1487,7 +1511,7 @@ class DevicePipeline:
                                          T, K)
                 hi, lo = fp_stage(cand, rowvalid, spec)
                 (n_out, n_par, n_act, n_ohi, n_olo, new_n,
-                 s_hi, s_lo, s_rank) = candidate_dedup_stage(
+                 s_hi, s_lo, s_rank, c_probe) = candidate_dedup_stage(
                     cand, parent, actid, rowvalid, hi, lo,
                     lhi, llo, ln, T, K,
                 )
@@ -1530,7 +1554,7 @@ class DevicePipeline:
                     vidx = jnp.where(take, g_idx, vidx)
                 return (i + 1, orows, opar, oact, ohi, olo,
                         on + app_n, lhi, llo, ln, vkind, vinv, vidx,
-                        act_en, agmax, ovf)
+                        act_en, agmax, ovf, probe + c_probe)
 
             def cond(carry):  # kspec: traced
                 return (carry[0] < n_chunks) & (carry[10] == 0)
@@ -1552,13 +1576,13 @@ class DevicePipeline:
                     jnp.zeros((n_actions,), jnp.int32),
                     jnp.zeros((n_actions,), jnp.int32),
                     jnp.bool_(False),
+                    jnp.zeros((2,), jnp.int32),
                 )
             (_i, orows, opar, oact, ohi, olo, on, _lh, _ll, _ln,
-             vkind, vinv, vidx, act_en, agmax, ovf) = jax.lax.while_loop(
-                cond, body, init
-            )
+             vkind, vinv, vidx, act_en, agmax, ovf,
+             probe) = jax.lax.while_loop(cond, body, init)
             return (orows, opar, oact, ohi, olo, on, vkind, vinv,
-                    vidx, act_en, agmax, ovf)
+                    vidx, counts_out(act_en, probe), agmax, ovf)
 
         return level
 
@@ -1719,7 +1743,7 @@ class DevicePipeline:
                     ),
                     new_n=on,
                     verdict=verdict,
-                    act_en=io.fetch(outs[9], np.int64),
+                    counts=io.fetch(outs[9], np.int64),
                     digest=None,  # host folds the probe survivors
                     launches=dispatched,
                 )
@@ -1744,7 +1768,7 @@ class DevicePipeline:
                 act=io.fetch(outs[2][:on]),
                 new_n=on,
                 verdict=verdict,
-                act_en=io.fetch(outs[10], np.int64),
+                counts=io.fetch(outs[10], np.int64),
                 digest=devlevel.digest_ints(
                     tuple(io.fetch(a) for a in outs[12])
                 ),

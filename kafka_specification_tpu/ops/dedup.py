@@ -4,10 +4,18 @@ This is the device-resident replacement for TLC's FPSet + StateQueue: the
 visited set is a sorted array of fingerprint pairs living in HBM; each BFS
 level sorts the candidate fingerprints (XLA sort on TPU), drops in-batch
 duplicates by adjacent comparison, and probes the visited set with a
-fixed-iteration vectorized binary search (jit-friendly: no data-dependent
-control flow).  The probe's insertion ranks are all the merge needs: it
-places the new entries by them and counts the visited entries' shifts from
-them (histogram + prefix sum), with no search of its own.
+vectorized binary search that a top-bits directory bounds: the probe first
+finds where each value of the top ``k`` bits of ``hi`` starts in the sorted
+set, then searches every query inside its own bucket only, for as many
+rounds as the fullest bucket needs (a device value: 5-7 rounds on hashed
+fingerprints where the pinned capacity alone would ask for 22; the bit
+length of the set where every entry shares its top bits, and the search is
+then the plain one).  Both lanes of the set ride one ``[2, cap]`` buffer,
+so a round is one gather.  Directory and buffer are rebuilt inside every
+probe from the set it is handed: no carried state, exact for any data.
+The probe's insertion ranks are all the merge needs:
+it places the new entries by them and counts the visited entries' shifts
+from them (histogram + prefix sum), with no search of its own.
 """
 
 from __future__ import annotations
@@ -40,40 +48,98 @@ def rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
     ``dedup_probe`` stage).
 
     set_hi/set_lo: uint32[cap] sorted ascending on (hi, lo) for the first
-    set_n entries (the rest is sentinel padding).  Fixed-iteration binary
-    search — static trip count, fully vectorized over queries.  Returns
-    (found_mask, rank) where rank is the insertion index (bisect_left).
+    set_n entries (the rest is sentinel padding).  Binary search bounded
+    by a top-bits directory (module docstring), fully vectorized over the
+    queries, which may come in any order.  Returns (found_mask, rank)
+    where rank is the insertion index (bisect_left).
     """
+    found, rank, _rounds = probe_sorted(set_hi, set_lo, set_n, q_hi, q_lo)
+    return found, rank
+
+
+def probe_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
+    """:func:`rank_sorted` and what it cost: -> (found, rank, rounds) with
+    ``rounds`` int32[2]: the search rounds this probe ran over its query
+    lanes (a device value) and the rounds a search over the whole
+    capacity runs (``cap.bit_length()``, a shape).  The level programs
+    sum it over their probes and hand it to the host with their counts
+    (level record ``probe_rounds`` / ``probe_rounds_plain``)."""
     with jax.named_scope(_PROBE):
-        return _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo)
+        found, rank, rounds = _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo)
+        plain = max(1, set_hi.shape[0].bit_length())
+        return found, rank, jnp.stack([rounds, jnp.int32(plain)])
 
 
-def _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
-    """rank_sorted's body, in no stage scope of its own."""
-    cap = set_hi.shape[0]
-    n_q = q_hi.shape[0]
-    lo_i = jnp.zeros((n_q,), jnp.int32)
-    hi_i = jnp.broadcast_to(jnp.asarray(set_n, jnp.int32), (n_q,))
-    iters = max(1, cap.bit_length())
+def directory_bits(n_q: int) -> int:
+    """Top bits of ``hi`` the probe's directory resolves, from the number
+    of query lanes (a shape): about one bucket per 16 lanes, between 64
+    and 65,536 buckets.  One more bit saves every lane a round and doubles
+    the boundary queries, each a search of the whole set (B rounds), so
+    the work 2^k B + n_q (log2 n - k) is least near 2^k = n_q / (B ln 2),
+    whatever the set holds.  Timed on the chip at 2,048 to 1,769,472 lanes
+    (PERF.md section 6, PR 31)."""
+    return min(16, max(6, (max(n_q, 1) // 16).bit_length() - 1))
+
+
+def _bit_length(x):
+    return (32 - jax.lax.clz(x)).astype(jnp.int32)
+
+
+def _search(pairs, lo_i, hi_i, q_hi, q_lo, rounds):
+    """`rounds` halvings of every lane's interval [lo_i, hi_i) of the
+    sorted `pairs` (uint32[2, cap]: the hi lanes, the lo lanes) -> lo_i.
+    `rounds` may be a device value: the loop stays rolled, one body."""
+    cap = pairs.shape[1]
 
     def body(_, carry):
         lo_i, hi_i = carry
         active = lo_i < hi_i  # guard: an empty interval must stay put (mid
         # would read one-past-the-end, which JAX clamps to the last element)
         mid = (lo_i + hi_i) // 2
-        midc = jnp.minimum(mid, cap - 1)
-        mh = set_hi[midc]
-        ml = set_lo[midc]
-        less = (mh < q_hi) | ((mh == q_hi) & (ml < q_lo))
+        m = pairs[:, jnp.minimum(mid, cap - 1)]
+        less = (m[0] < q_hi) | ((m[0] == q_hi) & (m[1] < q_lo))
         return (
             jnp.where(active & less, mid + 1, lo_i),
             jnp.where(active & ~less, mid, hi_i),
         )
 
-    lo_i, _ = jax.lax.fori_loop(0, iters, body, (lo_i, hi_i))
-    idx = jnp.minimum(lo_i, cap - 1)
-    found = (lo_i < set_n) & (set_hi[idx] == q_hi) & (set_lo[idx] == q_lo)
-    return found, lo_i
+    lo_i, _ = jax.lax.fori_loop(0, rounds, body, (lo_i, hi_i))
+    return lo_i
+
+
+def _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
+    """rank_sorted's body, in no stage scope of its own: -> (found, rank,
+    rounds run over the query lanes)."""
+    cap = set_hi.shape[0]
+    set_n = jnp.asarray(set_n, jnp.int32)
+    # both lanes in ONE buffer, read by one gather a round: on the chip a
+    # gather out of one [2, cap] operand costs a fifth of two gathers out
+    # of the two [cap] lanes (PERF.md section 6, PR 31)
+    pairs = jnp.stack([set_hi, set_lo])
+    k = directory_bits(q_hi.shape[0])
+    nb = 1 << k
+    # start[b] = lower bound of (b << (32 - k), 0) in the live prefix,
+    # start[nb] = set_n.  (hi, lo) order is monotone in hi >> (32 - k), so
+    # a query's lower bound in the set is its lower bound inside
+    # [start[b], start[b + 1]) for its own b: exact for any data
+    start = jnp.concatenate([
+        _search(
+            pairs,
+            jnp.zeros((nb,), jnp.int32), jnp.broadcast_to(set_n, (nb,)),
+            jnp.arange(nb, dtype=jnp.uint32) << (32 - k),
+            jnp.zeros((nb,), jnp.uint32),
+            _bit_length(set_n),
+        ),
+        set_n[None],
+    ])
+    # as many rounds as the fullest bucket needs: a handful on hashed
+    # fingerprints, bit_length(set_n) where one bucket holds the set
+    rounds = _bit_length(jnp.max(start[1:] - start[:-1]))
+    b = (q_hi >> (32 - k)).astype(jnp.int32)
+    rank = _search(pairs, start[b], start[b + 1], q_hi, q_lo, rounds)
+    at = pairs[:, jnp.minimum(rank, cap - 1)]
+    found = (rank < set_n) & (at[0] == q_hi) & (at[1] == q_lo)
+    return found, rank, rounds
 
 
 def member_sorted(set_hi, set_lo, set_n, q_hi, q_lo):

@@ -43,7 +43,7 @@ from ..engine.bfs import (
     walk_trace,
 )
 from ..engine.hostio import HostIO
-from ..engine.pipeline import stage
+from ..engine.pipeline import counts_out, split_counts, stage
 from ..ops import devlevel
 from ..pipeline_registry import resolve_pipeline
 from ..models.base import Model
@@ -503,8 +503,9 @@ def _make_sharded_step(
             )
             vn2 = vn
             rank = jnp.zeros((R,), jnp.int32)
+            probe = None
         else:
-            seen, rank = dedup.rank_sorted(vhi, vlo, vn, hi_s, lo_s)
+            seen, rank, probe = dedup.probe_sorted(vhi, vlo, vn, hi_s, lo_s)
             is_new = first & ~seen
 
         with stage("compact"):
@@ -555,7 +556,9 @@ def _make_sharded_step(
             jnp.stack(viol_idx)[None],
             jnp.any(deadlocked)[None],
             jnp.argmax(deadlocked)[None],
-            act_en[None],  # [1, n_actions] -> [D, n_actions]
+            # [1, n_actions + 2] -> [D, n_actions + 2]: the enabled counts
+            # and the probe's round counts (pipeline.counts_out)
+            counts_out(act_en, probe)[None],
             # per-action expansion overflow + pre-constraint guard counts:
             # the host sizes adaptive per-action compact buffers from the
             # guard histogram exactly as the single-device engine does
@@ -597,7 +600,7 @@ def _make_sharded_step(
             P("d", None),  # viol_idx [D, n_inv]
             P("d"),        # deadlock any
             P("d"),        # deadlock idx
-            P("d", None),  # act_en [D, n_actions]
+            P("d", None),  # counts [D, n_actions + 2]
             P("d", None),  # ovf_expand [D, n_actions]
             P("d", None),  # act_guard [D, n_actions]
             P("d"),        # ovf_dest
@@ -724,7 +727,7 @@ def _make_sharded_level(
         def body(carry):  # kspec: traced
             (i, orows, opar, oact, on, lhi, llo, ln,
              vkind, vshard, vinv, vidx,
-             act_en, agmax, dig, s_acc, r_acc, ovf, nclean) = carry
+             act_en, agmax, dig, s_acc, r_acc, ovf, nclean, probe) = carry
             start = i * B
             rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
             fvalid = (
@@ -754,7 +757,7 @@ def _make_sharded_level(
             # level-new sorted set (its ranks drive the gated merge
             # below), also_seen_in = the read-only visited shard
             (n_out, n_par, n_act, new_n, n_hi, n_lo, _l1, _l2, _l3,
-             n_rank) = sorted_dedup_stage(
+             n_rank, c_probe) = sorted_dedup_stage(
                 r_cand, r_parent, r_act,
                 ~((r_hi == sent) & (r_lo == sent)),
                 r_hi, r_lo, lhi, llo, ln, LN, R, K, False,
@@ -840,7 +843,8 @@ def _make_sharded_level(
                     jnp.where(take, vd, vshard),
                     jnp.where(take, inv_i, vinv),
                     jnp.where(take, vix_l, vidx),
-                    act_en, agmax, dig, s_acc, r_acc, ovf, nclean)
+                    act_en, agmax, dig, s_acc, r_acc, ovf, nclean,
+                    probe + c_probe)
 
         def cond(carry):  # kspec: traced
             return (carry[0] < ncs) & (carry[8] == 0)
@@ -862,16 +866,17 @@ def _make_sharded_level(
             jnp.zeros((5,), jnp.uint32),
             jnp.bool_(False),
             jnp.int32(0),
+            jnp.zeros((2,), jnp.int32),
         )
         (_i, orows, opar, oact, on, lhi, llo, _ln, vkind, vshard,
          vinv, vidx, act_en, agmax, dig, s_acc, r_acc, ovf,
-         nclean) = jax.lax.while_loop(cond, body, init)
+         nclean, probe) = jax.lax.while_loop(cond, body, init)
         # ONE O(capacity) merge per shard per level (the per-chunk path
         # pays one per chunk): every level-new entry is disjoint from
         # the visited shard by construction, so the rank-scatter merge
         # of the sorted level-new prefix lands the identical sorted
         # visited array
-        _s, rank_v = dedup.rank_sorted(vhi, vlo, vn, lhi, llo)
+        _s, rank_v, m_probe = dedup.probe_sorted(vhi, vlo, vn, lhi, llo)
         vhi, vlo, vn = dedup.merge_ranked(
             vhi, vlo, vn, lhi, llo, rank_v, on, vcap
         )
@@ -885,7 +890,8 @@ def _make_sharded_level(
             vlo[None],
             vn[None],
             vkind[None], vshard[None], vinv[None], vidx[None],
-            act_en[None],  # [1, n_actions]
+            # [1, n_actions + 2]: enabled counts, probe round counts
+            counts_out(act_en, probe + m_probe)[None],
             agmax[None],
             dc[None], dxh[None], dxl[None],  # digest accumulator...
             dlimbs[None],  # ... (count, xors, 16-bit sum limbs)
@@ -914,7 +920,7 @@ def _make_sharded_level(
             P("d", None),  # merged visited lo
             P("d"),        # merged visited counts
             P("d"), P("d"), P("d"), P("d"),  # verdict kind/shard/inv/idx
-            P("d", None),  # act_en [D, n_actions]
+            P("d", None),  # counts [D, n_actions + 2]
             P("d", None),  # agmax [D, n_actions]
             P("d"), P("d"), P("d"),  # digest count/xor_hi/xor_lo
             P("d", None),  # digest sum limbs [D, 4]
@@ -989,7 +995,7 @@ def _make_sharded_level_host(
         def body(carry):  # kspec: traced
             (i, orows, opar, oact, ohi, olo, on, lhi, llo, ln,
              vkind, vshard, vinv, vidx,
-             act_en, agmax, s_acc, r_acc, ovf, nclean) = carry
+             act_en, agmax, s_acc, r_acc, ovf, nclean, probe) = carry
             start = i * B
             rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
             fvalid = (
@@ -1015,7 +1021,7 @@ def _make_sharded_level_host(
             # shard's level-new sorted set, NO visited probe (that is
             # the host's one batched call after the program)
             (n_out, n_par, n_act, new_n, n_hi, n_lo, _l1, _l2, _l3,
-             n_rank) = sorted_dedup_stage(
+             n_rank, c_probe) = sorted_dedup_stage(
                 r_cand, r_parent, r_act,
                 ~((r_hi == sent) & (r_lo == sent)),
                 r_hi, r_lo, lhi, llo, ln, LN, R, K, False,
@@ -1089,7 +1095,8 @@ def _make_sharded_level_host(
                     jnp.where(take, vd, vshard),
                     jnp.where(take, inv_i, vinv),
                     jnp.where(take, vix_l, vidx),
-                    act_en, agmax, s_acc, r_acc, ovf, nclean)
+                    act_en, agmax, s_acc, r_acc, ovf, nclean,
+                    probe + c_probe)
 
         def cond(carry):  # kspec: traced
             return (carry[0] < ncs) & (carry[10] == 0)
@@ -1112,10 +1119,11 @@ def _make_sharded_level_host(
             jnp.zeros((5,), jnp.uint32),
             jnp.bool_(False),
             jnp.int32(0),
+            jnp.zeros((2,), jnp.int32),
         )
         (_i, orows, opar, oact, ohi, olo, on, _lh, _ll, _ln, vkind,
          vshard, vinv, vidx, act_en, agmax, s_acc, r_acc, ovf,
-         nclean) = jax.lax.while_loop(cond, body, init)
+         nclean, probe) = jax.lax.while_loop(cond, body, init)
         return (
             orows,  # [OC, K] -> [D*OC, K]
             opar,
@@ -1124,7 +1132,7 @@ def _make_sharded_level_host(
             olo,
             on[None],
             vkind[None], vshard[None], vinv[None], vidx[None],
-            act_en[None],
+            counts_out(act_en, probe)[None],
             agmax[None],
             s_acc[None], r_acc[None],  # [1, 5] framing accumulators
             ovf[None],
@@ -1147,7 +1155,7 @@ def _make_sharded_level_host(
             P("d"),        # candidate fingerprint lo lanes
             P("d"),        # per-shard pre-probe candidate counts
             P("d"), P("d"), P("d"), P("d"),  # verdict kind/shard/inv/idx
-            P("d", None),  # act_en [D, n_actions]
+            P("d", None),  # counts [D, n_actions + 2]
             P("d", None),  # agmax [D, n_actions]
             P("d", None),  # sent framing accumulator [D, 5]
             P("d", None),  # recv framing accumulator [D, 5]
@@ -2718,6 +2726,9 @@ def check_sharded(
             # the novelty masks — received candidates per OWNER shard
             lvl_en_per_shard = np.zeros(D, np.int64)
             lvl_recv_per_shard = np.zeros(D, np.int64)
+            # dedup_probe search rounds of the committed dispatches, all
+            # shards, and what whole-capacity searches would have run
+            lvl_rounds = np.zeros(2, np.int64)
             lvl_exch_bytes = lvl_exch_raw_bytes = 0
             # dispatched collective-bearing programs this level — one
             # launch PER SHARD each (the kspec_shard_launches_level
@@ -3114,9 +3125,10 @@ def check_sharded(
                 lvl_new_per_shard += newc
                 shard_visited += newc
                 if obs_.collect:
-                    act_en_np = io.fetch(act_en).astype(np.int64)
+                    act_en_np, probe = split_counts(io.fetch(act_en))
                     lvl_act_en += act_en_np.sum(axis=0)
                     lvl_en_per_shard += act_en_np.sum(axis=1)
+                    lvl_rounds[:] += probe
                 return False
 
             def _commit_timed(st):
@@ -3492,11 +3504,10 @@ def check_sharded(
                     lvl_recv_per_shard += counts
                     shard_visited += counts
                 if obs_.collect:
-                    act_en_np = io.fetch(outs[i_aen]).astype(
-                        np.int64
-                    )
+                    act_en_np, probe = split_counts(io.fetch(outs[i_aen]))
                     lvl_act_en += act_en_np.sum(axis=0)
                     lvl_en_per_shard += act_en_np.sum(axis=1)
+                    lvl_rounds[:] += probe
                 for d in range(D):
                     offs[d] = min(nc * B, lens[d])
                 prof_host_s += time.perf_counter() - t_commit
@@ -3622,6 +3633,8 @@ def check_sharded(
                     # (= launches PER SHARD; in-memory only, like the
                     # launch counters of the single-device engine)
                     "shard_launches": int(lvl_dispatches),
+                    "probe_rounds": int(lvl_rounds[0]),
+                    "probe_rounds_plain": int(lvl_rounds[1]),
                     # the single-device engine's host/device split and
                     # what the host launched and moved this level
                     # (engine/hostio.py; docs/observability.md)

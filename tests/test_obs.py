@@ -194,9 +194,68 @@ def test_stats_shim_record_for_record_identical(tmp_path):
          if k not in ("successor_launches", "launches_per_chunk_max",
                       "io_hidden_ms", "io_exposed_ms",
                       "overlap_efficiency", "host_probe_ms",
-                      "store_ms") + LEVEL_COUNTERS}
+                      "store_ms", "probe_rounds",
+                      "probe_rounds_plain") + LEVEL_COUNTERS}
         for r in r1.stats["levels"]
     ] == recs_bare
+
+
+# --- the probe's round counts (level records, both engines) -------------
+
+KIP320_LEVELS_TO_8 = [1, 6, 30, 138, 366, 1170, 2715, 5673, 10836]
+
+
+def _assert_probe_rounds(records):
+    """Every record: 0 <= probe_rounds <= probe_rounds_plain, and a level
+    that dispatched a probing program counts the capacity's rounds (a probe
+    of an empty set runs none of its own)."""
+    for rec in records:
+        assert 0 <= rec["probe_rounds"] <= rec["probe_rounds_plain"], rec
+        if rec["dispatches"]:
+            assert rec["probe_rounds_plain"] > 0, rec
+
+
+@pytest.mark.parametrize("pipeline", [None, "device"],
+                         ids=["fused", "whole-level"])
+def test_level_records_carry_the_probe_rounds(tmp_path, pipeline):
+    """configs/Kip320.cfg cut to depth 8: every level record says how many
+    search rounds its probes ran and how many a search of the whole
+    capacity would have; the counts are the golden's either way, and the
+    emitted stream does not gain the fields."""
+    from kafka_specification_tpu.utils.cfg import build_model, parse_cfg
+
+    model = build_model("Kip320", parse_cfg("configs/Kip320.cfg"))
+    run = RunContext(str(tmp_path / "run"))
+    res = check(model, max_depth=8, run=run, pipeline=pipeline)
+    assert res.ok and res.levels == KIP320_LEVELS_TO_8
+    records = res.stats["levels"]
+    assert len(records) == 8
+    _assert_probe_rounds(records)
+    # hashed fingerprints: the directory does the work, far under the
+    # fixed count, and the late levels do search (the set is not empty)
+    assert records[-1]["probe_rounds"] > 0
+    assert sum(r["probe_rounds"] for r in records) < \
+        0.6 * sum(r["probe_rounds_plain"] for r in records)
+    assert all("probe_rounds" not in r for r in _records(run.stats_path))
+
+
+def test_cut_level_carries_the_probe_rounds(tmp_path):
+    """The level a verdict cuts (the rejected KIP-320 design at three
+    replicas and one record a log, the smallest constants at which it
+    fails: configs/Kip320FirstTry.cfg's own job is the slow set's) has the
+    two fields too; verdict and counts are the ones tests/test_cex_cell.py
+    replays through the oracle."""
+    from kafka_specification_tpu.models import kip320
+    from kafka_specification_tpu.models.kafka_replication import Config
+
+    model = kip320.make_first_try_model(Config(3, 1, 1, 2))
+    res = check(model, min_bucket=1024, run=RunContext(str(tmp_path / "r")))
+    v = res.violation
+    assert (v.invariant, v.depth, len(v.trace), res.total) == \
+        ("WeakIsr", 11, 12, 78832)
+    cut = res.stats["cut_level"]
+    _assert_probe_rounds(res.stats["levels"] + [cut])
+    assert cut["probe_rounds_plain"] > 0 and cut["dispatches"] > 0
 
 
 # --- engine-threaded run dirs -------------------------------------------
@@ -249,7 +308,8 @@ def test_sharded_per_shard_breakdowns_and_imbalance(tmp_path):
         {k: v for k, v in r.items()
          if k not in ("exch_bytes", "exch_raw_bytes", "io_hidden_ms",
                       "io_exposed_ms", "shard_launches",
-                      "host_probe_ms", "step_ms", "host_ms")
+                      "host_probe_ms", "step_ms", "host_ms",
+                      "probe_rounds", "probe_rounds_plain")
          + LEVEL_COUNTERS}
         for r in res.stats["levels"]
     ] == recs

@@ -111,6 +111,27 @@ def test_merge_ranked_equals_sorted_union(visited_vals, new_vals):
     assert (mhi[vn + nn :] == SENT).all() and (mlo[vn + nn :] == SENT).all()
 
 
+@settings(max_examples=40, deadline=None)
+@given(
+    st.lists(st.integers(0, 2**64 - 2), min_size=0, max_size=80, unique=True),
+    st.lists(st.integers(0, 2**64 - 1), min_size=1, max_size=80),
+    st.integers(0, 9),
+    st.sampled_from([0, 16, 32, 48, 60]),
+)
+def test_rank_sorted_equals_searchsorted(set_keys, queries, slack, squeeze):
+    """rank_sorted == np.searchsorted(side="left") on the pairs as 64-bit
+    keys, and found == membership: any set, queries in any order with
+    duplicates; `squeeze` shifts the keys down so that every entry shares
+    its top bits (what an exact64 model's raw lanes look like)."""
+    keys = np.unique(np.array(set_keys, np.uint64) >> np.uint64(squeeze))
+    q = np.array(queries, np.uint64)
+    q = np.where(q == np.uint64(2**64 - 1), q, q >> np.uint64(squeeze))
+    # one capacity and one lane count, so one compiled program serves every
+    # example; `slack` entries fewer leave a sentinel tail of that length
+    keys = keys[: max(0, 80 - slack)]
+    _assert_rank(keys, 80, np.resize(q, 80))
+
+
 def _pairs(keys, size):
     """uint64 keys -> (hi, lo) uint32[size], sorted, sentinel-padded."""
     SENT = np.uint32(0xFFFFFFFF)
@@ -131,6 +152,105 @@ def _np_merge(vkeys, nkeys, out_cap):
 
 def _k(hi, lo):
     return (hi << 32) | lo
+
+
+_RANK_SORTED = jax.jit(dedup.rank_sorted)
+_MEMBER_SORTED = jax.jit(dedup.member_sorted)
+_PROBE_SORTED = jax.jit(dedup.probe_sorted)
+
+
+def _assert_rank(keys, cap, q):
+    """rank_sorted(the sorted `keys` in a capacity of `cap`, queries `q`)
+    against numpy on the 64-bit keys -> the probe's round counts."""
+    keys = np.sort(np.asarray(keys, np.uint64))
+    q = np.asarray(q, np.uint64)
+    n = len(keys)
+    hi, lo = _pairs(keys, cap)
+    q_hi = (q >> np.uint64(32)).astype(np.uint32)
+    q_lo = (q & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    args = (jnp.asarray(hi), jnp.asarray(lo), jnp.int32(n),
+            jnp.asarray(q_hi), jnp.asarray(q_lo))
+    found, rank = _RANK_SORTED(*args)
+    want = np.searchsorted(keys, q, side="left")
+    np.testing.assert_array_equal(np.asarray(rank), want)
+    np.testing.assert_array_equal(np.asarray(found), np.isin(q, keys))
+    np.testing.assert_array_equal(
+        np.asarray(_MEMBER_SORTED(*args)), np.isin(q, keys))
+    f2, r2, rounds = _PROBE_SORTED(*args)
+    np.testing.assert_array_equal(np.asarray(r2), want)
+    np.testing.assert_array_equal(np.asarray(f2), np.asarray(found))
+    rounds = np.asarray(rounds)
+    assert 0 <= rounds[0] <= max(n, 1).bit_length() <= rounds[1]
+    assert rounds[1] == max(1, cap.bit_length())
+    return rounds
+
+
+_ALL_ONES = 2**64 - 1
+_BUCKET = [_k(0x00080000, 7), _k(0x00080000, 9), _k(0x000800FF, 0),
+           _k(0x000FFFFF, 0xFFFFFFFF)]  # one bucket of a 13-bit directory
+# (name, set keys, capacity, queries)
+_RANK_CASES = [
+    ("empty_set", [], 8, [0, _k(3, 3), _ALL_ONES]),
+    ("set_n_eq_cap", [_k(i * 0x11111111, i) for i in range(8)], 8,
+     [0, _k(0x11111111, 1), _k(0x11111111, 2), _k(0x77777777, 7),
+      _k(0x77777777, 8), _ALL_ONES]),
+    ("set_n_1", [_k(5, 5)], 8, [0, _k(5, 4), _k(5, 5), _k(5, 6), _ALL_ONES]),
+    ("one_bucket_equal_hi", [_k(9, i * 3) for i in range(40)], 64,
+     [_k(9, i) for i in range(0, 125, 2)] + [_k(8, 1), _k(10, 0)]),
+    ("raw_lanes_zero_top_bits", list(range(0, 3000, 7)), 512,
+     list(range(0, 3100, 5))),
+    ("queries_unsorted", [_k(i * 0x01000193 % 2**32, i) for i in range(50)],
+     64, [_k(i * 0x01000193 % 2**32, i % 3) for i in range(70, -1, -1)]),
+    ("queries_duplicated", [_k(i << 24, i) for i in range(30)], 32,
+     [_k(7 << 24, 7)] * 9 + [_k(7 << 24, 8)] * 9 + [_k(3 << 24, 0)] * 5),
+    ("below_first_above_last", [_k(0x40000000 + i, 0) for i in range(20)],
+     32, [0, 1, _k(0x3FFFFFFF, 0xFFFFFFFF), _k(0x40000014, 0),
+          _k(0xF0000000, 0), _ALL_ONES - 1]),
+    ("bucket_first_and_last_entry",
+     [_k(0x0007FFFF, 0xFFFFFFFF)] + _BUCKET + [_k(0x00100000, 0)], 16,
+     _BUCKET + [_k(0x00080000, 0), _k(0x00080000, 8), _k(0x00100000, 0),
+                _k(0x0007FFFF, 0xFFFFFFFF)]),
+    ("sentinel_lanes", [_k(1, 1), _k(0xFFFFFFFF, 0xFFFFFFFE)], 8,
+     [_k(1, 1)] + [_ALL_ONES] * 12),
+    ("cap_not_a_power_of_two", [_k(i * 0x9E3779B1 % 2**32, i)
+                                for i in range(37)], 41,
+     [_k(i * 0x9E3779B1 % 2**32, i & 1) for i in range(64)]),
+    ("many_lanes_wide_directory", [_k(i * 0x9E3779B1 % 2**32, 1)
+                                   for i in range(300)], 300,
+     [_k(i * 0x85EBCA6B % 2**32, 1) for i in range(5000)]),
+]
+
+
+@pytest.mark.parametrize("case", _RANK_CASES, ids=[c[0] for c in _RANK_CASES])
+def test_rank_sorted_cases(case):
+    """The inputs a directory-bounded search could get wrong and the plain
+    one could not, each against numpy's searchsorted on the 64-bit keys."""
+    _name, keys, cap, q = case
+    _assert_rank(keys, cap, q)
+
+
+def test_probe_rounds_follow_the_fullest_bucket():
+    """The mechanism: on hashed pairs the probe runs the rounds its fullest
+    bucket needs, a third of what the capacity asks for; on a set whose
+    entries share their top bits it runs the set's bit length, the plain
+    search's count; the answers are numpy's in both."""
+    rng = np.random.default_rng(31)
+    n, cap = 1 << 17, 1 << 21
+    uniform = np.unique(rng.integers(0, 2**64 - 2**33, size=n + 64,
+                                     dtype=np.uint64))[:n]
+    one_bucket = np.unique(rng.integers(0, 2**40, size=n + 64,
+                                        dtype=np.uint64))[:n]
+    for keys, limit in ((uniform, 8), (one_bucket, n.bit_length())):
+        q = np.concatenate([
+            keys[rng.integers(0, n, size=n // 2)],
+            rng.integers(0, int(keys[-1]) + 2**20, size=n // 4,
+                         dtype=np.uint64),
+            np.full(n // 4, _ALL_ONES, np.uint64),  # the sentinel tail
+        ])
+        rounds = _assert_rank(keys, cap, q)
+        assert rounds[0] <= limit and rounds[1] == cap.bit_length()
+    # the one-bucket set ran (nearly) the plain count: the search is today's
+    assert rounds[0] >= n.bit_length() - 1
 
 
 # (name, visited keys, cap, new keys, M, out_cap, gate new_n to 0?,

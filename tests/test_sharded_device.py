@@ -103,6 +103,32 @@ def test_sharded_device_compressed_exchange(monkeypatch):
         res.stats["exchange_raw_bytes_total"]
 
 
+@pytest.mark.parametrize("pipeline", ["legacy", "device"],
+                         ids=["per-chunk", "whole-level"])
+def test_sharded_level_records_carry_the_probe_rounds(tmp_path, pipeline):
+    """The sharded twin of tests/test_obs.py's: configs/Kip320.cfg cut to
+    depth 8 on the mesh, every level record with the rounds its shards'
+    probes ran (each shard its own count: the loop holds no collective)
+    and the rounds whole-capacity searches would have run; the counts
+    are the golden's."""
+    from kafka_specification_tpu.utils.cfg import build_model, parse_cfg
+
+    model = build_model("Kip320", parse_cfg("configs/Kip320.cfg"))
+    res = check_sharded(model, max_depth=8, pipeline=pipeline,
+                        store_trace=False,
+                        run=RunContext(str(tmp_path / "run")))
+    assert res.ok
+    assert res.levels == [1, 6, 30, 138, 366, 1170, 2715, 5673, 10836]
+    records = res.stats["levels"]
+    assert len(records) == 8
+    for rec in records:
+        assert 0 <= rec["probe_rounds"] <= rec["probe_rounds_plain"], rec
+        assert rec["probe_rounds_plain"] > 0, rec
+    assert records[-1]["probe_rounds"] > 0
+    assert sum(r["probe_rounds"] for r in records) < \
+        0.6 * sum(r["probe_rounds_plain"] for r in records)
+
+
 @pytest.mark.perf
 def test_sharded_device_launches_per_level(tmp_path):
     """The O(1)-launches/level/shard contract, span-tracer-verified:
