@@ -2766,6 +2766,7 @@ def check(
                         rows_committed=lvl_rows_in,
                         chunks_committed=lvl_chunks,
                         chunks_discarded=lvl_discarded,
+                        chunks=lvl_chunks + lvl_discarded,
                         level_ms=round((time.perf_counter() - t_level) * 1e3, 1),
                         step_ms=round(prof_step * 1e3, 1),
                         host_ms=round(prof_host_s * 1e3, 1),
@@ -2884,6 +2885,9 @@ def check(
                         **rec,
                         "successor_launches": lvl_launches,
                         "launches_per_chunk_max": lvl_launches_max,
+                        # chunks the level streamed (a whole-level
+                        # program: the chunks it ran)
+                        "chunks": lvl_chunks,
                         "probe_rounds": int(lvl_rounds[0]),
                         "probe_rounds_plain": int(lvl_rounds[1]),
                         # what the host launched, moved and stored this

@@ -24,6 +24,17 @@ present); N <= 4 keeps the 2^N-bit subset lattice within one signed int32
 element (the packing dtype).
 
 WLOG the fixed `Leader` constant is replica 0.
+
+Sizes at the encoding's limit of 4 replicas (plain oracle, PR 32; the
+engine's counts are equal level by level): MaxOffset 2 / MaxVersion 2:
+165,312 states, diameter 21 (`oracle_bfs` over `make_oracle`, equal to the
+engine's count of ISSUE 32, `cli check --cpu --visited-backend host`);
+MaxOffset 3 / MaxVersion 3 (configs/AsyncIsrFourBroker.cfg, the benchmark's
+`asyncisr-4b`): 8,134,400 states, diameter 30, ValidHighWatermark holds on
+every state, all 31 levels oracle-derived
+(perfbench/golden/asyncisr-4b.derived.json).  Static fanout
+there is 37 choice slots (4 + 16 + 4 + 4 + 1 + 4 + 4), the packed state 4
+lanes, and three enabled candidates in four are duplicates by depth 14.
 """
 
 from __future__ import annotations

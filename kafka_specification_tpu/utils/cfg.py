@@ -111,7 +111,8 @@ KAFKA_VARIANTS = ("KafkaTruncateToHighWatermark", "Kip101", "Kip279")
 
 # shipped .cfg files whose stem is not a module name (TLC pairs Model.cfg
 # with Model.tla; these document their explicit `--module`)
-CFG_MODULE_ALIASES = {"Kip320Stretch": "Kip320", "Kip320FiveBroker": "Kip320"}
+CFG_MODULE_ALIASES = {"Kip320Stretch": "Kip320", "Kip320FiveBroker": "Kip320",
+                      "AsyncIsrFourBroker": "AsyncIsr"}
 
 
 def _setlen(v) -> int:

@@ -29,7 +29,7 @@ from test_oracle_replay import replay_through_oracle
 TINY = Config(2, 2, 1, 1)
 KIP101_LEVELS = [1, 4, 14, 44, 100, 166, 268, 456, 684, 976, 1292, 1486]
 CUT_KEYS = {"depth", "frontier", "rows_committed", "chunks_committed",
-            "chunks_discarded", "level_ms", "step_ms", "host_ms",
+            "chunks_discarded", "chunks", "level_ms", "step_ms", "host_ms",
             "successor_launches", "probe_rounds",
             "probe_rounds_plain"} | set(hostio.LEVEL_COUNTERS)
 # the fused path from 64 rows up, so a small chunk leaves launch 2 in flight
@@ -200,6 +200,7 @@ def test_multi_chunk_cut_reports_the_chunk_it_dropped(tmp_path, overlap):
     assert rec["frontier"] == 1486
     dropped = 1 if overlap else 0
     assert rec["chunks_discarded"] == dropped
+    assert rec["chunks"] == 4 + dropped  # every chunk the level dispatched
     assert rec["discarded_dispatches"] == dropped
     assert (rec["discarded_ms"] > 0) == overlap
     # the dropped chunk's launches are dispatches, not committed launches:

@@ -195,7 +195,7 @@ def test_stats_shim_record_for_record_identical(tmp_path):
                       "io_hidden_ms", "io_exposed_ms",
                       "overlap_efficiency", "host_probe_ms",
                       "store_ms", "probe_rounds",
-                      "probe_rounds_plain") + LEVEL_COUNTERS}
+                      "probe_rounds_plain", "chunks") + LEVEL_COUNTERS}
         for r in r1.stats["levels"]
     ] == recs_bare
 
