@@ -53,6 +53,7 @@ from ..resilience.retry import ChunkRetryHandler
 from ..utils.platform_guard import device_stamp
 from .hostio import HostIO
 from .pipeline import (
+    WORK_FIELDS,
     counts_out,
     fp_stage,
     grow_visited as _grow_visited,
@@ -66,6 +67,7 @@ from .pipeline import (
     split_counts,
     squeeze_stage,
     stage,
+    work_record,
 )
 
 # insert-or-find on the device hash table; table + claim lattice donated so
@@ -757,7 +759,7 @@ class _Step:
             # the shared winner-selection sequence (sort, first
             # occurrence, visited rank, compaction, rank-scatter merge)
             (out, out_parent, out_act, new_n, out_hi, out_lo,
-             vhi2, vlo2, vn2, _rank, probe) = sorted_dedup_stage(
+             vhi2, vlo2, vn2, _rank, work) = sorted_dedup_stage(
                 cand, parent, actid, valid, hi, lo,
                 vhi, vlo, vn, vcap, T, K, True,
             )
@@ -767,7 +769,7 @@ class _Step:
             return (
                 out, out_parent, out_act, new_n, vhi2, vlo2, vn2,
                 viol_any, viol_idx, dl_any, dl_idx,
-                counts_out(act_en, probe),
+                counts_out(act_en, work),
                 out_hi, out_lo, overflow, act_guard,
             )
 
@@ -2157,9 +2159,9 @@ def check(
             launches,
         ) = finalize()
         # the program's counts vector (pipeline.counts_out): a chunk that
-        # holds the verdict ran its probe like any other
-        act_en_np, probe = split_counts(io.fetch(counts, np.int64))
-        lvl_rounds[:] += probe
+        # holds the verdict ran its probe and merge like any other
+        act_en_np, work = split_counts(io.fetch(counts, np.int64))
+        lvl_work[:] += work
         lvl_chunks += 1
         lvl_rows_in += fp_n
         # frontier-level verdicts (states being expanded = level `depth`)
@@ -2353,8 +2355,8 @@ def check(
         nonlocal lvl_chunks, lvl_rows_in
         t_wait = time.perf_counter()
         out = fin()
-        act_en_np, probe = split_counts(out["counts"])
-        lvl_rounds[:] += probe
+        act_en_np, work = split_counts(out["counts"])
+        lvl_work[:] += work
         wait_s = time.perf_counter() - t_wait
         # the one program ran all the plan's chunks, or stopped at the
         # verdict's (its index is level-global, chunk i starts at i * B)
@@ -2554,9 +2556,10 @@ def check(
             lvl_act_en = np.zeros(len(model.actions), np.int64)
             lvl_launches = 0  # successor-kernel launches this level
             lvl_launches_max = 0  # ... and the per-chunk maximum
-            # dedup_probe search rounds the committed dispatches ran, and
-            # what searches over the whole capacity would have run
-            lvl_rounds = np.zeros(2, np.int64)
+            # pipeline.work_counts of the committed dispatches: the probes'
+            # search rounds and the merges' touched slots, each beside what
+            # the form over the whole capacity would have run
+            lvl_work = np.zeros(len(WORK_FIELDS), np.int64)
             lvl_probe_ms = 0.0  # deferred batched host-probe wall
             lvl_store_s = 0.0  # trace store / parent log wall (`store_ms`)
             lvl_chunks = lvl_rows_in = 0  # chunks committed, their rows
@@ -2771,8 +2774,7 @@ def check(
                         step_ms=round(prof_step * 1e3, 1),
                         host_ms=round(prof_host_s * 1e3, 1),
                         successor_launches=lvl_launches,
-                        probe_rounds=int(lvl_rounds[0]),
-                        probe_rounds_plain=int(lvl_rounds[1]),
+                        **work_record(lvl_work),
                         **io.take(),
                     )
                     obs_.level_cut(cut)
@@ -2888,8 +2890,7 @@ def check(
                         # chunks the level streamed (a whole-level
                         # program: the chunks it ran)
                         "chunks": lvl_chunks,
-                        "probe_rounds": int(lvl_rounds[0]),
-                        "probe_rounds_plain": int(lvl_rounds[1]),
+                        **work_record(lvl_work),
                         # what the host launched, moved and stored this
                         # level (engine/hostio.py; docs/observability.md)
                         **io.take(),

@@ -316,23 +316,45 @@ def _sort_first(hi, lo):  # kspec: traced
     return hi_s, lo_s, order, first
 
 
-def counts_out(act_en, probe=None):  # kspec: traced
+#: The level-record fields of :func:`work_counts`, in the vector's order.
+WORK_FIELDS = ("probe_rounds", "probe_rounds_plain",
+               "merge_slots", "merge_slots_plain")
+
+
+def work_counts(probe=None, merge=None):  # kspec: traced
+    """int32[4] (:data:`WORK_FIELDS`), the dedup work a program did beside its answers: the two
+    round counts of ``dedup.probe_sorted`` (rounds run, rounds a search of
+    the whole capacity runs), then the two slot counts of
+    ``dedup.merge_counted`` (slots touched, slots a capacity-wide merge
+    touches); zeros for the half not given.  Vectors of several probes and
+    merges add."""
+    zero = jnp.zeros((2,), jnp.int32)
+    return jnp.concatenate([zero if probe is None else probe,
+                            zero if merge is None else merge])
+
+
+def counts_out(act_en, work=None):  # kspec: traced
     """The counts a level program hands the host, in the ONE vector it
-    already fetches: the per-action enabled counts, then the two probe
-    round counts of ``dedup.probe_sorted`` summed over the program's
-    probes (zeros where it probes nothing).  :func:`split_counts` is the
-    host's half."""
-    if probe is None:
-        probe = jnp.zeros((2,), jnp.int32)
-    return jnp.concatenate([act_en, probe])
+    already fetches: the per-action enabled counts, then the four
+    :func:`work_counts` summed over the program's probes and merges (zeros
+    where it ran none).  :func:`split_counts` is the host's half."""
+    if work is None:
+        work = work_counts()
+    return jnp.concatenate([act_en, work])
 
 
 def split_counts(counts):
     """A fetched :func:`counts_out` vector (or a [D, n] stack of them,
-    one a shard) -> (act_en, probe): the enabled counts as fetched, and
-    int64[2], the two probe round counts summed over the shards."""
+    one a shard) -> (act_en, work): the enabled counts as fetched, and
+    int64[4], the :func:`work_counts` summed over the shards."""
     counts = np.asarray(counts, np.int64)
-    return counts[..., :-2], counts[..., -2:].reshape(-1, 2).sum(axis=0)
+    n = len(WORK_FIELDS)
+    return counts[..., :-n], counts[..., -n:].reshape(-1, n).sum(axis=0)
+
+
+def work_record(work):
+    """Summed :func:`work_counts` -> the four level-record fields."""
+    return dict(zip(WORK_FIELDS, (int(x) for x in work)))
 
 
 def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
@@ -353,8 +375,8 @@ def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
     device-resident level-new set the compacted rank indexes into.  The
     trailing out_rank return (insertion ranks of the compacted prefix in
     the PRIMARY set) lets with_merge=False callers run their own gated
-    merge_ranked; the last return is the probes' round counts
-    (``dedup.probe_sorted``, summed over the one or two probes)."""
+    merge_ranked; the last return is the stage's :func:`work_counts` (the
+    one or two probes' rounds, the merge's slots where with_merge)."""
     sent = jnp.uint32(dedup.SENT)
     # minimal-payload sort: only the original index rides through the
     # sort network; state rows/parents are gathered once afterwards
@@ -377,12 +399,13 @@ def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
         out_lo = jnp.full((T,), sent).at[pos].set(lo_s)
         out_rank = jnp.zeros((T,), jnp.int32).at[pos].set(rank)
         new_n = jnp.sum(is_new, dtype=jnp.int32)
+    slots = None
     if with_merge:
-        vhi, vlo, vn = dedup.merge_ranked(
+        vhi, vlo, vn, slots = dedup.merge_counted(
             vhi, vlo, vn, out_hi, out_lo, out_rank, new_n, vcap
         )
     return (out, out_parent, out_act, new_n, out_hi, out_lo,
-            vhi, vlo, vn, out_rank, probe)
+            vhi, vlo, vn, out_rank, work_counts(probe, slots))
 
 
 def candidate_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
@@ -409,8 +432,8 @@ def candidate_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
     later in wall time and with O(1) host syncs instead of O(chunks).
 
     Returns (out, out_parent, out_act, out_hi, out_lo, new_n,
-    n_hi, n_lo, n_rank, probe): the last is the probe's round counts
-    (``dedup.probe_sorted``)."""
+    n_hi, n_lo, n_rank, work): the last is the probe's rounds as
+    :func:`work_counts`."""
     sent = jnp.uint32(dedup.SENT)
     hi_s, lo_s, order, first = _sort_first(hi, lo)
     seen, rank, probe = dedup.probe_sorted(lhi, llo, ln, hi_s, lo_s)
@@ -432,7 +455,7 @@ def candidate_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
         out_hi = jnp.full((T,), sent).at[pos_c].set(hi)
         out_lo = jnp.full((T,), sent).at[pos_c].set(lo)
     return (out, out_parent, out_act, out_hi, out_lo, new_n,
-            n_hi, n_lo, n_rank, probe)
+            n_hi, n_lo, n_rank, work_counts(probe))
 
 
 # --------------------------------------------------------------------------
@@ -801,12 +824,12 @@ class FusedPipeline:
             hi, lo = fp_stage(out, rowvalid2, spec)
             if with_merge:
                 (out, out_parent, out_act, new_n, out_hi, out_lo,
-                 vhi, vlo, vn, _rank, probe) = sorted_dedup_stage(
+                 vhi, vlo, vn, _rank, work) = sorted_dedup_stage(
                     out, out_parent, out_act, rowvalid2, hi, lo,
                     vhi, vlo, vn, vcap, W, K, with_merge,
                 )
                 return (out, out_parent, out_act, new_n, out_hi, out_lo,
-                        vhi, vlo, vn, counts_out(act_en, probe))
+                        vhi, vlo, vn, counts_out(act_en, work))
             return (out, out_parent, out_act, n_en, hi, lo,
                     vhi, vlo, vn, counts_out(act_en))
 
@@ -1003,7 +1026,8 @@ class FusedPipeline:
                         [
                             int(ok_np[offs[i]: offs[i + 1]].sum())
                             for i in range(len(widths))
-                        ] + [0, 0],  # counts_out's layout: no probe ran
+                        ] + [0] * len(WORK_FIELDS),  # counts_out's
+                        # layout: no dedup ran
                         np.int64,
                     )
                     return (
@@ -1332,7 +1356,7 @@ class DevicePipeline:
 
             def body(carry):  # kspec: traced
                 (i, orows, opar, oact, on, lhi, llo, ln,
-                 vkind, vinv, vidx, act_en, agmax, dig, ovf, probe) = carry
+                 vkind, vinv, vidx, act_en, agmax, dig, ovf, work) = carry
                 with stage("guard"):
                     start = i * B
                     rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
@@ -1356,7 +1380,7 @@ class DevicePipeline:
                 # level-new (its ranks drive the gated merge below),
                 # also_seen_in = the read-only visited set
                 (n_out, n_par, n_act, new_n, n_hi, n_lo, _l1, _l2,
-                 _l3, n_rank, c_probe) = sorted_dedup_stage(
+                 _l3, n_rank, c_work) = sorted_dedup_stage(
                     cand, parent, actid, rowvalid, hi, lo,
                     lhi, llo, ln, LN, T, K, False,
                     also_seen_in=(vhi, vlo, vn),
@@ -1391,7 +1415,7 @@ class DevicePipeline:
                     orows = devlevel.append_rows(orows, n_out, on)
                     opar = devlevel.append_vec(opar, n_par + start, on)
                     oact = devlevel.append_vec(oact, n_act, on)
-                lhi, llo, ln = dedup.merge_ranked(
+                lhi, llo, ln, c_slots = dedup.merge_counted(
                     lhi, llo, ln, n_hi, n_lo, n_rank, app_n, LN
                 )
                 dig = devlevel.combine_digest(
@@ -1410,7 +1434,8 @@ class DevicePipeline:
                     vidx = jnp.where(take, g_idx, vidx)
                 return (i + 1, orows, opar, oact, on + app_n,
                         lhi, llo, ln, vkind, vinv, vidx,
-                        act_en, agmax, dig, ovf, probe + c_probe)
+                        act_en, agmax, dig, ovf,
+                        work + c_work + work_counts(merge=c_slots))
 
             def cond(carry):  # kspec: traced
                 return (carry[0] < n_chunks) & (carry[8] == 0)
@@ -1431,24 +1456,25 @@ class DevicePipeline:
                     jnp.zeros((n_actions,), jnp.int32),
                     devlevel.zero_digest(),
                     jnp.bool_(False),
-                    jnp.zeros((2,), jnp.int32),
+                    work_counts(),
                 )
             (_i, orows, opar, oact, on, lhi, llo, _ln, vkind, vinv,
-             vidx, act_en, agmax, dig, ovf, probe) = jax.lax.while_loop(
+             vidx, act_en, agmax, dig, ovf, work) = jax.lax.while_loop(
                 cond, body, init
             )
-            # ONE O(capacity) merge per level (the serial path pays one
+            # ONE visited merge per level (the serial path pays one
             # per chunk): every level-new entry is disjoint from the
             # visited set by construction, so the rank-scatter merge of
             # the sorted level-new prefix lands the identical sorted
             # visited array
             _f, rank_v, m_probe = dedup.probe_sorted(vhi, vlo, vn, lhi, llo)
-            vhi, vlo, vn = dedup.merge_ranked(
+            vhi, vlo, vn, m_slots = dedup.merge_counted(
                 vhi, vlo, vn, lhi, llo, rank_v, on, vcap
             )
             return (orows, opar, oact, on, vhi, vlo, vn, vkind, vinv,
-                    vidx, counts_out(act_en, probe + m_probe), agmax, dig,
-                    ovf)
+                    vidx,
+                    counts_out(act_en, work + work_counts(m_probe, m_slots)),
+                    agmax, dig, ovf)
 
         return level
 
@@ -1491,7 +1517,7 @@ class DevicePipeline:
 
             def body(carry):  # kspec: traced
                 (i, orows, opar, oact, ohi, olo, on, lhi, llo, ln,
-                 vkind, vinv, vidx, act_en, agmax, ovf, probe) = carry
+                 vkind, vinv, vidx, act_en, agmax, ovf, work) = carry
                 with stage("guard"):
                     start = i * B
                     rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
@@ -1511,7 +1537,7 @@ class DevicePipeline:
                                          T, K)
                 hi, lo = fp_stage(cand, rowvalid, spec)
                 (n_out, n_par, n_act, n_ohi, n_olo, new_n,
-                 s_hi, s_lo, s_rank, c_probe) = candidate_dedup_stage(
+                 s_hi, s_lo, s_rank, c_work) = candidate_dedup_stage(
                     cand, parent, actid, rowvalid, hi, lo,
                     lhi, llo, ln, T, K,
                 )
@@ -1541,7 +1567,7 @@ class DevicePipeline:
                     oact = devlevel.append_vec(oact, n_act, on)
                     ohi = devlevel.append_vec(ohi, n_ohi, on)
                     olo = devlevel.append_vec(olo, n_olo, on)
-                lhi, llo, ln = dedup.merge_ranked(
+                lhi, llo, ln, c_slots = dedup.merge_counted(
                     lhi, llo, ln, s_hi, s_lo, s_rank, app_n, LN
                 )
                 with stage("expand"):  # its counters and overflow flags
@@ -1554,7 +1580,8 @@ class DevicePipeline:
                     vidx = jnp.where(take, g_idx, vidx)
                 return (i + 1, orows, opar, oact, ohi, olo,
                         on + app_n, lhi, llo, ln, vkind, vinv, vidx,
-                        act_en, agmax, ovf, probe + c_probe)
+                        act_en, agmax, ovf,
+                        work + c_work + work_counts(merge=c_slots))
 
             def cond(carry):  # kspec: traced
                 return (carry[0] < n_chunks) & (carry[10] == 0)
@@ -1576,13 +1603,13 @@ class DevicePipeline:
                     jnp.zeros((n_actions,), jnp.int32),
                     jnp.zeros((n_actions,), jnp.int32),
                     jnp.bool_(False),
-                    jnp.zeros((2,), jnp.int32),
+                    work_counts(),
                 )
             (_i, orows, opar, oact, ohi, olo, on, _lh, _ll, _ln,
              vkind, vinv, vidx, act_en, agmax, ovf,
-             probe) = jax.lax.while_loop(cond, body, init)
+             work) = jax.lax.while_loop(cond, body, init)
             return (orows, opar, oact, ohi, olo, on, vkind, vinv,
-                    vidx, counts_out(act_en, probe), agmax, ovf)
+                    vidx, counts_out(act_en, work), agmax, ovf)
 
         return level
 

@@ -15,7 +15,10 @@ so a round is one gather.  Directory and buffer are rebuilt inside every
 probe from the set it is handed: no carried state, exact for any data.
 The probe's insertion ranks are all the merge needs:
 it places the new entries by them and counts the visited entries' shifts
-from them (histogram + prefix sum), with no search of its own.
+from them (histogram + prefix sum), with no search of its own, and it
+moves the live entries only: two rolled loops over fixed blocks, as many
+iterations as the set and the new list fill (device values), so a merge
+into a large pinned capacity costs what the set holds.
 """
 
 from __future__ import annotations
@@ -148,38 +151,102 @@ def member_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
     return found
 
 
+#: Slots one iteration of the merge's loops moves (the block is a shape:
+#: ``min(size, MERGE_BLOCK)``; how many blocks run is a device value).
+#: Timed on the chip at 16,384, 65,536 and 262,144: a full set costs the
+#: same at each, a small one least at the smallest (PERF.md section 6, PR 33).
+MERGE_BLOCK = 16384
+
+
 def merge_ranked(set_hi, set_lo, set_n, new_hi, new_lo, new_rank, new_n, out_cap):
     """Scatter-merge: sorted visited set + compacted sorted new pairs.
 
     new_hi/new_lo: [M] with the first new_n entries sorted ascending and
     disjoint from the visited set; new_rank: each new entry's insertion index
     in the visited set (from rank_sorted).  Builds the merged sorted array
-    with two scatters instead of re-sorting V+M keys:
+    by scattering instead of re-sorting V+M keys:
       target(new[j])     = rank[j] + j
       target(visited[i]) = i + (# new entries below visited[i])
     New and visited are disjoint, so new[j] < visited[i] exactly when
     rank[j] <= i: the count is the inclusive prefix sum of a histogram of
     the live ranks, and nothing is searched.  Lanes j >= new_n may hold any
-    rank and are masked out of it.  Out-of-range targets (sentinel tails)
-    drop or overwrite padding with sentinels — both harmless.  Returns
-    (hi[out_cap], lo[out_cap], n).  The ``dedup_merge`` stage.
+    rank and are masked out of it.  Only the LIVE entries move: the output
+    starts as sentinels, and two rolled loops over fixed blocks of
+    ``MERGE_BLOCK`` slots place the first new_n new entries and the first
+    set_n visited entries, ``ceil(n / block)`` iterations each (a device
+    value), so a merge costs what the set holds and not what its capacity
+    pins; a set of one block runs one iteration.  Targets past out_cap
+    drop.  Returns (hi[out_cap], lo[out_cap], n).  The ``dedup_merge``
+    stage.
     """
-    with jax.named_scope(_MERGE):
-        cap = set_hi.shape[0]
-        M = new_hi.shape[0]
-        j = jnp.arange(M, dtype=jnp.int32)
-        valid_new = j < new_n
-        tgt_new = jnp.where(valid_new, new_rank + j, out_cap)
+    hi, lo, n, _slots = merge_counted(
+        set_hi, set_lo, set_n, new_hi, new_lo, new_rank, new_n, out_cap
+    )
+    return hi, lo, n
 
-        # new entries below each visited slot (a rank of cap, above every
-        # slot, drops with the dead lanes)
-        hist = jnp.zeros((cap,), jnp.int32)
-        hist = hist.at[jnp.where(valid_new, new_rank, cap)].add(1, mode="drop")
-        tgt_old = jnp.arange(cap, dtype=jnp.int32) + jnp.cumsum(hist)
+
+def merge_counted(set_hi, set_lo, set_n, new_hi, new_lo, new_rank, new_n,
+                  out_cap):
+    """:func:`merge_ranked` and what it cost: -> (hi, lo, n, slots) with
+    ``slots`` int32[2]: the slots this merge's loops touched (blocks run x
+    block size, both sides: a device value) and the slots a merge over the
+    whole capacity touches (``cap + M``, a shape).  The level programs sum
+    it over their merges and hand it to the host with their counts (level
+    record ``merge_slots`` / ``merge_slots_plain``)."""
+    with jax.named_scope(_MERGE):
+        cap, M = set_hi.shape[0], new_hi.shape[0]
+        B, BM = min(cap, MERGE_BLOCK), min(M, MERGE_BLOCK)
+        set_n = jnp.asarray(set_n, jnp.int32)
+        new_n = jnp.asarray(new_n, jnp.int32)
+        blocks = (jnp.clip(set_n, 0, cap) + (B - 1)) // B
+        blocks_new = (jnp.clip(new_n, 0, M) + (BM - 1)) // BM
+        # a list that is no multiple of its block: the last block starts
+        # early (a slice must lie inside its operand) and overlaps the one
+        # before it
+
+        def place_new(k, carry):
+            out_hi, out_lo, hist = carry
+            s = jnp.minimum(k * BM, M - BM)
+            j = s + jnp.arange(BM, dtype=jnp.int32)
+            # the overlap is masked: its ranks are counted once
+            live = (j >= k * BM) & (j < new_n)
+            rank = jax.lax.dynamic_slice(new_rank, (s,), (BM,))
+            tgt = jnp.where(live, rank + j, out_cap)
+            # new entries below each visited slot (a rank of cap, above
+            # every slot, drops with the dead lanes)
+            hist = hist.at[jnp.where(live, rank, cap)].add(1, mode="drop")
+            hi = jax.lax.dynamic_slice(new_hi, (s,), (BM,))
+            lo = jax.lax.dynamic_slice(new_lo, (s,), (BM,))
+            return (out_hi.at[tgt].set(hi, mode="drop"),
+                    out_lo.at[tgt].set(lo, mode="drop"), hist)
+
+        def move_visited(k, carry):
+            out_hi, out_lo = carry
+            s = jnp.minimum(k * B, cap - B)
+            i = s + jnp.arange(B, dtype=jnp.int32)
+            # the overlap moves again, to the same slots
+            tgt = jnp.where(
+                i < set_n, i + jax.lax.dynamic_slice(shift, (s,), (B,)),
+                out_cap)
+            hi = jax.lax.dynamic_slice(set_hi, (s,), (B,))
+            lo = jax.lax.dynamic_slice(set_lo, (s,), (B,))
+            return (out_hi.at[tgt].set(hi, mode="drop"),
+                    out_lo.at[tgt].set(lo, mode="drop"))
 
         sent = jnp.uint32(SENT)
-        out_hi = jnp.full((out_cap,), sent)
-        out_lo = jnp.full((out_cap,), sent)
-        out_hi = out_hi.at[tgt_old].set(set_hi).at[tgt_new].set(new_hi)
-        out_lo = out_lo.at[tgt_old].set(set_lo).at[tgt_new].set(new_lo)
-        return out_hi, out_lo, set_n + new_n
+        out_hi, out_lo, hist = jax.lax.fori_loop(
+            0, blocks_new, place_new,
+            (jnp.full((out_cap,), sent), jnp.full((out_cap,), sent),
+             jnp.zeros((cap,), jnp.int32)),
+        )
+        # the streaming passes over the capacity that stay (three fills,
+        # this prefix sum) cost 1.5 ms at 4,194,304 slots where scattering
+        # them cost 59.5 (PERF.md section 6, PR 33)
+        shift = jnp.cumsum(hist)
+        out_hi, out_lo = jax.lax.fori_loop(
+            0, blocks, move_visited, (out_hi, out_lo)
+        )
+        slots = jnp.stack(
+            [blocks * B + blocks_new * BM, jnp.int32(cap + M)]
+        )
+        return out_hi, out_lo, set_n + new_n, slots

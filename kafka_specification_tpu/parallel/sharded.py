@@ -43,7 +43,14 @@ from ..engine.bfs import (
     walk_trace,
 )
 from ..engine.hostio import HostIO
-from ..engine.pipeline import counts_out, split_counts, stage
+from ..engine.pipeline import (
+    WORK_FIELDS,
+    counts_out,
+    split_counts,
+    stage,
+    work_counts,
+    work_record,
+)
 from ..ops import devlevel
 from ..pipeline_registry import resolve_pipeline
 from ..models.base import Model
@@ -485,6 +492,7 @@ def _make_sharded_step(
             invalid_s = (hi_s == sent) & (lo_s == sent)
             first = dedup.first_occurrence_mask(hi_s, lo_s, invalid_s)
         ovf_probe = jnp.bool_(False)
+        slots = None  # the rank-merge's slot counts, where one runs
         if hash_table:
             # per-shard HBM open-addressing table (ops/hashset): vhi/vlo
             # carry the table slots; insert-or-find replaces both the
@@ -522,7 +530,7 @@ def _make_sharded_step(
         if hash_table:
             pass  # vhi2/vlo2 already hold the updated table
         elif with_merge:
-            vhi2, vlo2, vn2 = dedup.merge_ranked(
+            vhi2, vlo2, vn2, slots = dedup.merge_counted(
                 vhi, vlo, vn, out_hi, out_lo, out_rank, new_n, vcap
             )
         else:
@@ -556,9 +564,9 @@ def _make_sharded_step(
             jnp.stack(viol_idx)[None],
             jnp.any(deadlocked)[None],
             jnp.argmax(deadlocked)[None],
-            # [1, n_actions + 2] -> [D, n_actions + 2]: the enabled counts
-            # and the probe's round counts (pipeline.counts_out)
-            counts_out(act_en, probe)[None],
+            # [1, n_actions + 4] -> [D, n_actions + 4]: the enabled counts
+            # and the probe's and merge's work counts (pipeline.counts_out)
+            counts_out(act_en, work_counts(probe, slots))[None],
             # per-action expansion overflow + pre-constraint guard counts:
             # the host sizes adaptive per-action compact buffers from the
             # guard histogram exactly as the single-device engine does
@@ -600,7 +608,7 @@ def _make_sharded_step(
             P("d", None),  # viol_idx [D, n_inv]
             P("d"),        # deadlock any
             P("d"),        # deadlock idx
-            P("d", None),  # counts [D, n_actions + 2]
+            P("d", None),  # counts [D, n_actions + 4]
             P("d", None),  # ovf_expand [D, n_actions]
             P("d", None),  # act_guard [D, n_actions]
             P("d"),        # ovf_dest
@@ -680,7 +688,7 @@ def _make_sharded_level(
     device-resident per-shard level-new sorted set) -> in-jit
     (count, xor, sum) digest folds (ops/devlevel) + framing-digest
     accumulation -> dynamic-offset next-frontier append.  The
-    O(capacity) visited merge runs ONCE per shard after the loop.
+    visited merge runs ONCE per shard after the loop.
 
     Bit-identity with the per-chunk path holds chunk for chunk: the
     routing, per-bucket stable sort and receiver lexsort are the same
@@ -727,7 +735,7 @@ def _make_sharded_level(
         def body(carry):  # kspec: traced
             (i, orows, opar, oact, on, lhi, llo, ln,
              vkind, vshard, vinv, vidx,
-             act_en, agmax, dig, s_acc, r_acc, ovf, nclean, probe) = carry
+             act_en, agmax, dig, s_acc, r_acc, ovf, nclean, work) = carry
             start = i * B
             rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
             fvalid = (
@@ -757,7 +765,7 @@ def _make_sharded_level(
             # level-new sorted set (its ranks drive the gated merge
             # below), also_seen_in = the read-only visited shard
             (n_out, n_par, n_act, new_n, n_hi, n_lo, _l1, _l2, _l3,
-             n_rank, c_probe) = sorted_dedup_stage(
+             n_rank, c_work) = sorted_dedup_stage(
                 r_cand, r_parent, r_act,
                 ~((r_hi == sent) & (r_lo == sent)),
                 r_hi, r_lo, lhi, llo, ln, LN, R, K, False,
@@ -822,7 +830,7 @@ def _make_sharded_level(
             orows = devlevel.append_rows(orows, n_out, on)
             opar = devlevel.append_vec(opar, n_par, on)
             oact = devlevel.append_vec(oact, n_act, on)
-            lhi, llo, ln = dedup.merge_ranked(
+            lhi, llo, ln, c_slots = dedup.merge_counted(
                 lhi, llo, ln, n_hi, n_lo, n_rank, app_n, LN
             )
             dig = devlevel.combine_digest(
@@ -844,7 +852,7 @@ def _make_sharded_level(
                     jnp.where(take, inv_i, vinv),
                     jnp.where(take, vix_l, vidx),
                     act_en, agmax, dig, s_acc, r_acc, ovf, nclean,
-                    probe + c_probe)
+                    work + c_work + work_counts(merge=c_slots))
 
         def cond(carry):  # kspec: traced
             return (carry[0] < ncs) & (carry[8] == 0)
@@ -866,18 +874,18 @@ def _make_sharded_level(
             jnp.zeros((5,), jnp.uint32),
             jnp.bool_(False),
             jnp.int32(0),
-            jnp.zeros((2,), jnp.int32),
+            work_counts(),
         )
         (_i, orows, opar, oact, on, lhi, llo, _ln, vkind, vshard,
          vinv, vidx, act_en, agmax, dig, s_acc, r_acc, ovf,
-         nclean, probe) = jax.lax.while_loop(cond, body, init)
-        # ONE O(capacity) merge per shard per level (the per-chunk path
+         nclean, work) = jax.lax.while_loop(cond, body, init)
+        # ONE visited merge per shard per level (the per-chunk path
         # pays one per chunk): every level-new entry is disjoint from
         # the visited shard by construction, so the rank-scatter merge
         # of the sorted level-new prefix lands the identical sorted
         # visited array
         _s, rank_v, m_probe = dedup.probe_sorted(vhi, vlo, vn, lhi, llo)
-        vhi, vlo, vn = dedup.merge_ranked(
+        vhi, vlo, vn, m_slots = dedup.merge_counted(
             vhi, vlo, vn, lhi, llo, rank_v, on, vcap
         )
         dc, dxh, dxl, dlimbs = dig
@@ -890,8 +898,8 @@ def _make_sharded_level(
             vlo[None],
             vn[None],
             vkind[None], vshard[None], vinv[None], vidx[None],
-            # [1, n_actions + 2]: enabled counts, probe round counts
-            counts_out(act_en, probe + m_probe)[None],
+            # [1, n_actions + 4]: enabled counts, dedup work counts
+            counts_out(act_en, work + work_counts(m_probe, m_slots))[None],
             agmax[None],
             dc[None], dxh[None], dxl[None],  # digest accumulator...
             dlimbs[None],  # ... (count, xors, 16-bit sum limbs)
@@ -920,7 +928,7 @@ def _make_sharded_level(
             P("d", None),  # merged visited lo
             P("d"),        # merged visited counts
             P("d"), P("d"), P("d"), P("d"),  # verdict kind/shard/inv/idx
-            P("d", None),  # counts [D, n_actions + 2]
+            P("d", None),  # counts [D, n_actions + 4]
             P("d", None),  # agmax [D, n_actions]
             P("d"), P("d"), P("d"),  # digest count/xor_hi/xor_lo
             P("d", None),  # digest sum limbs [D, 4]
@@ -995,7 +1003,7 @@ def _make_sharded_level_host(
         def body(carry):  # kspec: traced
             (i, orows, opar, oact, ohi, olo, on, lhi, llo, ln,
              vkind, vshard, vinv, vidx,
-             act_en, agmax, s_acc, r_acc, ovf, nclean, probe) = carry
+             act_en, agmax, s_acc, r_acc, ovf, nclean, work) = carry
             start = i * B
             rows = jax.lax.dynamic_slice(fbuf, (start, 0), (B, K))
             fvalid = (
@@ -1021,7 +1029,7 @@ def _make_sharded_level_host(
             # shard's level-new sorted set, NO visited probe (that is
             # the host's one batched call after the program)
             (n_out, n_par, n_act, new_n, n_hi, n_lo, _l1, _l2, _l3,
-             n_rank, c_probe) = sorted_dedup_stage(
+             n_rank, c_work) = sorted_dedup_stage(
                 r_cand, r_parent, r_act,
                 ~((r_hi == sent) & (r_lo == sent)),
                 r_hi, r_lo, lhi, llo, ln, LN, R, K, False,
@@ -1080,7 +1088,7 @@ def _make_sharded_level_host(
             oact = devlevel.append_vec(oact, n_act, on)
             ohi = devlevel.append_vec(ohi, n_hi, on)
             olo = devlevel.append_vec(olo, n_lo, on)
-            lhi, llo, ln = dedup.merge_ranked(
+            lhi, llo, ln, c_slots = dedup.merge_counted(
                 lhi, llo, ln, n_hi, n_lo, n_rank, app_n, LN
             )
             s_acc = _acc_digest(s_acc, sent_dig, clean)
@@ -1096,7 +1104,7 @@ def _make_sharded_level_host(
                     jnp.where(take, inv_i, vinv),
                     jnp.where(take, vix_l, vidx),
                     act_en, agmax, s_acc, r_acc, ovf, nclean,
-                    probe + c_probe)
+                    work + c_work + work_counts(merge=c_slots))
 
         def cond(carry):  # kspec: traced
             return (carry[0] < ncs) & (carry[10] == 0)
@@ -1119,11 +1127,11 @@ def _make_sharded_level_host(
             jnp.zeros((5,), jnp.uint32),
             jnp.bool_(False),
             jnp.int32(0),
-            jnp.zeros((2,), jnp.int32),
+            work_counts(),
         )
         (_i, orows, opar, oact, ohi, olo, on, _lh, _ll, _ln, vkind,
          vshard, vinv, vidx, act_en, agmax, s_acc, r_acc, ovf,
-         nclean, probe) = jax.lax.while_loop(cond, body, init)
+         nclean, work) = jax.lax.while_loop(cond, body, init)
         return (
             orows,  # [OC, K] -> [D*OC, K]
             opar,
@@ -1132,7 +1140,7 @@ def _make_sharded_level_host(
             olo,
             on[None],
             vkind[None], vshard[None], vinv[None], vidx[None],
-            counts_out(act_en, probe)[None],
+            counts_out(act_en, work)[None],
             agmax[None],
             s_acc[None], r_acc[None],  # [1, 5] framing accumulators
             ovf[None],
@@ -1155,7 +1163,7 @@ def _make_sharded_level_host(
             P("d"),        # candidate fingerprint lo lanes
             P("d"),        # per-shard pre-probe candidate counts
             P("d"), P("d"), P("d"), P("d"),  # verdict kind/shard/inv/idx
-            P("d", None),  # counts [D, n_actions + 2]
+            P("d", None),  # counts [D, n_actions + 4]
             P("d", None),  # agmax [D, n_actions]
             P("d", None),  # sent framing accumulator [D, 5]
             P("d", None),  # recv framing accumulator [D, 5]
@@ -2726,9 +2734,10 @@ def check_sharded(
             # the novelty masks — received candidates per OWNER shard
             lvl_en_per_shard = np.zeros(D, np.int64)
             lvl_recv_per_shard = np.zeros(D, np.int64)
-            # dedup_probe search rounds of the committed dispatches, all
-            # shards, and what whole-capacity searches would have run
-            lvl_rounds = np.zeros(2, np.int64)
+            # pipeline.work_counts of the committed dispatches, all shards:
+            # probe rounds and merge slots, each beside what the form over
+            # the whole capacity would have run
+            lvl_work = np.zeros(len(WORK_FIELDS), np.int64)
             lvl_exch_bytes = lvl_exch_raw_bytes = 0
             # dispatched collective-bearing programs this level — one
             # launch PER SHARD each (the kspec_shard_launches_level
@@ -3125,10 +3134,10 @@ def check_sharded(
                 lvl_new_per_shard += newc
                 shard_visited += newc
                 if obs_.collect:
-                    act_en_np, probe = split_counts(io.fetch(act_en))
+                    act_en_np, work = split_counts(io.fetch(act_en))
                     lvl_act_en += act_en_np.sum(axis=0)
                     lvl_en_per_shard += act_en_np.sum(axis=1)
-                    lvl_rounds[:] += probe
+                    lvl_work[:] += work
                 return False
 
             def _commit_timed(st):
@@ -3504,10 +3513,10 @@ def check_sharded(
                     lvl_recv_per_shard += counts
                     shard_visited += counts
                 if obs_.collect:
-                    act_en_np, probe = split_counts(io.fetch(outs[i_aen]))
+                    act_en_np, work = split_counts(io.fetch(outs[i_aen]))
                     lvl_act_en += act_en_np.sum(axis=0)
                     lvl_en_per_shard += act_en_np.sum(axis=1)
-                    lvl_rounds[:] += probe
+                    lvl_work[:] += work
                 for d in range(D):
                     offs[d] = min(nc * B, lens[d])
                 prof_host_s += time.perf_counter() - t_commit
@@ -3633,8 +3642,7 @@ def check_sharded(
                     # (= launches PER SHARD; in-memory only, like the
                     # launch counters of the single-device engine)
                     "shard_launches": int(lvl_dispatches),
-                    "probe_rounds": int(lvl_rounds[0]),
-                    "probe_rounds_plain": int(lvl_rounds[1]),
+                    **work_record(lvl_work),
                     # the single-device engine's host/device split and
                     # what the host launched and moved this level
                     # (engine/hostio.py; docs/observability.md)

@@ -31,7 +31,8 @@ KIP101_LEVELS = [1, 4, 14, 44, 100, 166, 268, 456, 684, 976, 1292, 1486]
 CUT_KEYS = {"depth", "frontier", "rows_committed", "chunks_committed",
             "chunks_discarded", "chunks", "level_ms", "step_ms", "host_ms",
             "successor_launches", "probe_rounds",
-            "probe_rounds_plain"} | set(hostio.LEVEL_COUNTERS)
+            "probe_rounds_plain", "merge_slots",
+            "merge_slots_plain"} | set(hostio.LEVEL_COUNTERS)
 # the fused path from 64 rows up, so a small chunk leaves launch 2 in flight
 FUSED = dict(min_bucket=64, compact_gate=64)
 

@@ -195,7 +195,8 @@ def test_stats_shim_record_for_record_identical(tmp_path):
                       "io_hidden_ms", "io_exposed_ms",
                       "overlap_efficiency", "host_probe_ms",
                       "store_ms", "probe_rounds",
-                      "probe_rounds_plain", "chunks") + LEVEL_COUNTERS}
+                      "probe_rounds_plain", "merge_slots",
+                      "merge_slots_plain", "chunks") + LEVEL_COUNTERS}
         for r in r1.stats["levels"]
     ] == recs_bare
 
@@ -256,6 +257,58 @@ def test_cut_level_carries_the_probe_rounds(tmp_path):
     cut = res.stats["cut_level"]
     _assert_probe_rounds(res.stats["levels"] + [cut])
     assert cut["probe_rounds_plain"] > 0 and cut["dispatches"] > 0
+    _assert_merge_slots(res.stats["levels"] + [cut])
+    assert cut["merge_slots"] > 0
+
+
+# --- the merge's slot counts (level records, both engines) --------------
+
+
+def _assert_merge_slots(records):
+    """Every record: 0 <= merge_slots <= merge_slots_plain, and a level
+    that dispatched a merging program counts the capacity's slots."""
+    for rec in records:
+        assert 0 <= rec["merge_slots"] <= rec["merge_slots_plain"], rec
+        if rec["dispatches"]:
+            assert rec["merge_slots_plain"] > 0, rec
+
+
+def _merge_slots(records):
+    return [(r["merge_slots"], r["merge_slots_plain"]) for r in records]
+
+
+@pytest.mark.parametrize("block", [None, 256],
+                         ids=["engine-block", "block-256"])
+@pytest.mark.parametrize("pipeline", [None, "device"],
+                         ids=["fused", "whole-level"])
+def test_level_records_carry_the_merge_slots(tmp_path, monkeypatch,
+                                             pipeline, block):
+    """configs/Kip320.cfg cut to depth 7, twice: every level record says
+    how many slots its merges' loops touched and how many merges over the
+    whole capacity touch; the two runs agree to the slot, the counts are
+    the golden's whatever the block (at 256 slots the loops run several
+    blocks a merge and touch a fraction of the capacity), and the emitted
+    stream does not gain the fields."""
+    from kafka_specification_tpu.ops import dedup
+    from kafka_specification_tpu.utils.cfg import build_model, parse_cfg
+
+    if block:
+        monkeypatch.setattr(dedup, "MERGE_BLOCK", block)
+    model = build_model("Kip320", parse_cfg("configs/Kip320.cfg"))
+    runs = []
+    for i in range(2):
+        run = RunContext(str(tmp_path / f"run{i}"))
+        res = check(model, max_depth=7, run=run, pipeline=pipeline)
+        assert res.ok and res.levels == KIP320_LEVELS_TO_8[:8]
+        runs.append(res.stats["levels"])
+        _assert_merge_slots(runs[-1])
+        assert all("merge_slots" not in r for r in _records(run.stats_path))
+    assert _merge_slots(runs[0]) == _merge_slots(runs[1])
+    assert runs[0][-1]["merge_slots"] > 0
+    if block:
+        assert all(r["merge_slots"] % block == 0 for r in runs[0])
+        assert sum(r["merge_slots"] for r in runs[0]) < \
+            0.5 * sum(r["merge_slots_plain"] for r in runs[0])
 
 
 # --- engine-threaded run dirs -------------------------------------------
@@ -309,7 +362,8 @@ def test_sharded_per_shard_breakdowns_and_imbalance(tmp_path):
          if k not in ("exch_bytes", "exch_raw_bytes", "io_hidden_ms",
                       "io_exposed_ms", "shard_launches",
                       "host_probe_ms", "step_ms", "host_ms",
-                      "probe_rounds", "probe_rounds_plain")
+                      "probe_rounds", "probe_rounds_plain",
+                      "merge_slots", "merge_slots_plain")
          + LEVEL_COUNTERS}
         for r in res.stats["levels"]
     ] == recs

@@ -8,6 +8,7 @@ from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal
 
 from kafka_specification_tpu.ops import dedup
 from kafka_specification_tpu.ops.packing import Field, StateSpec
@@ -306,24 +307,109 @@ def test_merge_ranked_cases(case):
     np.testing.assert_array_equal(np.asarray(mlo), wlo)
 
 
-def _eqn_names(jaxpr, inside_loop=False):
-    """(primitive name, inside a loop?, output shapes) of every equation,
-    sub-jaxprs included."""
+def _block_case(cap, M, set_n, new_n, seed):
+    """Random disjoint sorted key lists of set_n and new_n entries as
+    sentinel-padded lanes, the new entries' ranks, and ranks no live lane
+    could hold (beyond cap, negative) in the dead lanes j >= new_n."""
+    rng = np.random.default_rng(seed)
+    keys = np.unique(rng.integers(0, 2**64 - 2**33, size=4 * (set_n + new_n) + 8,
+                                  dtype=np.uint64))
+    keys = rng.permutation(keys)[: set_n + new_n]
+    vkeys, nkeys = np.sort(keys[:set_n]), np.sort(keys[set_n:])
+    rank = np.resize(np.array([cap + 1, -3, 1 << 30, 0, cap], np.int32), M)
+    rank[:new_n] = np.searchsorted(vkeys, nkeys)
+    return vkeys, nkeys, _pairs(vkeys, cap), _pairs(nkeys, M), rank
+
+
+def _merge_counted_at(monkeypatch, block, lanes, out_cap):
+    """dedup.merge_counted traced at MERGE_BLOCK = block (a fresh jit: the
+    block is read when the function is traced)."""
+    monkeypatch.setattr(dedup, "MERGE_BLOCK", block)
+    (vhi, vlo), vn, (nhi, nlo), rank, nn = lanes
+    return jax.jit(lambda *a: dedup.merge_counted(*a, out_cap))(
+        jnp.asarray(vhi), jnp.asarray(vlo), jnp.int32(vn), jnp.asarray(nhi),
+        jnp.asarray(nlo), jnp.asarray(rank), jnp.int32(nn),
+    )
+
+
+_B = 8
+# (cap, M, out_cap): neither list a multiple of the block and the output
+# twice the capacity; both multiples and the output the capacity itself
+_BLOCK_SHAPES = {"ragged": (20, 12, 40), "aligned": (32, 16, 32)}
+_BLOCK_EDGES = [
+    (shape, set_n, new_n)
+    for shape, (cap, M, out_cap) in _BLOCK_SHAPES.items()
+    for set_n in (0, 1, _B - 1, _B, _B + 1, cap)
+    for new_n in (0, 1, _B - 1, _B, _B + 1, M)
+    if set_n + new_n <= out_cap
+]
+
+
+@pytest.mark.parametrize(
+    "shape,set_n,new_n", _BLOCK_EDGES,
+    ids=[f"{s}-set{a}-new{b}" for s, a, b in _BLOCK_EDGES])
+def test_merge_ranked_block_edges(monkeypatch, shape, set_n, new_n):
+    """The loops over fixed blocks at a block of 8 slots: a list that ends
+    just before, at and just past a block edge, an empty, a one-entry and
+    a full one, on both sides; a last block that starts early because its
+    list is no multiple of the block; each against the plain numpy merge,
+    hi, lo, n and the sentinel tail, and the slot counts against the
+    blocks the two lists fill."""
+    cap, M, out_cap = _BLOCK_SHAPES[shape]
+    vkeys, nkeys, v, n, rank = _block_case(cap, M, set_n, new_n,
+                                           seed=1000 * set_n + new_n)
+    mhi, mlo, mn, slots = _merge_counted_at(
+        monkeypatch, _B, (v, set_n, n, rank, new_n), out_cap)
+    whi, wlo, wn = _np_merge(vkeys, nkeys, out_cap)
+    assert int(mn) == wn
+    np.testing.assert_array_equal(np.asarray(mhi), whi)
+    np.testing.assert_array_equal(np.asarray(mlo), wlo)
+    assert list(np.asarray(slots)) == [
+        -(-set_n // _B) * _B + -(-new_n // _B) * _B, cap + M]
+
+
+@pytest.mark.parametrize("set_n,new_n", [
+    (0, 0), (1, 0), (0, 1), (1000, 300), (16384, 16384), (16385, 16383),
+    (65536, 1), (65537, 4096), (190000, 65536), (262144 - 70000, 70000)])
+def test_merge_counts_the_blocks_it_runs(set_n, new_n):
+    """At the block the engine runs and a capacity of several blocks:
+    slots[0] is ceil(set_n / B) + ceil(new_n / B) blocks, slots[1]
+    the capacity-wide form's cap + M, and the merged set is numpy's."""
+    cap, M, B = 262144, 131072, dedup.MERGE_BLOCK
+    vkeys, nkeys, (vhi, vlo), (nhi, nlo), rank = _block_case(
+        cap, M, set_n, new_n, seed=set_n + new_n)
+    mhi, mlo, mn, slots = _MERGE_COUNTED(
+        jnp.asarray(vhi), jnp.asarray(vlo), jnp.int32(set_n), jnp.asarray(nhi),
+        jnp.asarray(nlo), jnp.asarray(rank), jnp.int32(new_n), cap)
+    assert list(np.asarray(slots)) == [
+        (-(-set_n // B) + -(-new_n // B)) * B, cap + M]
+    whi, wlo, wn = _np_merge(vkeys, nkeys, cap)
+    assert int(mn) == wn
+    np.testing.assert_array_equal(np.asarray(mhi), whi)
+    np.testing.assert_array_equal(np.asarray(mlo), wlo)
+
+
+_MERGE_COUNTED = jax.jit(dedup.merge_counted, static_argnums=7)
+
+
+def _input_deps(jaxpr):
+    """var -> the indices of the jaxpr's inputs it is computed from."""
+    deps = {v: {i} for i, v in enumerate(jaxpr.invars)}
     for eqn in jaxpr.eqns:
-        yield eqn.primitive.name, inside_loop, [
-            getattr(v.aval, "shape", ()) for v in eqn.outvars
-        ]
-        loop = inside_loop or eqn.primitive.name in ("while", "scan")
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqn_names(sub, loop)
+        d = set().union(*[deps.get(v, set()) for v in eqn.invars
+                          if not isinstance(v, Literal)])
+        deps.update({o: d for o in eqn.outvars})
+    return deps
 
 
-def test_merge_ranked_has_no_search_loop():
-    """merge_ranked counts from the ranks it is handed: no loop at all, so
-    no capacity-wide gather inside one (the per-slot binary search of the
-    new list, 12-19 gather pairs over the whole capacity per chunk, must
-    not come back unnoticed on a CPU-only check)."""
-    cap, M = 1024, 64
+def test_merge_ranked_moves_blocks_for_live_entries_only():
+    """What makes the merge cost what the set holds, held on a CPU-only
+    check: no gather or scatter anywhere in it (inside a loop or outside)
+    takes indices or updates wider than one block, so nothing per-element
+    runs over the capacity or over the M lanes; and each of its two loops
+    stops on a value computed from set_n (the visited side) or from new_n
+    (the new side), so the blocks run follow the live entries."""
+    cap, M, B = 1 << 20, 1 << 18, dedup.MERGE_BLOCK
     u = jax.ShapeDtypeStruct((cap,), jnp.uint32)
     m = jax.ShapeDtypeStruct((M,), jnp.uint32)
     i = jax.ShapeDtypeStruct((), jnp.int32)
@@ -331,10 +417,34 @@ def test_merge_ranked_has_no_search_loop():
     jaxpr = jax.make_jaxpr(
         lambda *a: dedup.merge_ranked(*a, cap)
     )(u, u, i, m, m, r, i).jaxpr
-    eqns = list(_eqn_names(jaxpr))
-    assert eqns
-    assert not [n for n, _, _ in eqns if n in ("while", "scan")]
-    assert not [
-        (n, shapes) for n, loop, shapes in eqns
-        if loop and n == "gather" and any(s[:1] == (cap,) for s in shapes)
-    ]
+
+    def indexed(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name.startswith(("gather", "scatter")):
+                yield eqn.primitive.name, [v.aval.shape for v in eqn.invars[1:]]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from indexed(sub)
+
+    found = list(indexed(jaxpr))
+    assert any(n.startswith("scatter") for n, _ in found)
+    assert not [(n, shapes) for n, shapes in found
+                if any(max(s, default=0) > B for s in shapes)]
+
+    deps = _input_deps(jaxpr)
+    stops_on = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "while":
+            continue
+        cond = eqn.params["cond_jaxpr"].jaxpr
+        nc, nb = eqn.params["cond_nconsts"], eqn.params["body_nconsts"]
+        read = {v for e in cond.eqns for v in e.invars
+                if not isinstance(v, Literal)}
+        outer = eqn.invars[:nc] + eqn.invars[nc + nb:]  # cond's own inputs
+        stops_on.append(set().union(*[
+            deps[o] for c, o in zip(cond.invars, outer)
+            if c in read and not isinstance(o, Literal)
+        ]))
+    SET_N, NEW_N = 2, 6
+    assert len(stops_on) == 2
+    assert sorted(d & {SET_N, NEW_N} and min(d & {SET_N, NEW_N})
+                  for d in stops_on) == [SET_N, NEW_N]

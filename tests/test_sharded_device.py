@@ -129,6 +129,40 @@ def test_sharded_level_records_carry_the_probe_rounds(tmp_path, pipeline):
         0.6 * sum(r["probe_rounds_plain"] for r in records)
 
 
+@pytest.mark.parametrize("pipeline", ["legacy", "device"],
+                         ids=["per-chunk", "whole-level"])
+def test_sharded_level_records_carry_the_merge_slots(tmp_path, monkeypatch,
+                                                     pipeline):
+    """configs/Kip320.cfg cut to depth 7 on the mesh at a merge block of
+    256 slots, twice: every level record holds the slots its shards'
+    merges touched (each shard its own count, no collective in the loops)
+    beside the slots capacity-wide merges touch, summed over the shards
+    (every shard runs the same shapes); the two runs agree to the slot
+    and the counts are the golden's."""
+    from kafka_specification_tpu.ops import dedup
+    from kafka_specification_tpu.utils.cfg import build_model, parse_cfg
+
+    monkeypatch.setattr(dedup, "MERGE_BLOCK", 256)
+    model = build_model("Kip320", parse_cfg("configs/Kip320.cfg"))
+    runs = []
+    for i in range(2):
+        res = check_sharded(model, max_depth=7, pipeline=pipeline,
+                            store_trace=False,
+                            run=RunContext(str(tmp_path / f"run{i}")))
+        assert res.ok
+        assert res.levels == [1, 6, 30, 138, 366, 1170, 2715, 5673]
+        runs.append(res.stats["levels"])
+    for rec in runs[0]:
+        shards = len(rec["shard_new"])
+        assert 0 < rec["merge_slots"] <= rec["merge_slots_plain"], rec
+        assert rec["merge_slots"] % 256 == 0, rec
+        assert rec["merge_slots_plain"] % shards == 0, rec
+    assert [(r["merge_slots"], r["merge_slots_plain"]) for r in runs[0]] \
+        == [(r["merge_slots"], r["merge_slots_plain"]) for r in runs[1]]
+    assert sum(r["merge_slots"] for r in runs[0]) < \
+        0.5 * sum(r["merge_slots_plain"] for r in runs[0])
+
+
 @pytest.mark.perf
 def test_sharded_device_launches_per_level(tmp_path):
     """The O(1)-launches/level/shard contract, span-tracer-verified:
