@@ -61,6 +61,7 @@ from .pipeline import (
     invariant_rows_program,
     invariant_stage,
     make_pipeline,
+    part,
     program_name,
     resolve_pipeline,
     sorted_dedup_stage,
@@ -342,6 +343,18 @@ class _Step:
         widths = self.norm_widths(bucket, compact)
         return bucket * self.C if widths is None else sum(widths)
 
+    def dedup_width(self, bucket: int, compact,
+                    squeeze_full: bool = False) -> int:
+        """Candidate rows the step program's fingerprint, sort and probe
+        run at: the expansion's width, halved by the pre-sort squeeze on
+        the uniform-shift compact path (:meth:`_build` says why)."""
+        T_exp = self.expand_width(bucket, compact)
+        shift = self.norm_widths(bucket, compact) is not None
+        per_action = isinstance(compact, (list, tuple))
+        if not shift or squeeze_full or per_action:
+            return T_exp
+        return max(256, T_exp >> 1)
+
     def make_expand(self, bucket: int, shift):
         """Expansion kernel: (states[B], fvalid[B]) ->
         (en_pre[B, C], cand[T, K], valid[T], parent[T], actid[T],
@@ -433,7 +446,7 @@ class _Step:
             for ai, a in enumerate(model.actions):
                 na = a.n_choices
                 W = widths[ai]
-                with stage("compact"):
+                with stage("compact"), part("select"):
                     ga = (
                         en_pre[:, bounds[ai] : bounds[ai + 1]]
                         & fvalid[:, None]
@@ -511,7 +524,7 @@ class _Step:
     def cached(self, key, build, **attrs):
         """Compile-cache insert-or-get: `build()` returns the un-jitted
         program, which is jitted here under its cache tag and the naming
-        version (``dvl_n1``; pipeline.program_name), so the HLO module
+        version (``dvl_n2``; pipeline.program_name), so the HLO module
         and the profiler's module line say which program ran.  The first
         call of a fresh entry is wrapped in a ``compile`` span
         (_CompileOnFirstCall) and the key is appended to the compiled
@@ -672,7 +685,6 @@ class _Step:
         spec, model = self.spec, self.model
         K = self.K
         widths = self.norm_widths(bucket, compact)
-        per_action = isinstance(compact, (list, tuple))
         shift = widths is not None  # truthy iff the compact path is on
         expand = self.make_expand(bucket, compact)
         # Candidate width the sort/probe/outputs run at.  On the compact
@@ -685,11 +697,7 @@ class _Step:
         # are already sized tight from measured enablement, so T is the
         # full compact width and the squeeze cannot overflow (it only
         # compacts rows to the front for the fingerprint/output stages).
-        T_exp = self.expand_width(bucket, compact)
-        if not shift or squeeze_full or per_action:
-            T = T_exp
-        else:
-            T = max(256, T_exp >> 1)
+        T = self.dedup_width(bucket, compact, squeeze_full)
 
         # Host-FpSet backend: the device holds no visited set, and the
         # native C++ open-addressing FpSet already dedups both in-batch and
@@ -2066,7 +2074,7 @@ def check(
         ):
             mode = "legacy-cross"
             (l_out, _lp, _la, l_new, _h1, _h2, _h3, l_viol, _vi,
-             l_dl, _di, _ae, l_hi, l_lo, _ag, _launch) = (
+             l_dl, _di, _ae, l_hi, l_lo, _ag, _launch, _lanes) = (
                 fp.legacy.run_chunk(
                     piece, fp_n, bucket, depth, *pre_v, cvcap
                 )
@@ -2135,7 +2143,7 @@ def check(
         nonlocal lvl_launches, lvl_launches_max, run_launches_max
         nonlocal lvl_act_en, a_w  # arena buffers grow via _grow_arena
         nonlocal ht_hi, ht_lo, ht_claim, hash_n
-        nonlocal lvl_store_s, lvl_chunks, lvl_rows_in
+        nonlocal lvl_store_s, lvl_chunks, lvl_rows_in, lvl_lanes
         (start, fp_n, bucket, finalize, pre_v, shadow, dispatch_s,
          t_staged, piece, pre_vcap, t_dispatch) = st
         queued_s = time.perf_counter() - t_staged
@@ -2157,6 +2165,7 @@ def check(
             out_lo,
             act_guard,
             launches,
+            lanes,
         ) = finalize()
         # the program's counts vector (pipeline.counts_out): a chunk that
         # holds the verdict ran its probe and merge like any other
@@ -2164,6 +2173,7 @@ def check(
         lvl_work[:] += work
         lvl_chunks += 1
         lvl_rows_in += fp_n
+        lvl_lanes += lanes
         # frontier-level verdicts (states being expanded = level `depth`)
         if check_invariants:
             viol_any_np = io.fetch(viol_any)
@@ -2213,6 +2223,7 @@ def check(
             return True
         t_host = time.perf_counter()
         t_host_wall = _now()
+        fetch0 = io.fetch_ms
         if host_set is not None and nn:
             if use_arena:
                 _grow_arena(nn)
@@ -2318,6 +2329,8 @@ def check(
         obs_.chunk_span(
             "host-assembly", t_host_wall, depth=depth, start=start, new=nn,
             backend=visited_backend,
+            # the part of it blocked in fetches (the rest is numpy)
+            fetch_ms=round(io.fetch_ms - fetch0, 3),
         )
         if collect_stats:
             lvl_act_en += act_en_np
@@ -2352,7 +2365,7 @@ def check(
         nonlocal verdict, lvl_new, prof_step, prof_host_s
         nonlocal lvl_launches, lvl_launches_max, run_launches_max
         nonlocal lvl_act_en, lvl_probe_ms, a_w, lvl_store_s
-        nonlocal lvl_chunks, lvl_rows_in
+        nonlocal lvl_chunks, lvl_rows_in, lvl_lanes
         t_wait = time.perf_counter()
         out = fin()
         act_en_np, work = split_counts(out["counts"])
@@ -2365,6 +2378,7 @@ def check(
             ran = out["verdict"][1] // plan[0] + 1
         lvl_chunks += ran
         lvl_rows_in += min(ran * plan[0], plan[2])
+        lvl_lanes += ran * out["lanes"]
         step_s = dispatch_s + wait_s
         prof_step += step_s
         launches = out["launches"]
@@ -2563,6 +2577,9 @@ def check(
             lvl_probe_ms = 0.0  # deferred batched host-probe wall
             lvl_store_s = 0.0  # trace store / parent log wall (`store_ms`)
             lvl_chunks = lvl_rows_in = 0  # chunks committed, their rows
+            # the width the dedup side was handed, summed over them: the
+            # lanes every sort, probe and compaction ran, live or padding
+            lvl_lanes = 0
             lvl_discarded = 0  # chunks dispatched and dropped at a verdict
             verdict = None  # (kind, global_frontier_idx, inv_name)
             # Host-native backend: assemble the next level in a preallocated
@@ -2770,6 +2787,7 @@ def check(
                         chunks_committed=lvl_chunks,
                         chunks_discarded=lvl_discarded,
                         chunks=lvl_chunks + lvl_discarded,
+                        dedup_lanes=lvl_lanes,
                         level_ms=round((time.perf_counter() - t_level) * 1e3, 1),
                         step_ms=round(prof_step * 1e3, 1),
                         host_ms=round(prof_host_s * 1e3, 1),
@@ -2890,6 +2908,8 @@ def check(
                         # chunks the level streamed (a whole-level
                         # program: the chunks it ran)
                         "chunks": lvl_chunks,
+                        # the lanes their dedup sides were handed
+                        "dedup_lanes": lvl_lanes,
                         **work_record(lvl_work),
                         # what the host launched, moved and stored this
                         # level (engine/hostio.py; docs/observability.md)

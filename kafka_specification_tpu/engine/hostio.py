@@ -5,15 +5,20 @@ Both per-chunk pipelines, the whole-level pipeline, the level loop and the
 sharded engine (``parallel/sharded.py``, through its own transfer functions)
 fetch device outputs and upload host arrays through :class:`HostIO`, so a
 level record can say what crossed (``d2h_bytes``, ``d2h_fetches``,
-``h2d_bytes``, ``h2d_puts``) and what was launched (``dispatches``,
-``discarded_dispatches``, ``discarded_ms``).  Counting is two integer
-additions a call and there is no span per transfer; a ``dispatch`` span
+``h2d_bytes``, ``h2d_puts``), how long the host was blocked in the
+crossing (``fetch_ms``: a fetch waits for the value to be computed AND
+for its transfer, one number; ``put_ms``) and what was launched
+(``dispatches``, ``discarded_dispatches``, ``discarded_ms``).  Counting is
+two integer additions and two clock reads a call and there is no span per
+transfer; a ``dispatch`` span
 (and, inside a profile, a ``kspec.dispatch <program>`` annotation) is
 written per program launched.  Lives in the engine, not in ``obs/``: it
 touches JAX arrays, and ``obs/`` stays jax-free.
 """
 
 from __future__ import annotations
+
+from time import perf_counter
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +30,10 @@ from ..obs.tracer import now
 LEVEL_COUNTERS = (
     "dispatches", "discarded_dispatches", "discarded_ms",
     "d2h_bytes", "d2h_fetches", "h2d_bytes", "h2d_puts",
+    "fetch_ms", "put_ms",
 )
+#: those of them that are host timings: two runs do not repeat them
+LEVEL_TIMINGS = ("discarded_ms", "fetch_ms", "put_ms")
 
 
 class _Dispatch:
@@ -66,32 +74,44 @@ class HostIO:
         self.discarded_ms = 0.0
         self.d2h_bytes = self.d2h_fetches = 0
         self.h2d_bytes = self.h2d_puts = 0
+        self.fetch_ms = self.put_ms = 0.0
 
     def take(self) -> dict:
         """This level's counters (LEVEL_COUNTERS), then zero them."""
         rec = {k: getattr(self, k) for k in LEVEL_COUNTERS}
-        rec["discarded_ms"] = round(rec["discarded_ms"], 3)
+        for k in LEVEL_TIMINGS:
+            rec[k] = round(rec[k], 3)
         self.reset()
         return rec
 
     # --- transfers ----------------------------------------------------------
     def fetch(self, x, dtype=None) -> np.ndarray:
         """``np.asarray(x, dtype)``; a device array is counted as one
-        fetch of its bytes (blocks until the value is computed)."""
-        if isinstance(x, jax.Array):
-            self.d2h_fetches += 1
-            self.d2h_bytes += x.nbytes
-            if self._fetch is not None:
-                x = self._fetch(x)
-        return np.asarray(x, dtype)
+        fetch of its bytes, and the time the host is blocked in it (until
+        the value is computed and has crossed) goes to ``fetch_ms``."""
+        if not isinstance(x, jax.Array):
+            return np.asarray(x, dtype)
+        self.d2h_fetches += 1
+        self.d2h_bytes += x.nbytes
+        t0 = perf_counter()
+        if self._fetch is not None:
+            x = self._fetch(x)
+        out = np.asarray(x, dtype)
+        self.fetch_ms += (perf_counter() - t0) * 1e3
+        return out
 
     def put(self, x, *where) -> jax.Array:
         """``jnp.asarray(x)`` (or the engine's own ``put(x, *where)``); a
-        host array is counted as one upload."""
-        if isinstance(x, np.ndarray):
+        host array is counted as one upload and timed into ``put_ms``."""
+        counted = isinstance(x, np.ndarray)
+        if counted:
             self.h2d_puts += 1
             self.h2d_bytes += x.nbytes
-        return jnp.asarray(x) if self._put is None else self._put(x, *where)
+            t0 = perf_counter()
+        out = jnp.asarray(x) if self._put is None else self._put(x, *where)
+        if counted:
+            self.put_ms += (perf_counter() - t0) * 1e3
+        return out
 
     # --- spans and dispatches -----------------------------------------------
     def span(self, kind: str, t0: float, **attrs) -> None:

@@ -137,7 +137,7 @@ PIPELINES = pipeline_names()
 #: which is what a profile's device events carry, so device time reads by
 #: stage instead of by XLA's fusion numbers (docs/observability.md
 #: § Stage vocabulary).  ops/ modules use the literal ``kspec.<stage>``
-#: strings (they cannot import the engine); tests/test_stage_names.py
+#: strings (they cannot import the engine); tests/test_tracing.py
 #: holds every scope found in a lowered program to this tuple.
 STAGES = (
     "guard", "expand", "compact", "fingerprint", "dedup_sort",
@@ -145,19 +145,50 @@ STAGES = (
 )
 STAGE_PREFIX = "kspec."
 
-#: part of every step program's module name (``dvl_n1``).  JAX strips
+#: the parts of ``compact``, the one stage that is four pieces of code.
+#: Every ``with stage("compact"):`` body runs under exactly one
+#: :func:`part` scope NESTED INSIDE the stage scope, so an operation's
+#: path reads ``jit(fsc_n2)/kspec.compact/part.squeeze/scatter``:
+#:
+#: - ``select``: the per-action index compaction of the guard matrix on
+#:   the device (engine/bfs.py ``_expand_compact``; the fused path does it
+#:   on the host, its ``compact-host`` span);
+#: - ``squeeze``: :func:`squeeze_stage`, enabled candidate rows to the
+#:   front of the buffer dedup is handed;
+#: - ``novel``: the compaction of the new states after dedup
+#:   (:func:`sorted_dedup_stage`, :func:`candidate_dedup_stage`, the
+#:   sharded step);
+#: - ``append``: the whole-level programs' next-frontier append and the
+#:   fills of their level buffers.
+#:
+#: The prefix is deliberately not ``kspec.``: a reader that takes the
+#: innermost ``kspec.*`` component as the stage skips a ``part.*`` one,
+#: so the stage split reads what it read before the parts existed.
+#: ``expand`` has no parts: its unpack, gather, kernels and pack fuse
+#: into one XLA fusion that a profile books whole to its root.
+COMPACT_PARTS = ("select", "squeeze", "novel", "append")
+PART_PREFIX = "part."
+
+#: part of every step program's module name (``dvl_n2``).  JAX strips
 #: debug metadata before it hashes a program for the persistent compile
 #: cache but DOES hash the module name, so a program compiled before a
 #: vocabulary change would be a cache hit that carries the old scopes.
 #: Bump this whenever STAGES or a scope's placement changes: the renamed
 #: programs recompile once and bake the new names in.
-NAMING_VERSION = 1
+NAMING_VERSION = 2
 
 
 def stage(name: str):
     """``jax.named_scope`` of one vocabulary stage."""
     assert name in STAGES, name
     return jax.named_scope(STAGE_PREFIX + name)
+
+
+def part(name: str):
+    """``jax.named_scope`` of one part of ``compact``; entered inside
+    ``stage("compact")``, never alone."""
+    assert name in COMPACT_PARTS, name
+    return jax.named_scope(PART_PREFIX + name)
 
 
 def program_name(tag: str) -> str:
@@ -227,7 +258,7 @@ def grow_visited(vhi, vlo, vcap: int, need: int, cache: Optional[dict]
 def squeeze_stage(cand, parent, actid, valid, width, K):  # kspec: traced
     """Stage 2: compact enabled candidate rows to the front of a `width`
     buffer; overflow=True iff more than `width` rows are enabled."""
-    with stage("compact"):
+    with stage("compact"), part("squeeze"):
         n_en = jnp.sum(valid, dtype=jnp.int32)
         spos = jnp.where(valid, jnp.cumsum(valid) - 1, width)
         out = jnp.zeros((width, K), jnp.uint32).at[spos].set(cand)
@@ -389,7 +420,7 @@ def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
         is_new = is_new & ~a_seen
         probe = probe + a_probe
     # compact new states to the front (OOB scatter indices are dropped)
-    with stage("compact"):
+    with stage("compact"), part("novel"):
         pos = jnp.where(is_new, jnp.cumsum(is_new) - 1, T)
         out = jnp.zeros((T, K), jnp.uint32).at[pos].set(cand[order])
         out_parent = jnp.full((T,), -1, jnp.int32).at[pos].set(
@@ -438,7 +469,7 @@ def candidate_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
     hi_s, lo_s, order, first = _sort_first(hi, lo)
     seen, rank, probe = dedup.probe_sorted(lhi, llo, ln, hi_s, lo_s)
     is_new = first & ~seen
-    with stage("compact"):
+    with stage("compact"), part("novel"):
         # sorted-order compaction: what the level-new merge consumes
         pos_s = jnp.where(is_new, jnp.cumsum(is_new) - 1, T)
         n_hi = jnp.full((T,), sent).at[pos_s].set(hi_s)
@@ -586,6 +617,9 @@ class LegacyPipeline:
             out, out_parent, out_act, new_n, vhi, vlo, vn,
             viol_any, viol_idx, dl_any, dl_idx, act_en,
             out_hi, out_lo, act_guard, dispatched,
+            # the width the committed attempt handed its dedup side
+            # (the level record's `dedup_lanes`)
+            self.step.dedup_width(bucket, compact_arg, attempt_sq_full),
         )
 
     def run_chunk_staged(self, piece, fp_n, bucket, depth,
@@ -847,6 +881,7 @@ class FusedPipeline:
         span: -> (sidx host, sidx, chloc, rowvalid on the device)."""
         t0 = _now()
         io = self.io
+        fetch0, put0 = io.fetch_ms, io.put_ms
         ga_np = io.fetch(ga)
         bounds = self._bounds
         W = int(sum(widths))
@@ -867,8 +902,11 @@ class FusedPipeline:
             rowvalid[off: off + n] = True
             off += w
         on_device = (io.put(sidx), io.put(chloc), io.put(rowvalid))
+        # the span's blocked part: the wait for launch 1's matrix and its
+        # transfer, and the three uploads; the rest is numpy
         io.span("compact-host", t0, depth=depth, rows=int(sum(counts)),
-                width=W)
+                width=W, fetch_ms=round(io.fetch_ms - fetch0, 3),
+                put_ms=round(io.put_ms - put0, 3))
         return (sidx,) + on_device
 
     # --- the chunk driver -------------------------------------------------
@@ -1034,6 +1072,7 @@ class FusedPipeline:
                         out, out_parent, out_act, nn, vhi, vlo, vn,
                         viol_any, viol_idx, dl_any, dl_idx, act_en,
                         out_hi, out_lo, act_guard_np, dispatched,
+                        int(sum(widths)),
                     )
 
                 finalize.launch = launch
@@ -1041,7 +1080,8 @@ class FusedPipeline:
             def finalize(launch=launch, act_en=act_en, committed=(
                     out, out_parent, out_act, new_n, vhi, vlo, vn,
                     viol_any, viol_idx, dl_any, dl_idx, None,
-                    out_hi, out_lo, act_guard_np, dispatched)):
+                    out_hi, out_lo, act_guard_np, dispatched,
+                    int(sum(widths)))):
                 # the first read of launch 2's outputs: the host blocks
                 # here until the update skeleton has run
                 act_en_np = io.fetch(act_en, np.int64)
@@ -1411,7 +1451,7 @@ class DevicePipeline:
                     ln_ovf = commit & ((ln + new_n) > LN)
                     commit_ok = commit & ~ovf & ~ln_ovf
                     app_n = jnp.where(commit_ok, new_n, 0)
-                with stage("compact"):
+                with stage("compact"), part("append"):
                     orows = devlevel.append_rows(orows, n_out, on)
                     opar = devlevel.append_vec(opar, n_par + start, on)
                     oact = devlevel.append_vec(oact, n_act, on)
@@ -1441,7 +1481,7 @@ class DevicePipeline:
                 return (carry[0] < n_chunks) & (carry[8] == 0)
 
             # the level's output and level-new buffers
-            with stage("compact"):
+            with stage("compact"), part("append"):
                 init = (
                     jnp.int32(0),
                     jnp.zeros((OC, K), jnp.uint32),
@@ -1561,7 +1601,7 @@ class DevicePipeline:
                     ln_ovf = commit & ((ln + new_n) > LN)
                     commit_ok = commit & ~ovf & ~ln_ovf
                     app_n = jnp.where(commit_ok, new_n, 0)
-                with stage("compact"):
+                with stage("compact"), part("append"):
                     orows = devlevel.append_rows(orows, n_out, on)
                     opar = devlevel.append_vec(opar, n_par + start, on)
                     oact = devlevel.append_vec(oact, n_act, on)
@@ -1587,7 +1627,7 @@ class DevicePipeline:
                 return (carry[0] < n_chunks) & (carry[10] == 0)
 
             # the level's output and level-new buffers
-            with stage("compact"):
+            with stage("compact"), part("append"):
                 init = (
                     jnp.int32(0),
                     jnp.zeros((OC, K), jnp.uint32),
@@ -1748,7 +1788,7 @@ class DevicePipeline:
         self.device_levels += 1
         if self.host_mode:
 
-            def finalize(outs=outs, dispatched=dispatched):
+            def finalize(outs=outs, dispatched=dispatched, T=T):
                 on = int(io.fetch(outs[5]))
                 vk = int(io.fetch(outs[6]))
                 verdict = None
@@ -1773,13 +1813,14 @@ class DevicePipeline:
                     counts=io.fetch(outs[9], np.int64),
                     digest=None,  # host folds the probe survivors
                     launches=dispatched,
+                    lanes=T,
                 )
 
             # visited refs unchanged: the host set is the visited state
             return vhi, vlo, vn, vcap, finalize
         new_vhi, new_vlo, new_vn = outs[4], outs[5], outs[6]
 
-        def finalize(outs=outs, dispatched=dispatched):
+        def finalize(outs=outs, dispatched=dispatched, T=T):
             on = int(io.fetch(outs[3]))
             vk = int(io.fetch(outs[7]))
             verdict = None
@@ -1800,6 +1841,7 @@ class DevicePipeline:
                     tuple(io.fetch(a) for a in outs[12])
                 ),
                 launches=dispatched,
+                lanes=T,
             )
 
         return new_vhi, new_vlo, new_vn, vcap, finalize

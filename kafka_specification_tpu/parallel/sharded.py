@@ -46,6 +46,7 @@ from ..engine.hostio import HostIO
 from ..engine.pipeline import (
     WORK_FIELDS,
     counts_out,
+    part,
     split_counts,
     stage,
     work_counts,
@@ -516,7 +517,7 @@ def _make_sharded_step(
             seen, rank, probe = dedup.probe_sorted(vhi, vlo, vn, hi_s, lo_s)
             is_new = first & ~seen
 
-        with stage("compact"):
+        with stage("compact"), part("novel"):
             pos = jnp.where(is_new, jnp.cumsum(is_new) - 1, R)
             out = jnp.zeros((R, K), jnp.uint32).at[pos].set(r_cand[order])
             out_parent = jnp.full((R,), -1, jnp.int32).at[pos].set(
@@ -2738,6 +2739,10 @@ def check_sharded(
             # probe rounds and merge slots, each beside what the form over
             # the whole capacity would have run
             lvl_work = np.zeros(len(WORK_FIELDS), np.int64)
+            # chunks committed, and the width their dedup sides were handed
+            # (`R` a shard a chunk), summed over chunks and shards: the
+            # single-device record's `chunks` and `dedup_lanes`
+            lvl_chunks = lvl_lanes = 0
             lvl_exch_bytes = lvl_exch_raw_bytes = 0
             # dispatched collective-bearing programs this level — one
             # launch PER SHARD each (the kspec_shard_launches_level
@@ -2984,9 +2989,12 @@ def check_sharded(
                 nonlocal verdict, lvl_act_en, lvl_new_per_shard
                 nonlocal lvl_en_per_shard, lvl_recv_per_shard
                 nonlocal shard_visited, lvl_exch_bytes, lvl_exch_raw_bytes
+                nonlocal lvl_chunks, lvl_lanes
                 ctx, outs, meta = st
                 bucket, frontier, took, chunk_off, _fv, t_chunk, _n = ctx
                 _attempt, _wt, _ca, T, W, R, compress, _launch = meta
+                lvl_chunks += 1
+                lvl_lanes += D * R
                 (
                     out, out_parent, out_act, new_n, _vh, _vl, _vn,
                     viol_any, viol_idx, dl_any, dl_idx, act_en,
@@ -3166,7 +3174,7 @@ def check_sharded(
                 nonlocal lvl_recv_per_shard, shard_visited
                 nonlocal lvl_exch_bytes, lvl_exch_raw_bytes
                 nonlocal lvl_dispatches, lvl_probe_ms
-                nonlocal prof_step, prof_host_s
+                nonlocal prof_step, prof_host_s, lvl_chunks, lvl_lanes
                 lens = [p.shape[0] for p in pending]
                 plan = sdev.plan_level(lens, chunk, min_bucket)
                 if plan is None:
@@ -3309,6 +3317,8 @@ def check_sharded(
                 # host-mode program carries no visited shards — the
                 # host sets below ARE the visited state)
                 t_commit = time.perf_counter()
+                lvl_chunks += nc
+                lvl_lanes += nc * D * R
                 if not host_mode:
                     dev_vhi, dev_vlo, dev_vn = outs[4], outs[5], outs[6]
                 counts = io.fetch(outs[i_cnt]).astype(np.int64)  # [D]
@@ -3642,6 +3652,8 @@ def check_sharded(
                     # (= launches PER SHARD; in-memory only, like the
                     # launch counters of the single-device engine)
                     "shard_launches": int(lvl_dispatches),
+                    "chunks": lvl_chunks,
+                    "dedup_lanes": lvl_lanes,
                     **work_record(lvl_work),
                     # the single-device engine's host/device split and
                     # what the host launched and moved this level

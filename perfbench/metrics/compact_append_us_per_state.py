@@ -1,0 +1,17 @@
+import partreduce
+
+META = {
+    "name": "compact_append_us_per_state", "unit": "us", "better": "lower",
+    "source": "device_trace", "layer": "level programs",
+    "moves": "states_per_s",
+    "what": "leaf device seconds under kspec.compact/part.append in the "
+            "traced pass, on the plane and in the window of the stage "
+            "metrics, x 1e6 over that pass's distinct states: the "
+            "whole-level programs' next-frontier append and the fills of "
+            "their level buffers; nothing to read on a program without "
+            "part scopes",
+}
+
+
+def read(ctx):
+    return partreduce.part_us_per_state(ctx, "append")

@@ -29,7 +29,8 @@ from test_oracle_replay import replay_through_oracle
 TINY = Config(2, 2, 1, 1)
 KIP101_LEVELS = [1, 4, 14, 44, 100, 166, 268, 456, 684, 976, 1292, 1486]
 CUT_KEYS = {"depth", "frontier", "rows_committed", "chunks_committed",
-            "chunks_discarded", "chunks", "level_ms", "step_ms", "host_ms",
+            "chunks_discarded", "chunks", "dedup_lanes", "level_ms",
+            "step_ms", "host_ms",
             "successor_launches", "probe_rounds",
             "probe_rounds_plain", "merge_slots",
             "merge_slots_plain"} | set(hostio.LEVEL_COUNTERS)

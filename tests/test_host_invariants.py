@@ -1,7 +1,7 @@
 """The engines' start and finish as cached programs (ISSUE 26): the
-invariant pass over host-held rows (`hinv_n1`; the sharded engine's
-`shi_n1` is the same body) and the initial states' pack + fingerprint
-(`init_n1`).
+invariant pass over host-held rows (`hinv_n2`; the sharded engine's
+`shi_n2` is the same body) and the initial states' pack + fingerprint
+(`init_n2`).
 
 CPU, a two-replica FiniteReplicatedLog whose initial state is NOT the
 all-zero packed row, so the empty state (what a padding row unpacks to)
@@ -192,8 +192,8 @@ def test_second_check_launches_three_programs_and_compiles_none(tmp_path):
 
 
 def test_both_engines_name_and_key_their_invariant_program_as_before():
-    """`shi_n1`, keyed ("shi", mesh, N, inv_sig): what `kip320-5b-x4`'s
-    compile cache and set-up hold; the single-device twin is `hinv_n1`."""
+    """`shi_n2`, keyed ("shi", mesh, N, inv_sig): what `kip320-5b-x4`'s
+    compile cache and set-up hold; the single-device twin is `hinv_n2`."""
     model, _ = _pair(("ShortLogs", "SomeLog"))
     mesh = Mesh(np.array(jax.devices()[:4]), ("d",))
     res = check_sharded(model, mesh=mesh, max_depth=1, min_bucket=8,
@@ -208,8 +208,8 @@ def test_both_engines_name_and_key_their_invariant_program_as_before():
     K = model.spec.num_lanes
     for key in keys:
         fn = model._step_cache[key]
-        assert fn.__name__ == pl.program_name(key[0]) == key[0] + "_n1"
+        assert fn.__name__ == pl.program_name(key[0]) == f"{key[0]}_n{pl.NAMING_VERSION}"
         rows = jax.ShapeDtypeStruct((key[-2], K), jax.numpy.uint32)
         text = fn.lower(rows, np.int32(1)).as_text(debug_info=True)
-        assert f"module @jit_{key[0]}_n1" in text
+        assert f"module @jit_{key[0]}_n{pl.NAMING_VERSION}" in text
         assert set(re.findall(r"kspec\.([a-z_]+)", text)) == {"invariants"}

@@ -196,7 +196,8 @@ def test_stats_shim_record_for_record_identical(tmp_path):
                       "overlap_efficiency", "host_probe_ms",
                       "store_ms", "probe_rounds",
                       "probe_rounds_plain", "merge_slots",
-                      "merge_slots_plain", "chunks") + LEVEL_COUNTERS}
+                      "merge_slots_plain", "chunks", "dedup_lanes")
+         + LEVEL_COUNTERS}
         for r in r1.stats["levels"]
     ] == recs_bare
 
@@ -363,7 +364,8 @@ def test_sharded_per_shard_breakdowns_and_imbalance(tmp_path):
                       "io_exposed_ms", "shard_launches",
                       "host_probe_ms", "step_ms", "host_ms",
                       "probe_rounds", "probe_rounds_plain",
-                      "merge_slots", "merge_slots_plain")
+                      "merge_slots", "merge_slots_plain", "chunks",
+                      "dedup_lanes")
          + LEVEL_COUNTERS}
         for r in res.stats["levels"]
     ] == recs

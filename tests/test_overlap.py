@@ -15,6 +15,7 @@ and a reclaim quiesces the merge worker before touching its files
 """
 
 import os
+import threading
 import time
 
 import numpy as np
@@ -481,10 +482,10 @@ def test_reclaim_quiesces_merge_worker_first(tmp_path, monkeypatch):
     from kafka_specification_tpu.storage.tiered import TieredFpSet
 
     real_merge = runs_mod.merge_runs
-    started = []
+    started = threading.Event()
 
     def slow_merge(rs, path, block=1 << 20, crash_hook=None):
-        started.append(path)
+        started.set()
         time.sleep(0.4)  # hold the merge mid-flight
         return real_merge(rs, path, block=block, crash_hook=crash_hook)
 
@@ -500,7 +501,9 @@ def test_reclaim_quiesces_merge_worker_first(tmp_path, monkeypatch):
     fps = rng.integers(1, 2**63, size=400, dtype=np.uint64)
     for i in range(0, fps.size, 50):
         ts.insert(fps[i : i + 50])
-    assert started, "background merge should have started"
+    # the worker picks the job up on its own thread: wait for it (bounded)
+    # so that the reclaim below finds the merge mid-flight, not queued
+    assert started.wait(timeout=30), "background merge should have started"
     # the reclaim path: sync merge must quiesce (adopt) first
     ts.merge()
     assert ts._merge_job is None

@@ -21,13 +21,17 @@ import pytest
 from jax import monitoring
 from jax.sharding import Mesh
 
-from kafka_specification_tpu.engine.hostio import LEVEL_COUNTERS
+from kafka_specification_tpu.engine.hostio import (
+    LEVEL_COUNTERS,
+    LEVEL_TIMINGS,
+)
 from kafka_specification_tpu.models import kip320
 from kafka_specification_tpu.models.kafka_replication import Config
 from kafka_specification_tpu.obs import RunContext, read_jsonl_tolerant
 from kafka_specification_tpu.oracle.interp import oracle_bfs
 from kafka_specification_tpu.parallel import sharded
 from kafka_specification_tpu.parallel.sharded import check_sharded
+from test_tracing import compact_parts_of
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = Config(2, 2, 1, 1)  # 277 states, diameter 11
@@ -135,8 +139,7 @@ def test_second_call_builds_nothing_and_repeats_bit_for_bit(
         assert r1.stats["device"]["fallback"] is None
         assert r1.stats["device"]["levels"] > 0
     drop = ("ts", "unix", "run_id", "level_ms", "step_ms", "host_ms",
-            "discarded_ms", "io_hidden_ms", "io_exposed_ms",
-            "host_probe_ms")
+            "io_hidden_ms", "io_exposed_ms", "host_probe_ms") + LEVEL_TIMINGS
     for a, b in zip(r1.stats["levels"], r2.stats["levels"]):
         assert ({k: v for k, v in a.items() if k not in drop}
                 == {k: v for k, v in b.items() if k not in drop})
@@ -196,8 +199,15 @@ def test_level_records_and_spans_of_a_sharded_run(tmp_path, pipeline):
     assert len(recs) == res.diameter + 1 and recs[-1]["new"] == 0
     for rec in recs:
         for key in ("step_ms", "host_ms", "level_ms", "shard_new",
-                    "exch_bytes", "shard_launches") + LEVEL_COUNTERS:
+                    "exch_bytes", "shard_launches", "chunks",
+                    "dedup_lanes") + LEVEL_COUNTERS:
             assert key in rec, key
+        # the lanes the shards' dedup sides ran hold every candidate, and
+        # the host's blocked time is part of the level's
+        assert rec["dedup_lanes"] >= rec["enabled_candidates"]
+        assert rec["chunks"] >= 1
+        assert 0 <= rec["fetch_ms"] <= rec["level_ms"] + 0.1
+        assert 0 <= rec["put_ms"] <= rec["level_ms"] + 0.1
         assert rec["dispatches"] >= rec["shard_launches"] >= 1
         assert rec["d2h_fetches"] > 0 and rec["d2h_bytes"] > 0
         assert rec["h2d_puts"] > 0 and rec["h2d_bytes"] > 0
@@ -275,6 +285,12 @@ def test_sharded_programs_carry_their_names_and_the_exchange_scope():
         assert found - {"exchange"} <= set(pl.STAGES), found
         assert {"guard", "expand", "fingerprint", "dedup_probe",
                 "dedup_merge", "invariants", "digest"} <= found, (tag, found)
+        # the parts of `compact` (pipeline.COMPACT_PARTS), each directly
+        # under the stage scope: the guard matrix's index compaction and
+        # the new states' compaction; the sharded level program appends
+        # outside the stage scope, as it did before the parts
+        parts = compact_parts_of(text)
+        assert parts == {"select", "novel"}, (tag, parts)
 
 
 def test_benchmark_cell_rehearses_on_four_virtual_devices():

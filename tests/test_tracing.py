@@ -4,6 +4,7 @@ records (docs/observability.md § Stage vocabulary, § Spans; ISSUE 23).
 CPU, toy models, each case a few seconds: what a profile of the chip reads
 by stage is decided by what these find in the lowered programs."""
 
+import functools
 import json
 import re
 
@@ -14,7 +15,10 @@ import pytest
 from kafka_specification_tpu.engine import check
 from kafka_specification_tpu.engine import pipeline as pl
 from kafka_specification_tpu.engine.bfs import _Step
-from kafka_specification_tpu.engine.hostio import LEVEL_COUNTERS
+from kafka_specification_tpu.engine.hostio import (
+    LEVEL_COUNTERS,
+    LEVEL_TIMINGS,
+)
 from kafka_specification_tpu.models import finite_replicated_log as frl
 from kafka_specification_tpu.obs import RunContext, read_jsonl_tolerant
 
@@ -43,6 +47,19 @@ PROGRAMS = {
 }
 
 
+# the parts of `compact` each program must hold (pl.COMPACT_PARTS): the
+# fused path selects on the host (`compact-host`), the whole-level programs
+# alone append
+PARTS = {
+    "fgd": set(), "hinv": set(), "init": set(),
+    "fsc": {"squeeze", "novel"},
+    "step": {"select", "squeeze", "novel"},
+    "dvl": set(pl.COMPACT_PARTS),
+    "dvh": set(pl.COMPACT_PARTS),
+}
+
+
+@functools.lru_cache(maxsize=None)
 def _lower(tag):
     """The lowered text of one `tag` program of the toy model."""
     m = _model()
@@ -99,6 +116,38 @@ def test_program_carries_its_stages_and_its_name(tag):
     assert re.search(rf"module @jit_{tag}_n{pl.NAMING_VERSION}\b", text)
 
 
+def compact_parts_of(text):
+    """The `part.<name>` scopes a lowered program holds, each held to its
+    place: a name of the vocabulary directly under `kspec.compact`, one a
+    path, and no operation of `kspec.compact` outside one:
+    `jit(fsc_n2)/kspec.compact/part.squeeze/scatter`."""
+    found = set()
+    compact = pl.STAGE_PREFIX + "compact"
+    for path in set(re.findall(r'"([^"]*(?:kspec\.compact|part\.)[^"]*)"',
+                               text)):
+        comps = path.split("/")
+        for i, comp in enumerate(comps):
+            if comp.startswith(pl.PART_PREFIX):
+                name = comp[len(pl.PART_PREFIX):]
+                assert name in pl.COMPACT_PARTS, path
+                assert comps[i - 1] == compact, path
+                found.add(name)
+            elif comp == compact:
+                assert comps[i + 1].startswith(pl.PART_PREFIX), path
+        assert sum(c.startswith(pl.PART_PREFIX) for c in comps) <= 1, path
+    return found
+
+
+@pytest.mark.parametrize("tag", sorted(PROGRAMS))
+def test_compact_runs_under_exactly_one_part(tag):
+    found = compact_parts_of(_lower(tag))
+    assert found == PARTS[tag], (tag, found ^ PARTS[tag])
+    assert ("compact" in PROGRAMS[tag][1]) == bool(found)
+    # a reader of the stage takes the innermost `kspec.*` component and
+    # must not meet the parts there
+    assert not pl.PART_PREFIX.startswith(pl.STAGE_PREFIX)
+
+
 # --- (b) spans: true starts, one root, a cause on every span ---------------
 
 def _spans(run):
@@ -139,6 +188,14 @@ def test_every_engine_span_reaches_check(tmp_path, pipeline):
             "store"} <= kinds
     if pipeline == "fused":
         assert "compact-host" in kinds
+    # the blocked part of the two host spans between launches: the fetches
+    # (and, selecting on the host, the uploads) inside them
+    for s in done.values():
+        if s["span"] == "compact-host":
+            assert 0 <= s["fetch_ms"] and 0 <= s["put_ms"]
+            assert s["fetch_ms"] + s["put_ms"] <= s["ms"] + 1e-2, s
+        elif s["span"] == "host-assembly" and pipeline == "fused":
+            assert 0 <= s["fetch_ms"] <= s["ms"] + 1e-2, s
     levels = [s for s in done.values() if s["span"] == "level"]
     assert all(s["parent_id"] == root["span_id"] for s in levels)
     t0s = [s["t0"] for s in sorted(levels, key=lambda s: s["depth"])]
@@ -165,9 +222,16 @@ def test_level_records_carry_repeatable_counters(tmp_path, pipeline):
         res = check(_model(), pipeline=pipeline,
                     run=RunContext(str(tmp_path / f"run{i}")), **KW)
         runs.append(res.stats["levels"])
-    exact = [k for k in LEVEL_COUNTERS if k != "discarded_ms"]
+    exact = [k for k in LEVEL_COUNTERS if k not in LEVEL_TIMINGS] + [
+        "chunks", "dedup_lanes"]
     for rec in runs[0]:
-        assert set(LEVEL_COUNTERS) | {"store_ms"} <= set(rec)
+        assert set(LEVEL_COUNTERS) | {"store_ms", "dedup_lanes"} <= set(rec)
+        # every candidate a level enabled lay in a lane its dedup sides
+        # were handed; the host's blocked time is part of the level's
+        assert rec["dedup_lanes"] >= rec["enabled_candidates"]
+        assert rec["dedup_lanes"] > 0 and rec["chunks"] >= 1
+        assert 0 <= rec["fetch_ms"] <= rec["level_ms"] + 0.1
+        assert 0 <= rec["put_ms"] <= rec["level_ms"] + 0.1
         assert rec["dispatches"] >= 1 and rec["d2h_fetches"] >= 1
         assert rec["d2h_bytes"] > 0 and rec["h2d_bytes"] > 0
         assert rec["discarded_dispatches"] == 0 == rec["discarded_ms"]
@@ -188,7 +252,8 @@ def test_level_records_carry_repeatable_counters(tmp_path, pipeline):
     # the emitted stream stays historical: none of it reaches stats.jsonl
     emitted = read_jsonl_tolerant(str(tmp_path / "run0" / "stats.jsonl"))
     assert emitted and not any(
-        set(LEVEL_COUNTERS) & set(r) or "store_ms" in r for r in emitted)
+        set(LEVEL_COUNTERS) & set(r) or "store_ms" in r
+        or "dedup_lanes" in r for r in emitted)
 
 
 # --- (d) a discarded dispatch is counted and marked ------------------------
