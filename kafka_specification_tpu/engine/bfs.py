@@ -693,11 +693,16 @@ class _Step:
         # single most expensive stage, and its cost is set by this width.
         # Uniform-shift buffers are ~4x oversized (~25% occupied), so the
         # squeeze halves (squeeze overflow re-runs with squeeze_full — the
-        # retry keeps results exact at every density).  Per-action widths
-        # are already sized tight from measured enablement, so T is the
-        # full compact width and the squeeze cannot overflow (it only
-        # compacts rows to the front for the fingerprint/output stages).
+        # retry keeps results exact at every density).  The squeeze is a
+        # change of width and nothing else: where T is the expansion's own
+        # width (per-action widths, sized tight from measured enablement;
+        # squeeze_full; the fused path's pooled layout) it would only move
+        # the enabled rows to the front in order, which the stable sort
+        # does again (masked rows fingerprint to the sentinel pair and
+        # sort last, ties break by index), so the sorted dedup skips it
+        # there and fingerprints the rows where they lie.
         T = self.dedup_width(bucket, compact, squeeze_full)
+        resizes = shift and T != self.expand_width(bucket, compact)
 
         # Host-FpSet backend: the device holds no visited set, and the
         # native C++ open-addressing FpSet already dedups both in-batch and
@@ -755,7 +760,7 @@ class _Step:
                     out_hi, out_lo, ovf_vec(sq_ovf), act_guard,
                 )
 
-            if shift:
+            if resizes:
                 cand, parent, actid, valid, _, sq_ovf = squeeze_stage(
                     cand, parent, actid, valid, T, K
                 )

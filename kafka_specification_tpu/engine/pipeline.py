@@ -148,16 +148,17 @@ STAGE_PREFIX = "kspec."
 #: the parts of ``compact``, the one stage that is four pieces of code.
 #: Every ``with stage("compact"):`` body runs under exactly one
 #: :func:`part` scope NESTED INSIDE the stage scope, so an operation's
-#: path reads ``jit(fsc_n2)/kspec.compact/part.squeeze/scatter``:
+#: path reads ``jit(step_n2)/kspec.compact/part.squeeze/scatter``:
 #:
 #: - ``select``: the per-action index compaction of the guard matrix on
 #:   the device (engine/bfs.py ``_expand_compact``; the fused path does it
 #:   on the host, its ``compact-host`` span);
 #: - ``squeeze``: :func:`squeeze_stage`, enabled candidate rows to the
-#:   front of the buffer dedup is handed;
+#:   front of a buffer of another width than the expansion's (a program
+#:   whose sorted dedup runs at the expansion's own width has none);
 #: - ``novel``: the compaction of the new states after dedup
-#:   (:func:`sorted_dedup_stage`, :func:`candidate_dedup_stage`, the
-#:   sharded step);
+#:   (:func:`novel_stage` inside :func:`sorted_dedup_stage`,
+#:   :func:`candidate_dedup_stage`, the sharded step);
 #: - ``append``: the whole-level programs' next-frontier append and the
 #:   fills of their level buffers.
 #:
@@ -349,26 +350,29 @@ def _sort_first(hi, lo):  # kspec: traced
 
 #: The level-record fields of :func:`work_counts`, in the vector's order.
 WORK_FIELDS = ("probe_rounds", "probe_rounds_plain",
-               "merge_slots", "merge_slots_plain")
+               "merge_slots", "merge_slots_plain",
+               "novel_rows", "novel_rows_plain")
 
 
-def work_counts(probe=None, merge=None):  # kspec: traced
-    """int32[4] (:data:`WORK_FIELDS`), the dedup work a program did beside its answers: the two
+def work_counts(probe=None, merge=None, novel=None):  # kspec: traced
+    """int32[6] (:data:`WORK_FIELDS`), the dedup work a program did beside its answers: the two
     round counts of ``dedup.probe_sorted`` (rounds run, rounds a search of
-    the whole capacity runs), then the two slot counts of
+    the whole capacity runs), the two slot counts of
     ``dedup.merge_counted`` (slots touched, slots a capacity-wide merge
-    touches); zeros for the half not given.  Vectors of several probes and
-    merges add."""
+    touches), then the two row counts of :func:`novel_stage` (rows its
+    loops touched, rows the full-width compaction touches); zeros for the
+    part not given.  Vectors of several probes, merges and compactions
+    add."""
     zero = jnp.zeros((2,), jnp.int32)
-    return jnp.concatenate([zero if probe is None else probe,
-                            zero if merge is None else merge])
+    return jnp.concatenate([zero if x is None else x
+                            for x in (probe, merge, novel)])
 
 
 def counts_out(act_en, work=None):  # kspec: traced
     """The counts a level program hands the host, in the ONE vector it
-    already fetches: the per-action enabled counts, then the four
-    :func:`work_counts` summed over the program's probes and merges (zeros
-    where it ran none).  :func:`split_counts` is the host's half."""
+    already fetches: the per-action enabled counts, then the six
+    :func:`work_counts` summed over the program's probes, merges and
+    compactions (zeros where it ran none).  :func:`split_counts` is the host's half."""
     if work is None:
         work = work_counts()
     return jnp.concatenate([act_en, work])
@@ -377,23 +381,128 @@ def counts_out(act_en, work=None):  # kspec: traced
 def split_counts(counts):
     """A fetched :func:`counts_out` vector (or a [D, n] stack of them,
     one a shard) -> (act_en, work): the enabled counts as fetched, and
-    int64[4], the :func:`work_counts` summed over the shards."""
+    int64[6], the :func:`work_counts` summed over the shards."""
     counts = np.asarray(counts, np.int64)
     n = len(WORK_FIELDS)
     return counts[..., :-n], counts[..., -n:].reshape(-1, n).sum(axis=0)
 
 
 def work_record(work):
-    """Summed :func:`work_counts` -> the four level-record fields."""
+    """Summed :func:`work_counts` -> the six level-record fields."""
     return dict(zip(WORK_FIELDS, (int(x) for x in work)))
+
+
+#: The most rows one iteration of :func:`novel_stage`'s loops moves (the
+#: block is a shape, :func:`novel_block`; how many blocks run is a device
+#: value).
+#: Timed on a TPU v5e, the function alone (PERF.md section 6, PR 37), ms at
+#: blocks of 8,192 / 16,384 / 32,768 against the full-width form: 278,528 x
+#: 3 lanes, 31% live, 12% new: 3.61 / 9.46 / 9.70 against 25.50; 475,136 x
+#: 4, 42% live, 10.5% new: 5.07 / 5.98 / 12.55 against 42.55; every lane
+#: live and new: 26.33 / 29.95 / 63.08 against 42.62; 19,661 x 4 full (two
+#: blocks a loop, the last one overlapping): 2.42 / 2.87 / 2.07 against
+#: 2.10.  4,096 reads as 8,192 does; the larger blocks are no steadier.
+NOVEL_BLOCK = 8192
+
+
+def novel_block(T: int) -> int:
+    """The block of a width: the fewest blocks no larger than
+    :data:`NOVEL_BLOCK` that cover ``T``, all of one size, so a full width
+    recomputes fewer rows than it has blocks.  (Blocks of 8,192 whatever
+    the width ran 16,384 rows a loop over 9,472 full lanes: 2.02 ms against
+    the full-width form's 1.63; 4,736 twice: 1.70.)"""
+    return -(-T // -(-T // NOVEL_BLOCK))
+
+
+def novel_stage(is_new, order, hi_s, lo_s, rank,  # kspec: traced
+                cand, parent, actid, T, K):
+    """The ``novel`` part of ``compact``: the new states of a sorted
+    dedup, compacted to the front in sorted-fingerprint order.
+
+    is_new / hi_s / lo_s / rank are in SORTED order (lane i is candidate
+    ``order[i]``); cand / parent / actid in candidate order.  -> (out[T, K],
+    out_parent, out_act, out_hi, out_lo, out_rank, new_n, rows): the
+    first new_n rows hold the new states, the rest the fills (zero rows,
+    -1 parents and action ids, sentinel fingerprints, rank 0).
+
+    Only the rows it keeps move.  One T-wide prefix sum numbers the new
+    lanes; a rolled loop over the LIVE prefix of the sorted order (the
+    sentinel pairs sort last, so every new lane lies in the first n_live)
+    writes the inverse map ``src[j]`` = the sorted lane of the j-th new
+    state, one lane scatter a block; a second rolled loop over the first
+    ``ceil(new_n / block)`` OUTPUT blocks gathers each output through
+    ``src`` and writes it as one slice into the pre-filled buffer.  Both
+    trip counts are device values, so the stage costs what a chunk keeps
+    and not what its layout pads.  The blocks of a width are of one size
+    (:func:`novel_block`); where they do not divide it the last block
+    starts early (a slice must lie inside its operand) and recomputes the
+    few rows of the overlap.  ``rows`` is
+    int32[2]: the rows the two loops touched (blocks run x block size)
+    and the rows the full-width compaction touches (``T``, a shape); the
+    level programs sum it over their dedup stages and hand it to the host
+    with their counts (level record ``novel_rows`` / ``novel_rows_plain``).
+    """
+    assert is_new.shape == (T,) and cand.shape == (T, K), (
+        is_new.shape, cand.shape, T, K)
+    sent = jnp.uint32(dedup.SENT)
+    with stage("compact"), part("novel"):
+        B = novel_block(T)
+        csum = jnp.cumsum(is_new, dtype=jnp.int32)
+        new_n = jnp.sum(is_new, dtype=jnp.int32)
+        n_live = jnp.sum(~((hi_s == sent) & (lo_s == sent)),
+                         dtype=jnp.int32)
+        blocks_live = (n_live + (B - 1)) // B
+        blocks_new = (new_n + (B - 1)) // B
+
+        def invert(k, src):
+            s = jnp.minimum(k * B, T - B)
+            i = s + jnp.arange(B, dtype=jnp.int32)
+            new = jax.lax.dynamic_slice(is_new, (s,), (B,))
+            pos = jax.lax.dynamic_slice(csum, (s,), (B,)) - 1
+            # the overlap writes the same lanes again; dead lanes drop
+            return src.at[jnp.where(new, pos, T)].set(i, mode="drop")
+
+        src = jax.lax.fori_loop(0, blocks_live, invert,
+                                jnp.zeros((T,), jnp.int32))
+
+        def gather(k, outs):
+            out, out_parent, out_act, out_hi, out_lo, out_rank = outs
+            s = jnp.minimum(k * B, T - B)
+            keep = (s + jnp.arange(B, dtype=jnp.int32)) < new_n
+            at = jax.lax.dynamic_slice(src, (s,), (B,))
+            row = order[at]
+
+            def put(buf, val, fill):
+                val = jnp.where(keep.reshape((B,) + (1,) * (val.ndim - 1)),
+                                val, fill)
+                return jax.lax.dynamic_update_slice(
+                    buf, val, (s,) + (0,) * (val.ndim - 1))
+
+            return (put(out, cand[row], jnp.uint32(0)),
+                    put(out_parent, parent[row], jnp.int32(-1)),
+                    put(out_act, actid[row], jnp.int32(-1)),
+                    put(out_hi, hi_s[at], sent),
+                    put(out_lo, lo_s[at], sent),
+                    put(out_rank, rank[at], jnp.int32(0)))
+
+        outs = jax.lax.fori_loop(
+            0, blocks_new, gather,
+            (jnp.zeros((T, K), jnp.uint32),
+             jnp.full((T,), -1, jnp.int32), jnp.full((T,), -1, jnp.int32),
+             jnp.full((T,), sent), jnp.full((T,), sent),
+             jnp.zeros((T,), jnp.int32)),
+        )
+        rows = jnp.stack([(blocks_live + blocks_new) * B, jnp.int32(T)])
+        return (*outs, new_n, rows)
 
 
 def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
                        vhi, vlo, vn, vcap, T, K, with_merge: bool,
                        also_seen_in=None):
     """Stage 4 (device backend): minimal-payload lexsort, first-occurrence
-    + visited-rank dedup, compaction of the new states to the front, and
-    (with_merge) the rank-scatter merge into the sorted visited set.
+    + visited-rank dedup, compaction of the new states to the front
+    (:func:`novel_stage`), and (with_merge) the rank-scatter merge into the
+    sorted visited set.
     Identical primitive sequence to the legacy in-step version — winners
     are decided by the stable sort over the same candidate order, which
     is what keeps the pipelines trace-bit-identical; this helper is the
@@ -407,8 +516,8 @@ def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
     trailing out_rank return (insertion ranks of the compacted prefix in
     the PRIMARY set) lets with_merge=False callers run their own gated
     merge_ranked; the last return is the stage's :func:`work_counts` (the
-    one or two probes' rounds, the merge's slots where with_merge)."""
-    sent = jnp.uint32(dedup.SENT)
+    one or two probes' rounds, the merge's slots where with_merge, the
+    compaction's rows)."""
     # minimal-payload sort: only the original index rides through the
     # sort network; state rows/parents are gathered once afterwards
     hi_s, lo_s, order, first = _sort_first(hi, lo)
@@ -419,24 +528,16 @@ def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
         a_seen, _ar, a_probe = dedup.probe_sorted(a_hi, a_lo, a_n, hi_s, lo_s)
         is_new = is_new & ~a_seen
         probe = probe + a_probe
-    # compact new states to the front (OOB scatter indices are dropped)
-    with stage("compact"), part("novel"):
-        pos = jnp.where(is_new, jnp.cumsum(is_new) - 1, T)
-        out = jnp.zeros((T, K), jnp.uint32).at[pos].set(cand[order])
-        out_parent = jnp.full((T,), -1, jnp.int32).at[pos].set(
-            parent[order])
-        out_act = jnp.full((T,), -1, jnp.int32).at[pos].set(actid[order])
-        out_hi = jnp.full((T,), sent).at[pos].set(hi_s)
-        out_lo = jnp.full((T,), sent).at[pos].set(lo_s)
-        out_rank = jnp.zeros((T,), jnp.int32).at[pos].set(rank)
-        new_n = jnp.sum(is_new, dtype=jnp.int32)
+    (out, out_parent, out_act, out_hi, out_lo, out_rank, new_n,
+     rows) = novel_stage(is_new, order, hi_s, lo_s, rank,
+                         cand, parent, actid, T, K)
     slots = None
     if with_merge:
         vhi, vlo, vn, slots = dedup.merge_counted(
             vhi, vlo, vn, out_hi, out_lo, out_rank, new_n, vcap
         )
     return (out, out_parent, out_act, new_n, out_hi, out_lo,
-            vhi, vlo, vn, out_rank, work_counts(probe, slots))
+            vhi, vlo, vn, out_rank, work_counts(probe, slots, rows))
 
 
 def candidate_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
@@ -852,18 +953,23 @@ class FusedPipeline:
                         for i in range(len(model.actions))
                     ]
                 )
-            out, out_parent, out_act, rowvalid2, n_en, _ovf = squeeze_stage(
-                cand, sidx, actid_f, ok, W, K
-            )
-            hi, lo = fp_stage(out, rowvalid2, spec)
             if with_merge:
+                # no squeeze: at the pooled width it narrows nothing, and
+                # the stable sort puts the masked rows last in any case
+                # (see _Step._build)
+                hi, lo = fp_stage(cand, ok, spec)
                 (out, out_parent, out_act, new_n, out_hi, out_lo,
                  vhi, vlo, vn, _rank, work) = sorted_dedup_stage(
-                    out, out_parent, out_act, rowvalid2, hi, lo,
+                    cand, sidx, actid_f, ok, hi, lo,
                     vhi, vlo, vn, vcap, W, K, with_merge,
                 )
                 return (out, out_parent, out_act, new_n, out_hi, out_lo,
                         vhi, vlo, vn, counts_out(act_en, work))
+            # device-hash backend: the squeezed rows ARE the output
+            out, out_parent, out_act, rowvalid2, n_en, _ovf = squeeze_stage(
+                cand, sidx, actid_f, ok, W, K
+            )
+            hi, lo = fp_stage(out, rowvalid2, spec)
             return (out, out_parent, out_act, n_en, hi, lo,
                     vhi, vlo, vn, counts_out(act_en))
 

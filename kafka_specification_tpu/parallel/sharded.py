@@ -565,8 +565,9 @@ def _make_sharded_step(
             jnp.stack(viol_idx)[None],
             jnp.any(deadlocked)[None],
             jnp.argmax(deadlocked)[None],
-            # [1, n_actions + 4] -> [D, n_actions + 4]: the enabled counts
-            # and the probe's and merge's work counts (pipeline.counts_out)
+            # [1, n_actions + 6] -> [D, n_actions + 6]: the enabled counts
+            # and the probe's and merge's work counts (pipeline.counts_out;
+            # this step's own full-width compaction counts no rows)
             counts_out(act_en, work_counts(probe, slots))[None],
             # per-action expansion overflow + pre-constraint guard counts:
             # the host sizes adaptive per-action compact buffers from the
@@ -609,7 +610,7 @@ def _make_sharded_step(
             P("d", None),  # viol_idx [D, n_inv]
             P("d"),        # deadlock any
             P("d"),        # deadlock idx
-            P("d", None),  # counts [D, n_actions + 4]
+            P("d", None),  # counts [D, n_actions + 6]
             P("d", None),  # ovf_expand [D, n_actions]
             P("d", None),  # act_guard [D, n_actions]
             P("d"),        # ovf_dest
@@ -899,7 +900,7 @@ def _make_sharded_level(
             vlo[None],
             vn[None],
             vkind[None], vshard[None], vinv[None], vidx[None],
-            # [1, n_actions + 4]: enabled counts, dedup work counts
+            # [1, n_actions + 6]: enabled counts, dedup work counts
             counts_out(act_en, work + work_counts(m_probe, m_slots))[None],
             agmax[None],
             dc[None], dxh[None], dxl[None],  # digest accumulator...
@@ -929,7 +930,7 @@ def _make_sharded_level(
             P("d", None),  # merged visited lo
             P("d"),        # merged visited counts
             P("d"), P("d"), P("d"), P("d"),  # verdict kind/shard/inv/idx
-            P("d", None),  # counts [D, n_actions + 4]
+            P("d", None),  # counts [D, n_actions + 6]
             P("d", None),  # agmax [D, n_actions]
             P("d"), P("d"), P("d"),  # digest count/xor_hi/xor_lo
             P("d", None),  # digest sum limbs [D, 4]
@@ -1164,7 +1165,7 @@ def _make_sharded_level_host(
             P("d"),        # candidate fingerprint lo lanes
             P("d"),        # per-shard pre-probe candidate counts
             P("d"), P("d"), P("d"), P("d"),  # verdict kind/shard/inv/idx
-            P("d", None),  # counts [D, n_actions + 4]
+            P("d", None),  # counts [D, n_actions + 6]
             P("d", None),  # agmax [D, n_actions]
             P("d", None),  # sent framing accumulator [D, 5]
             P("d", None),  # recv framing accumulator [D, 5]

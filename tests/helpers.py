@@ -59,3 +59,24 @@ def assert_matches_oracle(model, oracle, max_depth=None, min_bucket=32):
         for d in range(ores.violation[1] + 1):
             assert engine_levels[d] == ores.level_sets[d], f"level {d} diff"
     return res, ores
+
+
+def async_isr_under_constraint():
+    """AsyncIsr at 3 brokers with its folded bounds at 3 / 3 and an
+    explicit CONSTRAINT at 2: successors are pruned AFTER the guards, so a
+    compacted chunk has holes inside its segments' enabled prefixes (the
+    hand model alone folds its bounds into the guards and has none).
+    Levels to depth 9: 1, 5, 16, 42, 92, 171, 282, 414, 535, 614."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from kafka_specification_tpu.models import async_isr
+
+    def bounded(s):
+        return ((jnp.max(s["offs"]) <= 2) & (s["c_ver"] <= 2)
+                & (s["l_ver"] <= 2))
+
+    return dataclasses.replace(
+        async_isr.make_model(async_isr.AsyncIsrConfig(3, 3, 3)),
+        constraint=bounded)

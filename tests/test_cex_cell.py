@@ -18,6 +18,7 @@ import pytest
 
 from kafka_specification_tpu.engine import hostio
 from kafka_specification_tpu.engine.bfs import check, prepare
+from kafka_specification_tpu.engine.pipeline import WORK_FIELDS
 from kafka_specification_tpu.models import kip320
 from kafka_specification_tpu.models.kafka_replication import Config
 from kafka_specification_tpu.obs import RunContext, read_jsonl_tolerant
@@ -31,9 +32,7 @@ KIP101_LEVELS = [1, 4, 14, 44, 100, 166, 268, 456, 684, 976, 1292, 1486]
 CUT_KEYS = {"depth", "frontier", "rows_committed", "chunks_committed",
             "chunks_discarded", "chunks", "dedup_lanes", "level_ms",
             "step_ms", "host_ms",
-            "successor_launches", "probe_rounds",
-            "probe_rounds_plain", "merge_slots",
-            "merge_slots_plain"} | set(hostio.LEVEL_COUNTERS)
+            "successor_launches"} | set(WORK_FIELDS) | set(hostio.LEVEL_COUNTERS)
 # the fused path from 64 rows up, so a small chunk leaves launch 2 in flight
 FUSED = dict(min_bucket=64, compact_gate=64)
 

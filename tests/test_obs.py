@@ -188,16 +188,15 @@ def test_stats_shim_record_for_record_identical(tmp_path):
     # overlap attribution, and the dispatch / transfer / store counters
     # (engine/hostio.py) — in-memory only, never in the pinned stream
     from kafka_specification_tpu.engine.hostio import LEVEL_COUNTERS
+    from kafka_specification_tpu.engine.pipeline import WORK_FIELDS
 
     assert [
         {k: v for k, v in r.items()
          if k not in ("successor_launches", "launches_per_chunk_max",
                       "io_hidden_ms", "io_exposed_ms",
                       "overlap_efficiency", "host_probe_ms",
-                      "store_ms", "probe_rounds",
-                      "probe_rounds_plain", "merge_slots",
-                      "merge_slots_plain", "chunks", "dedup_lanes")
-         + LEVEL_COUNTERS}
+                      "store_ms", "chunks", "dedup_lanes")
+         + WORK_FIELDS + LEVEL_COUNTERS}
         for r in r1.stats["levels"]
     ] == recs_bare
 
@@ -260,6 +259,10 @@ def test_cut_level_carries_the_probe_rounds(tmp_path):
     assert cut["probe_rounds_plain"] > 0 and cut["dispatches"] > 0
     _assert_merge_slots(res.stats["levels"] + [cut])
     assert cut["merge_slots"] > 0
+    # and the new states' compaction's rows, over its committed chunks
+    for rec in res.stats["levels"] + [cut]:
+        assert 0 < rec["novel_rows"] and \
+            rec["novel_rows_plain"] == rec["dedup_lanes"], rec
 
 
 # --- the merge's slot counts (level records, both engines) --------------
@@ -312,6 +315,38 @@ def test_level_records_carry_the_merge_slots(tmp_path, monkeypatch,
             0.5 * sum(r["merge_slots_plain"] for r in runs[0])
 
 
+# --- the new states' compaction's row counts (level records) -------------
+
+
+@pytest.mark.parametrize("pipeline", [None, "device"],
+                         ids=["fused", "whole-level"])
+def test_level_records_carry_the_novel_rows(tmp_path, monkeypatch, pipeline):
+    """configs/Kip320.cfg cut to depth 7 at a compaction block of 256 rows,
+    twice: every level record says how many rows the loops of the new
+    states' compaction touched (blocks of the live prefix + blocks of new
+    rows) beside the rows a full-width compaction touches, which is the
+    width dedup was handed (`dedup_lanes`); the two runs agree to the
+    row, the loops touch a fraction of the width where a level is many
+    blocks wide, and the emitted stream does not gain the fields."""
+    from kafka_specification_tpu.engine import pipeline as pl
+    from kafka_specification_tpu.utils.cfg import build_model, parse_cfg
+
+    monkeypatch.setattr(pl, "NOVEL_BLOCK", 256)
+    model = build_model("Kip320", parse_cfg("configs/Kip320.cfg"))
+    runs = []
+    for i in range(2):
+        run = RunContext(str(tmp_path / f"run{i}"))
+        res = check(model, max_depth=7, run=run, pipeline=pipeline)
+        assert res.ok and res.levels == KIP320_LEVELS_TO_8[:8]
+        runs.append([(r["novel_rows"], r["novel_rows_plain"], r["dedup_lanes"])
+                     for r in res.stats["levels"]])
+        assert all("novel_rows" not in r for r in _records(run.stats_path))
+    assert runs[0] == runs[1]
+    for rows, plain, lanes in runs[0]:
+        assert 0 < rows and rows % 256 == 0 and plain == lanes
+    assert sum(r[0] for r in runs[0]) < 0.5 * sum(r[1] for r in runs[0])
+
+
 # --- engine-threaded run dirs -------------------------------------------
 
 
@@ -357,15 +392,14 @@ def test_sharded_per_shard_breakdowns_and_imbalance(tmp_path):
     # overlap accounting and the host/device split and transfer counters
     # of engine/hostio.py — in-memory only, never in the pinned stream
     from kafka_specification_tpu.engine.hostio import LEVEL_COUNTERS
+    from kafka_specification_tpu.engine.pipeline import WORK_FIELDS
 
     assert [
         {k: v for k, v in r.items()
          if k not in ("exch_bytes", "exch_raw_bytes", "io_hidden_ms",
                       "io_exposed_ms", "shard_launches",
-                      "host_probe_ms", "step_ms", "host_ms",
-                      "probe_rounds", "probe_rounds_plain",
-                      "merge_slots", "merge_slots_plain", "chunks",
-                      "dedup_lanes")
+                      "host_probe_ms", "step_ms", "host_ms", "chunks",
+                      "dedup_lanes") + WORK_FIELDS
          + LEVEL_COUNTERS}
         for r in res.stats["levels"]
     ] == recs

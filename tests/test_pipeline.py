@@ -108,6 +108,37 @@ def test_fused_vs_legacy_bit_identity_violating_model():
     assert r_leg.violation is not None  # the case actually violates
 
 
+def test_fused_vs_legacy_async_isr_under_constraint_with_trace():
+    """AsyncIsr at 3 brokers under an explicit CONSTRAINT
+    (helpers.async_isr_under_constraint: a fused chunk's segments have
+    holes inside their enabled prefixes and hand them to the sorted dedup
+    unsqueezed), cut at depth 9, trace store on: per-level counts as
+    `_assert_parity` holds them (the programs are warm by then), and
+    every level's rows, parents and action ids equal in discovery
+    order."""
+    from helpers import async_isr_under_constraint
+
+    _MODELS["AsyncIsr3Constraint"] = async_isr_under_constraint()
+    bufs = {}
+    for pipeline in ("legacy", "fused"):
+        bufs[pipeline] = []
+        res = check(_MODELS["AsyncIsr3Constraint"], pipeline=pipeline,
+                    max_depth=9, collect_trace=bufs[pipeline], **KW)
+        assert res.stats["pipeline"] == pipeline
+        assert res.stats["pipeline_fallback"] is False
+        assert res.levels == [1, 5, 16, 42, 92, 171, 282, 414, 535, 614]
+    assert len(bufs["legacy"]) == len(bufs["fused"]) == 10
+    for depth, (leg, fus) in enumerate(zip(bufs["legacy"], bufs["fused"])):
+        for what, a, b in zip(("rows", "parents", "action ids"), leg, fus):
+            if a is None or b is None:
+                assert a is b, (depth, what)
+                continue
+            np.testing.assert_array_equal(
+                np.asarray(a, np.int64), np.asarray(b, np.int64),
+                err_msg=f"level {depth} {what}")
+    _assert_parity("AsyncIsr3Constraint", max_depth=9)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("module", ["Kip101", "Kip320", "AsyncIsr"])
 def test_fused_vs_legacy_bit_identity_matrix(module):

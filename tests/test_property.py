@@ -10,7 +10,9 @@ import jax
 import jax.numpy as jnp
 from jax.extend.core import Literal
 
+from kafka_specification_tpu.engine import pipeline as pl
 from kafka_specification_tpu.ops import dedup
+from kafka_specification_tpu.ops.fingerprint import fingerprint_lanes
 from kafka_specification_tpu.ops.packing import Field, StateSpec
 
 
@@ -402,6 +404,36 @@ def _input_deps(jaxpr):
     return deps
 
 
+def _indexed_ops(jaxpr):
+    """(name, operand shapes past the first) of every gather and scatter
+    of a jaxpr, inside its loops too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith(("gather", "scatter")):
+            yield eqn.primitive.name, [v.aval.shape for v in eqn.invars[1:]]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _indexed_ops(sub)
+
+
+def _loops_stop_on(jaxpr):
+    """For each top-level `while` of a jaxpr, in order: the indices of the
+    jaxpr's inputs its stopping condition is computed from."""
+    deps = _input_deps(jaxpr)
+    stops_on = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "while":
+            continue
+        cond = eqn.params["cond_jaxpr"].jaxpr
+        nc, nb = eqn.params["cond_nconsts"], eqn.params["body_nconsts"]
+        read = {v for e in cond.eqns for v in e.invars
+                if not isinstance(v, Literal)}
+        outer = eqn.invars[:nc] + eqn.invars[nc + nb:]  # cond's own inputs
+        stops_on.append(set().union(*[
+            deps[o] for c, o in zip(cond.invars, outer)
+            if c in read and not isinstance(o, Literal)
+        ]))
+    return stops_on
+
+
 def test_merge_ranked_moves_blocks_for_live_entries_only():
     """What makes the merge cost what the set holds, held on a CPU-only
     check: no gather or scatter anywhere in it (inside a loop or outside)
@@ -418,33 +450,301 @@ def test_merge_ranked_moves_blocks_for_live_entries_only():
         lambda *a: dedup.merge_ranked(*a, cap)
     )(u, u, i, m, m, r, i).jaxpr
 
-    def indexed(jp):
-        for eqn in jp.eqns:
-            if eqn.primitive.name.startswith(("gather", "scatter")):
-                yield eqn.primitive.name, [v.aval.shape for v in eqn.invars[1:]]
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from indexed(sub)
-
-    found = list(indexed(jaxpr))
+    found = list(_indexed_ops(jaxpr))
     assert any(n.startswith("scatter") for n, _ in found)
     assert not [(n, shapes) for n, shapes in found
                 if any(max(s, default=0) > B for s in shapes)]
-
-    deps = _input_deps(jaxpr)
-    stops_on = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name != "while":
-            continue
-        cond = eqn.params["cond_jaxpr"].jaxpr
-        nc, nb = eqn.params["cond_nconsts"], eqn.params["body_nconsts"]
-        read = {v for e in cond.eqns for v in e.invars
-                if not isinstance(v, Literal)}
-        outer = eqn.invars[:nc] + eqn.invars[nc + nb:]  # cond's own inputs
-        stops_on.append(set().union(*[
-            deps[o] for c, o in zip(cond.invars, outer)
-            if c in read and not isinstance(o, Literal)
-        ]))
+    stops_on = _loops_stop_on(jaxpr)
     SET_N, NEW_N = 2, 6
     assert len(stops_on) == 2
     assert sorted(d & {SET_N, NEW_N} and min(d & {SET_N, NEW_N})
                   for d in stops_on) == [SET_N, NEW_N]
+
+
+# --------------------------------------------------------------------------
+# the `novel` part of `compact` (engine/pipeline.py novel_stage): the rows a
+# chunk keeps, against the full-width form it replaced
+# --------------------------------------------------------------------------
+
+def _plain_novel(is_new, order, hi_s, lo_s, rank, cand, parent, actid, T, K):
+    """The full-width compaction, kept here as the reference: three
+    gathers and six scatters over all T lanes, dead lanes' updates sent
+    out of bounds and dropped."""
+    sent = jnp.uint32(dedup.SENT)
+    pos = jnp.where(is_new, jnp.cumsum(is_new) - 1, T)
+    out = jnp.zeros((T, K), jnp.uint32).at[pos].set(cand[order])
+    out_parent = jnp.full((T,), -1, jnp.int32).at[pos].set(parent[order])
+    out_act = jnp.full((T,), -1, jnp.int32).at[pos].set(actid[order])
+    out_hi = jnp.full((T,), sent).at[pos].set(hi_s)
+    out_lo = jnp.full((T,), sent).at[pos].set(lo_s)
+    out_rank = jnp.zeros((T,), jnp.int32).at[pos].set(rank)
+    new_n = jnp.sum(is_new, dtype=jnp.int32)
+    return out, out_parent, out_act, out_hi, out_lo, out_rank, new_n
+
+
+_NOVEL_OUTPUTS = ("out", "out_parent", "out_act", "out_hi", "out_lo",
+                  "out_rank", "new_n")
+
+
+def _novel_case(T, K, n_live, new_lanes, seed):
+    """Sorted-order lanes of a dedup whose first n_live lanes are live
+    (distinct sorted fingerprints, the rest the sentinel pair) and whose
+    `new_lanes` (sorted indices below n_live) are new; candidate-order
+    rows, parents and action ids behind a random `order`; ranks and
+    payloads that no fill value could pass for."""
+    rng = np.random.default_rng(seed)
+    keys = np.sort(rng.choice(2**40, size=n_live, replace=False)
+                   .astype(np.uint64) << np.uint64(20))
+    hi_s, lo_s = _pairs(keys, T)
+    is_new = np.zeros(T, bool)
+    is_new[np.asarray(new_lanes, np.int64)] = True
+    order = rng.permutation(T).astype(np.int32)
+    rank = rng.integers(1, 1 << 20, size=T).astype(np.int32)
+    cand = rng.integers(1, 2**32, size=(T, K), dtype=np.uint32)
+    parent = rng.integers(0, 1 << 20, size=T).astype(np.int32)
+    actid = rng.integers(0, 50, size=T).astype(np.int32)
+    return tuple(jnp.asarray(x) for x in (
+        is_new, order, hi_s, lo_s, rank, cand, parent, actid))
+
+
+def _assert_novel(block, T, K, n_live, new_lanes, seed, monkeypatch):
+    monkeypatch.setattr(pl, "NOVEL_BLOCK", block)
+    args = _novel_case(T, K, n_live, new_lanes, seed)
+    got = jax.jit(lambda *a: pl.novel_stage(*a, T, K))(*args)
+    want = jax.jit(lambda *a: _plain_novel(*a, T, K))(*args)
+    for name, g, w in zip(_NOVEL_OUTPUTS, got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+    B = pl.novel_block(T)  # 8 rows at T 32, 7 at T 20 (three blocks, one row recomputed), 5 at T 5
+    assert list(np.asarray(got[-1])) == [
+        (-(-n_live // B) + -(-len(new_lanes) // B)) * B, T]
+
+
+def _novel_edges():
+    """(id, T, n_live, new lanes) at a block of 8 rows: T a multiple of
+    the block, none, and smaller than one; n_live and new_n at 0, 1, T and
+    around a block edge; the new lanes the first, the last, a scattered
+    draw and one run laid across a block boundary."""
+    cases = []
+    for T in (32, 20, 5):
+        for n_live in sorted({0, 1, _B - 1, _B, _B + 1, T - 1, T}):
+            if not 0 <= n_live <= T:
+                continue
+            picks = {"none": [], "all": list(range(n_live))}
+            if n_live:
+                picks["first"] = [0]
+                picks["last"] = [n_live - 1]
+                picks["every3rd"] = list(range(0, n_live, 3))
+            if n_live > _B + 2:
+                # a run of new rows with a block boundary inside it, in
+                # the input (lanes 5..10) and so in the output too
+                picks["run"] = list(range(_B - 3, _B + 3))
+                picks["allbut1"] = list(range(1, n_live))
+            for name, lanes in picks.items():
+                cases.append((f"T{T}-live{n_live}-{name}", T, n_live, lanes))
+    return cases
+
+
+_NOVEL_EDGES = _novel_edges()
+
+
+@pytest.mark.parametrize("case", _NOVEL_EDGES, ids=[c[0] for c in _NOVEL_EDGES])
+def test_novel_stage_block_edges(monkeypatch, case):
+    """`novel_stage` at a block of 8 rows against the full-width form:
+    every output array bit for bit, fills included, and the rows it says
+    its loops touched against the blocks the live prefix and the new
+    states fill."""
+    _id, T, n_live, lanes = case
+    _assert_novel(_B, T, 3, n_live, lanes, seed=T * 100 + n_live,
+                  monkeypatch=monkeypatch)
+
+
+@settings(max_examples=40, deadline=None)
+@given(st.data())
+def test_novel_stage_equals_the_full_width_form(data):
+    """Random masks at random widths, blocks and lane counts."""
+    T = data.draw(st.integers(1, 70))
+    block = data.draw(st.sampled_from([1, 4, 8, 16, 64]))
+    K = data.draw(st.integers(1, 4))
+    n_live = data.draw(st.integers(0, T))
+    mask = data.draw(st.lists(st.booleans(), min_size=n_live, max_size=n_live))
+    lanes = [i for i, b in enumerate(mask) if b]
+    with pytest.MonkeyPatch.context() as mp:
+        _assert_novel(block, T, K, n_live, lanes,
+                      seed=data.draw(st.integers(0, 2**16)), monkeypatch=mp)
+
+
+_B100K = pl.novel_block(100000)
+
+
+@pytest.mark.parametrize("n_live,new_n", [
+    (0, 0), (1, 1), (_B100K, _B100K), (_B100K + 1, 1),
+    (40000, _B100K + 1), (100000, 30000), (100000, 100000)])
+def test_novel_stage_counts_the_blocks_it_runs(n_live, new_n):
+    """At the block the engine runs and a width of several blocks (no
+    multiple of NOVEL_BLOCK: thirteen blocks of 7,693 rows): rows[0] is
+    ceil(n_live / B) + ceil(new_n / B) blocks, rows[1] the width, and the
+    outputs are the full-width form's."""
+    T, K, B = 100000, 4, _B100K
+    assert B == 7693 and 13 * B - T < 13
+    rng = np.random.default_rng(n_live + new_n)
+    lanes = np.sort(rng.choice(n_live, size=new_n, replace=False))
+    args = _novel_case(T, K, n_live, lanes, seed=n_live)
+    got = _NOVEL(*args, T, K)
+    want = _PLAIN_NOVEL(*args, T, K)
+    for name, g, w in zip(_NOVEL_OUTPUTS, got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    assert list(np.asarray(got[-1])) == [
+        (-(-n_live // B) + -(-new_n // B)) * B, T]
+
+
+_NOVEL = jax.jit(pl.novel_stage, static_argnums=(8, 9))
+_PLAIN_NOVEL = jax.jit(_plain_novel, static_argnums=(8, 9))
+
+
+def test_novel_stage_moves_blocks_for_the_rows_it_keeps_only():
+    """What makes `novel` cost what a chunk keeps, held on a CPU-only
+    check: no gather or scatter in it takes indices or updates wider than
+    one block, and its two loops stop on values computed from the
+    fingerprint lanes (the live prefix) and from is_new (the new states)."""
+    T, K, B = 1 << 20, 4, pl.NOVEL_BLOCK
+    u = jax.ShapeDtypeStruct((T,), jnp.uint32)
+    i = jax.ShapeDtypeStruct((T,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda *a: pl.novel_stage(*a, T, K))(
+        jax.ShapeDtypeStruct((T,), bool), i, u, u, i,
+        jax.ShapeDtypeStruct((T, K), jnp.uint32), i, i).jaxpr
+
+    found = list(_indexed_ops(jaxpr))
+    assert {n.split("-")[0] for n, _ in found} == {"gather", "scatter"}
+    assert not [(n, shapes) for n, shapes in found
+                if any(max(s, default=0) > B for s in shapes)]
+    stops_on = _loops_stop_on(jaxpr)
+    IS_NEW, HI_S, LO_S = 0, 2, 3
+    assert stops_on == [{HI_S, LO_S}, {IS_NEW}]
+
+
+def _sorted_dedup(with_plain_novel, monkeypatch, *args, **kw):
+    if with_plain_novel:
+        monkeypatch.setattr(
+            pl, "novel_stage",
+            lambda *a: (*_plain_novel(*a), jnp.zeros((2,), jnp.int32)))
+    return jax.jit(lambda *a: pl.sorted_dedup_stage(*a, **kw))(*args)
+
+
+def test_sorted_dedup_first_copy_decides_the_parent(monkeypatch):
+    """The whole dedup stage over candidates with duplicate fingerprints
+    (in the chunk and against the visited set) at a block of 8 rows:
+    every output the full-width form's, and each new state's parent and
+    action id those of its FIRST copy in candidate order."""
+    monkeypatch.setattr(pl, "NOVEL_BLOCK", _B)
+    T, K, vcap = 44, 2, 64
+    rng = np.random.default_rng(7)
+    keys = rng.integers(1, 2**32, size=(12, K), dtype=np.uint32)
+    pick = rng.integers(0, 12, size=T)
+    cand = keys[pick]
+    valid = rng.random(T) < 0.8
+    parent = np.arange(T, dtype=np.int32) + 100
+    actid = (np.arange(T, dtype=np.int32) * 7) % 5
+    hi, lo = fingerprint_lanes(jnp.asarray(cand), True)
+    hi = jnp.where(valid, hi, jnp.uint32(dedup.SENT))
+    lo = jnp.where(valid, lo, jnp.uint32(dedup.SENT))
+    # three of the twelve states are visited already
+    vk = _k(*(np.asarray(x, np.uint64)
+              for x in fingerprint_lanes(jnp.asarray(keys[:3]), True)))
+    vhi, vlo = _pairs(vk, vcap)
+    args = (jnp.asarray(cand), jnp.asarray(parent), jnp.asarray(actid),
+            jnp.asarray(valid), hi, lo, jnp.asarray(vhi), jnp.asarray(vlo),
+            jnp.int32(3))
+    kw = dict(vcap=vcap, T=T, K=K, with_merge=True)
+    got = _sorted_dedup(False, monkeypatch, *args, **kw)
+    want = _sorted_dedup(True, monkeypatch, *args, **kw)
+    for g, w in zip(got[:-1], want[:-1]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    out, out_parent, out_act, new_n = (np.asarray(x) for x in got[:4])
+    assert 0 < int(new_n) < 10
+    for row, par, act in zip(out[:new_n], out_parent[:new_n], out_act[:new_n]):
+        first = next(i for i in range(T)
+                     if valid[i] and (cand[i] == row).all())
+        assert (par, act) == (parent[first], actid[first])
+        assert not any((row == k).all() for k in keys[:3])
+
+
+@pytest.fixture(scope="module")
+def constrained_chunk():
+    """One chunk of AsyncIsr at 3 brokers under an explicit CONSTRAINT, as
+    the fused path lays it out: the frontier of level 6, the pooled
+    (state, choice) index vectors of its guard matrix, and a visited set
+    of the levels before it."""
+    from kafka_specification_tpu.engine import check
+    from kafka_specification_tpu.engine.bfs import _Step
+    from helpers import async_isr_under_constraint
+
+    model = async_isr_under_constraint()
+    levels = []
+    res = check(model, max_depth=6, min_bucket=32, pipeline="legacy",
+                collect_levels=levels)
+    assert res.levels == [1, 5, 16, 42, 92, 171, 282]
+    bucket, vcap = 512, 1024
+    K = model.spec.num_lanes
+    rows = np.zeros((bucket, K), np.uint32)
+    rows[:282] = np.asarray(levels[6])
+    fvalid = np.arange(bucket) < 282
+    fused = pl.FusedPipeline(_Step(model), model, None, None, None, True,
+                             "device", None, 2, 32)
+    ga = fused.guard_step(bucket)(jnp.asarray(rows), jnp.asarray(fvalid))[0]
+    counts = np.asarray(ga).reshape(bucket, -1)
+    bounds = fused._bounds
+    widths = tuple(
+        256 * -(-max(1, int(counts[:, bounds[i]:bounds[i + 1]].sum())) // 256)
+        for i in range(len(model.actions)))
+    _sidx, sidx, chloc, rowvalid = fused._compact(ga, widths, 7)
+    seen = np.concatenate([np.asarray(lv) for lv in levels])
+    vk = _k(*(np.asarray(x, np.uint64) for x in fingerprint_lanes(
+        jnp.asarray(seen), model.spec.exact64)))
+    vhi, vlo = _pairs(vk, vcap)
+    args = (jnp.asarray(rows), sidx, chloc, rowvalid, jnp.asarray(vhi),
+            jnp.asarray(vlo), jnp.int32(len(vk)))
+    return fused, bucket, widths, vcap, args
+
+
+def test_fused_successors_without_the_squeeze(constrained_chunk, monkeypatch):
+    """`_build_succ`'s program, which hands the pooled layout straight to
+    the sorted dedup, against the same program with the squeeze its
+    parent ran before it: all ten outputs equal, on a chunk whose
+    segments have constraint-pruned holes inside their enabled prefixes
+    and whose candidates repeat fingerprints across segments."""
+    fused, bucket, widths, vcap, args = constrained_chunk
+    K, W = fused.spec.num_lanes, sum(widths)
+    got = jax.jit(fused._build_succ(bucket, widths, vcap, True, True))(*args)
+
+    real = pl.sorted_dedup_stage
+    seen_inputs = {}
+
+    def squeezed_first(cand, parent, actid, valid, hi, lo, *rest, **kw):
+        seen_inputs["w"] = cand.shape[0]
+        cand, parent, actid, valid, _n, _ovf = pl.squeeze_stage(
+            cand, parent, actid, valid, W, K)
+        hi, lo = pl.fp_stage(cand, valid, fused.spec)
+        return real(cand, parent, actid, valid, hi, lo, *rest, **kw)
+
+    monkeypatch.setattr(pl, "sorted_dedup_stage", squeezed_first)
+    want = jax.jit(fused._build_succ(bucket, widths, vcap, True, True))(*args)
+    assert seen_inputs == {"w": W}
+    assert len(got) == len(want) == 10
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    # the chunk is the shape the test is for
+    raw = jax.jit(fused._build_succ(bucket, widths, vcap, True, False))(*args)
+    cand, ok = np.asarray(raw[0]), np.asarray(raw[1])
+    rowvalid = np.asarray(args[3])
+    offs = np.cumsum((0,) + widths)
+    assert any((rowvalid[a:b] & ~ok[a:b]).any() and
+               ok[a:b][np.flatnonzero(rowvalid[a:b] & ~ok[a:b])[0]:].any()
+               for a, b in zip(offs[:-1], offs[1:])), "no hole inside a segment"
+    seg = np.searchsorted(offs, np.arange(W), side="right")
+    first_seg = {}
+    assert any(first_seg.setdefault(cand[i].tobytes(), seg[i]) != seg[i]
+               for i in np.flatnonzero(ok)), "no fingerprint in two segments"
+    new_n = int(got[3])
+    assert 0 < new_n < int(ok.sum())

@@ -163,6 +163,51 @@ def test_sharded_level_records_carry_the_merge_slots(tmp_path, monkeypatch,
         0.5 * sum(r["merge_slots_plain"] for r in runs[0])
 
 
+@pytest.mark.parametrize("pipeline", ["legacy", "device"],
+                         ids=["per-chunk", "whole-level"])
+def test_sharded_level_records_carry_the_novel_rows(tmp_path, monkeypatch,
+                                                    pipeline):
+    """configs/Kip320.cfg cut to depth 7 on four devices at a compaction
+    block of 256 rows, twice: every level record holds the rows its
+    shards' `novel_stage` loops touched beside the rows full-width
+    compactions of the same dedup stages touch (`R` a shard), summed over
+    the shards.  The whole-level program compacts through the shared
+    dedup stage, whose loops follow each shard's own live prefix and new
+    states (no collective in them); the per-chunk step keeps its own
+    full-width compaction, which is not `novel_stage` and counts nothing.
+    The two runs agree to the row and the counts are the golden's."""
+    from kafka_specification_tpu.engine import pipeline as pl
+    from kafka_specification_tpu.utils.cfg import build_model, parse_cfg
+
+    monkeypatch.setattr(pl, "NOVEL_BLOCK", 256)
+    model = build_model("Kip320", parse_cfg("configs/Kip320.cfg"))
+    runs = []
+    for i in range(2):
+        res = check_sharded(model, max_depth=7, pipeline=pipeline,
+                            store_trace=False, mesh=_mesh(4),
+                            run=RunContext(str(tmp_path / f"run{i}")))
+        assert res.ok
+        assert res.levels == [1, 6, 30, 138, 366, 1170, 2715, 5673]
+        runs.append(res.stats["levels"])
+    for rec in runs[0]:
+        assert len(rec["shard_new"]) == 4
+        assert 0 <= rec["novel_rows"] <= rec["novel_rows_plain"], rec
+    assert [(r["novel_rows"], r["novel_rows_plain"]) for r in runs[0]] \
+        == [(r["novel_rows"], r["novel_rows_plain"]) for r in runs[1]]
+    # levels 1-6 run the per-chunk step under the compact gate on either
+    # pipeline; level 7 (13,164 candidates for 5,673 states in 98,304
+    # lanes) is the whole-level program's where the pipeline has one
+    assert all(r["novel_rows"] == r["novel_rows_plain"] == 0
+               for r in runs[0][:6])
+    last = runs[0][6]
+    if pipeline == "legacy":
+        assert last["novel_rows"] == last["novel_rows_plain"] == 0
+    else:
+        assert last["novel_rows_plain"] == last["dedup_lanes"]
+        assert last["novel_rows"] % 256 == 0
+        assert 0 < last["novel_rows"] < 0.25 * last["novel_rows_plain"], last
+
+
 @pytest.mark.perf
 def test_sharded_device_launches_per_level(tmp_path):
     """The O(1)-launches/level/shard contract, span-tracer-verified:

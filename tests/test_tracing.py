@@ -48,11 +48,12 @@ PROGRAMS = {
 
 
 # the parts of `compact` each program must hold (pl.COMPACT_PARTS): the
-# fused path selects on the host (`compact-host`), the whole-level programs
-# alone append
+# fused path selects on the host (`compact-host`) and hands its pooled
+# layout to the sorted dedup unsqueezed (a squeeze at the width it is
+# handed narrows nothing), the whole-level programs alone append
 PARTS = {
     "fgd": set(), "hinv": set(), "init": set(),
-    "fsc": {"squeeze", "novel"},
+    "fsc": {"novel"},
     "step": {"select", "squeeze", "novel"},
     "dvl": set(pl.COMPACT_PARTS),
     "dvh": set(pl.COMPACT_PARTS),
@@ -120,7 +121,7 @@ def compact_parts_of(text):
     """The `part.<name>` scopes a lowered program holds, each held to its
     place: a name of the vocabulary directly under `kspec.compact`, one a
     path, and no operation of `kspec.compact` outside one:
-    `jit(fsc_n2)/kspec.compact/part.squeeze/scatter`."""
+    `jit(step_n2)/kspec.compact/part.squeeze/scatter`."""
     found = set()
     compact = pl.STAGE_PREFIX + "compact"
     for path in set(re.findall(r'"([^"]*(?:kspec\.compact|part\.)[^"]*)"',
