@@ -53,7 +53,7 @@ from ..resilience.retry import ChunkRetryHandler
 from ..utils.platform_guard import device_stamp
 from .hostio import HostIO
 from .pipeline import (
-    WORK_FIELDS,
+    work_width,
     counts_out,
     fp_stage,
     grow_visited as _grow_visited,
@@ -583,7 +583,7 @@ class _Step:
         N = _next_pow2(len(inits))
         inits += inits[:1] * (N - len(inits))
         fn = self.cached(
-            ("init", N), lambda: init_rows_program(self.spec),
+            ("init", N), lambda: init_rows_program(self.model),
             program="init", bucket=N,
         )
         launch = obs_.dispatch("init", bucket=N)
@@ -750,7 +750,7 @@ class _Step:
                 out, out_parent, out_act, rowvalid, n_en, sq_ovf = (
                     squeeze_stage(cand, parent, actid, valid, T, K)
                 )
-                out_hi, out_lo = fp_stage(out, rowvalid, spec)
+                out_hi, out_lo, _orbit = fp_stage(out, rowvalid, model)
                 viol_any, viol_idx = invariant_stage(
                     model, states, fvalid, with_invariants
                 )
@@ -768,13 +768,13 @@ class _Step:
             else:
                 overflow = ovf_vec()
 
-            hi, lo = fp_stage(cand, valid, spec)
+            hi, lo, orbit = fp_stage(cand, valid, model)
             # the shared winner-selection sequence (sort, first
             # occurrence, visited rank, compaction, rank-scatter merge)
             (out, out_parent, out_act, new_n, out_hi, out_lo,
              vhi2, vlo2, vn2, _rank, work) = sorted_dedup_stage(
                 cand, parent, actid, valid, hi, lo,
-                vhi, vlo, vn, vcap, T, K, True,
+                vhi, vlo, vn, vcap, T, K, True, orbit=orbit,
             )
             viol_any, viol_idx = invariant_stage(
                 model, states, fvalid, with_invariants
@@ -1282,6 +1282,27 @@ def check(
     require_encoding_sound(model)
     if prepared is not None and prepared.model is not model:
         raise ValueError("prepared kernels wrap a different model object")
+    # TLC's SYMMETRY (Model.symmetry): a state's key is its orbit's
+    # (pipeline.fp_stage), which no host twin recomputes from a stored row
+    # (resilience/integrity.fingerprint_rows is the PLAIN fingerprint), so
+    # whatever validates rows against keys is refused, by name, rather
+    # than run on keys it cannot check
+    symmetric = model.symmetry is not None
+    if symmetric:
+        for what, given in (
+            ("checkpoint_dir", checkpoint_dir is not None),
+            ("seed", seed is not None),
+            ("integrity_shadow", bool(integrity_shadow)),
+        ):
+            if given:
+                raise ValueError(
+                    f"{model.name}: {what}= is not supported under SYMMETRY "
+                    f"{model.symmetry.operator} (a stored row's key is its "
+                    "orbit's, and resilience/integrity.fingerprint_rows, "
+                    "which validates a checkpoint, a seed and a shadowed "
+                    "chunk, recomputes the plain fingerprint); drop the "
+                    "SYMMETRY stanza or the option"
+                )
     step_builder = prepared.step if prepared is not None else _Step(model)
     K, C = spec.num_lanes, step_builder.C
 
@@ -1332,7 +1353,9 @@ def check(
     # the kill switch (bench baselines, emergency escape hatch)
     chain = _integ.LevelDigestChain() if _integ.enabled() else None
     shadow_rate = (
-        _integ.shadow_rate(integrity_shadow) if chain is not None else 0.0
+        _integ.shadow_rate(integrity_shadow)
+        if chain is not None and not symmetric  # (an env-set rate too)
+        else 0.0
     )
     ckpt_store = None  # built once ckpt_ident is known
     # newest durably checkpointed level (None = not checkpointing):
@@ -1545,6 +1568,10 @@ def check(
     depth = 0
     violation = None
     result_stats: dict = {}
+    if symmetric:
+        result_stats["symmetry"] = model.symmetry.describe()
+    # the work counts behind the enabled counts of a program's vector
+    n_work = work_width(model, visited_backend)
     collect_stats = obs_.collect
     obs_.config(
         model=model.name,
@@ -2174,7 +2201,7 @@ def check(
         ) = finalize()
         # the program's counts vector (pipeline.counts_out): a chunk that
         # holds the verdict ran its probe and merge like any other
-        act_en_np, work = split_counts(io.fetch(counts, np.int64))
+        act_en_np, work = split_counts(io.fetch(counts, np.int64), n_work)
         lvl_work[:] += work
         lvl_chunks += 1
         lvl_rows_in += fp_n
@@ -2373,7 +2400,7 @@ def check(
         nonlocal lvl_chunks, lvl_rows_in, lvl_lanes
         t_wait = time.perf_counter()
         out = fin()
-        act_en_np, work = split_counts(out["counts"])
+        act_en_np, work = split_counts(out["counts"], n_work)
         lvl_work[:] += work
         wait_s = time.perf_counter() - t_wait
         # the one program ran all the plan's chunks, or stopped at the
@@ -2541,11 +2568,16 @@ def check(
                     # frontier loaded from a CRC-consistent corrupted
                     # checkpoint) is caught HERE, before it poisons
                     # successors
-                    _integ.count_check()
-                    chain.verify_level(
-                        depth,
-                        _integ.fingerprint_rows(frontier_np, spec.exact64),
-                    )
+                    # (under SYMMETRY the chain holds orbit keys, which
+                    # the host cannot recompute from rows: the sealed
+                    # counts still chain, the rows are not re-read)
+                    if not symmetric:
+                        _integ.count_check()
+                        chain.verify_level(
+                            depth,
+                            _integ.fingerprint_rows(
+                                frontier_np, spec.exact64),
+                        )
                 elif sp and frontier_np.paths():
                     # disk-spilled frontier: the flip lands in a segment
                     # FILE (there is no long-lived host buffer to flip);
@@ -2578,7 +2610,7 @@ def check(
             # pipeline.work_counts of the committed dispatches: the probes'
             # search rounds and the merges' touched slots, each beside what
             # the form over the whole capacity would have run
-            lvl_work = np.zeros(len(WORK_FIELDS), np.int64)
+            lvl_work = np.zeros(n_work, np.int64)
             lvl_probe_ms = 0.0  # deferred batched host-probe wall
             lvl_store_s = 0.0  # trace store / parent log wall (`store_ms`)
             lvl_chunks = lvl_rows_in = 0  # chunks committed, their rows

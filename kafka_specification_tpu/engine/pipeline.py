@@ -119,6 +119,7 @@ import numpy as np
 
 from ..obs.tracer import now as _now
 from ..ops import dedup, devlevel
+from ..ops.canon import canon_of
 from ..ops.fingerprint import fingerprint_lanes
 from ..pipeline_registry import (  # noqa: F401 — re-exported API
     PIPELINE_ENV,
@@ -140,7 +141,7 @@ PIPELINES = pipeline_names()
 #: strings (they cannot import the engine); tests/test_tracing.py
 #: holds every scope found in a lowered program to this tuple.
 STAGES = (
-    "guard", "expand", "compact", "fingerprint", "dedup_sort",
+    "guard", "expand", "compact", "canon", "fingerprint", "dedup_sort",
     "dedup_probe", "dedup_merge", "invariants", "digest",
 )
 STAGE_PREFIX = "kspec."
@@ -175,7 +176,9 @@ PART_PREFIX = "part."
 #: cache but DOES hash the module name, so a program compiled before a
 #: vocabulary change would be a cache hit that carries the old scopes.
 #: Bump this whenever STAGES or a scope's placement changes: the renamed
-#: programs recompile once and bake the new names in.
+#: programs recompile once and bake the new names in.  (``canon``, PR 38,
+#: needed no bump: the scope exists only in the programs of a model with a
+#: ``symmetry``, none of which was ever compiled without it.)
 NAMING_VERSION = 2
 
 
@@ -269,12 +272,31 @@ def squeeze_stage(cand, parent, actid, valid, width, K):  # kspec: traced
         return out, out_parent, out_act, rowvalid, n_en, n_en > width
 
 
-def fp_stage(cand, valid, spec):  # kspec: traced
-    """Stage 3: masked (hi, lo) fingerprints."""
+def fp_stage(cand, valid, model):  # kspec: traced
+    """Stage 3: masked (hi, lo) fingerprints -> (hi, lo, orbit).
+
+    Under the model's ``symmetry`` (TLC's SYMMETRY) a candidate's key is
+    its ORBIT's: the ``canon`` stage (ops/canon.py) forms every image of
+    every valid row and fingerprints the least one.  The rows flow on
+    unchanged, only the pair becomes the orbit's.  `orbit` is then (the
+    orbit's size of every valid lane i32[T], the rows whose images the
+    stage formed i32), which a sorted dedup sums over its new states
+    (:func:`sorted_dedup_stage`); None for a model with no symmetry, whose
+    programs are what they were.  Every single-device program that
+    fingerprints candidates comes through here (`valid` None: every row
+    is one, the initial states')."""
+    if model.symmetry is not None:
+        if valid is None:
+            valid = jnp.ones((cand.shape[0],), bool)
+        with stage("canon"):
+            hi, lo, size, rows = canon_of(model).keys(cand, valid)
+        return hi, lo, (size, rows)
     sent = jnp.uint32(dedup.SENT)
     with stage("fingerprint"):
-        hi, lo = fingerprint_lanes(cand, spec.exact64)
-        return jnp.where(valid, hi, sent), jnp.where(valid, lo, sent)
+        hi, lo = fingerprint_lanes(cand, model.spec.exact64)
+        if valid is None:
+            return hi, lo, None
+        return jnp.where(valid, hi, sent), jnp.where(valid, lo, sent), None
 
 
 def invariant_stage(model, states, fvalid, with_invariants: bool):  # kspec: traced
@@ -321,15 +343,17 @@ def invariant_rows_program(model, N: int):
     return invariant_rows
 
 
-def init_rows_program(spec):
+def init_rows_program(model):
     """Pack + fingerprint the stacked initial states, un-jitted:
-    {field: i32[N, ...]} -> (rows u32[N, K], hi u32[N], lo u32[N])."""
+    {field: i32[N, ...]} -> (rows u32[N, K], hi u32[N], lo u32[N]); the
+    pair is the orbit's under the model's symmetry (:func:`fp_stage`)."""
+    spec = model.spec
 
     def init_rows(states):  # kspec: traced
         with stage("expand"):
             rows = jax.vmap(spec.pack)(states)
-        with stage("fingerprint"):
-            return (rows, *fingerprint_lanes(rows, spec.exact64))
+        hi, lo, _orbit = fp_stage(rows, None, model)
+        return rows, hi, lo
 
     return init_rows
 
@@ -352,10 +376,26 @@ def _sort_first(hi, lo):  # kspec: traced
 WORK_FIELDS = ("probe_rounds", "probe_rounds_plain",
                "merge_slots", "merge_slots_plain",
                "novel_rows", "novel_rows_plain")
+#: Two more behind them in the programs of a model with a ``symmetry``
+#: that decide novelty on the device (the sorted visited backend): the
+#: rows whose images the ``canon`` stage formed (blocks run x block size),
+#: and the sum over the dispatch's NEW states of their orbits' sizes,
+#: which over a level EQUALS the unreduced job's count of that level.
+CANON_FIELDS = ("canon_rows", "orbit_states")
 
 
-def work_counts(probe=None, merge=None, novel=None):  # kspec: traced
-    """int32[6] (:data:`WORK_FIELDS`), the dedup work a program did beside its answers: the two
+def work_width(model, visited_backend: str = "device") -> int:
+    """How many work counts trail the counts vector of `model`'s programs
+    (:func:`split_counts`'s `n`)."""
+    symmetric = model.symmetry is not None and visited_backend == "device"
+    return len(WORK_FIELDS) + (len(CANON_FIELDS) if symmetric else 0)
+
+
+def work_counts(probe=None, merge=None, novel=None, canon=None,  # kspec: traced
+                symmetric: bool = False):
+    """int32[6] (:data:`WORK_FIELDS`; int32[8] in the programs of a model
+    with a symmetry: `canon`, the pair of :data:`CANON_FIELDS`, or
+    `symmetric` alone for a part that canonicalised nothing), the dedup work a program did beside its answers: the two
     round counts of ``dedup.probe_sorted`` (rounds run, rounds a search of
     the whole capacity runs), the two slot counts of
     ``dedup.merge_counted`` (slots touched, slots a capacity-wide merge
@@ -364,8 +404,10 @@ def work_counts(probe=None, merge=None, novel=None):  # kspec: traced
     part not given.  Vectors of several probes, merges and compactions
     add."""
     zero = jnp.zeros((2,), jnp.int32)
-    return jnp.concatenate([zero if x is None else x
-                            for x in (probe, merge, novel)])
+    parts = [probe, merge, novel]
+    if symmetric or canon is not None:
+        parts.append(canon)
+    return jnp.concatenate([zero if x is None else x for x in parts])
 
 
 def counts_out(act_en, work=None):  # kspec: traced
@@ -378,18 +420,19 @@ def counts_out(act_en, work=None):  # kspec: traced
     return jnp.concatenate([act_en, work])
 
 
-def split_counts(counts):
+def split_counts(counts, n: int = len(WORK_FIELDS)):
     """A fetched :func:`counts_out` vector (or a [D, n] stack of them,
     one a shard) -> (act_en, work): the enabled counts as fetched, and
-    int64[6], the :func:`work_counts` summed over the shards."""
+    int64[n], the :func:`work_counts` summed over the shards (`n`:
+    :func:`work_width`)."""
     counts = np.asarray(counts, np.int64)
-    n = len(WORK_FIELDS)
     return counts[..., :-n], counts[..., -n:].reshape(-1, n).sum(axis=0)
 
 
 def work_record(work):
-    """Summed :func:`work_counts` -> the six level-record fields."""
-    return dict(zip(WORK_FIELDS, (int(x) for x in work)))
+    """Summed :func:`work_counts` -> the six level-record fields (eight
+    under a symmetry)."""
+    return dict(zip(WORK_FIELDS + CANON_FIELDS, (int(x) for x in work)))
 
 
 #: The most rows one iteration of :func:`novel_stage`'s loops moves (the
@@ -498,7 +541,7 @@ def novel_stage(is_new, order, hi_s, lo_s, rank,  # kspec: traced
 
 def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
                        vhi, vlo, vn, vcap, T, K, with_merge: bool,
-                       also_seen_in=None):
+                       also_seen_in=None, orbit=None):
     """Stage 4 (device backend): minimal-payload lexsort, first-occurrence
     + visited-rank dedup, compaction of the new states to the front
     (:func:`novel_stage`), and (with_merge) the rank-scatter merge into the
@@ -517,7 +560,11 @@ def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
     the PRIMARY set) lets with_merge=False callers run their own gated
     merge_ranked; the last return is the stage's :func:`work_counts` (the
     one or two probes' rounds, the merge's slots where with_merge, the
-    compaction's rows)."""
+    compaction's rows).
+
+    orbit: :func:`fp_stage`'s third return.  Where it is not None the work
+    counts end in the ``canon`` pair: the stage's rows, and the orbit sizes
+    of the NEW states summed (one masked gather through the sort order)."""
     # minimal-payload sort: only the original index rides through the
     # sort network; state rows/parents are gathered once afterwards
     hi_s, lo_s, order, first = _sort_first(hi, lo)
@@ -536,8 +583,14 @@ def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
         vhi, vlo, vn, slots = dedup.merge_counted(
             vhi, vlo, vn, out_hi, out_lo, out_rank, new_n, vcap
         )
+    canon = None
+    if orbit is not None:
+        size, canon_rows = orbit
+        with stage("canon"):
+            canon = jnp.stack([canon_rows, jnp.sum(
+                jnp.where(is_new, size[order], 0), dtype=jnp.int32)])
     return (out, out_parent, out_act, new_n, out_hi, out_lo,
-            vhi, vlo, vn, out_rank, work_counts(probe, slots, rows))
+            vhi, vlo, vn, out_rank, work_counts(probe, slots, rows, canon))
 
 
 def candidate_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
@@ -942,7 +995,7 @@ class FusedPipeline:
                 # host backend: validity is resolved at C speed on the
                 # host (run_chunk compacts by the ok mask), so no device
                 # squeeze scatter is needed at all
-                hi, lo = fp_stage(cand, ok, spec)
+                hi, lo, _orbit = fp_stage(cand, ok, model)
                 return cand, ok, hi, lo
             with stage("expand"):
                 act_en = jnp.stack(
@@ -957,11 +1010,11 @@ class FusedPipeline:
                 # no squeeze: at the pooled width it narrows nothing, and
                 # the stable sort puts the masked rows last in any case
                 # (see _Step._build)
-                hi, lo = fp_stage(cand, ok, spec)
+                hi, lo, orbit = fp_stage(cand, ok, model)
                 (out, out_parent, out_act, new_n, out_hi, out_lo,
                  vhi, vlo, vn, _rank, work) = sorted_dedup_stage(
                     cand, sidx, actid_f, ok, hi, lo,
-                    vhi, vlo, vn, vcap, W, K, with_merge,
+                    vhi, vlo, vn, vcap, W, K, with_merge, orbit=orbit,
                 )
                 return (out, out_parent, out_act, new_n, out_hi, out_lo,
                         vhi, vlo, vn, counts_out(act_en, work))
@@ -969,7 +1022,7 @@ class FusedPipeline:
             out, out_parent, out_act, rowvalid2, n_en, _ovf = squeeze_stage(
                 cand, sidx, actid_f, ok, W, K
             )
-            hi, lo = fp_stage(out, rowvalid2, spec)
+            hi, lo, _orbit = fp_stage(out, rowvalid2, model)
             return (out, out_parent, out_act, n_en, hi, lo,
                     vhi, vlo, vn, counts_out(act_en))
 
@@ -1496,6 +1549,9 @@ class DevicePipeline:
         check_invariants = self.check_invariants
         check_deadlock = self.check_deadlock
         n_actions = len(model.actions)
+        # (under a symmetry every work vector of the program ends in the
+        # canon pair: `work_counts(symmetric=)`)
+        symmetric = model.symmetry is not None
 
         def level(fbuf, f_total, n_chunks, vhi, vlo, vn):  # kspec: traced
             sent = jnp.uint32(dedup.SENT)
@@ -1520,7 +1576,7 @@ class DevicePipeline:
                 (cand, parent, actid, rowvalid, _n_en,
                  sq_ovf) = squeeze_stage(cand, parent, actid, valid,
                                          T, K)
-                hi, lo = fp_stage(cand, rowvalid, spec)
+                hi, lo, orbit = fp_stage(cand, rowvalid, model)
                 # the SHARED winner-selection sequence (one source of
                 # truth with the fused/legacy paths): primary set =
                 # level-new (its ranks drive the gated merge below),
@@ -1529,7 +1585,7 @@ class DevicePipeline:
                  _l3, n_rank, c_work) = sorted_dedup_stage(
                     cand, parent, actid, rowvalid, hi, lo,
                     lhi, llo, ln, LN, T, K, False,
-                    also_seen_in=(vhi, vlo, vn),
+                    also_seen_in=(vhi, vlo, vn), orbit=orbit,
                 )
                 # verdicts, serial-commit priority
                 with stage("invariants"):
@@ -1557,6 +1613,10 @@ class DevicePipeline:
                     ln_ovf = commit & ((ln + new_n) > LN)
                     commit_ok = commit & ~ovf & ~ln_ovf
                     app_n = jnp.where(commit_ok, new_n, 0)
+                if symmetric:
+                    with stage("canon"):  # only committed states count
+                        c_work = c_work.at[-1].set(
+                            jnp.where(commit_ok, c_work[-1], 0))
                 with stage("compact"), part("append"):
                     orows = devlevel.append_rows(orows, n_out, on)
                     opar = devlevel.append_vec(opar, n_par + start, on)
@@ -1581,7 +1641,8 @@ class DevicePipeline:
                 return (i + 1, orows, opar, oact, on + app_n,
                         lhi, llo, ln, vkind, vinv, vidx,
                         act_en, agmax, dig, ovf,
-                        work + c_work + work_counts(merge=c_slots))
+                        work + c_work + work_counts(merge=c_slots,
+                                                    symmetric=symmetric))
 
             def cond(carry):  # kspec: traced
                 return (carry[0] < n_chunks) & (carry[8] == 0)
@@ -1602,7 +1663,7 @@ class DevicePipeline:
                     jnp.zeros((n_actions,), jnp.int32),
                     devlevel.zero_digest(),
                     jnp.bool_(False),
-                    work_counts(),
+                    work_counts(symmetric=symmetric),
                 )
             (_i, orows, opar, oact, on, lhi, llo, _ln, vkind, vinv,
              vidx, act_en, agmax, dig, ovf, work) = jax.lax.while_loop(
@@ -1619,7 +1680,8 @@ class DevicePipeline:
             )
             return (orows, opar, oact, on, vhi, vlo, vn, vkind, vinv,
                     vidx,
-                    counts_out(act_en, work + work_counts(m_probe, m_slots)),
+                    counts_out(act_en, work + work_counts(
+                        m_probe, m_slots, symmetric=symmetric)),
                     agmax, dig, ovf)
 
         return level
@@ -1681,7 +1743,7 @@ class DevicePipeline:
                 (cand, parent, actid, rowvalid, _n_en,
                  sq_ovf) = squeeze_stage(cand, parent, actid, valid,
                                          T, K)
-                hi, lo = fp_stage(cand, rowvalid, spec)
+                hi, lo, _orbit = fp_stage(cand, rowvalid, model)
                 (n_out, n_par, n_act, n_ohi, n_olo, new_n,
                  s_hi, s_lo, s_rank, c_work) = candidate_dedup_stage(
                     cand, parent, actid, rowvalid, hi, lo,

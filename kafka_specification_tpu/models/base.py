@@ -47,6 +47,57 @@ class Invariant:
     pred: PredicateKernel
 
 
+@dataclass(frozen=True)
+class FieldRole:
+    """How one state field moves under a permutation ``g`` of a symmetric
+    constant set (see :class:`Symmetry`).  Three roles and their products:
+
+    - ``axis``: the axis of the field's shape that the set indexes
+      (``end[replica]``: 0); the image's slot ``g(i)`` holds slot ``i``;
+    - ``value`` ``"member"``: the elements ARE members of the set (a leader
+      id); a value in ``[0, n)`` maps to ``g(value)``, every other value is
+      a fixed sentinel (None, Nil, an absent slot);
+    - ``value`` ``"mask"``: the elements are subsets of the set as bitmasks
+      (an ISR); bit ``j`` of the image is bit ``g^-1(j)`` of the element.
+
+    ``ldr[replica]`` is indexed AND member-valued, ``isr[replica]`` indexed
+    AND a mask; a field the set touches in neither way has no entry."""
+
+    axis: Optional[int] = None
+    value: Optional[str] = None  # None | "member" | "mask"
+
+    def __post_init__(self):
+        assert self.value in (None, "member", "mask"), self.value
+        assert self.axis is not None or self.value is not None
+
+
+@dataclass(frozen=True)
+class Symmetry:
+    """TLC's ``SYMMETRY`` over ``Permutations(set_name)``: the full
+    permutation group of a constant set of ``n`` model values, and per
+    field of the state how it moves under one (:class:`FieldRole`).  Two
+    states are one iff some permutation maps one to the other; the engine
+    keys a state by its orbit (ops/canon.py), the oracle twin yields
+    canonical members (its own code).  `operator` is the identifier a
+    ``.cfg`` names in its ``SYMMETRY`` stanza."""
+
+    set_name: str
+    n: int
+    roles: dict  # field name -> FieldRole
+    operator: str = "Symm"
+
+    @property
+    def order(self) -> int:
+        """|G| = n!"""
+        import math
+
+        return math.factorial(self.n)
+
+    def describe(self) -> dict:
+        """The manifest's ``result.symmetry``."""
+        return {"set": self.set_name, "order": self.order}
+
+
 @dataclass
 class Model:
     name: str
@@ -65,6 +116,10 @@ class Model:
     # and StrongIsr share their quantifier core); engines fall back to the
     # per-invariant preds when None (and for single-invariant re-checks).
     invariants_fused: Optional[Callable] = None
+    # TLC's SYMMETRY, switched on by the .cfg's stanza (utils/cfg.py): the
+    # single-device engine then stores one state an orbit.  None: every
+    # state is its own orbit, and no program of the model changes.
+    symmetry: Optional[Symmetry] = None
 
     def __post_init__(self):
         # spec-width soundness at EVERY model construction: each declared

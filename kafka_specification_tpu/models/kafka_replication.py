@@ -30,14 +30,22 @@ THEOREM at Kip320.tla:169. We expose both the literal predicate
 
 from __future__ import annotations
 
+import dataclasses
+import math
 from dataclasses import dataclass
+from itertools import permutations, product
 
 import jax.numpy as jnp
 import numpy as np
 
 from ..ops.packing import Field, StateSpec
-from ..oracle.interp import OracleAction
-from .base import Action, Invariant
+from ..oracle.interp import (
+    OracleAction,
+    OracleModel,
+    OracleSymmetry,
+    reduce_by_symmetry,
+)
+from .base import Action, FieldRole, Invariant, Symmetry
 
 NONE = -1  # KafkaReplication.tla:38
 NIL = -1  # KafkaReplication.tla:39
@@ -100,6 +108,35 @@ def make_spec(cfg: Config) -> StateSpec:
             Field("req_ldr", (E + 1,), ABSENT, N - 1),
             Field("req_isr", (E + 1,), 0, cfg.full_isr),
         ]
+    )
+
+
+def symmetry(cfg: Config) -> Symmetry:
+    """How :func:`make_spec`'s fields move under a permutation of
+    `Replicas` (TLC: ``SYMMETRY Symm``, ``Symm == Permutations(Replicas)``
+    in the conventional ``MC`` wrapper module).  The spec is symmetric in
+    its replicas: Init treats them alike, every action quantifies over
+    `Replicas`, the only CHOOSEs are Min / Max over offsets, and the
+    invariants quantify over replicas (tests/test_symmetry.py holds the
+    equivariance to the oracle).  Record ids, epochs and offsets are not
+    replicas and do not move."""
+    by_replica = FieldRole(axis=0)
+    return Symmetry(
+        set_name="Replicas",
+        n=cfg.n,
+        roles={
+            "end": by_replica,
+            "rid": by_replica,
+            "repoch": by_replica,
+            "hw": by_replica,
+            "ep": by_replica,
+            "ldr": FieldRole(axis=0, value="member"),
+            "isr": FieldRole(axis=0, value="mask"),
+            "qldr": FieldRole(value="member"),
+            "qisr": FieldRole(value="mask"),
+            "req_ldr": FieldRole(value="member"),
+            "req_isr": FieldRole(value="mask"),
+        },
     )
 
 
@@ -906,3 +943,149 @@ def o_type_ok(cfg: Config):
         return NIL <= qep <= cfg.e and NONE <= qldr < cfg.n
 
     return ("TypeOk", pred)
+
+
+# oracle symmetry (TLC's SYMMETRY over Permutations(Replicas)) --------------
+#
+# Written on the canonical Python state above, sharing nothing with
+# ops/canon.py: the engine's orbit counts are held to these.
+
+
+def o_permute(cfg: Config, s, g):
+    """The image of state `s` under the replica permutation `g` (``g[i]``
+    is the image of replica ``i``; None and Nil are fixed)."""
+    logs, rstates, nrid, nep, reqs, (qep, qldr, qisr) = s
+    n = cfg.n
+    inv = [0] * n
+    for i in range(n):
+        inv[g[i]] = i
+
+    def member(v):
+        return g[v] if v >= 0 else v
+
+    def subset(xs):
+        return frozenset(g[x] for x in xs)
+
+    new_rs = []
+    for j in range(n):
+        hw, ep, ldr, isr = rstates[inv[j]]
+        new_rs.append((hw, ep, member(ldr), subset(isr)))
+    return (
+        tuple(logs[inv[j]] for j in range(n)),
+        tuple(new_rs),
+        nrid,
+        nep,
+        frozenset((e, member(l), subset(risr)) for (e, l, risr) in reqs),
+        (qep, member(qldr), subset(qisr)),
+    )
+
+
+def _o_order_key(s):
+    """A total order on states (frozensets compare by inclusion, so they
+    become sorted tuples): the least image under it is the orbit's
+    canonical member."""
+    logs, rstates, nrid, nep, reqs, (qep, qldr, qisr) = s
+    return (
+        logs,
+        tuple((hw, ep, ldr, tuple(sorted(isr))) for hw, ep, ldr, isr in rstates),
+        nrid,
+        nep,
+        tuple(sorted((e, l, tuple(sorted(risr))) for e, l, risr in reqs)),
+        (qep, qldr, tuple(sorted(qisr))),
+    )
+
+
+def _o_replica_signature(cfg: Config, s, i):
+    """What can be said of replica `i` without naming a replica: equal for
+    `i` in `s` and ``g(i)`` in ``g(s)``, whatever `g`."""
+    logs, rstates, _, _, reqs, (_, qldr, qisr) = s
+    hw, ep, ldr, isr = rstates[i]
+    return (
+        logs[i],
+        hw,
+        ep,
+        0 if ldr == NONE else (1 if ldr == i else 2),
+        len(isr),
+        i in isr,
+        qldr == i,
+        i in qisr,
+        tuple(sorted((e, l == i, i in risr) for e, l, risr in reqs)),
+        sum(1 for r in rstates if r[2] == i),
+        sum(1 for r in rstates if i in r[3]),
+    )
+
+
+def _o_tie_orders(groups):
+    """Every way to lay the tie groups out in order, group by group."""
+    return product(*(permutations(grp) for grp in groups))
+
+
+def o_canonical(cfg: Config, s):
+    """-> (canonical member of `s`'s orbit, the orbit's size).
+
+    Exact, and short of all N! images: replicas are ordered by a
+    replica-free signature, and only the orders inside a tie are tried.
+    A permutation that sorts the signatures of ``h(s)`` is one that sorts
+    those of `s`, composed with `h`, so every member of an orbit tries the
+    same set of images and takes the same least one; and the sorting
+    permutations that reach it are one coset of the stabiliser, so their
+    count is its order (tests/test_symmetry.py holds both to the brute
+    force over all N!)."""
+    n = cfg.n
+    sigs = [_o_replica_signature(cfg, s, i) for i in range(n)]
+    order = sorted(range(n), key=lambda i: sigs[i])
+    groups, last = [], None
+    for i in order:
+        if sigs[i] == last:
+            groups[-1].append(i)
+        else:
+            groups.append([i])
+            last = sigs[i]
+    best = best_key = None
+    hits = 0
+    for laid in _o_tie_orders(groups):
+        g = [0] * n
+        pos = 0
+        for grp in laid:
+            for i in grp:
+                g[i] = pos
+                pos += 1
+        t = o_permute(cfg, s, g)
+        k = _o_order_key(t)
+        if best_key is None or k < best_key:
+            best, best_key, hits = t, k, 1
+        elif k == best_key:
+            hits += 1
+    return best, math.factorial(n) // hits
+
+
+def o_canonical_brute(cfg: Config, s):
+    """:func:`o_canonical` by the definition: the least of all N! images,
+    and N! over the images equal to `s` itself."""
+    images = [o_permute(cfg, s, g) for g in permutations(range(cfg.n))]
+    best = min(images, key=_o_order_key)
+    return best, len(images) // sum(1 for t in images if t == s)
+
+
+def reduced(built, cfg: Config, symmetric: bool):
+    """A family model (or its oracle twin) as built, or with the replica
+    symmetry switched on: the one place the family's factories apply it."""
+    if not symmetric:
+        return built
+    if isinstance(built, OracleModel):
+        return reduce_by_symmetry(built, o_symmetry(cfg))
+    sym = symmetry(cfg)
+    return dataclasses.replace(
+        built, name=f"{built.name}/SYMMETRY({sym.set_name})", symmetry=sym
+    )
+
+
+def o_symmetry(cfg: Config) -> OracleSymmetry:
+    sym = symmetry(cfg)
+    return OracleSymmetry(
+        set_name=sym.set_name,
+        n=cfg.n,
+        order=sym.order,
+        permute=lambda s, g: o_permute(cfg, s, g),
+        canonical=lambda s: o_canonical(cfg, s),
+    )

@@ -321,7 +321,8 @@ def ft_become_follower(cfg: Config):
 # --------------------------------------------------------------------------
 
 
-def make_model(cfg: Config, invariants: Sequence[str] = DEFAULT_INVARIANTS) -> Model:
+def make_model(cfg: Config, invariants: Sequence[str] = DEFAULT_INVARIANTS,
+               symmetric: bool = False) -> Model:
     """Kip320!Next (Kip320.tla:150-159)."""
     actions = [
         kr.controller_elect_leader(cfg),
@@ -334,7 +335,7 @@ def make_model(cfg: Config, invariants: Sequence[str] = DEFAULT_INVARIANTS) -> M
         fenced_become_follower_and_truncate(cfg),
         fenced_follower_fetch(cfg),
     ]
-    return Model(
+    return kr.reduced(Model(
         name=f"Kip320({cfg.n}r,L{cfg.l},R{cfg.r},E{cfg.e})",
         spec=kr.make_spec(cfg),
         init_states=lambda: [kr.init_state(cfg)],
@@ -342,11 +343,12 @@ def make_model(cfg: Config, invariants: Sequence[str] = DEFAULT_INVARIANTS) -> M
         invariants=_invariant_kernels(cfg, invariants),
         decode=kr.make_decode(cfg),
         meta={"variant": "Kip320", "cfg": cfg},
-    )
+    ), cfg, symmetric)
 
 
 def make_first_try_model(
-    cfg: Config, invariants: Sequence[str] = DEFAULT_INVARIANTS
+    cfg: Config, invariants: Sequence[str] = DEFAULT_INVARIANTS,
+    symmetric: bool = False,
 ) -> Model:
     """Kip320FirstTry!Next (Kip320FirstTry.tla:159-169)."""
     actions = [
@@ -361,7 +363,7 @@ def make_first_try_model(
         ft_follower_fetch(cfg),
         ft_follower_truncate(cfg),
     ]
-    return Model(
+    return kr.reduced(Model(
         name=f"Kip320FirstTry({cfg.n}r,L{cfg.l},R{cfg.r},E{cfg.e})",
         spec=kr.make_spec(cfg),
         init_states=lambda: [kr.init_state(cfg)],
@@ -369,7 +371,7 @@ def make_first_try_model(
         invariants=_invariant_kernels(cfg, invariants),
         decode=kr.make_decode(cfg),
         meta={"variant": "Kip320FirstTry", "cfg": cfg},
-    )
+    ), cfg, symmetric)
 
 
 # ==========================================================================
@@ -622,7 +624,8 @@ def o_ft_become_follower(cfg: Config):
     return OracleAction("BecomeFollower", successors)
 
 
-def make_oracle(cfg: Config, invariants: Sequence[str] = DEFAULT_INVARIANTS) -> OracleModel:
+def make_oracle(cfg: Config, invariants: Sequence[str] = DEFAULT_INVARIANTS,
+                symmetric: bool = False) -> OracleModel:
     actions = [
         kr.o_controller_elect_leader(cfg),
         kr.o_controller_shrink_isr(cfg),
@@ -634,17 +637,18 @@ def make_oracle(cfg: Config, invariants: Sequence[str] = DEFAULT_INVARIANTS) -> 
         o_fenced_become_follower_and_truncate(cfg),
         o_fenced_follower_fetch(cfg),
     ]
-    return OracleModel(
+    return kr.reduced(OracleModel(
         name="Kip320-oracle",
         init_states=lambda: [kr.o_init(cfg)],
         actions=actions,
         invariants=_invariant_oracles(cfg, invariants),
         meta={"variant": "Kip320", "cfg": cfg},
-    )
+    ), cfg, symmetric)
 
 
 def make_first_try_oracle(
-    cfg: Config, invariants: Sequence[str] = DEFAULT_INVARIANTS
+    cfg: Config, invariants: Sequence[str] = DEFAULT_INVARIANTS,
+    symmetric: bool = False,
 ) -> OracleModel:
     actions = [
         kr.o_controller_elect_leader(cfg),
@@ -658,10 +662,10 @@ def make_first_try_oracle(
         o_ft_follower_fetch(cfg),
         o_ft_follower_truncate(cfg),
     ]
-    return OracleModel(
+    return kr.reduced(OracleModel(
         name="Kip320FirstTry-oracle",
         init_states=lambda: [kr.o_init(cfg)],
         actions=actions,
         invariants=_invariant_oracles(cfg, invariants),
         meta={"variant": "Kip320FirstTry", "cfg": cfg},
-    )
+    ), cfg, symmetric)

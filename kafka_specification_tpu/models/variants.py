@@ -67,7 +67,8 @@ _VARIANTS = {
 
 
 def make_model(
-    variant: str, cfg: kr.Config, invariants: Sequence[str] = DEFAULT_INVARIANTS
+    variant: str, cfg: kr.Config, invariants: Sequence[str] = DEFAULT_INVARIANTS,
+    symmetric: bool = False,
 ) -> Model:
     trunc_fn, _, action_name = _VARIANTS[variant]
     spec = kr.make_spec(cfg)
@@ -84,7 +85,7 @@ def make_model(
         kr.become_follower_and_truncate_to(cfg, action_name, trunc_fn(cfg)),
         kr.follower_replicate(cfg),
     ]
-    return Model(
+    return kr.reduced(Model(
         name=f"{variant}({cfg.n}r,L{cfg.l},R{cfg.r},E{cfg.e})",
         spec=spec,
         init_states=lambda: [kr.init_state(cfg)],
@@ -92,11 +93,12 @@ def make_model(
         invariants=_invariant_kernels(cfg, invariants),
         decode=kr.make_decode(cfg),
         meta={"variant": variant, "cfg": cfg},
-    )
+    ), cfg, symmetric)
 
 
 def make_oracle(
-    variant: str, cfg: kr.Config, invariants: Sequence[str] = DEFAULT_INVARIANTS
+    variant: str, cfg: kr.Config, invariants: Sequence[str] = DEFAULT_INVARIANTS,
+    symmetric: bool = False,
 ) -> OracleModel:
     _, o_trunc_fn, action_name = _VARIANTS[variant]
     actions = [
@@ -110,10 +112,10 @@ def make_oracle(
         kr.o_become_follower_and_truncate_to(cfg, action_name, o_trunc_fn(cfg)),
         kr.o_follower_replicate(cfg),
     ]
-    return OracleModel(
+    return kr.reduced(OracleModel(
         name=f"{variant}-oracle",
         init_states=lambda: [kr.o_init(cfg)],
         actions=actions,
         invariants=_invariant_oracles(cfg, invariants),
         meta={"variant": variant, "cfg": cfg},
-    )
+    ), cfg, symmetric)

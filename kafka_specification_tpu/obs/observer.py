@@ -327,7 +327,7 @@ class RunObserver:
         # them (`--pipeline device`); mesh size and what the exchange
         # carried (sharded engine); the level a verdict cut
         for key in ("device", "devices", "exchange_compressed",
-                    "exchange_bytes_total", "cut_level"):
+                    "exchange_bytes_total", "cut_level", "symmetry"):
             if key in s:
                 summary[key] = s[key]
         if result.violation is not None:

@@ -25,6 +25,21 @@ class OracleAction:
     successors: Callable[[object], Iterable[object]]
 
 
+@dataclass(frozen=True)
+class OracleSymmetry:
+    """TLC's ``SYMMETRY`` over the full permutation group of a constant
+    set, on the oracle's own state values.  `permute(s, g)` is the image of
+    state `s` under `g` (a tuple, ``g[i]`` the image of member ``i``);
+    `canonical(s)` -> (the one member every state of `s`'s orbit maps to,
+    the orbit's size ``order / |stabiliser|``)."""
+
+    set_name: str
+    n: int
+    order: int
+    permute: Callable[[object, tuple], object]
+    canonical: Callable[[object], tuple]
+
+
 @dataclass
 class OracleModel:
     name: str
@@ -34,6 +49,36 @@ class OracleModel:
     constraint: Optional[Callable[[object], bool]] = None
     # same vocabulary as Model.meta (drives TLA-style trace rendering)
     meta: dict = field(default_factory=dict)
+    # set by :func:`reduce_by_symmetry`: the model's states are orbits
+    symmetry: Optional[OracleSymmetry] = None
+
+
+def reduce_by_symmetry(model: OracleModel, sym: OracleSymmetry) -> OracleModel:
+    """The same transition relation over ORBITS: every initial state and
+    every successor is replaced by its orbit's canonical member, so a
+    caller that keeps a plain set of what the model hands it (oracle_bfs,
+    the benchmark's own loop) counts orbits, as TLC does under SYMMETRY.
+    Distance from Init is the same for every member of an orbit, so the
+    per-level counts do not depend on which member stands for it."""
+    canon = sym.canonical
+
+    def reduced(action):
+        def successors(s, _succ=action.successors):
+            for t in _succ(s):
+                yield canon(t)[0]
+
+        return OracleAction(action.name, successors)
+
+    inits = model.init_states
+    return OracleModel(
+        name=f"{model.name}/SYMMETRY({sym.set_name})",
+        init_states=lambda: list(dict.fromkeys(canon(s)[0] for s in inits())),
+        actions=[reduced(a) for a in model.actions],
+        invariants=model.invariants,
+        constraint=model.constraint,
+        meta=dict(model.meta),
+        symmetry=sym,
+    )
 
 
 @dataclass

@@ -1657,6 +1657,16 @@ def check_sharded(
     from ..analysis import require_encoding_sound
 
     require_encoding_sound(model)
+    if model.symmetry is not None:
+        # this engine fingerprints candidates at five sites of its own
+        # (fingerprint_lanes), none through pipeline.fp_stage: run here the
+        # model would be searched UNREDUCED with no word said
+        raise ValueError(
+            f"{model.name}: check_sharded does not support SYMMETRY "
+            f"{model.symmetry.operator} yet (the sharded engine keys states "
+            "by their own fingerprints, not their orbits'); run the "
+            "single-device engine, or drop the SYMMETRY stanza"
+        )
     if mesh is None:
         mesh = Mesh(np.array(jax.devices()), ("d",))
     D = mesh.devices.size

@@ -947,6 +947,12 @@ class Daemon:
                 spec, cfg, emitted, job_invariants(spec["module"], cfg)
             )
             rows = level_rows
+            if getattr(cfg, "symmetry", None) is not None:
+                # under SYMMETRY a stored row's key is its orbit's, which
+                # the artifact's verify pass and engine.check(seed=) cannot
+                # recompute (integrity.fingerprint_rows is the plain
+                # fingerprint): verdict-only entry, exact hits alone
+                rows = None
             if rows is not None:
                 # an exhausted run's trace store carries one trailing
                 # EMPTY level (the final zero-new iteration) beyond the

@@ -88,6 +88,7 @@ def shape_key(module: str, cfg: TlcConfig, emitted: bool,
         tuple(invariants),
         tuple(cfg.constraints),
         bool(cfg.check_deadlock),
+        cfg.symmetry,  # a reduced job never shares an unreduced one's kernels
     )
 
 
@@ -100,6 +101,7 @@ def model_key(module: str, cfg: TlcConfig, emitted: bool) -> tuple:
         bool(emitted),
         canonical_constants(cfg.constants),
         tuple(cfg.constraints),
+        cfg.symmetry,
     )
 
 

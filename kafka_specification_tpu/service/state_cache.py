@@ -127,9 +127,14 @@ class CacheKey:
     check_deadlock: bool
     max_depth: Optional[int] = None
     max_states: Optional[int] = None
+    # the .cfg's SYMMETRY operator: a reduced job's counts are orbits, so
+    # it shares no entry with the unreduced job of equal constants (keyed
+    # only where set: every entry written before the field keeps its digest)
+    symmetry: Optional[str] = None
 
     def base_dict(self) -> dict:
         return {
+            **({"symmetry": self.symmetry} if self.symmetry else {}),
             "module": self.module,
             "emitted": bool(self.emitted),
             "constants": [[k, list(v) if isinstance(v, tuple) else v]
@@ -176,6 +181,7 @@ def key_for_job(spec: dict, cfg, emitted: bool, invariants: tuple) -> CacheKey:
         check_deadlock=bool(cfg.check_deadlock),
         max_depth=spec.get("max_depth"),
         max_states=spec.get("max_states"),
+        symmetry=getattr(cfg, "symmetry", None),
     )
 
 

@@ -9,8 +9,17 @@ Supported subset (what TLC configs for this corpus need):
   CONSTANT / CONSTANTS   name = value   (ints, model-value sets {a, b, c})
   INVARIANT / INVARIANTS name...
   CONSTRAINT name                        (AsyncIsr's bound; see below)
-  SPECIFICATION / INIT / NEXT            (parsed, informational — each module
+  SPECIFICATION                          (parsed, informational — each module
                                           has exactly one Spec shape)
+  INIT / NEXT                            (each module has exactly one Init and
+                                          one Next; the names are not resolved,
+                                          and a logged line says so)
+  SYMMETRY name                          (TLC's symmetry reduction: `name` must
+                                          be the operator the module declares,
+                                          `Symm == Permutations(Replicas)` in
+                                          the MC wrapper modules below; the
+                                          single-device engine then stores one
+                                          state an orbit, docs/engine.md)
   CHECK_DEADLOCK TRUE|FALSE              (default FALSE: the bounded models
                                           deadlock by design, SURVEY.md §2.4)
   \\* and (* ... *) comments
@@ -24,9 +33,12 @@ in configs/AsyncIsr.cfg).
 
 from __future__ import annotations
 
+import logging
 import re
 from dataclasses import dataclass, field
 from pathlib import Path
+
+_log = logging.getLogger(__name__)
 
 
 @dataclass
@@ -36,6 +48,7 @@ class TlcConfig:
     constraints: list = field(default_factory=list)
     specification: str | None = None
     check_deadlock: bool = False
+    symmetry: str | None = None  # the SYMMETRY stanza's operator name
 
 
 _SECTIONS = {
@@ -99,7 +112,19 @@ def parse_cfg(path_or_text) -> TlcConfig:
             cfg.specification = line.split()[0]
         elif section == "check_deadlock":
             cfg.check_deadlock = line.strip().upper() == "TRUE"
-        # INIT/NEXT/SYMMETRY: parsed and ignored (corpus uses SPECIFICATION)
+        elif section == "symmetry":
+            if cfg.symmetry is not None or len(line.split()) != 1:
+                raise ValueError(
+                    f"SYMMETRY takes one operator name, got {line!r}"
+                    + (f" after {cfg.symmetry!r}" if cfg.symmetry else "")
+                )
+            cfg.symmetry = line
+        elif section in ("init", "next"):
+            # not resolved: every module here has one Init and one Next
+            _log.warning(
+                "%s %s: ignored (each module's own %s is checked)",
+                section.upper(), line, section.capitalize(),
+            )
     return cfg
 
 
@@ -112,7 +137,45 @@ KAFKA_VARIANTS = ("KafkaTruncateToHighWatermark", "Kip101", "Kip279")
 # shipped .cfg files whose stem is not a module name (TLC pairs Model.cfg
 # with Model.tla; these document their explicit `--module`)
 CFG_MODULE_ALIASES = {"Kip320Stretch": "Kip320", "Kip320FiveBroker": "Kip320",
-                      "AsyncIsrFourBroker": "AsyncIsr"}
+                      "AsyncIsrFourBroker": "AsyncIsr",
+                      "MCKip320FiveBroker": "MCKip320"}
+
+# The wrapper modules a TLC user writes to run a spec under SYMMETRY: the
+# corpus's modules define no symmetry set, so the conventional
+# `MC<Spec>.tla` does (`EXTENDS <Spec>, TLC` and
+# `Symm == Permutations(Replicas)`), and the .cfg says `SYMMETRY Symm`.
+# wrapper -> (the module it extends, the operator it defines).
+MC_MODULES = {
+    "MCKip320": ("Kip320", "Symm"),
+    "MCKip320FirstTry": ("Kip320FirstTry", "Symm"),
+    **{f"MC{v}": (v, "Symm") for v in KAFKA_VARIANTS},
+}
+
+
+def resolve_symmetry(module: str, cfg: TlcConfig) -> tuple:
+    """-> (the module whose Spec is checked, whether the SYMMETRY stanza
+    switches its reduction on).  A wrapper module without the stanza is the
+    module it extends; a stanza naming an operator the module does not
+    define is TLC's "unknown operator", an error here too (it was parsed
+    and ignored until PR 38: unreduced counts with no word said)."""
+    base, operator = MC_MODULES.get(module, (module, None))
+    if cfg.symmetry is None:
+        return base, False
+    if operator is None:
+        hint = next((w for w, (b, _) in MC_MODULES.items() if b == module), None)
+        raise ValueError(
+            f"SYMMETRY {cfg.symmetry}: module {module!r} defines no such "
+            "operator (unknown operator)"
+            + (f"; its wrapper {hint!r} defines `Symm == "
+               f"Permutations(Replicas)`: pass --module {hint}" if hint else
+               "; no symmetry set is declared for it")
+        )
+    if cfg.symmetry != operator:
+        raise ValueError(
+            f"SYMMETRY {cfg.symmetry}: module {module!r} defines "
+            f"{operator!r}, not {cfg.symmetry!r} (unknown operator)"
+        )
+    return base, True
 
 
 def _setlen(v) -> int:
@@ -128,6 +191,7 @@ def resolved_invariants(module: str, cfg) -> tuple:
     it lives here next to build_model's own resolution rather than as a
     second table that could drift.  Unknown modules raise KeyError, the
     same loud failure build_model gives them."""
+    module = MC_MODULES.get(module, (module,))[0]
     if module in ("IdSequence", "FiniteReplicatedLog"):
         return ("TypeOk",)  # fixed by the builders; cfg selection ignored
     if module in KAFKA_VARIANTS or module in ("Kip320", "Kip320FirstTry"):
@@ -177,6 +241,13 @@ def build_model(
     if emitted and oracle:
         raise ValueError("emitted models have no oracle twin (the oracle IS "
                          "an independent path; use oracle=False)")
+    module, symmetric = resolve_symmetry(module, cfg)
+    if symmetric and emitted:
+        raise ValueError(
+            f"SYMMETRY {cfg.symmetry}: the emitted kernel source declares "
+            "no field roles; build the hand model (--hand)"
+        )
+
     def _sound(built):
         # build-time encoding-soundness gate (analysis; KSPEC_ANALYZE=0
         # disables): an unsound (config, schema) pair refuses to build —
@@ -237,22 +308,25 @@ def build_model(
         elif module in KAFKA_VARIANTS:
             from ..models import variants as m
 
-            built = (m.make_oracle if oracle else m.make_model)(module, kcfg, invs)
+            built = (m.make_oracle if oracle else m.make_model)(
+                module, kcfg, invs, symmetric=symmetric)
         else:
             from ..models import kip320 as m
 
             if module == "Kip320":
-                built = (m.make_oracle if oracle else m.make_model)(kcfg, invs)
+                built = (m.make_oracle if oracle else m.make_model)(
+                    kcfg, invs, symmetric=symmetric)
             else:
                 built = (
                     m.make_first_try_oracle if oracle else m.make_first_try_model
-                )(kcfg, invs)
+                )(kcfg, invs, symmetric=symmetric)
         # Partitions = K (authored constant, not in the reference): the
         # K-partition product space — the reading of the "5 brokers /
         # 3 partitions" stretch workload (BASELINE.md note; models/product.py)
         built = _with_names(built, c)
         k = _setlen(c.get("Partitions", 1))
         if k > 1:
+            # (a model under SYMMETRY is refused there, by name)
             from ..models.product import product_model, product_oracle
 
             built = (product_oracle if oracle else product_model)(built, k)

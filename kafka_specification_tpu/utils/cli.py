@@ -1362,6 +1362,25 @@ def main(argv=None):
     model = _build_or_fail(
         module, tlc_cfg, emitted=_kernel_source(args, module)
     )
+    if args.cmd == "check" and getattr(model, "symmetry", None) is not None:
+        # the engines raise on these too (engine.bfs.check, check_sharded);
+        # said here first, as a usage error and before a run directory opens
+        refused = [flag for flag, given in (
+            ("--sharded", args.sharded),
+            ("--checkpoint", args.checkpoint),
+            ("--integrity-shadow", getattr(args, "integrity_shadow", None)),
+        ) if given]
+        if refused:
+            print(
+                f"error: SYMMETRY {model.symmetry.operator} "
+                f"({tlc_cfg.symmetry!r} in the .cfg) is supported on the "
+                f"single-device engine without {' / '.join(refused)}: a "
+                "state's key is its orbit's there, which the sharded "
+                "engine does not compute and a checkpoint's validation "
+                "cannot recompute; drop the option or the SYMMETRY stanza",
+                file=sys.stderr,
+            )
+            return 2
     run_ctx = None
     if args.cmd == "check" and _is_obs_coordinator():
         # every check invocation gets a run directory: manifest + stats +

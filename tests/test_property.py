@@ -724,7 +724,7 @@ def test_fused_successors_without_the_squeeze(constrained_chunk, monkeypatch):
         seen_inputs["w"] = cand.shape[0]
         cand, parent, actid, valid, _n, _ovf = pl.squeeze_stage(
             cand, parent, actid, valid, W, K)
-        hi, lo = pl.fp_stage(cand, valid, fused.spec)
+        hi, lo, _orbit = pl.fp_stage(cand, valid, fused.model)
         return real(cand, parent, actid, valid, hi, lo, *rest, **kw)
 
     monkeypatch.setattr(pl, "sorted_dedup_stage", squeezed_first)

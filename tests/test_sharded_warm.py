@@ -206,13 +206,19 @@ def test_level_records_and_spans_of_a_sharded_run(tmp_path, pipeline):
         # the host's blocked time is part of the level's
         assert rec["dedup_lanes"] >= rec["enabled_candidates"]
         assert rec["chunks"] >= 1
-        assert 0 <= rec["fetch_ms"] <= rec["level_ms"] + 0.1
-        assert 0 <= rec["put_ms"] <= rec["level_ms"] + 0.1
+        assert rec["fetch_ms"] >= 0 and rec["put_ms"] >= 0
         assert rec["dispatches"] >= rec["shard_launches"] >= 1
         assert rec["d2h_fetches"] > 0 and rec["d2h_bytes"] > 0
         assert rec["h2d_puts"] > 0 and rec["h2d_bytes"] > 0
         assert rec["discarded_dispatches"] <= rec["dispatches"]
         assert sum(rec["shard_new"]) == rec["new"]
+    # the blocked time is part of the run's: a record holds what crossed
+    # since the last one, so level 1's holds the uploads made before its
+    # span began, and a per-level bound (`fetch_ms <= level_ms + 0.1`, until
+    # PR 38) compared host clocks of different windows: it failed once in
+    # the driver's loaded run of PR 37's tree and once in five here
+    assert sum(r["fetch_ms"] + r["put_ms"] for r in recs) <= \
+        res.seconds * 1e3 + 1.0
     assert sum(r["exch_bytes"] for r in recs) == \
         res.stats["exchange_bytes_total"] > 0
     first, second = (_spans(d) for d in dirs)

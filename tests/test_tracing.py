@@ -32,7 +32,9 @@ def _model():
 
 # --- (a) every stage of the vocabulary lands in the lowered programs -------
 
-ALL = set(pl.STAGES)
+# (`canon`, PR 38, is in the programs of a model with a `symmetry` alone:
+# the toy model has none, and tests/test_symmetry.py holds both halves)
+ALL = set(pl.STAGES) - {"canon"}
 DEDUP = {"dedup_sort", "dedup_probe", "dedup_merge"}
 PROGRAMS = {
     # tag: (visited backend, the stages the program contains)
