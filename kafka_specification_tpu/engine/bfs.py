@@ -88,14 +88,33 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, (n - 1)).bit_length()
 
 
+def indexing_equations(jaxpr) -> tuple:
+    """(``gather``, ``scatter*``) equations of a jaxpr, sub-jaxprs
+    included: how often the program it lowers to indexes by gather or
+    scatter (each one XLA gather / scatter, 6-8 ns an element on the chip
+    whatever the axis's length: PERF.md section 6, PR 39)."""
+    gathers = scatters = 0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        gathers += name == "gather"
+        scatters += name.startswith("scatter")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            g, s = indexing_equations(sub)
+            gathers, scatters = gathers + g, scatters + s
+    return gathers, scatters
+
+
 class _CompileOnFirstCall:
     """Cache entry for a freshly built jitted step: the FIRST call is where
     jax traces + XLA compiles (jax.jit is lazy), so exactly that call is
     wrapped in a ``compile`` span — then the wrapper replaces itself with
     the bare jitted function.  This is what lets a warm serving path PROVE
     its cache hits: a job that re-uses every step shows zero compile spans
-    in its trace (service/kernel_cache, docs/service.md).  With no active
-    tracer the wrapper costs one dict store and disappears."""
+    in its trace (service/kernel_cache, docs/service.md).  The span says
+    what the program is made of too: ``gathers`` / ``scatters``
+    (:func:`indexing_equations` of the jaxpr the call traced; read back
+    from jit's own trace cache, so the program is traced once).  With no
+    active tracer the wrapper costs one dict store and disappears."""
 
     def __init__(self, fn, cache: dict, key, **attrs):
         self.fn = fn
@@ -110,7 +129,12 @@ class _CompileOnFirstCall:
         out = self.fn(*args)
         cur = _tr.current_tracer()
         if cur is not None:
-            cur.emit_span("compile", t0, _tr.now(), **self._attrs)
+            t1 = _tr.now()
+            gathers, scatters = indexing_equations(
+                self.fn.trace(*args).jaxpr.jaxpr
+            )
+            cur.emit_span("compile", t0, t1, gathers=gathers,
+                          scatters=scatters, **self._attrs)
         # swap in the bare jitted fn iff this entry is still current (a
         # capacity-growth eviction may already have dropped the key)
         if self._cache.get(self._key) is self:
@@ -356,7 +380,7 @@ class _Step:
         return max(256, T_exp >> 1)
 
     def make_expand(self, bucket: int, shift):
-        """Expansion kernel: (states[B], fvalid[B]) ->
+        """Expansion kernel: (frontier[B, K], states[B], fvalid[B]) ->
         (en_pre[B, C], cand[T, K], valid[T], parent[T], actid[T],
          act_en[n_actions], act_guard[n_actions], overflow[n_actions])
         with T = expand_width(bucket, shift).  act_en counts enabled
@@ -396,7 +420,7 @@ class _Step:
         M = B * C
 
         @stage("expand")
-        def _expand_full(states, fvalid):
+        def _expand_full(frontier, states, fvalid):
             en_pre, en, packed = jax.vmap(self._expand_one)(states)  # [B,C]x2, [B,C,K]
             en = en & fvalid[:, None]
             guard_en = en_pre & fvalid[:, None]
@@ -429,7 +453,7 @@ class _Step:
                 jnp.zeros((n_actions,), bool),
             )
 
-        def _expand_compact(states, fvalid):
+        def _expand_compact(frontier, states, fvalid):
             def _guards_one(state):
                 parts = []
                 for a in model.actions:
@@ -462,7 +486,9 @@ class _Step:
                 with stage("expand"):
                     sidx = cidx // na
                     ch = cidx % na
-                    gstate = jax.tree.map(lambda x: x[sidx], states)
+                    # the parent rows gathered packed, once, then unpacked:
+                    # one K-lane gather an action, not one a field
+                    gstate = jax.vmap(spec.unpack)(frontier[sidx])
                     ok, nxt = jax.vmap(a.kernel)(gstate, ch)
                     ok = ok & rowvalid
                     if model.constraint is not None:
@@ -730,7 +756,7 @@ class _Step:
                 act_en,
                 act_guard,
                 exp_ovf,
-            ) = expand(states, fvalid)
+            ) = expand(frontier, states, fvalid)
             with stage("guard" if shift else "expand"):
                 deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
                 dl_any = jnp.any(deadlocked)

@@ -969,8 +969,9 @@ class FusedPipeline:
 
         def step(frontier, sidx, chloc, rowvalid, vhi, vlo, vn):  # kspec: traced
             with stage("expand"):
-                states = jax.vmap(spec.unpack)(frontier)
-                gstate = jax.tree.map(lambda x: x[sidx], states)
+                # the chunk's parent rows gathered packed, once ([W, K]
+                # lanes), then unpacked: not one gather a field
+                gstate = jax.vmap(spec.unpack)(frontier[sidx])
                 cand_parts, ok_parts = [], []
                 for i, a in enumerate(model.actions):
                     # kspec: allow(host-materialization) offs is the
@@ -1567,7 +1568,7 @@ class DevicePipeline:
                     ) < f_total
                     states = jax.vmap(spec.unpack)(rows)
                 (en_pre, cand, valid, parent, actid, a_en, a_guard,
-                 exp_ovf) = expand(states, fvalid)
+                 exp_ovf) = expand(rows, states, fvalid)
                 with stage("guard"):
                     deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
                 viol_any, viol_idx = invariant_stage(
@@ -1734,7 +1735,7 @@ class DevicePipeline:
                     ) < f_total
                     states = jax.vmap(spec.unpack)(rows)
                 (en_pre, cand, valid, parent, actid, a_en, a_guard,
-                 exp_ovf) = expand(states, fvalid)
+                 exp_ovf) = expand(rows, states, fvalid)
                 with stage("guard"):
                     deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
                 viol_any, viol_idx = invariant_stage(

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 
 from ..ops.packing import Field, StateSpec
 from ..oracle.interp import OracleAction, OracleModel
-from .base import Action, Invariant, Model
+from .base import Action, Invariant, Model, read, write
 
 NIL = -1  # AsyncIsr.tla:38
 LEADER = 0  # WLOG (Leader \in Replicas, :29)
@@ -164,7 +164,7 @@ def controller_shrink_isr(cfg: AsyncIsrConfig):
             **s,
             "c_isr": isr,
             "c_ver": ver,
-            "upd_isr": s["upd_isr"].at[ver].set(isr),
+            "upd_isr": write(s["upd_isr"], ver, isr),
         }
 
     return Action("ControllerShrinkIsr", cfg.n, kernel,
@@ -175,14 +175,14 @@ def controller_handle_request(cfg: AsyncIsrConfig):
     # ControllerHandleRequest (:81-86): pick any pending request whose version
     # CASes against the controller's; choice = the request's ISR subset.
     def kernel(s, subset):
-        pending = ((s["req_bits"][s["c_ver"]] >> subset) & 1) == 1
+        pending = ((read(s["req_bits"], s["c_ver"]) >> subset) & 1) == 1
         enabled = pending & (s["c_ver"] < cfg.max_version)
         ver = jnp.minimum(s["c_ver"] + 1, cfg.max_version)
         return enabled, {
             **s,
             "c_isr": subset,
             "c_ver": ver,
-            "upd_isr": s["upd_isr"].at[ver].set(subset),
+            "upd_isr": write(s["upd_isr"], ver, subset),
         }
 
     return Action("ControllerHandleRequest", 1 << cfg.n, kernel,
@@ -197,8 +197,9 @@ def leader_request_shrink_isr(cfg: AsyncIsrConfig):
         isr = s["l_isr"] & ~_bit(r)
         return enabled, {
             **s,
-            "req_bits": s["req_bits"].at[s["l_ver"]].set(
-                s["req_bits"][s["l_ver"]] | (jnp.int32(1) << isr)
+            "req_bits": write(
+                s["req_bits"], s["l_ver"],
+                read(s["req_bits"], s["l_ver"]) | (jnp.int32(1) << isr),
             ),
             "l_pend": s["l_pend"] | isr,
             "l_pver": s["l_ver"],
@@ -211,12 +212,15 @@ def leader_request_shrink_isr(cfg: AsyncIsrConfig):
 def leader_request_expand_isr(cfg: AsyncIsrConfig):
     # LeaderRequestExpandIsr (:102-115): candidate must have reached the HW
     def kernel(s, r):
-        enabled = (((s["l_isr"] >> r) & 1) == 0) & (s["offs"][r] >= _hw(cfg, s))
+        enabled = (((s["l_isr"] >> r) & 1) == 0) & (
+            read(s["offs"], r) >= _hw(cfg, s)
+        )
         isr = s["l_isr"] | _bit(r)
         return enabled, {
             **s,
-            "req_bits": s["req_bits"].at[s["l_ver"]].set(
-                s["req_bits"][s["l_ver"]] | (jnp.int32(1) << isr)
+            "req_bits": write(
+                s["req_bits"], s["l_ver"],
+                read(s["req_bits"], s["l_ver"]) | (jnp.int32(1) << isr),
             ),
             "l_pend": s["l_pend"] | isr,
             "l_pver": s["l_ver"],
@@ -233,8 +237,9 @@ def leader_write(cfg: AsyncIsrConfig):
         enabled = s["offs"][LEADER] < cfg.max_offset
         return enabled, {
             **s,
-            "offs": s["offs"].at[LEADER].set(
-                jnp.minimum(s["offs"][LEADER] + 1, cfg.max_offset)
+            "offs": write(
+                s["offs"], LEADER,
+                jnp.minimum(s["offs"][LEADER] + 1, cfg.max_offset),
             ),
         }
 
@@ -244,10 +249,10 @@ def leader_write(cfg: AsyncIsrConfig):
 def leader_handle_update(cfg: AsyncIsrConfig):
     # LeaderHandleUpdate (:121-129): adopt any newer update, clear pending
     def kernel(s, v):
-        enabled = (s["upd_isr"][v] >= 0) & (v > s["l_ver"])
+        enabled = (read(s["upd_isr"], v) >= 0) & (v > s["l_ver"])
         return enabled, {
             **s,
-            "l_isr": jnp.maximum(s["upd_isr"][v], 0),
+            "l_isr": jnp.maximum(read(s["upd_isr"], v), 0),
             "l_ver": v,
             "l_pend": jnp.int32(0),
             "l_pver": jnp.int32(NIL),
@@ -260,10 +265,12 @@ def leader_handle_update(cfg: AsyncIsrConfig):
 def follower_replicate(cfg: AsyncIsrConfig):
     # FollowerReplicate (:131-135)
     def kernel(s, r):
-        enabled = (r != LEADER) & (s["offs"][r] < s["offs"][LEADER])
+        enabled = (r != LEADER) & (read(s["offs"], r) < s["offs"][LEADER])
         return enabled, {
             **s,
-            "offs": s["offs"].at[r].set(jnp.minimum(s["offs"][r] + 1, cfg.max_offset)),
+            "offs": write(
+                s["offs"], r, jnp.minimum(read(s["offs"], r) + 1, cfg.max_offset)
+            ),
         }
 
     return Action("FollowerReplicate", cfg.n, kernel,

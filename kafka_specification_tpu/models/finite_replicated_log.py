@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from ..ops.packing import Field, StateSpec
 from ..oracle.interp import OracleAction, OracleModel
-from .base import Action, Invariant, Model
+from .base import Action, Invariant, Model, read, write
 
 NIL = -1
 
@@ -51,23 +51,26 @@ def make_model(
         # Append(replica, record, offset), offset = endOffset, ~IsFull (:99-103)
         r = choice // R
         record = choice % R
-        end = state["end"][r]
+        end = read(state["end"], r)
         enabled = end < L
         off = jnp.minimum(end, L - 1)
-        rec = state["rec"].at[r, off].set(jnp.where(enabled, record, state["rec"][r, off]))
-        new_end = state["end"].at[r].set(jnp.where(enabled, end + 1, end))
+        rec = write(
+            state["rec"], (r, off),
+            jnp.where(enabled, record, read(state["rec"], r, off)),
+        )
+        new_end = write(state["end"], r, jnp.where(enabled, end + 1, end))
         return enabled, {"end": new_end, "rec": rec}
 
     def truncate_to(state, choice):
         # TruncateTo(replica, newEndOffset <= endOffset); Nil-fill (:105-109)
         r = choice // L
         new_end = choice % L
-        end = state["end"][r]
+        end = read(state["end"], r)
         enabled = new_end <= end
         offs = jnp.arange(L)
-        row = jnp.where(offs < new_end, state["rec"][r], NIL)
-        rec = state["rec"].at[r].set(jnp.where(enabled, row, state["rec"][r]))
-        ends = state["end"].at[r].set(jnp.where(enabled, new_end, end))
+        row = jnp.where(offs < new_end, read(state["rec"], r), NIL)
+        rec = write(state["rec"], r, jnp.where(enabled, row, read(state["rec"], r)))
+        ends = write(state["end"], r, jnp.where(enabled, new_end, end))
         return enabled, {"end": ends, "rec": rec}
 
     def replicate_to(state, choice):
@@ -77,14 +80,15 @@ def make_model(
         src = choice // (N - 1)
         dst_i = choice % (N - 1)
         dst = jnp.where(dst_i >= src, dst_i + 1, dst_i)  # Replicas \ {src}
-        off = state["end"][dst]
-        enabled = (off < L) & (off < state["end"][src])
+        off = read(state["end"], dst)
+        enabled = (off < L) & (off < read(state["end"], src))
         offc = jnp.minimum(off, L - 1)
-        record = state["rec"][src, offc]
-        rec = state["rec"].at[dst, offc].set(
-            jnp.where(enabled, record, state["rec"][dst, offc])
+        record = read(state["rec"], src, offc)
+        rec = write(
+            state["rec"], (dst, offc),
+            jnp.where(enabled, record, read(state["rec"], dst, offc)),
         )
-        ends = state["end"].at[dst].set(jnp.where(enabled, off + 1, off))
+        ends = write(state["end"], dst, jnp.where(enabled, off + 1, off))
         return enabled, {"end": ends, "rec": rec}
 
     def type_ok(state):

@@ -45,7 +45,7 @@ from ..oracle.interp import (
     OracleSymmetry,
     reduce_by_symmetry,
 )
-from .base import Action, FieldRole, Invariant, Symmetry
+from .base import Action, FieldRole, Invariant, Symmetry, read, write
 
 NONE = -1  # KafkaReplication.tla:38
 NIL = -1  # KafkaReplication.tla:39
@@ -187,15 +187,23 @@ def _member(mask, r):
 
 def _is_true_leader(s, l):
     # IsTrueLeader (:128-131)
-    return (s["qldr"] == l) & (s["ldr"][l] == l) & (s["ep"][l] == s["qep"])
+    return (
+        (s["qldr"] == l)
+        & (read(s["ldr"], l) == l)
+        & (read(s["ep"], l) == s["qep"])
+    )
 
 
 def _caught_up(s, l, f, end_offset):
     # IsFollowerCaughtUp(leader, follower, endOffset) (:219-225):
     # following /\ (endOffset = 0 \/ (leader has a record at endOffset-1
     # /\ follower HasOffset(endOffset-1)))
-    following = s["ldr"][f] == l
-    nonzero = (end_offset > 0) & (end_offset <= s["end"][l]) & (s["end"][f] >= end_offset)
+    following = read(s["ldr"], f) == l
+    nonzero = (
+        (end_offset > 0)
+        & (end_offset <= read(s["end"], l))
+        & (read(s["end"], f) >= end_offset)
+    )
     return following & ((end_offset == 0) | nonzero)
 
 
@@ -210,9 +218,9 @@ def _truncate_log(s, r, new_end):
     caller must guard new_end <= end[r]."""
     offs = jnp.arange(s["rid"].shape[1])
     keep = offs < new_end
-    rid = s["rid"].at[r].set(jnp.where(keep, s["rid"][r], NIL))
-    repoch = s["repoch"].at[r].set(jnp.where(keep, s["repoch"][r], NIL))
-    end = s["end"].at[r].set(new_end)
+    rid = write(s["rid"], r, jnp.where(keep, read(s["rid"], r), NIL))
+    repoch = write(s["repoch"], r, jnp.where(keep, read(s["repoch"], r), NIL))
+    end = write(s["end"], r, new_end)
     return rid, repoch, end
 
 
@@ -229,8 +237,8 @@ def _ctrl_update_isr(cfg, s, new_leader, new_isr):
         "qep": ec,
         "qldr": new_leader,
         "qisr": new_isr,
-        "req_ldr": s["req_ldr"].at[ec].set(new_leader),
-        "req_isr": s["req_isr"].at[ec].set(new_isr),
+        "req_ldr": write(s["req_ldr"], ec, new_leader),
+        "req_isr": write(s["req_isr"], ec, new_isr),
     }
 
 
@@ -271,14 +279,14 @@ def controller_elect_leader(cfg: Config):
 def become_leader(cfg: Config):
     # BecomeLeader (:186-195), choice = request (keyed by its unique epoch)
     def kernel(s, e):
-        l = s["req_ldr"][e]
+        l = read(s["req_ldr"], e)
         lc = jnp.clip(l, 0, cfg.n - 1)
-        enabled = (l >= 0) & (e > s["ep"][lc])  # leader # None /\ epoch newer
+        enabled = (l >= 0) & (e > read(s["ep"], lc))  # leader # None /\ epoch newer
         return enabled, {
             **s,
-            "ep": s["ep"].at[lc].set(e),
-            "ldr": s["ldr"].at[lc].set(lc),
-            "isr": s["isr"].at[lc].set(s["req_isr"][e]),
+            "ep": write(s["ep"], lc, e),
+            "ldr": write(s["ldr"], lc, lc),
+            "isr": write(s["isr"], lc, read(s["req_isr"], e)),
             # hw unchanged — the stale-HW subtlety (:183-185, :191)
         }
 
@@ -289,16 +297,20 @@ def become_leader(cfg: Config):
 def leader_write(cfg: Config):
     # LeaderWrite (:202-207), choice = replica; id/offset are forced
     def kernel(s, r):
-        end = s["end"][r]
-        enabled = (s["ldr"][r] == r) & (s["nrid"] < cfg.r) & (end < cfg.l)
+        end = read(s["end"], r)
+        enabled = (read(s["ldr"], r) == r) & (s["nrid"] < cfg.r) & (end < cfg.l)
         off = jnp.minimum(end, cfg.l - 1)
         return enabled, {
             **s,
-            "rid": s["rid"].at[r, off].set(jnp.where(enabled, s["nrid"], s["rid"][r, off])),
-            "repoch": s["repoch"].at[r, off].set(
-                jnp.where(enabled, s["ep"][r], s["repoch"][r, off])
+            "rid": write(
+                s["rid"], (r, off),
+                jnp.where(enabled, s["nrid"], read(s["rid"], r, off)),
             ),
-            "end": s["end"].at[r].set(jnp.where(enabled, end + 1, end)),
+            "repoch": write(
+                s["repoch"], (r, off),
+                jnp.where(enabled, read(s["ep"], r), read(s["repoch"], r, off)),
+            ),
+            "end": write(s["end"], r, jnp.where(enabled, end + 1, end)),
             "nrid": jnp.minimum(s["nrid"] + 1, cfg.r),
         }
 
@@ -313,7 +325,7 @@ def _quorum_update(s, l, new_isr):
     return enabled, {
         **s,
         "qisr": new_isr,
-        "isr": s["isr"].at[l].set(new_isr),
+        "isr": write(s["isr"], l, new_isr),
     }
 
 
@@ -321,9 +333,9 @@ def leader_shrink_isr(cfg: Config):
     # LeaderShrinkIsr (:233-239), choice = (leader, replica in isr \ {leader})
     def kernel(s, c):
         l, f = c // cfg.n, c % cfg.n
-        in_isr = (f != l) & _member(s["isr"][l], f)
-        lagging = ~_caught_up(s, l, f, s["end"][l])
-        ok, nxt = _quorum_update(s, l, s["isr"][l] & ~_bit(f))
+        in_isr = (f != l) & _member(read(s["isr"], l), f)
+        lagging = ~_caught_up(s, l, f, read(s["end"], l))
+        ok, nxt = _quorum_update(s, l, read(s["isr"], l) & ~_bit(f))
         return in_isr & lagging & ok, nxt
 
     return Action("LeaderShrinkIsr", cfg.n * cfg.n, kernel,
@@ -334,9 +346,9 @@ def leader_expand_isr(cfg: Config):
     # LeaderExpandIsr (:248-254), choice = (leader, replica not in isr)
     def kernel(s, c):
         l, f = c // cfg.n, c % cfg.n
-        outside = ~_member(s["isr"][l], f)
-        caught = _caught_up(s, l, f, s["hw"][l])
-        ok, nxt = _quorum_update(s, l, s["isr"][l] | _bit(f))
+        outside = ~_member(read(s["isr"], l), f)
+        caught = _caught_up(s, l, f, read(s["hw"], l))
+        ok, nxt = _quorum_update(s, l, read(s["isr"], l) | _bit(f))
         return outside & caught & ok, nxt
 
     return Action("LeaderExpandIsr", cfg.n * cfg.n, kernel,
@@ -347,13 +359,13 @@ def leader_inc_high_watermark(cfg: Config):
     # LeaderIncHighWatermark (:264-271), choice = leader; offset forced = hw.
     # No epoch verification — the pre-KIP-320 hole (:256-263).
     def kernel(s, l):
-        hw = s["hw"][l]
-        presumes = s["ldr"][l] == l
+        hw = read(s["hw"], l)
+        presumes = read(s["ldr"], l) == l
         in_offsets = hw < cfg.l  # \E offset \in Offsets (:264)
         follows = (s["ldr"] == l) & (s["end"] > hw)  # HasOffset(f, hw) (:267-269)
-        all_isr = _forall_isr(cfg, s["isr"][l], follows)
+        all_isr = _forall_isr(cfg, read(s["isr"], l), follows)
         enabled = presumes & in_offsets & all_isr
-        return enabled, {**s, "hw": s["hw"].at[l].set(jnp.minimum(hw + 1, cfg.l))}
+        return enabled, {**s, "hw": write(s["hw"], l, jnp.minimum(hw + 1, cfg.l))}
 
     return Action("LeaderIncHighWatermark", cfg.n, kernel,
                   writes=frozenset({"hw"}))
@@ -372,11 +384,11 @@ def become_follower_and_truncate_to(cfg: Config, name: str, trunc_offset_fn):
 
     def kernel(s, c):
         r, e = c // (cfg.e + 1), c % (cfg.e + 1)
-        l = s["req_ldr"][e]
+        l = read(s["req_ldr"], e)
         lc = jnp.clip(l, 0, cfg.n - 1)
-        enabled = (l >= 0) & (lc != r) & (e > s["ep"][r])
+        enabled = (l >= 0) & (lc != r) & (e > read(s["ep"], r))
         toff = trunc_offset_fn(s, lc, r)
-        enabled = enabled & (toff <= s["end"][r])  # TruncateTo guard (FRL:106)
+        enabled = enabled & (toff <= read(s["end"], r))  # TruncateTo guard (FRL:106)
         toff = jnp.clip(toff, 0, cfg.l)
         rid, repoch, end = _truncate_log(s, r, toff)
         return enabled, {
@@ -384,10 +396,10 @@ def become_follower_and_truncate_to(cfg: Config, name: str, trunc_offset_fn):
             "rid": rid,
             "repoch": repoch,
             "end": end,
-            "ep": s["ep"].at[r].set(e),
-            "ldr": s["ldr"].at[r].set(lc),
-            "isr": s["isr"].at[r].set(s["req_isr"][e]),
-            "hw": s["hw"].at[r].set(jnp.minimum(toff, s["hw"][r])),  # (:293)
+            "ep": write(s["ep"], r, e),
+            "ldr": write(s["ldr"], r, lc),
+            "isr": write(s["isr"], r, read(s["req_isr"], e)),
+            "hw": write(s["hw"], r, jnp.minimum(toff, read(s["hw"], r))),  # (:293)
         }
 
     return Action(name, cfg.n * (cfg.e + 1), kernel,
@@ -400,25 +412,33 @@ def follower_replicate(cfg: Config):
     # Unfenced: no epoch check (:297-301).
     def kernel(s, c):
         f, l = c // cfg.n, c % cfg.n
-        off = s["end"][f]
+        off = read(s["end"], f)
         enabled = (
-            (s["ldr"][l] == l)
-            & (s["ldr"][f] == l)
+            (read(s["ldr"], l) == l)
+            & (read(s["ldr"], f) == l)
             & (off < cfg.l)
-            & (off < s["end"][l])
+            & (off < read(s["end"], l))
         )
         offc = jnp.minimum(off, cfg.l - 1)
-        new_hw = jnp.minimum(s["hw"][l], off + 1)  # (:306-309)
+        new_hw = jnp.minimum(read(s["hw"], l), off + 1)  # (:306-309)
         return enabled, {
             **s,
-            "rid": s["rid"].at[f, offc].set(
-                jnp.where(enabled, s["rid"][l, offc], s["rid"][f, offc])
+            "rid": write(
+                s["rid"], (f, offc),
+                jnp.where(
+                    enabled, read(s["rid"], l, offc), read(s["rid"], f, offc)
+                ),
             ),
-            "repoch": s["repoch"].at[f, offc].set(
-                jnp.where(enabled, s["repoch"][l, offc], s["repoch"][f, offc])
+            "repoch": write(
+                s["repoch"], (f, offc),
+                jnp.where(
+                    enabled,
+                    read(s["repoch"], l, offc),
+                    read(s["repoch"], f, offc),
+                ),
             ),
-            "end": s["end"].at[f].set(jnp.where(enabled, off + 1, off)),
-            "hw": s["hw"].at[f].set(jnp.where(enabled, new_hw, s["hw"][f])),
+            "end": write(s["end"], f, jnp.where(enabled, off + 1, off)),
+            "hw": write(s["hw"], f, jnp.where(enabled, new_hw, read(s["hw"], f))),
         }
 
     return Action("FollowerReplicate", cfg.n * cfg.n, kernel,
@@ -434,7 +454,7 @@ def truncate_to_hw_offset(cfg: Config):
     # BecomeFollowerTruncateToHighWatermark: truncate to own HW
     # (KafkaTruncateToHighWatermark.tla:29-31)
     def fn(s, l, r):
-        return s["hw"][r]
+        return read(s["hw"], r)
 
     return fn
 
@@ -447,21 +467,24 @@ def kip101_offset(cfg: Config):
 
     def fn(s, l, r):
         offs = jnp.arange(cfg.l)
-        r_end = s["end"][r]
-        epoch = s["repoch"][r, jnp.clip(r_end - 1, 0, cfg.l - 1)]  # latest record's epoch
-        l_end = s["end"][l]
+        r_end = read(s["end"], r)
+        # latest record's epoch
+        epoch = read(s["repoch"], r, jnp.clip(r_end - 1, 0, cfg.l - 1))
+        l_end = read(s["end"], l)
         # OffsetsWithLargerEpochs(leader, epoch) (Kip101.tla:27-29)
-        larger = (offs < l_end) & (s["repoch"][l] > epoch)
+        larger = (offs < l_end) & (read(s["repoch"], l) > epoch)
         any_larger = jnp.any(larger)
         min_larger = jnp.min(jnp.where(larger, offs, cfg.l))
-        latest_match = s["repoch"][l, jnp.clip(l_end - 1, 0, cfg.l - 1)] == epoch
+        latest_match = (
+            read(s["repoch"], l, jnp.clip(l_end - 1, 0, cfg.l - 1)) == epoch
+        )
         lookup = jnp.where(
             l_end == 0,
-            s["hw"][r],  # leader empty -> follower hw (Kip101.tla:32-33)
+            read(s["hw"], r),  # leader empty -> follower hw (Kip101.tla:32-33)
             jnp.where(
                 latest_match,
                 l_end,  # latest epoch match -> leader end offset (:34-35)
-                jnp.where(any_larger, min_larger, s["hw"][r]),  # (:36-39)
+                jnp.where(any_larger, min_larger, read(s["hw"], r)),  # (:36-39)
             ),
         )
         return jnp.where(r_end == 0, 0, lookup)  # Kip101.tla:42-43
@@ -480,15 +503,15 @@ def kip279_offset(cfg: Config):
     def fn(s, l, r):
         offs = jnp.arange(cfg.l)
         match = (
-            (offs < s["end"][r])
-            & (offs < s["end"][l])
-            & (s["rid"][r] == s["rid"][l])
-            & (s["repoch"][r] == s["repoch"][l])
+            (offs < read(s["end"], r))
+            & (offs < read(s["end"], l))
+            & (read(s["rid"], r) == read(s["rid"], l))
+            & (read(s["repoch"], r) == read(s["repoch"], l))
         )
         any_match = jnp.any(match)
         max_match = jnp.max(jnp.where(match, offs, -1))
         return jnp.where(
-            (s["end"][l] == 0) | ~any_match, 0, max_match + 1
+            (read(s["end"], l) == 0) | ~any_match, 0, max_match + 1
         )
 
     return fn

@@ -24,7 +24,7 @@ from typing import Sequence
 import jax.numpy as jnp
 
 from ..oracle.interp import OracleAction, OracleModel
-from .base import Action, Model
+from .base import Action, Model, read, write
 from . import kafka_replication as kr
 from .kafka_replication import NONE, Config, _bit, _member, _forall_isr
 from .variants import _invariant_kernels, _invariant_oracles, DEFAULT_INVARIANTS
@@ -38,7 +38,11 @@ from .variants import _invariant_kernels, _invariant_oracles, DEFAULT_INVARIANTS
 def _following_epoch(s, l, f):
     # IsFollowingLeaderEpoch (Kip320.tla:39-42): leader presumes leadership,
     # follower follows it, and epochs match.
-    return (s["ldr"][l] == l) & (s["ldr"][f] == l) & (s["ep"][f] == s["ep"][l])
+    return (
+        (read(s["ldr"], l) == l)
+        & (read(s["ldr"], f) == l)
+        & (read(s["ep"], f) == read(s["ep"], l))
+    )
 
 
 def fenced_follower_fetch(cfg: Config):
@@ -46,22 +50,30 @@ def fenced_follower_fetch(cfg: Config):
     # the follower having the leader's epoch.
     def kernel(s, c):
         f, l = c // cfg.n, c % cfg.n
-        off = s["end"][f]
+        off = read(s["end"], f)
         enabled = (
-            _following_epoch(s, l, f) & (off < cfg.l) & (off < s["end"][l])
+            _following_epoch(s, l, f) & (off < cfg.l) & (off < read(s["end"], l))
         )
         offc = jnp.minimum(off, cfg.l - 1)
-        new_hw = jnp.minimum(s["hw"][l], off + 1)
+        new_hw = jnp.minimum(read(s["hw"], l), off + 1)
         return enabled, {
             **s,
-            "rid": s["rid"].at[f, offc].set(
-                jnp.where(enabled, s["rid"][l, offc], s["rid"][f, offc])
+            "rid": write(
+                s["rid"], (f, offc),
+                jnp.where(
+                    enabled, read(s["rid"], l, offc), read(s["rid"], f, offc)
+                ),
             ),
-            "repoch": s["repoch"].at[f, offc].set(
-                jnp.where(enabled, s["repoch"][l, offc], s["repoch"][f, offc])
+            "repoch": write(
+                s["repoch"], (f, offc),
+                jnp.where(
+                    enabled,
+                    read(s["repoch"], l, offc),
+                    read(s["repoch"], f, offc),
+                ),
             ),
-            "end": s["end"].at[f].set(jnp.where(enabled, off + 1, off)),
-            "hw": s["hw"].at[f].set(jnp.where(enabled, new_hw, s["hw"][f])),
+            "end": write(s["end"], f, jnp.where(enabled, off + 1, off)),
+            "hw": write(s["hw"], f, jnp.where(enabled, new_hw, read(s["hw"], f))),
         }
 
     return Action("FencedFollowerFetch", cfg.n * cfg.n, kernel,
@@ -75,11 +87,11 @@ def fenced_leader_inc_high_watermark(cfg: Config):
     # guard of its own — with an empty local ISR the \A is vacuous and only
     # HasOffset(leader, hw) gates; kept literal.)
     def kernel(s, l):
-        hw = s["hw"][l]
-        has_off = hw < s["end"][l]
+        hw = read(s["hw"], l)
+        has_off = hw < read(s["end"], l)
         cond = _following_epoch_vec(cfg, s, l) & (s["end"] > hw)
-        enabled = has_off & _forall_isr(cfg, s["isr"][l], cond)
-        return enabled, {**s, "hw": s["hw"].at[l].set(jnp.minimum(hw + 1, cfg.l))}
+        enabled = has_off & _forall_isr(cfg, read(s["isr"], l), cond)
+        return enabled, {**s, "hw": write(s["hw"], l, jnp.minimum(hw + 1, cfg.l))}
 
     return Action("FencedLeaderIncHighWatermark", cfg.n, kernel,
                   writes=frozenset({"hw"}))
@@ -87,7 +99,7 @@ def fenced_leader_inc_high_watermark(cfg: Config):
 
 def _following_epoch_vec(cfg, s, l):
     """IsFollowingLeaderEpoch(l, f) for all f as a vector over f."""
-    return (s["ldr"][l] == l) & (s["ldr"] == l) & (s["ep"] == s["ep"][l])
+    return (read(s["ldr"], l) == l) & (s["ldr"] == l) & (s["ep"] == read(s["ep"], l))
 
 
 def fenced_leader_shrink_isr(cfg: Config):
@@ -95,9 +107,9 @@ def fenced_leader_shrink_isr(cfg: Config):
     # not following the current epoch or whose end offset lags.
     def kernel(s, c):
         l, f = c // cfg.n, c % cfg.n
-        in_isr = (f != l) & _member(s["isr"][l], f)
-        stale = ~_following_epoch(s, l, f) | (s["end"][f] < s["end"][l])
-        ok, nxt = kr._quorum_update(s, l, s["isr"][l] & ~_bit(f))
+        in_isr = (f != l) & _member(read(s["isr"], l), f)
+        stale = ~_following_epoch(s, l, f) | (read(s["end"], f) < read(s["end"], l))
+        ok, nxt = kr._quorum_update(s, l, read(s["isr"], l) & ~_bit(f))
         return in_isr & stale & ok, nxt
 
     return Action("FencedLeaderShrinkIsr", cfg.n * cfg.n, kernel,
@@ -110,14 +122,14 @@ def fenced_leader_expand_isr(cfg: Config):
     # HasHighWatermarkReachedCurrentEpoch (:87-92).
     def kernel(s, c):
         l, f = c // cfg.n, c % cfg.n
-        outside = ~_member(s["isr"][l], f)
-        hw = s["hw"][l]
-        follower_at_hw = (hw == 0) | (s["end"][f] >= hw)  # :94-98
-        hw_at_epoch = (hw == s["end"][l]) | (
-            (hw < s["end"][l])
-            & (s["repoch"][l, jnp.minimum(hw, cfg.l - 1)] == s["ep"][l])
+        outside = ~_member(read(s["isr"], l), f)
+        hw = read(s["hw"], l)
+        follower_at_hw = (hw == 0) | (read(s["end"], f) >= hw)  # :94-98
+        hw_at_epoch = (hw == read(s["end"], l)) | (
+            (hw < read(s["end"], l))
+            & (read(s["repoch"], l, jnp.minimum(hw, cfg.l - 1)) == read(s["ep"], l))
         )  # :87-92
-        ok, nxt = kr._quorum_update(s, l, s["isr"][l] | _bit(f))
+        ok, nxt = kr._quorum_update(s, l, read(s["isr"], l) | _bit(f))
         return (
             outside & _following_epoch(s, l, f) & follower_at_hw & hw_at_epoch & ok
         ), nxt
@@ -135,17 +147,17 @@ def fenced_become_follower_and_truncate(cfg: Config):
 
     def kernel(s, c):
         r, e = c // (cfg.e + 1), c % (cfg.e + 1)
-        l = s["req_ldr"][e]
+        l = read(s["req_ldr"], e)
         lc = jnp.clip(l, 0, cfg.n - 1)
         enabled = (
             (l >= 0)
             & (lc != r)
-            & (e > s["ep"][r])
-            & (s["ldr"][lc] == lc)  # ReplicaPresumesLeadership(leader) (:142)
-            & (s["ep"][lc] == e)  # leader on the request's epoch (:143)
+            & (e > read(s["ep"], r))
+            & (read(s["ldr"], lc) == lc)  # ReplicaPresumesLeadership(leader) (:142)
+            & (read(s["ep"], lc) == e)  # leader on the request's epoch (:143)
         )
         toff = trunc(s, lc, r)
-        enabled = enabled & (toff <= s["end"][r])
+        enabled = enabled & (toff <= read(s["end"], r))
         toff = jnp.clip(toff, 0, cfg.l)
         rid, repoch, end = kr._truncate_log(s, r, toff)
         return enabled, {
@@ -153,10 +165,10 @@ def fenced_become_follower_and_truncate(cfg: Config):
             "rid": rid,
             "repoch": repoch,
             "end": end,
-            "ep": s["ep"].at[r].set(e),
-            "ldr": s["ldr"].at[r].set(lc),
-            "isr": s["isr"].at[r].set(s["req_isr"][e]),
-            "hw": s["hw"].at[r].set(jnp.minimum(toff, s["hw"][r])),  # (:145)
+            "ep": write(s["ep"], r, e),
+            "ldr": write(s["ldr"], r, lc),
+            "isr": write(s["isr"], r, read(s["req_isr"], e)),
+            "hw": write(s["hw"], r, jnp.minimum(toff, read(s["hw"], r))),  # (:145)
         }
 
     return Action("FencedBecomeFollowerAndTruncate", cfg.n * (cfg.e + 1),
@@ -172,13 +184,13 @@ def _caught_up_to_epoch(cfg, s, l, f, end_offset):
     # IsFollowerCaughtUpToLeaderEpoch (Kip320FirstTry.tla:49-57): presumed
     # leadership + following + the records at endOffset-1 carry the same
     # epoch on both logs (ids need not match).
-    base = (s["ldr"][l] == l) & (s["ldr"][f] == l)
+    base = (read(s["ldr"], l) == l) & (read(s["ldr"], f) == l)
     off = jnp.clip(end_offset - 1, 0, cfg.l - 1)
     nonzero = (
         (end_offset > 0)
-        & (end_offset <= s["end"][l])
-        & (end_offset <= s["end"][f])
-        & (s["repoch"][f, off] == s["repoch"][l, off])
+        & (end_offset <= read(s["end"], l))
+        & (end_offset <= read(s["end"], f))
+        & (read(s["repoch"], f, off) == read(s["repoch"], l, off))
     )
     return base & ((end_offset == 0) | nonzero)
 
@@ -190,15 +202,15 @@ def ft_follower_truncate(cfg: Config):
 
     def kernel(s, c):
         l, f = c // cfg.n, c % cfg.n
-        base = (s["ldr"][l] == l) & (s["ldr"][f] == l)
-        f_end = s["end"][f]
+        base = (read(s["ldr"], l) == l) & (read(s["ldr"], f) == l)
+        f_end = read(s["end"], f)
         last = jnp.clip(f_end - 1, 0, cfg.l - 1)
         epoch_mismatch = (
             (f_end > 0)
-            & (f_end <= s["end"][l])  # HasOffset(leader, f_end - 1)
-            & (s["repoch"][l, last] != s["repoch"][f, last])
+            & (f_end <= read(s["end"], l))  # HasOffset(leader, f_end - 1)
+            & (read(s["repoch"], l, last) != read(s["repoch"], f, last))
         )
-        needs = (f_end > s["end"][l]) | epoch_mismatch
+        needs = (f_end > read(s["end"], l)) | epoch_mismatch
         toff = trunc(s, l, f)
         enabled = base & needs & (toff <= f_end)
         toff = jnp.clip(toff, 0, cfg.l)
@@ -208,7 +220,7 @@ def ft_follower_truncate(cfg: Config):
             "rid": rid,
             "repoch": repoch,
             "end": end,
-            "hw": s["hw"].at[f].set(jnp.minimum(toff, s["hw"][f])),  # (:81)
+            "hw": write(s["hw"], f, jnp.minimum(toff, read(s["hw"], f))),  # (:81)
         }
 
     return Action("FollowerTruncate", cfg.n * cfg.n, kernel,
@@ -219,18 +231,18 @@ def ft_improved_leader_inc_high_watermark(cfg: Config):
     # ImprovedLeaderIncHighWatermark (Kip320FirstTry.tla:90-97): every ISR
     # member caught up (by epoch) to hw+1.
     def kernel(s, l):
-        hw = s["hw"][l]
-        presumes = s["ldr"][l] == l
-        has_entry = hw < s["end"][l]
+        hw = read(s["hw"], l)
+        presumes = read(s["ldr"], l) == l
+        has_entry = hw < read(s["end"], l)
         off = jnp.minimum(hw, cfg.l - 1)
         cond = (
             (s["ldr"] == l)
-            & (hw + 1 <= s["end"][l])
+            & (hw + 1 <= read(s["end"], l))
             & (hw + 1 <= s["end"])
-            & (s["repoch"][:, off] == s["repoch"][l, off])
+            & (read(s["repoch"], slice(None), off) == read(s["repoch"], l, off))
         )
-        enabled = presumes & has_entry & _forall_isr(cfg, s["isr"][l], cond)
-        return enabled, {**s, "hw": s["hw"].at[l].set(jnp.minimum(hw + 1, cfg.l))}
+        enabled = presumes & has_entry & _forall_isr(cfg, read(s["isr"], l), cond)
+        return enabled, {**s, "hw": write(s["hw"], l, jnp.minimum(hw + 1, cfg.l))}
 
     return Action("ImprovedLeaderIncHighWatermark", cfg.n, kernel,
                   writes=frozenset({"hw"}))
@@ -241,24 +253,32 @@ def ft_follower_fetch(cfg: Config):
     # up (by epoch) to own end offset.
     def kernel(s, c):
         f, l = c // cfg.n, c % cfg.n
-        off = s["end"][f]
+        off = read(s["end"], f)
         enabled = (
             _caught_up_to_epoch(cfg, s, l, f, off)
             & (off < cfg.l)
-            & (off < s["end"][l])
+            & (off < read(s["end"], l))
         )
         offc = jnp.minimum(off, cfg.l - 1)
-        new_hw = jnp.minimum(s["hw"][l], off + 1)
+        new_hw = jnp.minimum(read(s["hw"], l), off + 1)
         return enabled, {
             **s,
-            "rid": s["rid"].at[f, offc].set(
-                jnp.where(enabled, s["rid"][l, offc], s["rid"][f, offc])
+            "rid": write(
+                s["rid"], (f, offc),
+                jnp.where(
+                    enabled, read(s["rid"], l, offc), read(s["rid"], f, offc)
+                ),
             ),
-            "repoch": s["repoch"].at[f, offc].set(
-                jnp.where(enabled, s["repoch"][l, offc], s["repoch"][f, offc])
+            "repoch": write(
+                s["repoch"], (f, offc),
+                jnp.where(
+                    enabled,
+                    read(s["repoch"], l, offc),
+                    read(s["repoch"], f, offc),
+                ),
             ),
-            "end": s["end"].at[f].set(jnp.where(enabled, off + 1, off)),
-            "hw": s["hw"].at[f].set(jnp.where(enabled, new_hw, s["hw"][f])),
+            "end": write(s["end"], f, jnp.where(enabled, off + 1, off)),
+            "hw": write(s["hw"], f, jnp.where(enabled, new_hw, read(s["hw"], f))),
         }
 
     return Action("FollowerFetch", cfg.n * cfg.n, kernel,
@@ -269,9 +289,9 @@ def ft_leader_shrink_isr(cfg: Config):
     # LeaderShrinkIsrBetterFencing (Kip320FirstTry.tla:114-120)
     def kernel(s, c):
         l, f = c // cfg.n, c % cfg.n
-        in_isr = (f != l) & _member(s["isr"][l], f)
-        lagging = ~_caught_up_to_epoch(cfg, s, l, f, s["end"][l])
-        ok, nxt = kr._quorum_update(s, l, s["isr"][l] & ~_bit(f))
+        in_isr = (f != l) & _member(read(s["isr"], l), f)
+        lagging = ~_caught_up_to_epoch(cfg, s, l, f, read(s["end"], l))
+        ok, nxt = kr._quorum_update(s, l, read(s["isr"], l) & ~_bit(f))
         return in_isr & lagging & ok, nxt
 
     return Action("LeaderShrinkIsrBetterFencing", cfg.n * cfg.n, kernel,
@@ -283,14 +303,14 @@ def ft_leader_expand_isr(cfg: Config):
     # HasHighWatermarkReachedCurrentEpoch guard (:122-127).
     def kernel(s, c):
         l, f = c // cfg.n, c % cfg.n
-        outside = ~_member(s["isr"][l], f)
-        hw = s["hw"][l]
+        outside = ~_member(read(s["isr"], l), f)
+        hw = read(s["hw"], l)
         caught = _caught_up_to_epoch(cfg, s, l, f, hw)
-        hw_at_epoch = (hw == s["end"][l]) | (
-            (hw < s["end"][l])
-            & (s["repoch"][l, jnp.minimum(hw, cfg.l - 1)] == s["ep"][l])
+        hw_at_epoch = (hw == read(s["end"], l)) | (
+            (hw < read(s["end"], l))
+            & (read(s["repoch"], l, jnp.minimum(hw, cfg.l - 1)) == read(s["ep"], l))
         )
-        ok, nxt = kr._quorum_update(s, l, s["isr"][l] | _bit(f))
+        ok, nxt = kr._quorum_update(s, l, read(s["isr"], l) | _bit(f))
         return outside & caught & hw_at_epoch & ok, nxt
 
     return Action("LeaderExpandIsrBetterFencing", cfg.n * cfg.n, kernel,
@@ -302,14 +322,14 @@ def ft_become_follower(cfg: Config):
     # keep the log and hw (no truncation on leader change in this design).
     def kernel(s, c):
         r, e = c // (cfg.e + 1), c % (cfg.e + 1)
-        l = s["req_ldr"][e]
+        l = read(s["req_ldr"], e)
         lc = jnp.clip(l, 0, cfg.n - 1)
-        enabled = (l >= 0) & (lc != r) & (e > s["ep"][r])
+        enabled = (l >= 0) & (lc != r) & (e > read(s["ep"], r))
         return enabled, {
             **s,
-            "ep": s["ep"].at[r].set(e),
-            "ldr": s["ldr"].at[r].set(lc),
-            "isr": s["isr"].at[r].set(s["req_isr"][e]),
+            "ep": write(s["ep"], r, e),
+            "ldr": write(s["ldr"], r, lc),
+            "isr": write(s["isr"], r, read(s["req_isr"], e)),
         }
 
     return Action("BecomeFollower", cfg.n * (cfg.e + 1), kernel,

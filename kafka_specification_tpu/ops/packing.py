@@ -9,7 +9,7 @@ always Nil); this module guarantees a canonical bit layout.
 Each field is an integer tensor with a known inclusive value range
 [lo, hi].  Values are stored biased (v - lo) in ceil(log2(hi-lo+1)) bits.
 Elements never straddle a lane boundary (the packer pads instead), which keeps
-pack/unpack a pure gather/shift — friendly to XLA fusion on TPU.
+pack/unpack shifts, masks and static slices — friendly to XLA fusion on TPU.
 """
 
 from __future__ import annotations
@@ -48,8 +48,9 @@ class StateSpec:
     """Bit-layout codec for a tuple of Fields -> uint32[num_lanes].
 
     pack/unpack are vectorizable (jax.vmap) and jit-friendly: the layout is
-    computed once in Python; at trace time packing is a segment-sum of shifted
-    values and unpacking a gather + shift + mask.
+    computed once in Python; at trace time packing is a sum a lane over the
+    static slice of shifted values the lane holds, and unpacking a broadcast
+    of each lane over its elements + shift + mask.
     """
 
     def __init__(self, fields: Sequence[Field], force_hashed: bool = False):
@@ -95,6 +96,10 @@ class StateSpec:
         self._masks = np.asarray([(1 << w) - 1 for w in widths], np.uint32)
         self._los = np.asarray(los, np.int32)
         self._num_elements = len(lane_ids)
+        # the flat elements of lane k are [lane_starts[k], lane_starts[k+1])
+        self._lane_starts = np.searchsorted(
+            self._lane_ids, np.arange(self.num_lanes + 1)
+        )
         # per-field slices into the flat element vector
         self._field_slices = {}
         ofs = 0
@@ -134,13 +139,29 @@ class StateSpec:
         flat = self._flatten(state)
         biased = (flat - self._los).astype(jnp.uint32) & self._masks
         shifted = biased << self._shifts
-        # widths don't overlap within a lane, so sum == bitwise-or
-        lanes = jnp.zeros((self.num_lanes,), jnp.uint32)
-        return lanes.at[self._lane_ids].add(shifted)
+        # widths don't overlap within a lane, so sum == bitwise-or; the
+        # lane table is a constant, so each lane sums its own static slice
+        # (a scatter-add by lane id is a scatter a row under vmap)
+        starts = self._lane_starts
+        return jnp.stack(
+            [
+                jnp.sum(shifted[starts[k]: starts[k + 1]], dtype=jnp.uint32)
+                for k in range(self.num_lanes)
+            ]
+        )
 
     def unpack(self, lanes: jnp.ndarray) -> dict:
         """uint32[num_lanes] -> dict of int32 tensors. vmap over leading axes."""
-        vals = (lanes[self._lane_ids] >> self._shifts) & self._masks
+        starts = self._lane_starts
+        # each lane broadcast over its own elements (static slices of the
+        # constant lane table; indexing by it is a gather a row under vmap)
+        spread = jnp.concatenate(
+            [
+                jnp.broadcast_to(lanes[k], (int(starts[k + 1] - starts[k]),))
+                for k in range(self.num_lanes)
+            ]
+        )
+        vals = (spread >> self._shifts) & self._masks
         flat = vals.astype(jnp.int32) + self._los
         return self._unflatten(flat)
 

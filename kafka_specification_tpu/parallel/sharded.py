@@ -456,7 +456,7 @@ def _make_sharded_step(
 
         states = jax.vmap(spec.unpack)(frontier)
         en_pre, cand, valid, parent, actid, act_en, act_guard, ovf_expand = expand(
-            states, fvalid
+            frontier, states, fvalid
         )
         deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
 
@@ -745,7 +745,7 @@ def _make_sharded_level(
             ) < flen
             states = jax.vmap(spec.unpack)(rows)
             (en_pre, cand, valid, parent, actid, a_en, a_guard,
-             exp_ovf) = expand(states, fvalid)
+             exp_ovf) = expand(rows, states, fvalid)
             deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
             with stage("fingerprint"):
                 hi, lo = fingerprint_lanes(cand, spec.exact64)
@@ -1013,7 +1013,7 @@ def _make_sharded_level_host(
             ) < flen
             states = jax.vmap(spec.unpack)(rows)
             (en_pre, cand, valid, parent, actid, a_en, a_guard,
-             exp_ovf) = expand(states, fvalid)
+             exp_ovf) = expand(rows, states, fvalid)
             deadlocked = fvalid & ~jnp.any(en_pre, axis=1)
             with stage("fingerprint"):
                 hi, lo = fingerprint_lanes(cand, spec.exact64)

@@ -80,3 +80,25 @@ def async_isr_under_constraint():
     return dataclasses.replace(
         async_isr.make_model(async_isr.AsyncIsrConfig(3, 3, 3)),
         constraint=bounded)
+
+
+def hand_models() -> dict:
+    """name -> factory of every hand model at its benchmark cell's constants
+    (3 and 5 brokers, LogSize / MaxRecords / MaxLeaderEpoch 2; AsyncIsr at 4
+    brokers, MaxOffset / MaxVersion 3) and the three historical variants."""
+    from kafka_specification_tpu.models import async_isr, kip320, variants
+    from kafka_specification_tpu.models.kafka_replication import Config
+
+    c3, c5 = Config(3, 2, 2, 2), Config(5, 2, 2, 2)
+    return {
+        "Kip320/3": lambda: kip320.make_model(c3),
+        "Kip320/5": lambda: kip320.make_model(c5),
+        "Kip320FirstTry/3": lambda: kip320.make_first_try_model(c3),
+        "AsyncIsr/4": lambda: async_isr.make_model(
+            async_isr.AsyncIsrConfig(4, 3, 3)),
+        "MCKip320/5": lambda: kip320.make_model(c5, symmetric=True),
+        "Kip101/3": lambda: variants.make_model("Kip101", c3),
+        "Kip279/3": lambda: variants.make_model("Kip279", c3),
+        "KafkaTruncateToHighWatermark/3": lambda: variants.make_model(
+            "KafkaTruncateToHighWatermark", c3),
+    }
