@@ -138,7 +138,8 @@ KAFKA_VARIANTS = ("KafkaTruncateToHighWatermark", "Kip101", "Kip279")
 # with Model.tla; these document their explicit `--module`)
 CFG_MODULE_ALIASES = {"Kip320Stretch": "Kip320", "Kip320FiveBroker": "Kip320",
                       "AsyncIsrFourBroker": "AsyncIsr",
-                      "MCKip320FiveBroker": "MCKip320"}
+                      "MCKip320FiveBroker": "MCKip320",
+                      "Kip279FourBroker": "Kip279"}
 
 # The wrapper modules a TLC user writes to run a spec under SYMMETRY: the
 # corpus's modules define no symmetry set, so the conventional

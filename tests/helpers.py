@@ -2,6 +2,9 @@
 
 from __future__ import annotations
 
+import importlib.util
+import os
+
 import jax
 import numpy as np
 
@@ -102,3 +105,18 @@ def hand_models() -> dict:
         "KafkaTruncateToHighWatermark/3": lambda: variants.make_model(
             "KafkaTruncateToHighWatermark", c3),
     }
+
+
+def perfbench_tests(name: str) -> dict:
+    """The tests and fixtures of `perfbench/tests/<name>.py`, for a tier-1
+    wrapper to take as its own (`globals().update(...)`): `pytest
+    perfbench/tests` is the harness's own judgement of itself, and
+    `perfbench/` is a directory of scripts and no package, so the file is
+    loaded by path."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench", "tests", name + ".py")
+    spec = importlib.util.spec_from_file_location("perfbench_" + name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return {k: v for k, v in vars(mod).items()
+            if not k.startswith("_") and k != "pytest"}

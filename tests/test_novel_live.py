@@ -6,15 +6,7 @@ reader to records with and without the two fields.
 CPU, no chip, seconds.  Loaded by path, as `tests/test_parts.py` loads its
 file: `perfbench/` is a directory of scripts and no package."""
 
-import importlib.util
-import os
+from helpers import perfbench_tests
 
-_PATH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "perfbench", "tests", "test_novel_live.py")
-_spec = importlib.util.spec_from_file_location("perfbench_test_novel_live", _PATH)
-_mod = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(_mod)
-
-# the tests and the fixture they ask for, collected as this module's own
-globals().update({k: v for k, v in vars(_mod).items()
-                  if not k.startswith("_") and k != "pytest"})
+# the tests and the fixtures they ask for, collected as this module's own
+globals().update(perfbench_tests("test_novel_live"))

@@ -8,15 +8,7 @@ perfbench/tests` is the harness's own judgement of itself
 (`selfcheck.py --all`); loaded here by path, since `perfbench/` is a
 directory of scripts and no package."""
 
-import importlib.util
-import os
-
-_PATH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "perfbench", "tests", "test_parts.py")
-_spec = importlib.util.spec_from_file_location("perfbench_test_parts", _PATH)
-_mod = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(_mod)
+from helpers import perfbench_tests
 
 # the tests and the fixtures they ask for, collected as this module's own
-globals().update({k: v for k, v in vars(_mod).items()
-                  if not k.startswith("_") and k != "pytest"})
+globals().update(perfbench_tests("test_parts"))
