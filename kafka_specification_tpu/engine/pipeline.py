@@ -361,19 +361,23 @@ def init_rows_program(model):
 def _sort_first(hi, lo):  # kspec: traced
     """The sort half of stage 4: stable lexsort of the fingerprint pairs
     and the first-occurrence mask over the sorted order -> (hi_s, lo_s,
-    order, first).  ONE source of the winner-selection order for both
-    dedup stages."""
+    order, first, n_live).  ONE source of the winner-selection order for
+    both dedup stages.  The sentinel pairs sort last, so the live lanes
+    are the first ``n_live`` (a device value): what the probes search
+    (``dedup.probe_sorted``'s ``q_n``) and :func:`novel_stage` walks."""
     sent = jnp.uint32(dedup.SENT)
     with stage("dedup_sort"):
         order = jnp.lexsort((lo, hi))
         hi_s, lo_s = hi[order], lo[order]
         invalid_s = (hi_s == sent) & (lo_s == sent)
         first = dedup.first_occurrence_mask(hi_s, lo_s, invalid_s)
-    return hi_s, lo_s, order, first
+        n_live = jnp.sum(~invalid_s, dtype=jnp.int32)
+    return hi_s, lo_s, order, first, n_live
 
 
 #: The level-record fields of :func:`work_counts`, in the vector's order.
 WORK_FIELDS = ("probe_rounds", "probe_rounds_plain",
+               "probe_lanes", "probe_lanes_plain",
                "merge_slots", "merge_slots_plain",
                "novel_rows", "novel_rows_plain")
 #: Two more behind them in the programs of a model with a ``symmetry``
@@ -393,26 +397,27 @@ def work_width(model, visited_backend: str = "device") -> int:
 
 def work_counts(probe=None, merge=None, novel=None, canon=None,  # kspec: traced
                 symmetric: bool = False):
-    """int32[6] (:data:`WORK_FIELDS`; int32[8] in the programs of a model
+    """int32[8] (:data:`WORK_FIELDS`; int32[10] in the programs of a model
     with a symmetry: `canon`, the pair of :data:`CANON_FIELDS`, or
-    `symmetric` alone for a part that canonicalised nothing), the dedup work a program did beside its answers: the two
-    round counts of ``dedup.probe_sorted`` (rounds run, rounds a search of
-    the whole capacity runs), the two slot counts of
+    `symmetric` alone for a part that canonicalised nothing), the dedup work a program did beside its answers: the four
+    counts of ``dedup.probe_sorted`` (rounds run, rounds a search of
+    the whole capacity runs; query lanes searched, query lanes handed),
+    the two slot counts of
     ``dedup.merge_counted`` (slots touched, slots a capacity-wide merge
     touches), then the two row counts of :func:`novel_stage` (rows its
     loops touched, rows the full-width compaction touches); zeros for the
     part not given.  Vectors of several probes, merges and compactions
     add."""
-    zero = jnp.zeros((2,), jnp.int32)
-    parts = [probe, merge, novel]
+    parts = [(probe, 4), (merge, 2), (novel, 2)]
     if symmetric or canon is not None:
-        parts.append(canon)
-    return jnp.concatenate([zero if x is None else x for x in parts])
+        parts.append((canon, 2))
+    return jnp.concatenate([
+        jnp.zeros((n,), jnp.int32) if x is None else x for x, n in parts])
 
 
 def counts_out(act_en, work=None):  # kspec: traced
     """The counts a level program hands the host, in the ONE vector it
-    already fetches: the per-action enabled counts, then the six
+    already fetches: the per-action enabled counts, then the eight
     :func:`work_counts` summed over the program's probes, merges and
     compactions (zeros where it ran none).  :func:`split_counts` is the host's half."""
     if work is None:
@@ -430,7 +435,7 @@ def split_counts(counts, n: int = len(WORK_FIELDS)):
 
 
 def work_record(work):
-    """Summed :func:`work_counts` -> the six level-record fields (eight
+    """Summed :func:`work_counts` -> the eight level-record fields (ten
     under a symmetry)."""
     return dict(zip(WORK_FIELDS + CANON_FIELDS, (int(x) for x in work)))
 
@@ -449,21 +454,19 @@ NOVEL_BLOCK = 8192
 
 
 def novel_block(T: int) -> int:
-    """The block of a width: the fewest blocks no larger than
-    :data:`NOVEL_BLOCK` that cover ``T``, all of one size, so a full width
-    recomputes fewer rows than it has blocks.  (Blocks of 8,192 whatever
-    the width ran 16,384 rows a loop over 9,472 full lanes: 2.02 ms against
-    the full-width form's 1.63; 4,736 twice: 1.70.)"""
-    return -(-T // -(-T // NOVEL_BLOCK))
+    """The block of a width: ``dedup.even_block`` at :data:`NOVEL_BLOCK`
+    (the probe's blocks follow the same rule at its own constant)."""
+    return dedup.even_block(T, NOVEL_BLOCK)
 
 
 def novel_stage(is_new, order, hi_s, lo_s, rank,  # kspec: traced
-                cand, parent, actid, T, K):
+                cand, parent, actid, n_live, T, K):
     """The ``novel`` part of ``compact``: the new states of a sorted
     dedup, compacted to the front in sorted-fingerprint order.
 
     is_new / hi_s / lo_s / rank are in SORTED order (lane i is candidate
-    ``order[i]``); cand / parent / actid in candidate order.  -> (out[T, K],
+    ``order[i]``); cand / parent / actid in candidate order; n_live is
+    :func:`_sort_first`'s, the length of the live prefix.  -> (out[T, K],
     out_parent, out_act, out_hi, out_lo, out_rank, new_n, rows): the
     first new_n rows hold the new states, the rest the fills (zero rows,
     -1 parents and action ids, sentinel fingerprints, rank 0).
@@ -492,8 +495,6 @@ def novel_stage(is_new, order, hi_s, lo_s, rank,  # kspec: traced
         B = novel_block(T)
         csum = jnp.cumsum(is_new, dtype=jnp.int32)
         new_n = jnp.sum(is_new, dtype=jnp.int32)
-        n_live = jnp.sum(~((hi_s == sent) & (lo_s == sent)),
-                         dtype=jnp.int32)
         blocks_live = (n_live + (B - 1)) // B
         blocks_new = (new_n + (B - 1)) // B
 
@@ -567,17 +568,18 @@ def sorted_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
     of the NEW states summed (one masked gather through the sort order)."""
     # minimal-payload sort: only the original index rides through the
     # sort network; state rows/parents are gathered once afterwards
-    hi_s, lo_s, order, first = _sort_first(hi, lo)
-    seen, rank, probe = dedup.probe_sorted(vhi, vlo, vn, hi_s, lo_s)
+    hi_s, lo_s, order, first, n_live = _sort_first(hi, lo)
+    seen, rank, probe = dedup.probe_sorted(vhi, vlo, vn, hi_s, lo_s, n_live)
     is_new = first & ~seen
     if also_seen_in is not None:
         a_hi, a_lo, a_n = also_seen_in
-        a_seen, _ar, a_probe = dedup.probe_sorted(a_hi, a_lo, a_n, hi_s, lo_s)
+        a_seen, _ar, a_probe = dedup.probe_sorted(
+            a_hi, a_lo, a_n, hi_s, lo_s, n_live)
         is_new = is_new & ~a_seen
         probe = probe + a_probe
     (out, out_parent, out_act, out_hi, out_lo, out_rank, new_n,
      rows) = novel_stage(is_new, order, hi_s, lo_s, rank,
-                         cand, parent, actid, T, K)
+                         cand, parent, actid, n_live, T, K)
     slots = None
     if with_merge:
         vhi, vlo, vn, slots = dedup.merge_counted(
@@ -620,8 +622,8 @@ def candidate_dedup_stage(cand, parent, actid, valid, hi, lo,  # kspec: traced
     n_hi, n_lo, n_rank, work): the last is the probe's rounds as
     :func:`work_counts`."""
     sent = jnp.uint32(dedup.SENT)
-    hi_s, lo_s, order, first = _sort_first(hi, lo)
-    seen, rank, probe = dedup.probe_sorted(lhi, llo, ln, hi_s, lo_s)
+    hi_s, lo_s, order, first, n_live = _sort_first(hi, lo)
+    seen, rank, probe = dedup.probe_sorted(lhi, llo, ln, hi_s, lo_s, n_live)
     is_new = first & ~seen
     with stage("compact"), part("novel"):
         # sorted-order compaction: what the level-new merge consumes
@@ -1675,7 +1677,9 @@ class DevicePipeline:
             # visited set by construction, so the rank-scatter merge of
             # the sorted level-new prefix lands the identical sorted
             # visited array
-            _f, rank_v, m_probe = dedup.probe_sorted(vhi, vlo, vn, lhi, llo)
+            # (the level-new set is sorted and `on` long: its live prefix)
+            _f, rank_v, m_probe = dedup.probe_sorted(
+                vhi, vlo, vn, lhi, llo, on)
             vhi, vlo, vn, m_slots = dedup.merge_counted(
                 vhi, vlo, vn, lhi, llo, rank_v, on, vcap
             )

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .dedup import even_block
 from .fingerprint import fingerprint_lanes
 
 SENT = 0xFFFFFFFF  # ops/dedup.SENT (the masked lanes' fingerprint pair)
@@ -59,9 +60,8 @@ CANON_BLOCK = 8192
 
 
 def canon_block(T: int) -> int:
-    """The block of a width: the fewest blocks no larger than
-    :data:`CANON_BLOCK` that cover ``T``, all of one size."""
-    return -(-T // -(-T // CANON_BLOCK))
+    """The block of a width: ``dedup.even_block`` at :data:`CANON_BLOCK`."""
+    return even_block(T, CANON_BLOCK)
 
 
 class Canon:

@@ -13,6 +13,12 @@ length of the set where every entry shares its top bits, and the search is
 then the plain one).  Both lanes of the set ride one ``[2, cap]`` buffer,
 so a round is one gather.  Directory and buffer are rebuilt inside every
 probe from the set it is handed: no carried state, exact for any data.
+The queries are searched in blocks, and only the blocks of their live
+prefix: a caller whose query list is sorted, sentinel pairs last (every
+dedup stage's is: the sort puts them there), says how many lanes are live
+(``q_n``, a device value) and the lanes at or past it are never searched
+and read ``found`` False, ``rank`` 0; a caller with queries in any order
+omits it and every lane is searched (``rank_sorted``, ``member_sorted``).
 The probe's insertion ranks are all the merge needs:
 it places the new entries by them and counts the visited entries' shifts
 from them (histogram + prefix sum), with no search of its own, and it
@@ -53,24 +59,66 @@ def rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
     set_hi/set_lo: uint32[cap] sorted ascending on (hi, lo) for the first
     set_n entries (the rest is sentinel padding).  Binary search bounded
     by a top-bits directory (module docstring), fully vectorized over the
-    queries, which may come in any order.  Returns (found_mask, rank)
-    where rank is the insertion index (bisect_left).
+    queries, which may come in any order: every lane is searched (no
+    ``q_n``; :func:`probe_sorted` is the form for a sorted list with a
+    live prefix).  Returns (found_mask, rank) where rank is the insertion
+    index (bisect_left).
     """
-    found, rank, _rounds = probe_sorted(set_hi, set_lo, set_n, q_hi, q_lo)
+    found, rank, _work = probe_sorted(set_hi, set_lo, set_n, q_hi, q_lo)
     return found, rank
 
 
-def probe_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
-    """:func:`rank_sorted` and what it cost: -> (found, rank, rounds) with
-    ``rounds`` int32[2]: the search rounds this probe ran over its query
+#: The most query lanes one iteration of the probe's block loop searches
+#: (the block is a shape, :func:`even_block`; how many blocks run is a
+#: device value).
+#: Timed on a TPU v5e, the function alone (PERF.md section 6, PR 41), ms at
+#: blocks of 2,048 / 8,192 / 16,384 / 65,536 against the search of every
+#: lane: 278,528 lanes, 31% live, a set of 1.2 million in 4,194,304: 7.15 /
+#: 7.13 / 7.49 / 8.89 against 16.82; 639,000 lanes, 44% live, 3.1 million
+#: in 8,388,608: 20.45 / 20.06 / 21.71 / 24.06 against 43.93; every lane
+#: live: 16.98 / 16.36 / 16.37 / 18.09 against 16.85 and 40.15 / 39.31 /
+#: 42.38 / 43.24 against 43.89.  Up to 16,384 the ``[2, cap]`` buffer stays
+#: in ``S(1)`` wherever the search reads it; from 32,768 the compiler
+#: places it otherwise.
+PROBE_BLOCK = 8192
+
+
+def even_block(n: int, most: int) -> int:
+    """The block of a width: the fewest blocks no larger than ``most``
+    that cover ``n``, all of one size, so a full width recomputes fewer
+    rows than it has blocks (the last block starts early where the block
+    does not divide the width).  (Blocks of 8,192 whatever the width ran
+    16,384 rows a loop over 9,472 full lanes: 2.02 ms against the
+    full-width form's 1.63; 4,736 twice: 1.70; PERF.md section 6, PR 37.)"""
+    return -(-n // -(-n // most))
+
+
+def probe_sorted(set_hi, set_lo, set_n, q_hi, q_lo, q_n=None):
+    """:func:`rank_sorted` and what it cost: -> (found, rank, work).
+
+    q_n: the length of the live prefix of the queries, a device value.
+    The caller promises that every query it cares about lies below
+    ``q_n`` (a sorted list whose sentinel pairs sort last); lanes at or
+    past it are dead: ``found`` False, ``rank`` 0, never searched.  The
+    lanes run in blocks of :func:`even_block` ``(T, PROBE_BLOCK)``,
+    ``ceil(q_n / block)`` of them (a rolled loop), so a probe costs what
+    the chunk holds and not what its layout pads.  ``None``: every lane
+    is searched, in any order.
+
+    ``work`` is int32[4]: the search rounds this probe ran over its query
     lanes (a device value) and the rounds a search over the whole
-    capacity runs (``cap.bit_length()``, a shape).  The level programs
-    sum it over their probes and hand it to the host with their counts
-    (level record ``probe_rounds`` / ``probe_rounds_plain``)."""
+    capacity runs (``cap.bit_length()``, a shape); the query lanes it
+    searched (blocks run x block size, a device value) and the lanes it
+    was handed (``T``, a shape).  The level programs sum it over their
+    probes and hand it to the host with their counts (level record
+    ``probe_rounds`` / ``probe_rounds_plain`` / ``probe_lanes`` /
+    ``probe_lanes_plain``)."""
     with jax.named_scope(_PROBE):
-        found, rank, rounds = _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo)
+        found, rank, rounds, lanes = _rank_sorted(
+            set_hi, set_lo, set_n, q_hi, q_lo, q_n)
         plain = max(1, set_hi.shape[0].bit_length())
-        return found, rank, jnp.stack([rounds, jnp.int32(plain)])
+        return found, rank, jnp.stack(
+            [rounds, jnp.int32(plain), lanes, jnp.int32(q_hi.shape[0])])
 
 
 def directory_bits(n_q: int) -> int:
@@ -110,16 +158,16 @@ def _search(pairs, lo_i, hi_i, q_hi, q_lo, rounds):
     return lo_i
 
 
-def _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
-    """rank_sorted's body, in no stage scope of its own: -> (found, rank,
-    rounds run over the query lanes)."""
-    cap = set_hi.shape[0]
+def _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo, q_n=None):
+    """probe_sorted's body, in no stage scope of its own: -> (found, rank,
+    rounds run over the query lanes, query lanes searched)."""
+    cap, T = set_hi.shape[0], q_hi.shape[0]
     set_n = jnp.asarray(set_n, jnp.int32)
     # both lanes in ONE buffer, read by one gather a round: on the chip a
     # gather out of one [2, cap] operand costs a fifth of two gathers out
     # of the two [cap] lanes (PERF.md section 6, PR 31)
     pairs = jnp.stack([set_hi, set_lo])
-    k = directory_bits(q_hi.shape[0])
+    k = directory_bits(T)
     nb = 1 << k
     # start[b] = lower bound of (b << (32 - k), 0) in the live prefix,
     # start[nb] = set_n.  (hi, lo) order is monotone in hi >> (32 - k), so
@@ -138,11 +186,36 @@ def _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
     # as many rounds as the fullest bucket needs: a handful on hashed
     # fingerprints, bit_length(set_n) where one bucket holds the set
     rounds = _bit_length(jnp.max(start[1:] - start[:-1]))
-    b = (q_hi >> (32 - k)).astype(jnp.int32)
-    rank = _search(pairs, start[b], start[b + 1], q_hi, q_lo, rounds)
-    at = pairs[:, jnp.minimum(rank, cap - 1)]
-    found = (rank < set_n) & (at[0] == q_hi) & (at[1] == q_lo)
-    return found, rank, rounds
+
+    def lanes(q_hi, q_lo):
+        b = (q_hi >> (32 - k)).astype(jnp.int32)
+        rank = _search(pairs, start[b], start[b + 1], q_hi, q_lo, rounds)
+        at = pairs[:, jnp.minimum(rank, cap - 1)]
+        return (rank < set_n) & (at[0] == q_hi) & (at[1] == q_lo), rank
+
+    if q_n is None:
+        return (*lanes(q_hi, q_lo), rounds, jnp.int32(T))
+    B = even_block(T, PROBE_BLOCK)
+    q_n = jnp.clip(jnp.asarray(q_n, jnp.int32), 0, T)
+    blocks = (q_n + (B - 1)) // B
+
+    def block(i, carry):
+        found, rank = carry
+        # a width that is no multiple of its block: the last block starts
+        # early (a slice must lie inside its operand) and searches the
+        # overlap again, to the same answers
+        s = jnp.minimum(i * B, T - B)
+        f, r = lanes(jax.lax.dynamic_slice(q_hi, (s,), (B,)),
+                     jax.lax.dynamic_slice(q_lo, (s,), (B,)))
+        live = (s + jnp.arange(B, dtype=jnp.int32)) < q_n
+        return (jax.lax.dynamic_update_slice(found, f & live, (s,)),
+                jax.lax.dynamic_update_slice(rank, jnp.where(live, r, 0),
+                                             (s,)))
+
+    found, rank = jax.lax.fori_loop(
+        0, blocks, block,
+        (jnp.zeros((T,), bool), jnp.zeros((T,), jnp.int32)))
+    return found, rank, rounds, blocks * B
 
 
 def member_sorted(set_hi, set_lo, set_n, q_hi, q_lo):

@@ -492,6 +492,8 @@ def _make_sharded_step(
             hi_s, lo_s = r_hi[order], r_lo[order]
             invalid_s = (hi_s == sent) & (lo_s == sent)
             first = dedup.first_occurrence_mask(hi_s, lo_s, invalid_s)
+            # the sentinel pairs sort last: the probe's live prefix
+            n_live = jnp.sum(~invalid_s, dtype=jnp.int32)
         ovf_probe = jnp.bool_(False)
         slots = None  # the rank-merge's slot counts, where one runs
         if hash_table:
@@ -514,7 +516,8 @@ def _make_sharded_step(
             rank = jnp.zeros((R,), jnp.int32)
             probe = None
         else:
-            seen, rank, probe = dedup.probe_sorted(vhi, vlo, vn, hi_s, lo_s)
+            seen, rank, probe = dedup.probe_sorted(
+                vhi, vlo, vn, hi_s, lo_s, n_live)
             is_new = first & ~seen
 
         with stage("compact"), part("novel"):
@@ -565,7 +568,7 @@ def _make_sharded_step(
             jnp.stack(viol_idx)[None],
             jnp.any(deadlocked)[None],
             jnp.argmax(deadlocked)[None],
-            # [1, n_actions + 6] -> [D, n_actions + 6]: the enabled counts
+            # [1, n_actions + 8] -> [D, n_actions + 8]: the enabled counts
             # and the probe's and merge's work counts (pipeline.counts_out;
             # this step's own full-width compaction counts no rows)
             counts_out(act_en, work_counts(probe, slots))[None],
@@ -610,7 +613,7 @@ def _make_sharded_step(
             P("d", None),  # viol_idx [D, n_inv]
             P("d"),        # deadlock any
             P("d"),        # deadlock idx
-            P("d", None),  # counts [D, n_actions + 6]
+            P("d", None),  # counts [D, n_actions + 8]
             P("d", None),  # ovf_expand [D, n_actions]
             P("d", None),  # act_guard [D, n_actions]
             P("d"),        # ovf_dest
@@ -886,7 +889,8 @@ def _make_sharded_level(
         # the visited shard by construction, so the rank-scatter merge
         # of the sorted level-new prefix lands the identical sorted
         # visited array
-        _s, rank_v, m_probe = dedup.probe_sorted(vhi, vlo, vn, lhi, llo)
+        # (the level-new set is sorted and `on` long: its live prefix)
+        _s, rank_v, m_probe = dedup.probe_sorted(vhi, vlo, vn, lhi, llo, on)
         vhi, vlo, vn, m_slots = dedup.merge_counted(
             vhi, vlo, vn, lhi, llo, rank_v, on, vcap
         )
@@ -900,7 +904,7 @@ def _make_sharded_level(
             vlo[None],
             vn[None],
             vkind[None], vshard[None], vinv[None], vidx[None],
-            # [1, n_actions + 6]: enabled counts, dedup work counts
+            # [1, n_actions + 8]: enabled counts, dedup work counts
             counts_out(act_en, work + work_counts(m_probe, m_slots))[None],
             agmax[None],
             dc[None], dxh[None], dxl[None],  # digest accumulator...
@@ -930,7 +934,7 @@ def _make_sharded_level(
             P("d", None),  # merged visited lo
             P("d"),        # merged visited counts
             P("d"), P("d"), P("d"), P("d"),  # verdict kind/shard/inv/idx
-            P("d", None),  # counts [D, n_actions + 6]
+            P("d", None),  # counts [D, n_actions + 8]
             P("d", None),  # agmax [D, n_actions]
             P("d"), P("d"), P("d"),  # digest count/xor_hi/xor_lo
             P("d", None),  # digest sum limbs [D, 4]
@@ -1165,7 +1169,7 @@ def _make_sharded_level_host(
             P("d"),        # candidate fingerprint lo lanes
             P("d"),        # per-shard pre-probe candidate counts
             P("d"), P("d"), P("d"), P("d"),  # verdict kind/shard/inv/idx
-            P("d", None),  # counts [D, n_actions + 6]
+            P("d", None),  # counts [D, n_actions + 8]
             P("d", None),  # agmax [D, n_actions]
             P("d", None),  # sent framing accumulator [D, 5]
             P("d", None),  # recv framing accumulator [D, 5]

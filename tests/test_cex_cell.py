@@ -208,6 +208,10 @@ def test_multi_chunk_cut_reports_the_chunk_it_dropped(tmp_path, overlap):
     # two fused launches a committed chunk, launch 1 and 2 of the dropped one
     assert rec["successor_launches"] == 2 * 4
     assert rec["dispatches"] == 2 * (4 + dropped)
+    # the probe's lanes are the committed chunks' too: one probe a chunk
+    # over the layout dedup was handed, its live prefix searched
+    assert 0 < rec["probe_lanes"] <= rec["probe_lanes_plain"] \
+        == rec["dedup_lanes"]
     discarded = [s for s in _spans(tmp_path / "run")
                  if s["span"] == "dispatch" and s.get("discarded")]
     assert [s["program"] for s in discarded] == ["fsc"] * dropped
@@ -229,6 +233,9 @@ def test_whole_level_program_cut_books_the_chunks_it_ran(tmp_path):
     assert (rec["rows_committed"], rec["chunks_committed"]) == (1024, 4)
     assert (rec["chunks_discarded"], rec["discarded_dispatches"]) == (0, 0)
     assert (rec["successor_launches"], rec["dispatches"]) == (1, 1)
+    # two probes a chunk it ran and the level-new rank
+    assert 0 < rec["probe_lanes"] < rec["probe_lanes_plain"]
+    assert rec["probe_lanes_plain"] > 2 * rec["dedup_lanes"]
 
 
 # --- (c) the spans ----------------------------------------------------------

@@ -244,10 +244,11 @@ def test_cut_level_carries_the_probe_rounds(tmp_path):
     """The level a verdict cuts (the rejected KIP-320 design at three
     replicas and one record a log, the smallest constants at which it
     fails: configs/Kip320FirstTry.cfg's own job is the slow set's) has the
-    two fields too; verdict and counts are the ones tests/test_cex_cell.py
+    probe's four fields too; verdict and counts are the ones tests/test_cex_cell.py
     replays through the oracle."""
     from kafka_specification_tpu.models import kip320
     from kafka_specification_tpu.models.kafka_replication import Config
+    from kafka_specification_tpu.ops import dedup
 
     model = kip320.make_first_try_model(Config(3, 1, 1, 2))
     res = check(model, min_bucket=1024, run=RunContext(str(tmp_path / "r")))
@@ -259,10 +260,63 @@ def test_cut_level_carries_the_probe_rounds(tmp_path):
     assert cut["probe_rounds_plain"] > 0 and cut["dispatches"] > 0
     _assert_merge_slots(res.stats["levels"] + [cut])
     assert cut["merge_slots"] > 0
+    _assert_probe_lanes(res.stats["levels"] + [cut], dedup.PROBE_BLOCK)
+    assert 0 < cut["probe_lanes"] <= cut["probe_lanes_plain"] \
+        == cut["dedup_lanes"]
     # and the new states' compaction's rows, over its committed chunks
     for rec in res.stats["levels"] + [cut]:
         assert 0 < rec["novel_rows"] and \
             rec["novel_rows_plain"] == rec["dedup_lanes"], rec
+
+
+# --- the probe's lane counts (level records, both engines) --------------
+
+def _assert_probe_lanes(records, block):
+    """Every record: whole blocks of query lanes searched, no more than
+    the lanes the probes were handed plus a block's rounding a probe (a
+    width that is no multiple of its block searches its overlap twice),
+    and at least the candidates the level's dispatches held."""
+    for rec in records:
+        assert 0 <= rec["probe_lanes"] <= \
+            rec["probe_lanes_plain"] + 3 * block * rec["dispatches"], rec
+        if rec["dispatches"]:
+            assert rec["probe_lanes_plain"] >= rec["dedup_lanes"] > 0, rec
+            # (the cut level's record has no candidate count)
+            assert rec["probe_lanes"] >= rec.get("enabled_candidates", 0), rec
+
+
+@pytest.mark.parametrize("pipeline", [None, "device"],
+                         ids=["fused", "whole-level"])
+def test_level_records_carry_the_probe_lanes(tmp_path, monkeypatch, pipeline):
+    """configs/Kip320.cfg cut to depth 7 at a probe block of 256 lanes,
+    twice: every level record says how many query lanes its probes searched
+    (blocks of the live prefix of each probe's sorted queries) beside the
+    lanes they were handed; one probe a dispatch on the fused path, so
+    handed == `dedup_lanes` there, and two a chunk and the level-new rank
+    in a whole-level program; the two runs agree to the lane, the probes
+    search a fraction of the layout where a level is many blocks wide, the
+    counts are the golden's, and the emitted stream does not gain the
+    fields."""
+    from kafka_specification_tpu.ops import dedup
+    from kafka_specification_tpu.utils.cfg import build_model, parse_cfg
+
+    monkeypatch.setattr(dedup, "PROBE_BLOCK", 256)
+    model = build_model("Kip320", parse_cfg("configs/Kip320.cfg"))
+    runs = []
+    for i in range(2):
+        run = RunContext(str(tmp_path / f"run{i}"))
+        res = check(model, max_depth=7, run=run, pipeline=pipeline)
+        assert res.ok and res.levels == KIP320_LEVELS_TO_8[:8]
+        _assert_probe_lanes(res.stats["levels"], 256)
+        runs.append([(r["probe_lanes"], r["probe_lanes_plain"],
+                      r["dedup_lanes"]) for r in res.stats["levels"]])
+        assert all("probe_lanes" not in r for r in _records(run.stats_path))
+    assert runs[0] == runs[1]
+    for _lanes, plain, handed in runs[0]:
+        assert plain == handed if pipeline is None else plain >= handed
+    if pipeline == "device":  # the last level ran the whole-level program
+        assert runs[0][-1][1] > 2 * runs[0][-1][2]
+    assert sum(r[0] for r in runs[0]) < 0.6 * sum(r[1] for r in runs[0])
 
 
 # --- the merge's slot counts (level records, both engines) --------------

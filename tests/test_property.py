@@ -185,6 +185,8 @@ def _assert_rank(keys, cap, q):
     rounds = np.asarray(rounds)
     assert 0 <= rounds[0] <= max(n, 1).bit_length() <= rounds[1]
     assert rounds[1] == max(1, cap.bit_length())
+    # no q_n: every lane is searched, whatever order the queries come in
+    assert rounds[2] == rounds[3] == len(q)
     return rounds
 
 
@@ -254,6 +256,157 @@ def test_probe_rounds_follow_the_fullest_bucket():
         assert rounds[0] <= limit and rounds[1] == cap.bit_length()
     # the one-bucket set ran (nearly) the plain count: the search is today's
     assert rounds[0] >= n.bit_length() - 1
+
+
+# --------------------------------------------------------------------------
+# the live prefix (probe_sorted's q_n): sorted queries, sentinel pairs last,
+# searched in blocks of the first q_n lanes only
+# --------------------------------------------------------------------------
+
+def _assert_live_prefix(keys, cap, q, T, q_n, block, monkeypatch):
+    """probe_sorted(..., q_n) at a block of `block` lanes over the sorted
+    queries `q` padded with sentinel pairs to `T` lanes: `found` and `rank`
+    are the present form's (no q_n) bit for bit on every lane below q_n,
+    and numpy's; False and 0 at and past it; the lanes it says it searched
+    are the blocks the prefix fills.  -> the four work counts."""
+    monkeypatch.setattr(dedup, "PROBE_BLOCK", block)
+    keys = np.sort(np.asarray(keys, np.uint64))
+    q = np.sort(np.asarray(q, np.uint64))
+    assert len(q) <= T and 0 <= q_n <= T
+    hi, lo = _pairs(keys, cap)
+    q_hi, q_lo = _pairs(q, T)
+    args = (jnp.asarray(hi), jnp.asarray(lo), jnp.int32(len(keys)),
+            jnp.asarray(q_hi), jnp.asarray(q_lo))
+    found, rank, work = jax.jit(
+        lambda *a: dedup.probe_sorted(*a))(*args, jnp.int32(q_n))
+    f0, r0, w0 = _PROBE_SORTED(*args)
+    found, rank, f0, r0 = (np.asarray(x) for x in (found, rank, f0, r0))
+    assert found.dtype == f0.dtype and rank.dtype == r0.dtype
+    assert found.shape == rank.shape == (T,)
+    np.testing.assert_array_equal(found[:q_n], f0[:q_n])
+    np.testing.assert_array_equal(rank[:q_n], r0[:q_n])
+    padded = _k(q_hi.astype(np.uint64), q_lo.astype(np.uint64))[:q_n]
+    np.testing.assert_array_equal(
+        rank[:q_n], np.searchsorted(keys, padded, side="left"))
+    np.testing.assert_array_equal(found[:q_n], np.isin(padded, keys))
+    assert not found[q_n:].any() and not rank[q_n:].any()
+    work, w0 = np.asarray(work), np.asarray(w0)
+    B = dedup.even_block(T, block)
+    assert list(work) == [w0[0], w0[1], -(-q_n // B) * B, T]
+    assert list(w0[2:]) == [T, T]
+    return work
+
+
+_ONE_BUCKET = [_k(9, i * 3) for i in range(40)]
+_HASHED = [_k(i * 0x9E3779B1 % 2**32, i) for i in range(37)]
+# (name, set keys, capacity, live queries)
+_LIVE_SETS = [
+    ("empty_set", [], 8, [0, _k(3, 3), _k(3, 3), _k(0xFFFFFFFF, 0)]),
+    ("full_set", _HASHED, 37,
+     [_k(i * 0x9E3779B1 % 2**32, i & 1) for i in range(20)]),
+    ("one_bucket_equal_hi", _ONE_BUCKET, 64,
+     [_k(9, i) for i in range(0, 125, 5)] + [_k(8, 1), _k(10, 0)]),
+    ("queries_duplicated", [_k(i << 24, i) for i in range(30)], 32,
+     [_k(7 << 24, 7)] * 9 + [_k(7 << 24, 8)] * 9 + [_k(3 << 24, 0)] * 5),
+]
+
+
+def _live_prefix_cases():
+    """Every set above at a block of 8 lanes and a width below the block,
+    equal to it, a multiple of it and none (T 20: three blocks of 7, the
+    last one starting a lane early), with q_n at 0, 1, around a block
+    edge, the live lanes' count and T."""
+    cases = []
+    for name, keys, cap, q in _LIVE_SETS:
+        for T in (5, 8, 20, 32):
+            live = q[:T]
+            for q_n in sorted({0, 1, 7, 8, 9, len(live), T}):
+                if q_n <= T:
+                    cases.append((f"{name}-T{T}-q_n{q_n}", keys, cap, live,
+                                  T, q_n))
+    return cases
+
+
+_LIVE_PREFIX = _live_prefix_cases()
+
+
+@pytest.mark.parametrize("case", _LIVE_PREFIX,
+                         ids=[c[0] for c in _LIVE_PREFIX])
+def test_probe_sorted_live_prefix_edges(monkeypatch, case):
+    """`probe_sorted(..., q_n)` against the form that searches every lane:
+    each lane below q_n bit for bit, each lane at or past it dead, a q_n
+    short of the live lanes included (the contract is the caller's count,
+    not the sentinels)."""
+    _id, keys, cap, q, T, q_n = case
+    _assert_live_prefix(keys, cap, q, T, q_n, _B, monkeypatch)
+
+
+@settings(max_examples=40, deadline=None)
+@given(st.data())
+def test_probe_sorted_live_prefix_equals_the_full_search(data):
+    """Random sets, sorted query lists, widths, blocks and prefixes."""
+    T = data.draw(st.integers(1, 70))
+    block = data.draw(st.sampled_from([1, 4, 8, 16, 64]))
+    top = data.draw(st.sampled_from([2**12, 2**40, 2**64 - 2**33]))
+    draw_keys = st.lists(st.integers(0, top - 1), max_size=60)
+    keys = sorted(set(data.draw(draw_keys)))
+    cap = len(keys) + data.draw(st.integers(0, 9)) or 1
+    fresh = data.draw(st.lists(st.integers(0, top - 1), max_size=T))
+    old = data.draw(st.lists(st.sampled_from(keys), max_size=T)) if keys else []
+    q = (fresh + old)[:T]
+    q_n = data.draw(st.integers(0, T))
+    with pytest.MonkeyPatch.context() as mp:
+        _assert_live_prefix(keys, cap, q, T, q_n, block, mp)
+
+
+_PB100K = dedup.even_block(100000, dedup.PROBE_BLOCK)
+
+
+@pytest.mark.parametrize("q_n", [0, 1, _PB100K, _PB100K + 1, 40000, 100000])
+def test_probe_sorted_counts_the_blocks_it_runs(q_n):
+    """At the block the engine runs and a width of several blocks that is
+    no multiple of it: `probe_lanes` is ceil(q_n / B) x B exactly,
+    `probe_lanes_plain` the width."""
+    T, cap = 100000, 1 << 17
+    B = _PB100K
+    assert T % B and -(-T // B) * B - T < -(-T // B)
+    rng = np.random.default_rng(q_n)
+    keys = np.unique(rng.integers(0, 2**64 - 2**33, size=50000,
+                                  dtype=np.uint64))
+    q = np.concatenate([keys[rng.integers(0, len(keys), size=q_n // 2)],
+                        rng.integers(0, 2**64 - 2**33, size=q_n - q_n // 2,
+                                     dtype=np.uint64)])
+    with pytest.MonkeyPatch.context() as mp:
+        work = _assert_live_prefix(keys, cap, q, T, q_n, dedup.PROBE_BLOCK,
+                                   mp)
+    assert list(work[2:]) == [-(-q_n // B) * B, T]
+
+
+def test_probe_sorted_searches_blocks_of_the_live_prefix_only():
+    """What makes the probe cost what a chunk holds, held on a CPU-only
+    check: with a q_n no gather in it takes indices wider than one block
+    of queries or the directory's boundary list (a shape, built from the
+    SET), and its block loop stops on a value computed from q_n alone;
+    without one the search is the T-wide one."""
+    cap, T, B = 1 << 22, 1 << 20, dedup.PROBE_BLOCK
+    nb = 1 << dedup.directory_bits(T)
+    u = jax.ShapeDtypeStruct((cap,), jnp.uint32)
+    q = jax.ShapeDtypeStruct((T,), jnp.uint32)
+    i = jax.ShapeDtypeStruct((), jnp.int32)
+    jaxpr = jax.make_jaxpr(dedup.probe_sorted)(u, u, i, q, q, i).jaxpr
+    found = list(_indexed_ops(jaxpr))
+    assert {n.split("-")[0] for n, _ in found} == {"gather"}
+    assert not [(n, shapes) for n, shapes in found
+                if any(max(s, default=0) > max(B, nb + 1) for s in shapes)]
+    SET_N, Q_N = 2, 5
+    stops_on = _loops_stop_on(jaxpr)
+    assert len(stops_on) == 2 and Q_N not in stops_on[0]  # the directory's
+    assert stops_on[1] == {Q_N}
+    plain = jax.make_jaxpr(
+        lambda *a: dedup.probe_sorted(*a))(u, u, i, q, q).jaxpr
+    assert any(max(s, default=0) == T for _n, shapes in _indexed_ops(plain)
+               for s in shapes)
+    assert SET_N in _loops_stop_on(plain)[1]
 
 
 # (name, visited keys, cap, new keys, M, out_cap, gate new_n to 0?,
@@ -466,10 +619,11 @@ def test_merge_ranked_moves_blocks_for_live_entries_only():
 # chunk keeps, against the full-width form it replaced
 # --------------------------------------------------------------------------
 
-def _plain_novel(is_new, order, hi_s, lo_s, rank, cand, parent, actid, T, K):
+def _plain_novel(is_new, order, hi_s, lo_s, rank, cand, parent, actid,
+                 _n_live, T, K):
     """The full-width compaction, kept here as the reference: three
     gathers and six scatters over all T lanes, dead lanes' updates sent
-    out of bounds and dropped."""
+    out of bounds and dropped (it needs no live prefix)."""
     sent = jnp.uint32(dedup.SENT)
     pos = jnp.where(is_new, jnp.cumsum(is_new) - 1, T)
     out = jnp.zeros((T, K), jnp.uint32).at[pos].set(cand[order])
@@ -491,7 +645,8 @@ def _novel_case(T, K, n_live, new_lanes, seed):
     (distinct sorted fingerprints, the rest the sentinel pair) and whose
     `new_lanes` (sorted indices below n_live) are new; candidate-order
     rows, parents and action ids behind a random `order`; ranks and
-    payloads that no fill value could pass for."""
+    payloads that no fill value could pass for; n_live last, as
+    `_sort_first` counts it from the sorted lanes."""
     rng = np.random.default_rng(seed)
     keys = np.sort(rng.choice(2**40, size=n_live, replace=False)
                    .astype(np.uint64) << np.uint64(20))
@@ -503,8 +658,10 @@ def _novel_case(T, K, n_live, new_lanes, seed):
     cand = rng.integers(1, 2**32, size=(T, K), dtype=np.uint32)
     parent = rng.integers(0, 1 << 20, size=T).astype(np.int32)
     actid = rng.integers(0, 50, size=T).astype(np.int32)
+    counted = pl._sort_first(jnp.asarray(hi_s), jnp.asarray(lo_s))[4]
+    assert int(counted) == n_live
     return tuple(jnp.asarray(x) for x in (
-        is_new, order, hi_s, lo_s, rank, cand, parent, actid))
+        is_new, order, hi_s, lo_s, rank, cand, parent, actid)) + (counted,)
 
 
 def _assert_novel(block, T, K, n_live, new_lanes, seed, monkeypatch):
@@ -598,29 +755,31 @@ def test_novel_stage_counts_the_blocks_it_runs(n_live, new_n):
         (-(-n_live // B) + -(-new_n // B)) * B, T]
 
 
-_NOVEL = jax.jit(pl.novel_stage, static_argnums=(8, 9))
-_PLAIN_NOVEL = jax.jit(_plain_novel, static_argnums=(8, 9))
+_NOVEL = jax.jit(pl.novel_stage, static_argnums=(9, 10))
+_PLAIN_NOVEL = jax.jit(_plain_novel, static_argnums=(9, 10))
 
 
 def test_novel_stage_moves_blocks_for_the_rows_it_keeps_only():
     """What makes `novel` cost what a chunk keeps, held on a CPU-only
     check: no gather or scatter in it takes indices or updates wider than
-    one block, and its two loops stop on values computed from the
-    fingerprint lanes (the live prefix) and from is_new (the new states)."""
+    one block, and its two loops stop on the live prefix it is handed
+    (`_sort_first`'s count) and on a value computed from is_new (the new
+    states)."""
     T, K, B = 1 << 20, 4, pl.NOVEL_BLOCK
     u = jax.ShapeDtypeStruct((T,), jnp.uint32)
     i = jax.ShapeDtypeStruct((T,), jnp.int32)
     jaxpr = jax.make_jaxpr(lambda *a: pl.novel_stage(*a, T, K))(
         jax.ShapeDtypeStruct((T,), bool), i, u, u, i,
-        jax.ShapeDtypeStruct((T, K), jnp.uint32), i, i).jaxpr
+        jax.ShapeDtypeStruct((T, K), jnp.uint32), i, i,
+        jax.ShapeDtypeStruct((), jnp.int32)).jaxpr
 
     found = list(_indexed_ops(jaxpr))
     assert {n.split("-")[0] for n, _ in found} == {"gather", "scatter"}
     assert not [(n, shapes) for n, shapes in found
                 if any(max(s, default=0) > B for s in shapes)]
     stops_on = _loops_stop_on(jaxpr)
-    IS_NEW, HI_S, LO_S = 0, 2, 3
-    assert stops_on == [{HI_S, LO_S}, {IS_NEW}]
+    IS_NEW, N_LIVE = 0, 8
+    assert stops_on == [{N_LIVE}, {IS_NEW}]
 
 
 def _sorted_dedup(with_plain_novel, monkeypatch, *args, **kw):

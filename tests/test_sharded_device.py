@@ -131,6 +131,47 @@ def test_sharded_level_records_carry_the_probe_rounds(tmp_path, pipeline):
 
 @pytest.mark.parametrize("pipeline", ["legacy", "device"],
                          ids=["per-chunk", "whole-level"])
+def test_sharded_level_records_carry_the_probe_lanes(tmp_path, monkeypatch,
+                                                     pipeline):
+    """The sharded twin of tests/test_obs.py's, at a probe block of 256
+    lanes, twice: every level record with the query lanes its shards'
+    probes searched (each shard its own live prefix: the loop holds no
+    collective) beside the lanes they were handed: `dedup_lanes` where a
+    shard probes once a chunk (`shs`), more than twice that in the
+    whole-level program (`shl`: two probes a chunk and the level-new
+    rank); whole blocks, the two runs equal to the lane, a fraction of the
+    layout over the run; the counts are the golden's."""
+    from kafka_specification_tpu.ops import dedup
+    from kafka_specification_tpu.utils.cfg import build_model, parse_cfg
+
+    monkeypatch.setattr(dedup, "PROBE_BLOCK", 256)
+    model = build_model("Kip320", parse_cfg("configs/Kip320.cfg"))
+    runs = []
+    for i in range(2):
+        res = check_sharded(model, max_depth=8, pipeline=pipeline,
+                            store_trace=False,
+                            run=RunContext(str(tmp_path / f"run{i}")))
+        assert res.ok
+        assert res.levels == [1, 6, 30, 138, 366, 1170, 2715, 5673, 10836]
+        runs.append(res.stats["levels"])
+    for rec in runs[0]:
+        assert 0 <= rec["probe_lanes"] <= rec["probe_lanes_plain"], rec
+        assert rec["probe_lanes"] >= rec["enabled_candidates"], rec
+        if pipeline == "legacy":
+            assert rec["probe_lanes_plain"] == rec["dedup_lanes"], rec
+        else:  # (the narrow levels run `shs` under the gate)
+            assert rec["probe_lanes_plain"] >= rec["dedup_lanes"], rec
+    if pipeline == "device":
+        assert runs[0][-1]["probe_lanes_plain"] > \
+            2 * runs[0][-1]["dedup_lanes"]
+    assert [(r["probe_lanes"], r["probe_lanes_plain"]) for r in runs[0]] \
+        == [(r["probe_lanes"], r["probe_lanes_plain"]) for r in runs[1]]
+    assert sum(r["probe_lanes"] for r in runs[0]) < \
+        0.6 * sum(r["probe_lanes_plain"] for r in runs[0])
+
+
+@pytest.mark.parametrize("pipeline", ["legacy", "device"],
+                         ids=["per-chunk", "whole-level"])
 def test_sharded_level_records_carry_the_merge_slots(tmp_path, monkeypatch,
                                                      pipeline):
     """configs/Kip320.cfg cut to depth 7 on the mesh at a merge block of

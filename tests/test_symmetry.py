@@ -38,6 +38,7 @@ import numpy as np
 import pytest
 
 from kafka_specification_tpu.engine.bfs import check, prepare
+from kafka_specification_tpu.engine.pipeline import CANON_FIELDS, WORK_FIELDS
 from kafka_specification_tpu.models import kafka_replication as kr
 from kafka_specification_tpu.models import kip320, variants
 from kafka_specification_tpu.models.base import FieldRole, Symmetry
@@ -422,8 +423,8 @@ def test_a_model_without_symmetry_lowers_with_no_canon_operation():
     assert "kspec.canon" in reduced
     # the counts vector grows by the two canon counts, and only there
     n_act = len(kip320.make_model(CFG3).actions)
-    assert f"tensor<{n_act + 6}xi32>" in plain
-    assert f"tensor<{n_act + 8}xi32>" in reduced
+    assert f"tensor<{n_act + len(WORK_FIELDS)}xi32>" in plain
+    assert f"tensor<{n_act + len(WORK_FIELDS) + len(CANON_FIELDS)}xi32>" in reduced
 
 
 # -- (f) the oracle's canonical form against the brute force ------------------
