@@ -1268,12 +1268,12 @@ def check(
     overlap: async level-pipelined execution ($KSPEC_OVERLAP is the env
     twin; default ON, ``off``/False = the historical serial behavior and
     the bit-identity oracle).  Three overlaps (docs/engine.md § Async
-    execution): (1) a two-slot staged chunk pipeline — chunk k+1's
-    device programs are dispatched before chunk k's host commit
-    (fingerprint-set insert, arena assembly, digest folds) runs, so host
-    work drains behind the in-flight update-skeleton launch (JAX async
-    dispatch; per-chunk ``step`` spans carry dispatch/device-wait
-    attribution); (2) disk-tier spill-run merges run on a background
+    execution): (1) a staged chunk pipeline — chunk k+1's guard launch
+    and chunk k's update-skeleton launch are dispatched before chunk
+    k-1's host commit (fingerprint-set insert, arena assembly, digest
+    folds) and chunk k+1's host compaction run, so host work drains
+    behind the in-flight update-skeleton launch (JAX async dispatch;
+    per-chunk ``step`` spans carry dispatch/device-wait attribution); (2) disk-tier spill-run merges run on a background
     worker (storage/tiered.py — lookups keep serving from the immutable
     inputs, adoption and error propagation happen on this thread);
     (3) checkpoint writes move to a writer thread (the engine snapshots
@@ -2189,49 +2189,46 @@ def check(
         nact[:a_w] = a_act[:a_w]
         a_act = nact
 
-    def _commit_chunk(st) -> bool:
-        """Commit one staged chunk: block on its device outputs
-        (finalize), run the verdict checks and shadow oracle, then the
-        backend-specific host assembly — the visited-set insert, arena/
-        trace accumulation and digest folds.  Commits run strictly in
-        dispatch order on this thread; returns True when a verdict
-        fired (the level stops and any younger staged chunk is
-        discarded uncommitted)."""
-        nonlocal vhi, vlo, vn, verdict, lvl_new, prof_step, prof_host_s
-        nonlocal lvl_launches, lvl_launches_max, run_launches_max
-        nonlocal lvl_act_en, a_w  # arena buffers grow via _grow_arena
-        nonlocal ht_hi, ht_lo, ht_claim, hash_n
-        nonlocal lvl_store_s, lvl_chunks, lvl_rows_in, lvl_lanes
-        (start, fp_n, bucket, finalize, pre_v, shadow, dispatch_s,
-         t_staged, piece, pre_vcap, t_dispatch) = st
+    def _take_rows(outs, nn: int):
+        """The device-backend commit's slices of a chunk's outputs (rows,
+        parents, action ids, and the fingerprint lanes where a chain
+        folds them), enqueued on the device stream; None where nothing
+        is new."""
+        if not nn:
+            return None
+        # finalize()'s tuple: out, out_parent, out_act ... out_hi, out_lo
+        return tuple(
+            io.head(outs[i], nn)
+            for i in ((0, 1, 2) + ((12, 13) if chain is not None else ()))
+        )
+
+    def _commit_wait(st):
+        """First half of a chunk's commit, and the loop's one blocking
+        wait on a successor program: finalize() (the read of its counts
+        vector), the level's counters, the verdict flags (outputs of the
+        chunk's guard launch, long computed) and, where there is no
+        verdict, `new_n`.  On the sorted `device` backend the slices of
+        the chunk's rows are enqueued HERE: the loop calls this before it
+        queues the next successor launch on the in-order device stream,
+        so the commit's fetches do not wait behind that launch (and a
+        chunk that holds the verdict is never sliced, as in serial
+        order)."""
+        nonlocal verdict, lvl_chunks, lvl_rows_in, lvl_lanes, lvl_ahead
+        start, fp_n, finalize, t_staged, was_ahead = (
+            st[0], st[1], st[3], st[7], st[11])
         queued_s = time.perf_counter() - t_staged
         t_wait = time.perf_counter()
-        (
-            out,
-            out_parent,
-            out_act,
-            new_n,
-            _vh,
-            _vl,
-            _vn,
-            viol_any,
-            viol_idx,
-            dl_any,
-            dl_idx,
-            counts,
-            out_hi,
-            out_lo,
-            act_guard,
-            launches,
-            lanes,
-        ) = finalize()
+        outs = finalize()
+        new_n, viol_any, viol_idx, dl_any, dl_idx, counts = (
+            outs[3], *outs[7:12])
         # the program's counts vector (pipeline.counts_out): a chunk that
         # holds the verdict ran its probe and merge like any other
         act_en_np, work = split_counts(io.fetch(counts, np.int64), n_work)
         lvl_work[:] += work
         lvl_chunks += 1
+        lvl_ahead += was_ahead
         lvl_rows_in += fp_n
-        lvl_lanes += lanes
+        lvl_lanes += outs[16]
         # frontier-level verdicts (states being expanded = level `depth`)
         if check_invariants:
             viol_any_np = io.fetch(viol_any)
@@ -2242,39 +2239,72 @@ def check(
         if verdict is None and check_deadlock and bool(io.fetch(dl_any)):
             verdict = ("deadlock", start + int(io.fetch(dl_idx)),
                        "Deadlock")
-        nn = 0
+        nn, rows = 0, None
         if verdict is None:
             nn = int(io.fetch(new_n))
-            if shadow:
-                # pre_vcap: the visited capacity AT DISPATCH — the next
-                # chunk's dispatch may have grown `vcap` before this
-                # commit, and the shadow cross-exec replays against the
-                # pre-chunk visited refs, which are sized at the old
-                # capacity
-                _shadow_exec(
-                    piece, fp_n, bucket, start, pre_v, pre_vcap,
-                    out, out_hi, out_lo, nn, viol_any, dl_any,
-                )
+            if host_set is None and ht_hi is None:
+                rows = _take_rows(outs, nn)
+        return (outs, act_en_np, queued_s, time.perf_counter() - t_wait,
+                nn, rows)
+
+    def _commit_chunk(st, waited=None) -> bool:
+        """Commit one staged chunk: block on its device outputs and read
+        its verdict (_commit_wait, unless the loop has run it already:
+        `waited`), run the shadow oracle, then the backend-specific host
+        assembly — the visited-set insert, arena/trace accumulation and
+        digest folds.  Commits run strictly in dispatch order on this
+        thread; returns True when a verdict fired (the level stops and
+        any younger staged chunk is discarded uncommitted)."""
+        nonlocal vhi, vlo, vn, lvl_new, prof_step, prof_host_s
+        nonlocal lvl_launches, lvl_launches_max, run_launches_max
+        nonlocal lvl_act_en, a_w  # arena buffers grow via _grow_arena
+        nonlocal ht_hi, ht_lo, ht_claim, hash_n
+        nonlocal lvl_store_s
+        (start, fp_n, bucket, _finalize, pre_v, shadow, dispatch_s,
+         _t_staged, piece, pre_vcap, t_dispatch, was_ahead) = st
+        (outs, act_en_np, queued_s, wait_s, nn, rows
+         ) = waited if waited is not None else _commit_wait(st)
+        (out, out_parent, out_act, new_n, _vh, _vl, _vn, viol_any,
+         _viol_idx, dl_any, _dl_idx, _counts, out_hi, out_lo, _act_guard,
+         launches, _lanes) = outs
+        if verdict is None and shadow:
+            # pre_vcap: the visited capacity AT DISPATCH — the next
+            # chunk's dispatch may have grown `vcap` before this
+            # commit, and the shadow cross-exec replays against the
+            # pre-chunk visited refs, which are sized at the old
+            # capacity
+            t_shadow = time.perf_counter()
+            _shadow_exec(
+                piece, fp_n, bucket, start, pre_v, pre_vcap,
+                out, out_hi, out_lo, nn, viol_any, dl_any,
+            )
+            wait_s += time.perf_counter() - t_shadow
         # a chunk that holds the verdict is booked like any other (its
         # step time, launches and `step` span are the cut level's)
-        wait_s = time.perf_counter() - t_wait
         step_s = dispatch_s + wait_s
         prof_step += step_s
         lvl_launches += launches
         lvl_launches_max = max(lvl_launches_max, launches)
         run_launches_max = max(run_launches_max, launches)
-        # dispatch vs device-wait attribution (overlap accounting): with
-        # overlap on, queued_ms is how long the chunk sat staged while
-        # the previous chunk committed — device time hidden behind host
-        # work; wait_ms is the residual block on the outputs at commit
-        # the span is the true interval, dispatch to outputs in hand
-        # (dispatch + queued + wait); step_ms stays dispatch + wait
+        # dispatch vs device-wait attribution (overlap accounting):
+        # dispatch_ms is the host's time in the chunk's own stages (the
+        # upload and launch 1, the compaction between the launches,
+        # launch 2), wherever in the schedule they ran; queued_ms is how
+        # long the chunk sat staged after launch 2 while the host
+        # committed the chunk before it and compacted the one after it —
+        # device time hidden behind host work; wait_ms is the residual
+        # block on the outputs, the verdict reads included (_commit_wait).
+        # The span is the true interval, first stage to the start of the
+        # chunk's host assembly: in an `ahead` chunk it also spans the
+        # other chunks' work between its stages.  step_ms stays dispatch
+        # + wait
         obs_.chunk_span(
             "step", t_dispatch, depth=depth, start=start, rows=fp_n,
             bucket=bucket, launches=launches,
             dispatch_ms=round(dispatch_s * 1e3, 2),
             wait_ms=round(wait_s * 1e3, 2),
             queued_ms=round(queued_s * 1e3, 2),
+            **({"ahead": True} if was_ahead else {}),
             **({"verdict": verdict[0]} if verdict is not None else {}),
         )
         if verdict is not None:
@@ -2369,18 +2399,15 @@ def check(
                     )
                 )
         elif nn:
-            lvl_rows.append(io.fetch(out[:nn]))
-            lvl_parent.append(io.fetch(out_parent[:nn]) + start)
-            lvl_act.append(io.fetch(out_act[:nn]))
+            lvl_rows.append(io.fetch(rows[0]))
+            lvl_parent.append(io.fetch(rows[1]) + start)
+            lvl_act.append(io.fetch(rows[2]))
             lvl_new += nn
             if chain is not None:
                 # device backend: the in-jit dedup already
                 # compacted exactly the new states to the front
                 chain.fold(
-                    _integ.pair_u64(
-                        io.fetch(out_hi[:nn]),
-                        io.fetch(out_lo[:nn]),
-                    )
+                    _integ.pair_u64(io.fetch(rows[3]), io.fetch(rows[4]))
                 )
         host_s = time.perf_counter() - t_host
         prof_host_s += host_s
@@ -2556,6 +2583,8 @@ def check(
     integrity_fail: Optional[IntegrityError] = None
     run_launches_max = 0  # per-chunk max actually DISPATCHED this run
     overlap_staged_peak = 0  # most chunks ever staged at once (<= 2)
+    # ... and most chunks beside them with only a guard stage run (<= 1)
+    overlap_ahead_peak = 0
 
     def _io_counters():
         return worker_counters((io_worker, ckpt_worker))
@@ -2640,6 +2669,9 @@ def check(
             lvl_probe_ms = 0.0  # deferred batched host-probe wall
             lvl_store_s = 0.0  # trace store / parent log wall (`store_ms`)
             lvl_chunks = lvl_rows_in = 0  # chunks committed, their rows
+            # those of them whose guard launch went out before the chunk
+            # before them had its successor launch (`chunks_ahead`)
+            lvl_ahead = 0
             # the width the dedup side was handed, summed over them: the
             # lanes every sort, probe and compaction ran, live or padding
             lvl_lanes = 0
@@ -2660,18 +2692,21 @@ def check(
                 a_act = np.empty(a_cap, np.int32)
                 a_w = 0
             prof_step = prof_host_s = 0.0
-            # Two-slot staged chunk pipeline (KSPEC_OVERLAP, docs/
-            # engine.md § Async execution): each chunk's device programs
-            # are DISPATCHED first (pipe.run_chunk_staged — JAX async
+            # Staged chunk pipeline (KSPEC_OVERLAP, docs/engine.md
+            # § Async execution): each chunk's device programs are
+            # DISPATCHED first (pipe.run_chunk_staged — JAX async
             # dispatch leaves the update-skeleton launch draining), and
             # the PREVIOUS chunk's host commit (fingerprint-set insert,
-            # arena assembly, digest folds) runs while it drains.  At
-            # most two chunks are ever staged (the one committing + the
-            # one dispatched); commits happen strictly in chunk order,
-            # so counts, novelty decisions, first-violation and traces
-            # are bit-identical to the serial path — which is literally
-            # this same code with overlap_on False (dispatch followed by
-            # an immediate commit).
+            # arena assembly, digest folds) runs while it drains; in a
+            # level of fused chunks the NEXT chunk's guard launch goes
+            # out before the launch and its host compaction runs behind
+            # it too.  At most two chunks are ever staged (the one
+            # committing + the one dispatched) plus one of which only
+            # the guard stage has run; commits happen strictly in chunk
+            # order, so counts, novelty decisions, first-violation and
+            # traces are bit-identical to the serial path — which is
+            # literally this same code with overlap_on False (dispatch
+            # followed by an immediate commit, nothing ahead).
             staged = None
             # Device-resident level path (DevicePipeline, engine/
             # pipeline.py): ONE dispatched while_loop program runs every
@@ -2761,14 +2796,40 @@ def check(
                 )
             else:
                 tail_chunks = _f_chunks(frontier_np, chunk)
-            for start, piece in tail_chunks:
-                if start < dev_handled:
-                    continue  # committed by the device-resident span
+            # the fused pipeline's two halves of a chunk, where the loop
+            # may run them apart (never a whole-level `device` pipeline's
+            # per-chunk tail, never `legacy`)
+            stager = pipe if getattr(pipe, "name", "") == "fused" else None
+            chunks_it = (c for c in tail_chunks if c[0] >= dev_handled)
+            nxt = next(chunks_it, None)
+            ahead = None  # the NEXT chunk's guard stage, where it ran
+            while nxt is not None:
+                (start, piece), nxt = nxt, next(chunks_it, None)
                 governor.poll(depth)  # deadline watchdog (cheap)
                 fp_n = piece.shape[0]
                 bucket = _next_pow2(max(fp_n, min_bucket))
                 M = bucket * C
+                mine, ahead = ahead, None
+                from_ahead = mine is not None
+                waited = None
                 if visited_backend == "device":
+                    if staged is not None:
+                        # the loop's one blocking wait on a successor
+                        # program: the staged chunk's counts, verdict
+                        # flags and `new_n`, its rows' slices enqueued
+                        # before this chunk's successor launch is
+                        waited = _commit_wait(staged)
+                        if verdict is not None:
+                            # the level stops HERE, before anything more
+                            # is queued: of this chunk only the guard
+                            # stage has run, where it ran ahead (its
+                            # launch read and closed, nothing in flight)
+                            _commit_chunk(staged, waited)
+                            staged = None
+                            if mine is not None:
+                                mine.drop()
+                                lvl_discarded = 1
+                            break
                     need = int(io.fetch(vn)) + M
                     if need > vcap:
                         # one shared growth policy with the device level
@@ -2798,35 +2859,70 @@ def check(
                 # replays the chunk from the same starting state (jax
                 # arrays are immutable, so holding them is free)
                 pre_v = (vhi, vlo, vn) if shadow else None
+                # The guard stage of the chunk AFTER this one goes out
+                # before this chunk's successor launch (it reads the
+                # frontier only), where the code can see that it will
+                # run fused: overlap on, a further chunk, its bucket
+                # through the gate.  This chunk's own stages then run
+                # first, in serial order, unless they ran ahead too.
+                # `mine` / `ahead`: a pipeline.StagedGuard
+                n_fp = nxt[1].shape[0] if nxt is not None else 0
+                n_bucket = _next_pow2(max(n_fp, min_bucket))
+                if (overlap_on and stager is not None and n_fp
+                        and stager._gate(n_bucket)):
+                    if mine is None and stager._gate(bucket):
+                        mine = stager.guard_stage(piece, fp_n, bucket, depth)
+                        stager.compact_stage(mine)
+                    ahead = stager.guard_stage(nxt[1], n_fp, n_bucket, depth)
+                    overlap_ahead_peak = 1
                 t_attempt = time.perf_counter()
-                t_dispatch = _now()
-                vhi, vlo, vn, finalize = pipe.run_chunk_staged(
-                    piece, fp_n, bucket, depth, vhi, vlo, vn, vcap
-                )
+                if mine is None:
+                    t_dispatch, dispatch_s = _now(), 0.0
+                    vhi, vlo, vn, finalize = pipe.run_chunk_staged(
+                        piece, fp_n, bucket, depth, vhi, vlo, vn, vcap
+                    )
+                else:
+                    # the `step` span runs from the chunk's first stage
+                    t_dispatch, dispatch_s = mine.t0, mine.host_s
+                    vhi, vlo, vn, finalize = stager.run_chunk_staged(
+                        piece, fp_n, bucket, depth, vhi, vlo, vn, vcap,
+                        ahead=mine,
+                    )
                 cur = (
                     start, fp_n, bucket, finalize, pre_v, shadow,
-                    time.perf_counter() - t_attempt, time.perf_counter(),
-                    piece, vcap, t_dispatch,
+                    dispatch_s + time.perf_counter() - t_attempt,
+                    time.perf_counter(), piece, vcap, t_dispatch,
+                    # the committed attempt's guard launch went out
+                    # before the previous chunk's successor launch
+                    int(from_ahead and getattr(finalize, "ahead", False)),
                 )
                 if overlap_on:
                     overlap_staged_peak = max(
                         overlap_staged_peak, 2 if staged is not None else 1
                     )
-                    if staged is not None and _commit_chunk(staged):
+                    if staged is not None and _commit_chunk(staged, waited):
                         # a verdict in chunk k: the just-dispatched chunk
                         # k+1 is DISCARDED uncommitted — exactly what the
                         # serial path's break does (its device work is
                         # pure and side-effect-free until commit); its
                         # open launch is closed as discarded, so the
                         # level's counters hold it (a legacy chunk has
-                        # none: its dispatch is complete)
+                        # none: its dispatch is complete).  So is chunk
+                        # k+2's guard launch, where it went out ahead
                         launch = getattr(finalize, "launch", None)
                         if launch is not None:
                             launch.finish(discarded=True)
                         staged = None
                         lvl_discarded = 1
+                        if ahead is not None:
+                            ahead.drop()
+                            lvl_discarded = 2
                         break
                     staged = cur
+                    if ahead is not None:
+                        # the next chunk's host compaction, behind this
+                        # chunk's successor launch
+                        stager.compact_stage(ahead)
                 else:
                     if _commit_chunk(cur):
                         break
@@ -2850,6 +2946,8 @@ def check(
                         chunks_committed=lvl_chunks,
                         chunks_discarded=lvl_discarded,
                         chunks=lvl_chunks + lvl_discarded,
+                        # of the committed ones (`chunks_committed`)
+                        chunks_ahead=lvl_ahead,
                         dedup_lanes=lvl_lanes,
                         level_ms=round((time.perf_counter() - t_level) * 1e3, 1),
                         step_ms=round(prof_step * 1e3, 1),
@@ -2971,6 +3069,9 @@ def check(
                         # chunks the level streamed (a whole-level
                         # program: the chunks it ran)
                         "chunks": lvl_chunks,
+                        # those whose guard launch went out before the
+                        # chunk before them had its successor launch
+                        "chunks_ahead": lvl_ahead,
                         # the lanes their dedup sides were handed
                         "dedup_lanes": lvl_lanes,
                         **work_record(lvl_work),
@@ -3166,11 +3267,13 @@ def check(
             ),
             "transient_retries": chunk_retry.retries_total,
             "degradations": chunk_retry.degradations,
-            # async-overlap accounting (overlap.py): the staged-chunk
-            # bound is structural (two slots) — tests pin peak <= 2
+            # async-overlap accounting (overlap.py): the staging bound
+            # is structural (two open successor launches, one chunk
+            # beside them whose guard stage ran ahead) — tests pin both
             "overlap": {
                 "enabled": overlap_on,
                 "staged_chunks_peak": overlap_staged_peak,
+                "guard_ahead_peak": overlap_ahead_peak,
                 "sync_ckpt_io_s": round(sync_io_s, 4),
                 **(
                     {"io_worker": io_worker.stats()}

@@ -113,6 +113,23 @@ class HostIO:
             self.put_ms += (perf_counter() - t0) * 1e3
         return out
 
+    def prefetch(self, *xs) -> None:
+        """Start the device-to-host copy of `xs` NOW, behind the program
+        that computes them: the `fetch` that reads each later finds it
+        on the host (or on its way) and pays no round trip of its own
+        after the program ends.  Counts nothing; `fetch` does."""
+        for x in xs:
+            if isinstance(x, jax.Array):
+                x.copy_to_host_async()
+
+    def head(self, x, n: int):
+        """``x[:n]`` of a device array: a slice program enqueued on the
+        in-order device stream NOW, no transfer and nothing counted.  The
+        level loop cuts a committed chunk's rows with it before it queues
+        the next successor program, so their fetch does not wait behind
+        that program (docs/engine.md § Async execution)."""
+        return x[:n]
+
     # --- spans and dispatches -----------------------------------------------
     def span(self, kind: str, t0: float, **attrs) -> None:
         """A completed host span that started at `t0` and ends now."""

@@ -111,6 +111,7 @@ compose, and docs/engine.md walks through the interface.
 
 from __future__ import annotations
 
+from time import perf_counter
 from typing import Optional
 
 import jax
@@ -842,6 +843,44 @@ class PooledWidths:
         return tuple(out)
 
 
+class StagedGuard:
+    """One fused chunk between its two launches: what
+    :meth:`FusedPipeline.guard_stage` dispatched and, once
+    :meth:`FusedPipeline.compact_stage` has run, what shapes launch 2."""
+
+    __slots__ = ("fp_n", "bucket", "depth", "t0", "host_s", "frontier",
+                 "outs", "launch", "dispatched", "error", "act_guard_np",
+                 "widths", "compacted")
+
+    def __init__(self, fp_n: int, bucket: int, depth: int):
+        self.fp_n, self.bucket, self.depth = fp_n, bucket, depth
+        #: when the chunk's first stage began (its `step` span's start) and
+        #: the host's seconds in its stages so far (the span's dispatch_ms)
+        self.t0, self.host_s = _now(), 0.0
+        self.frontier = None  # the padded piece, on the device
+        #: launch 1's outputs: ga, act_guard, viol_any, viol_idx, dl_any,
+        #: dl_idx
+        self.outs = None
+        self.launch = None  # launch 1's dispatch, open until it is read
+        self.dispatched = 0  # 1 once launch 1 went out
+        self.error: Optional[Exception] = None  # what a stage raised
+        self.act_guard_np = self.widths = None
+        #: _compact's result: sidx on the host; sidx, chloc, rowvalid on
+        #: the device
+        self.compacted = None
+
+    def drop(self) -> None:
+        """The chunk will not be committed from this stage (a failure, a
+        verdict in an older chunk, a fallback): launch 1, where it is
+        still open, ends as discarded."""
+        if self.launch is not None:
+            self.launch.finish(discarded=True)
+
+    def fail(self, e: Exception) -> None:
+        self.error = e
+        self.drop()
+
+
 class FusedPipeline:
     """Successor mega-kernels: 2 dispatched programs per chunk (guard
     matrix -> host flatnonzero compaction -> update skeleton), bit-
@@ -1078,31 +1117,100 @@ class FusedPipeline:
         )
         return finalize()
 
+    def guard_stage(self, piece, fp_n, bucket, depth,
+                    attempt: int = 0) -> "StagedGuard":
+        """First half of a fused chunk: upload the piece and dispatch
+        launch 1.  It reads the frontier piece only, never the visited
+        set, so the level loop may run it one chunk ahead (before the
+        previous chunk's successor launch is queued).  Never raises: what
+        goes wrong is kept on the result, and :meth:`run_chunk_staged`
+        handles it at the chunk's own turn, on its one failure ladder."""
+        from .bfs import _pad_rows
+
+        t0 = perf_counter()
+        g = StagedGuard(fp_n, bucket, depth)
+        try:
+            # escalated=True on BOTH inject and handle: the fused
+            # programs are the adaptive (escalated-shape) family, so
+            # KSPEC_FAULT=compile_oom rehearses exactly this path's
+            # degradation to legacy
+            injected = self.fault.chunk_error(escalated=True)
+            if injected is not None:
+                raise injected
+            g.frontier = self.io.put(_pad_rows(piece, bucket))
+            fvalid = jnp.arange(bucket) < fp_n
+            g.launch = self.io.dispatch("fgd", attempt=attempt,
+                                        depth=depth, bucket=bucket)
+            g.outs = self.guard_step(bucket)(g.frontier, fvalid)
+            g.dispatched = 1  # launch 1: the guard matrix
+            # what the host reads of it (the matrix and its counts here,
+            # the verdict flags at the commit) crosses as soon as it is
+            # computed, not a round trip a read
+            ga, act_guard, viol_any, _vi, dl_any, _di = g.outs
+            self.io.prefetch(ga, act_guard, viol_any, dl_any)
+        except Exception as e:  # noqa: BLE001 — XLA compile/run
+            g.fail(e)
+        g.host_s += perf_counter() - t0
+        return g
+
+    def compact_stage(self, g: "StagedGuard") -> None:
+        """Between the launches, all of it on the host: read launch 1's
+        counts (this read forces it), size the pooled widths, compact the
+        guard matrix and upload the index vectors (``_compact``).  Runs
+        chunk after chunk, ahead or not: ``PooledWidths.hw`` is
+        order-dependent.  A second call, or one after a failure, does
+        nothing; never raises (as :meth:`guard_stage`)."""
+        if g.error is not None or g.widths is not None:
+            return
+        t0 = perf_counter()
+        try:
+            ga, act_guard = g.outs[0], g.outs[1]
+            # the counts shape launch 2: this read forces launch 1
+            g.act_guard_np = self.io.fetch(act_guard, np.int64)
+            g.launch.finish()
+            widths = self.pool.widths_for(
+                g.bucket, g.act_guard_np.astype(np.float64), g.fp_n
+            )
+            g.compacted = self._compact(ga, widths, g.depth)
+            g.widths = widths
+        except Exception as e:  # noqa: BLE001 — XLA runtime
+            g.fail(e)
+        g.host_s += perf_counter() - t0
+
     def run_chunk_staged(self, piece, fp_n, bucket, depth,
-                         vhi, vlo, vn, vcap, reset: bool = True):
+                         vhi, vlo, vn, vcap, reset: bool = True,
+                         ahead: Optional["StagedGuard"] = None):
         """Dispatch both fused launches; -> (vhi, vlo, vn, finalize).
 
-        The guard matrix is forced here (its counts drive the host
-        compaction that shapes launch 2), but launch 2's outputs stay
-        in-flight: the overlap driver in check() dispatches chunk k+1's
-        programs BEFORE calling chunk k's finalize(), so the host
-        compaction/arena assembly of one chunk runs while the other's
-        update-skeleton/dedup launch drains on device (the two-slot
-        staging queue; docs/engine.md § Async execution).  finalize()
+        The composition of the chunk's two halves: :meth:`guard_stage`
+        (the upload and launch 1), then :meth:`compact_stage` (the guard
+        matrix is forced there: its counts drive the host compaction
+        that shapes launch 2) and launch 2, whose outputs stay
+        in-flight.  The overlap driver in check() runs the first half of
+        chunk k+1, and this method for chunk k, BEFORE it commits chunk
+        k-1, so a chunk's host work runs while another's update-skeleton/
+        dedup launch drains on device (docs/engine.md § Async execution);
+        `ahead` is this chunk's first half where the driver has run it
+        already.  A failure there is raised HERE, into the one failure
+        ladder (retry, then the sticky fallback to legacy), and the
+        chunk is re-run in serial order.  finalize()
         blocks on the outputs and returns run_chunk's exact tuple —
         with overlap off check() finalizes immediately, which IS the
         historical serial behavior.  `finalize.launch` is launch 2's
         open dispatch: a caller that drops the chunk uncommitted (a
-        verdict in the chunk before it) finishes it as discarded.  The
+        verdict in the chunk before it) finishes it as discarded;
+        `finalize.ahead` says the committed attempt took `ahead`.  The
         returned visited refs chain the next chunk's dispatch on the
         device backend (functional, still in-flight — JAX async dispatch
         pipelines them)."""
         if not self._gate(bucket):
+            if ahead is not None:
+                # the run fell back to legacy after this chunk's guard
+                # launch went out
+                ahead.drop()
             return self.legacy.run_chunk_staged(
                 piece, fp_n, bucket, depth, vhi, vlo, vn, vcap
             )
-        from .bfs import _pad_rows
-
         if reset:
             self.chunk_retry.reset_chunk()
         io = self.io
@@ -1112,29 +1220,19 @@ class FusedPipeline:
         while True:
             launch = None
             try:
-                # escalated=True on BOTH inject and handle: the fused
-                # programs are the adaptive (escalated-shape) family, so
-                # KSPEC_FAULT=compile_oom rehearses exactly this path's
-                # degradation to legacy
-                injected = self.fault.chunk_error(escalated=True)
-                if injected is not None:
-                    raise injected
-                frontier = io.put(_pad_rows(piece, bucket))
-                fvalid = jnp.arange(bucket) < fp_n
-                launch = io.dispatch("fgd", attempt=attempt, depth=depth,
-                                     bucket=bucket)
-                (ga, act_guard, viol_any, viol_idx, dl_any, dl_idx
-                 ) = self.guard_step(bucket)(frontier, fvalid)
-                dispatched += 1  # launch 1: the guard matrix
-                # the counts shape launch 2: this read forces launch 1
-                act_guard_np = io.fetch(act_guard, np.int64)
-                launch.finish()
-                widths = self.pool.widths_for(
-                    bucket, act_guard_np.astype(np.float64), fp_n
-                )
-                sidx, sidx_d, chloc_d, rowvalid_d = self._compact(
-                    ga, widths, depth
-                )
+                took_ahead = ahead is not None
+                g, ahead = ahead, None
+                if g is None:
+                    g = self.guard_stage(piece, fp_n, bucket, depth,
+                                         attempt)
+                self.compact_stage(g)
+                dispatched += g.dispatched
+                if g.error is not None:
+                    raise g.error
+                frontier, act_guard_np, widths = (
+                    g.frontier, g.act_guard_np, g.widths)
+                viol_any, viol_idx, dl_any, dl_idx = g.outs[2:]
+                sidx, sidx_d, chloc_d, rowvalid_d = g.compacted
                 # launch 2 stays in flight: finalize() closes its span
                 # where the host first blocks on its outputs
                 launch = io.dispatch("fsc", attempt=attempt, depth=depth,
@@ -1147,6 +1245,9 @@ class FusedPipeline:
                 if self.visited_backend != "host":
                     (out, out_parent, out_act, new_n, out_hi, out_lo,
                      vhi, vlo, vn, act_en) = outs
+                    # the three scalars-and-counts the level loop blocks
+                    # on cross behind the program, in one wait
+                    io.prefetch(act_en, new_n, vn)
             except Exception as e:  # noqa: BLE001 — XLA compile/run
                 if launch is not None:
                     launch.finish(discarded=True)
@@ -1237,8 +1338,9 @@ class FusedPipeline:
                         int(sum(widths)),
                     )
 
-                finalize.launch = launch
+                finalize.launch, finalize.ahead = launch, took_ahead
                 return vhi, vlo, vn, finalize
+
             def finalize(launch=launch, act_en=act_en, committed=(
                     out, out_parent, out_act, new_n, vhi, vlo, vn,
                     viol_any, viol_idx, dl_any, dl_idx, None,
@@ -1250,7 +1352,7 @@ class FusedPipeline:
                 launch.finish()
                 return committed[:11] + (act_en_np,) + committed[12:]
 
-            finalize.launch = launch
+            finalize.launch, finalize.ahead = launch, took_ahead
             return vhi, vlo, vn, finalize
 
     def _actid_np(self, widths: tuple) -> np.ndarray:

@@ -9,12 +9,21 @@ One knob governs every overlap: ``KSPEC_OVERLAP`` (env) /
 bit-identity oracle the overlap tests compare against
 (tests/test_overlap.py).  The four overlaps this module underpins:
 
-1. **double-buffered chunk pipeline** (engine/bfs.py + pipeline.py):
-   no thread at all — JAX async dispatch is the worker.  The level loop
-   stages at most TWO chunks: chunk k+1's device programs are dispatched
-   before chunk k's host commit (fingerprint-set insert, arena assembly,
-   digest folds) runs, so the C-speed host work drains behind the
-   in-flight update-skeleton launch.
+1. **staged chunk pipeline** (engine/bfs.py + pipeline.py): no thread
+   at all — JAX async dispatch is the worker.  In a level of fused
+   chunks the level loop runs, around chunk k's successor launch S(k):
+   W(k-1), the one blocking wait on a successor program (its counts,
+   chunk k-1's verdict flags, new_n, vn; the capacity check; chunk
+   k-1's row slices enqueued); G(k+1), the upload and guard launch of
+   chunk k+1, BEFORE S(k) (it reads the frontier only); S(k); then,
+   behind S(k), A(k-1) (fetch of the enqueued slices, fingerprint-set
+   insert, arena assembly, digest folds) and H(k+1) (the guard counts,
+   widths, host
+   compaction and index uploads that shape S(k+1)).  At most TWO
+   successor launches are open, plus at most ONE chunk of which only
+   the guard stage has run; commits stay strictly in chunk order.  The
+   host blocks at W and, in H, on a guard program queued ahead of the
+   running successor program (docs/engine.md § Async execution).
 2. **background spill-run merges** (storage/tiered.py): k-way merges run
    on an :class:`AsyncWorker`.  Inputs are immutable sorted runs, so
    lookups keep serving from them until the merged output is atomically

@@ -30,7 +30,8 @@ from test_oracle_replay import replay_through_oracle
 TINY = Config(2, 2, 1, 1)
 KIP101_LEVELS = [1, 4, 14, 44, 100, 166, 268, 456, 684, 976, 1292, 1486]
 CUT_KEYS = {"depth", "frontier", "rows_committed", "chunks_committed",
-            "chunks_discarded", "chunks", "dedup_lanes", "level_ms",
+            "chunks_discarded", "chunks", "chunks_ahead", "dedup_lanes",
+            "level_ms",
             "step_ms", "host_ms",
             "successor_launches"} | set(WORK_FIELDS) | set(hostio.LEVEL_COUNTERS)
 # the fused path from 64 rows up, so a small chunk leaves launch 2 in flight
@@ -187,9 +188,11 @@ def test_cut_level_and_level_records_sum_to_the_run_totals(
 @pytest.mark.parametrize("overlap", [True, False], ids=["overlap", "serial"])
 def test_multi_chunk_cut_reports_the_chunk_it_dropped(tmp_path, overlap):
     """Level 11 of Kip101 in chunks of 256 rows: the violating row sits in
-    the fourth, so with overlap on the fifth is in flight at the verdict and
-    is dropped; the serial path never dispatched it.  Same verdict, same
-    trace."""
+    the fourth of six.  With overlap on the fifth chunk's guard stage has run
+    ahead (ISSUE 42) when the verdict is read with the fourth's counts,
+    before the fifth's successor launch is queued: that stage is all that is
+    dropped, and nothing in flight is discarded.  The serial path never
+    dispatched it.  Same verdict, same trace."""
     model, kw = _kip101_verdict()
     res = check(model, run=RunContext(str(tmp_path / "run")), chunk_size=256,
                 overlap=overlap, **FUSED, **kw)
@@ -202,19 +205,20 @@ def test_multi_chunk_cut_reports_the_chunk_it_dropped(tmp_path, overlap):
     dropped = 1 if overlap else 0
     assert rec["chunks_discarded"] == dropped
     assert rec["chunks"] == 4 + dropped  # every chunk the level dispatched
-    assert rec["discarded_dispatches"] == dropped
-    assert (rec["discarded_ms"] > 0) == overlap
-    # the dropped chunk's launches are dispatches, not committed launches:
-    # two fused launches a committed chunk, launch 1 and 2 of the dropped one
+    assert (rec["discarded_dispatches"], rec["discarded_ms"]) == (0, 0)
+    # of the four committed chunks, all but the level's first went out ahead
+    assert rec["chunks_ahead"] == (3 if overlap else 0)
+    # the dropped chunk's guard launch is a dispatch, not a committed
+    # launch: two fused launches a committed chunk, launch 1 of the fifth
     assert rec["successor_launches"] == 2 * 4
-    assert rec["dispatches"] == 2 * (4 + dropped)
+    assert rec["dispatches"] == 2 * 4 + dropped
     # the probe's lanes are the committed chunks' too: one probe a chunk
     # over the layout dedup was handed, its live prefix searched
     assert 0 < rec["probe_lanes"] <= rec["probe_lanes_plain"] \
         == rec["dedup_lanes"]
     discarded = [s for s in _spans(tmp_path / "run")
                  if s["span"] == "dispatch" and s.get("discarded")]
-    assert [s["program"] for s in discarded] == ["fsc"] * dropped
+    assert discarded == []
     ref = check(model, **FUSED, **kw)  # the same path, one chunk a level
     assert render_trace(model.meta, ref.violation.trace) == \
         render_trace(model.meta, v.trace)

@@ -266,6 +266,111 @@ def test_overlap_bit_identity_sharded_host_backend(monkeypatch):
     assert on.stats["overlap"]["staged_chunks_peak"] <= 2
 
 
+# --- the fused chunk loop's schedule (ISSUE 42) ----------------------------
+
+# the fused path from 32 rows up, in chunks of 32: frl(2,2,3)'s widest
+# levels stream three chunks, each through the two fused launches
+FUSED32 = dict(min_bucket=32, chunk_size=32, compact_gate=32)
+
+
+def _level_counts(res):
+    return [(lv["frontier"], lv["enabled_candidates"], lv["new"],
+             lv["duplicates"], lv["chunks"]) for lv in res.stats["levels"]]
+
+
+@pytest.mark.parametrize("backend", ["device", "device-hash", "host"])
+def test_overlap_bit_identity_levels_of_three_fused_chunks(
+        monkeypatch, tmp_path, backend):
+    """A level of three or more FUSED chunks: with overlap on a chunk's
+    guard launch goes out one chunk ahead and (sorted `device` backend)
+    its rows are cut before the next successor launch is queued; level
+    counts, duplicates and the stamped digest chains equal overlap
+    off's."""
+    import numpy.testing as npt
+
+    from kafka_specification_tpu.resilience.checkpoints import verify_file
+
+    seen = {}
+    for flag in ("0", "1"):
+        monkeypatch.setenv("KSPEC_OVERLAP", flag)
+        ck = str(tmp_path / f"ck-{flag}")
+        res = check(frl.make_model(2, 2, 3), visited_backend=backend,
+                    checkpoint_dir=ck, run=RunContext(str(tmp_path / flag)),
+                    **FUSED32)
+        assert res.stats["pipeline"] == "fused"
+        assert not res.stats.get("degradations")
+        seen[flag] = (
+            _verdict(res), _level_counts(res),
+            verify_file(os.path.join(ck, "bfs_checkpoint.npz"))[
+                "digest_chain"],
+            [lv["chunks_ahead"] for lv in res.stats["levels"]],
+            res.stats["overlap"],
+        )
+    off, on = seen["0"], seen["1"]
+    assert on[0] == off[0] and on[1] == off[1]
+    npt.assert_array_equal(on[2], off[2])
+    chunks = [c[4] for c in on[1]]
+    assert max(chunks) >= 3
+    # every chunk but a level's first went out ahead, whatever the backend
+    assert on[3] == [n - 1 for n in chunks] and not any(off[3])
+    assert (on[4]["staged_chunks_peak"], on[4]["guard_ahead_peak"]) == (2, 1)
+    assert (off[4]["staged_chunks_peak"], off[4]["guard_ahead_peak"]) == (0, 0)
+
+
+@pytest.mark.parametrize("backend", ["device", "host"])
+def test_overlap_bit_identity_verdict_in_a_middle_chunk(
+        monkeypatch, tmp_path, backend):
+    """`configs/Kip101.cfg` in chunks of 256: the violating row lies in the
+    fourth of level 12's six chunks (the first, on the `host` backend).
+    With overlap on, on the `host` backend the next chunk's successor
+    launch AND the guard launch of the one after it are out when the
+    commit reads the verdict: both are dropped and booked `discarded`.  On the sorted `device` backend the
+    verdict flags are read with the fourth chunk's counts, before the
+    fifth's successor launch is queued: only the fifth's guard stage has
+    run, and nothing in flight is discarded.  The first violation, the
+    trace and every committed level equal overlap off's."""
+    from kafka_specification_tpu.utils.cfg import build_model, parse_cfg
+
+    cfg = parse_cfg("configs/Kip101.cfg")
+    model = build_model("Kip101", cfg)
+    seen = {}
+    for flag in ("0", "1"):
+        monkeypatch.setenv("KSPEC_OVERLAP", flag)
+        res = check(model, run=RunContext(str(tmp_path / flag)),
+                    check_deadlock=cfg.check_deadlock, min_bucket=64,
+                    compact_gate=64, chunk_size=256,
+                    visited_backend=backend)
+        dropped = sorted(
+            r["program"] for r in read_jsonl_tolerant(
+                str(tmp_path / flag / "spans.jsonl"))
+            if r.get("span") == "dispatch" and r.get("discarded"))
+        seen[flag] = (res, dropped)
+    (off, off_dropped), (on, on_dropped) = seen["0"], seen["1"]
+    assert not off.ok and _verdict(on) == _verdict(off)
+    assert _trace_values(on) == _trace_values(off)
+    assert _level_counts(on) == _level_counts(off)
+    assert max(c[4] for c in _level_counts(on)) >= 3
+    cut, cut_off = on.stats["cut_level"], off.stats["cut_level"]
+    # (the `host` backend's arena holds a level in another order: its
+    # violating row lies in another chunk, with two more behind it)
+    done = cut_off["chunks_committed"]
+    assert cut["chunks_committed"] == done == (4 if backend == "device" else 1)
+    assert cut["rows_committed"] == cut_off["rows_committed"]
+    assert cut["chunks_ahead"] == done - 1
+    assert off_dropped == []
+    assert (cut_off["chunks_discarded"],
+            cut_off["discarded_dispatches"]) == (0, 0)
+    if backend == "host":
+        assert on_dropped == ["fgd", "fsc"]
+        assert (cut["chunks_discarded"], cut["discarded_dispatches"],
+                cut["chunks"]) == (2, 2, done + 2)
+        assert cut["discarded_ms"] > 0
+    else:
+        assert on_dropped == []
+        assert (cut["chunks_discarded"], cut["discarded_dispatches"],
+                cut["chunks"]) == (1, 0, done + 1)
+
+
 # --- staging bounds + span evidence (satellite: test coverage) ------------
 
 
@@ -279,9 +384,54 @@ def test_two_slot_pipeline_never_holds_more_than_two_chunks(monkeypatch):
     # multiple chunks per level -> both slots used, and the structural
     # bound holds
     assert ov["staged_chunks_peak"] == 2
+    # ... and beside the two open successor launches at most one chunk
+    # whose guard stage has run ahead (none here: below the default gate
+    # a chunk is a legacy chunk, which is never split)
+    assert ov["guard_ahead_peak"] == 0
     monkeypatch.setenv("KSPEC_OVERLAP", "0")
     res2 = check(frl.make_model(2, 2, 3), min_bucket=32, chunk_size=32)
     assert res2.stats["overlap"]["staged_chunks_peak"] <= 1
+    assert res2.stats["overlap"]["guard_ahead_peak"] == 0
+
+
+@pytest.mark.perf
+def test_staging_bound_two_successor_launches_and_one_guard_ahead(
+        monkeypatch):
+    """The bound restated for fused chunks (ISSUE 42): at most two open
+    successor launches (the chunk committing and the one dispatched) plus
+    at most one chunk of which only the guard stage has run, counted where
+    the loop stages them, in every level of every length."""
+    monkeypatch.setenv("KSPEC_OVERLAP", "1")
+    from kafka_specification_tpu.engine import pipeline as pl
+
+    open_guards, peak = set(), [0]
+    guard_stage = pl.FusedPipeline.guard_stage
+    staged = pl.FusedPipeline.run_chunk_staged
+
+    def counting_guard(self, *a, **kw):
+        g = guard_stage(self, *a, **kw)
+        open_guards.add(id(g))
+        peak[0] = max(peak[0], len(open_guards))
+        return g
+
+    def counting_staged(self, *a, ahead=None, **kw):
+        before = set(open_guards)
+        out = staged(self, *a, ahead=ahead, **kw)
+        # the chunk has its successor launch: neither its guard stage, run
+        # ahead or inside this call, is a guard alone any longer
+        open_guards.intersection_update(before)
+        open_guards.discard(id(ahead))
+        return out
+
+    monkeypatch.setattr(pl.FusedPipeline, "guard_stage", counting_guard)
+    monkeypatch.setattr(pl.FusedPipeline, "run_chunk_staged",
+                        counting_staged)
+    res = check(frl.make_model(2, 2, 3), **FUSED32)
+    ov = res.stats["overlap"]
+    assert (ov["staged_chunks_peak"], ov["guard_ahead_peak"]) == (2, 1)
+    # a chunk's own guard and the next chunk's: two StagedGuards exist for
+    # the moment between them, of which one is ahead
+    assert peak[0] == 2 and len(open_guards) <= 1
 
 
 @pytest.mark.perf
