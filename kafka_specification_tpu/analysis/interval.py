@@ -121,9 +121,6 @@ class IVal:
     def ndim(self):
         return self.lo.ndim
 
-    def is_concrete(self) -> bool:
-        return bool(np.all(self.lo == self.hi))
-
     def concrete_scalar(self) -> Optional[int]:
         if self.shape == () and self.lo.item() == self.hi.item():
             return int(self.lo.item())

@@ -1051,7 +1051,7 @@ class FusedPipeline:
             if with_merge:
                 # no squeeze: at the pooled width it narrows nothing, and
                 # the stable sort puts the masked rows last in any case
-                # (see _Step._build)
+                # (see _Step.build_raw)
                 hi, lo, orbit = fp_stage(cand, ok, model)
                 (out, out_parent, out_act, new_n, out_hi, out_lo,
                  vhi, vlo, vn, _rank, work) = sorted_dedup_stage(
@@ -1414,7 +1414,7 @@ class DevicePipeline:
       level-new sorted set alone, and the level's novel candidates come
       back (rows + fingerprint lanes, chunk-major CANDIDATE order) for
       ONE batched host FpSet / tiered-run probe per level
-      (engine.bfs._commit_device_level) — host syncs drop from
+      (engine.level.commit_device_level) — host syncs drop from
       O(chunks) to O(1) per level on the production backend.
 
     Both require analyzer-proven per-field value hulls
@@ -1464,7 +1464,7 @@ class DevicePipeline:
         #: level program carries NO visited set — intra-level novelty
         #: against the level-new sorted set only, and the host probes
         #: the level's novel candidates in ONE batched call per level
-        #: (engine.bfs._commit_device_level's host branch)
+        #: (engine.level.commit_device_level's host branch)
         self.host_mode = visited_backend == "host"
         from ..pipeline_registry import backend_fallback_reason
 

@@ -168,10 +168,6 @@ class RunContext:
         _atomic_write_json(self.manifest_path, self.manifest,
                            fsync=self.durable)
 
-    def update_manifest(self, **fields) -> None:
-        self.manifest.update(fields)
-        self.write_manifest()
-
     def record_config(self, **fields) -> None:
         """Stamp run configuration (module, engine, knobs...) — keys land
         under manifest['config'], merged across calls (a resumed run may

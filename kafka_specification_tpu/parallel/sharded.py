@@ -40,7 +40,12 @@ from ..engine.bfs import (
     Violation,
     _next_pow2,
     _Step,
-    walk_trace,
+    build_violation as _build_violation,
+    chain_stamp,
+    decode_packed,
+    init_violation_result,
+    readback_chain,
+    u64 as _u64,
 )
 from ..engine.hostio import HostIO
 from ..engine.pipeline import (
@@ -1729,25 +1734,9 @@ def check_sharded(
         bad0 = _first_violation(init_packed)
         if bad0 is not None:
             inv, idx = bad0
-            st = {
-                k: np.asarray(v)
-                for k, v in spec.unpack(jnp.asarray(init_packed[idx])).items()
-            }
-            dec = model.decode(st) if model.decode else st
-            res = CheckResult(
-                model.name,
-                [n0],
-                n0,
-                0,
-                Violation(
-                    invariant=inv.name,
-                    depth=0,
-                    state=dec,
-                    trace=[("<init>", dec)],
-                ),
-                time.perf_counter() - t0,
-                0.0,
-                stats={"devices": D},
+            res = init_violation_result(
+                model, inv, init_packed[idx], [n0], n0,
+                time.perf_counter() - t0, stats={"devices": D},
             )
             sp_.finish()
             obs_.finish(res)
@@ -1778,9 +1767,6 @@ def check_sharded(
     host_sets = None
     spill_base = None
     ephemeral_spill = None
-
-    def _u64(hi, lo):
-        return (hi.astype(np.uint64) << np.uint64(32)) | lo.astype(np.uint64)
 
     # distribute inits to owner shards; per-shard sorted visited arrays
     hi0, lo0 = fingerprint_lanes(jnp.asarray(init_packed), spec.exact64)
@@ -2380,19 +2366,6 @@ def check_sharded(
             _integ.flip_bit(levels_arr)
         return levels_arr
 
-    def _chain_stamp() -> dict:
-        # never stamp an UNANCHORED chain (rebuilt from a pre-integrity
-        # checkpoint: digests unknown) — see engine.bfs._chain_stamp
-        return (
-            {"digest_chain": chain.to_array()}
-            if chain is not None and chain.anchored
-            else {}
-        )
-
-    def _readback_chain(path: str, at_depth: int) -> None:
-        if chain is not None and chain.anchored:
-            _integ.readback_chain(path, depth=at_depth)
-
     def _save_checkpoint(sync: bool = False):
         if host_sets is not None and use_disk:
             # record run manifests + hot dumps — the runs ARE the durable
@@ -2441,7 +2414,7 @@ def check_sharded(
                 levels=_levels_for_save(),
                 total=total,
                 **extra,
-                **_chain_stamp(),
+                **chain_stamp(chain),
             )
             # single-process runs carry the payload (incl. its layout
             # stamp) inline; multi-process mains stamp their own
@@ -2450,7 +2423,7 @@ def check_sharded(
 
             def _main_done(path, m=marks, d=depth):
                 _advance_spill_gc(m)
-                _readback_chain(path, d)
+                readback_chain(chain, path, d)
 
             _store_save(main, on_done=_main_done, sync=sync)
             return
@@ -2560,9 +2533,9 @@ def check_sharded(
                 mesh_D=D,
                 mesh_P=jax.process_count(),
                 **extra,
-                **_chain_stamp(),
+                **chain_stamp(chain),
             ),
-            on_done=lambda p, d=depth: _readback_chain(p, d),
+            on_done=lambda p, d=depth: readback_chain(chain, p, d),
             sync=sync,
         )
 
@@ -2631,10 +2604,6 @@ def check_sharded(
         # run can start aging out of the deletion barrier
         _save_checkpoint()
 
-    def decode_row(row):
-        st = {k: np.asarray(v) for k, v in spec.unpack(jnp.asarray(row)).items()}
-        return model.decode(st) if model.decode else st
-
     # per level, shard-major discovery order: (rows, parent_global, act)
     trace_store = []
     if store_trace:
@@ -2656,21 +2625,15 @@ def check_sharded(
     collect_trace = store_trace or plog is not None
 
     def build_violation(inv_name, d_level, idx):
-        """Full trace when any source can resolve it, else None (the
-        caller reports the violating state trace-less)."""
-        if store_trace:
-            return walk_trace(
-                trace_store, model.actions, decode_row, inv_name, d_level,
-                idx, obs=obs_,
-            )
-        if plog is not None and plog.has_levels(d_level):
-            # per-shard on-disk parent logs: O(depth) single-row reads —
-            # this is what makes sharded traces survive checkpoint resume
-            return walk_trace(
-                plog.view(), model.actions, decode_row, inv_name, d_level,
-                idx, obs=obs_, source="disk",
-            )
-        return None
+        # (the per-shard on-disk parent logs are what makes sharded
+        # traces survive a checkpoint resume)
+        on_disk = not store_trace and plog is not None \
+            and plog.has_levels(d_level)
+        return _build_violation(
+            model, trace_store if store_trace else None,
+            plog.view() if on_disk else None, inv_name, d_level, idx,
+            obs=obs_,
+        )
 
     _shard_beat(depth, event="start", resumed=bool(resumed))
     cut = False
@@ -3607,7 +3570,7 @@ def check_sharded(
                 violation = build_violation(inv_name, depth, gidx) or Violation(
                     invariant=inv_name,
                     depth=depth,
-                    state=decode_row(row),
+                    state=decode_packed(model, row),
                     trace=[],
                 )
                 break
@@ -3863,7 +3826,7 @@ def check_sharded(
                 ) or Violation(
                     invariant=inv.name,
                     depth=depth,
-                    state=decode_row(rows[idx]),
+                    state=decode_packed(model, rows[idx]),
                     trace=[],
                 )
             sp_.finish()

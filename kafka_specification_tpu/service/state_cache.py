@@ -654,10 +654,6 @@ class StateSpaceCache:
                 continue
         return removed
 
-    def iter_entries(self):
-        """Corpus view of this cache (see :func:`iter_corpus`)."""
-        return iter_corpus(self.root)
-
 
 def iter_corpus(root: str):
     """Yield every readable, self-consistent entry record under a cache

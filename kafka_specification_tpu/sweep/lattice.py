@@ -105,14 +105,6 @@ class LatticeSpec:
             "sheets": [s.record() for s in self.sheets],
         }
 
-    def axis_names(self) -> list:
-        seen: list = []
-        for s in self.sheets:
-            for a in s.axes:
-                if a.name not in seen:
-                    seen.append(a.name)
-        return seen
-
 
 @dataclass
 class LatticePoint:

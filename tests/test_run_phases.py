@@ -1,0 +1,210 @@
+"""`check()` as a driver over a run-state object and five phases (ISSUE 43;
+docs/engine.md § The run and its phases): what a run does, held to a golden
+that the SAME test body wrote at the parent commit (4fec30a, before `check`
+was taken apart), and the one verdict path both engines call.
+
+The golden (`tests/data/run_phases_golden.json`) holds, for the small Kip320
+job (2 brokers, 277 states, diameter 11; chunks of 16 rows through an
+8-row gate, so levels stream several chunks) under each pipeline and visited
+backend: the level counts, every level record with its host timings dropped
+(keys in order), the digest chain a checkpointed run stamps, and the names of
+the engine thread's spans in the order they ended.  It also holds the
+rendered counterexample of each engine.  Written by
+`python tests/test_run_phases.py --write` on the parent's tree; never by a
+test run.  A statement of the commit path that moves across another shows
+here as a counter, a key or a span out of place."""
+
+import dataclasses
+import gc
+import json
+import os
+import sys
+import time
+
+if __name__ == "__main__":  # (pytest runs from the root, with tests/ added)
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import conftest  # noqa: F401  (the suite's platform: CPU, 8 devices)
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from kafka_specification_tpu.engine.bfs import check
+from kafka_specification_tpu.engine.hostio import LEVEL_TIMINGS
+from kafka_specification_tpu.models import id_sequence, kip320
+from kafka_specification_tpu.models.base import Invariant
+from kafka_specification_tpu.models.kafka_replication import Config
+from kafka_specification_tpu.obs import RunContext, read_jsonl_tolerant
+from kafka_specification_tpu.parallel.sharded import check_sharded
+from kafka_specification_tpu.utils.pretty import render_trace
+
+GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
+                      "run_phases_golden.json")
+CFG = Config(2, 2, 1, 1)
+KW = dict(min_bucket=8, compact_gate=8, chunk_size=16)
+PIPELINES = ("fused", "device", "legacy")
+BACKENDS = ("device", "device-hash", "host")
+# host clocks: two runs do not repeat them
+DROP = ("ts", "unix", "run_id", "level_ms", "step_ms", "host_ms", "store_ms",
+        "io_hidden_ms", "io_exposed_ms", "overlap_efficiency",
+        "host_probe_ms") + LEVEL_TIMINGS
+# the span another thread ends (the checkpoint writer's), and the one
+# whose presence says what an earlier call left in the step cache
+OFF_THREAD = ("compile", "checkpoint-write")
+FIRST_TRY = Config(3, 1, 1, 2)  # WeakIsr at depth 11, 78,832 states
+
+
+def _golden():
+    with open(GOLDEN) as f:
+        return json.load(f)
+
+
+def _span_names(run_dir):
+    names = []
+    for r in read_jsonl_tolerant(os.path.join(run_dir, "spans.jsonl")):
+        if r.get("kind") != "span" or r.get("ph") != "E":
+            continue
+        if r["span"] in OFF_THREAD:
+            continue
+        names.append(r["span"] + (":" + r["program"]
+                                  if r["span"] == "dispatch" else ""))
+    return names
+
+
+def _observe(tmp, pipeline, backend):
+    """One checkpointed run of the small job on a fresh model -> what the
+    golden holds of it (JSON types only)."""
+    model = kip320.make_model(CFG)
+    run_dir, ck = os.path.join(tmp, "run"), os.path.join(tmp, "ck")
+    res = check(model, run=RunContext(run_dir), checkpoint_dir=ck,
+                pipeline=pipeline, visited_backend=backend, **KW)
+    with np.load(os.path.join(ck, "bfs_checkpoint.npz")) as z:
+        chain = np.array(z["digest_chain"]).tolist()
+    return {
+        "levels": res.levels,
+        "total": res.total,
+        "records": [[[k, v] for k, v in rec.items() if k not in DROP]
+                    for rec in res.stats["levels"]],
+        "chain": chain,
+        "spans": _span_names(run_dir),
+    }
+
+
+def _violation(res, model):
+    v = res.violation
+    return {
+        "levels": res.levels,
+        "total": res.total,
+        "invariant": v.invariant,
+        "depth": v.depth,
+        "state": repr(v.state),
+        "trace": [[a, repr(s)] for a, s in v.trace],
+        "rendered": render_trace(model.meta, v.trace),
+    }
+
+
+def _init_violation_model():
+    base = id_sequence.make_model(3)
+    return dataclasses.replace(base, invariants=[
+        Invariant("NotZero", lambda s: s["nextId"] != 0)])
+
+
+def _verdicts():
+    """The verdict of each engine on the violating jobs."""
+    mesh = Mesh(np.array(jax.devices()[:2]), ("d",))
+    out = {}
+    for name, run in (
+            ("single", lambda m: check(m, min_bucket=1024)),
+            ("sharded-2", lambda m: check_sharded(m, mesh=mesh,
+                                                  min_bucket=1024))):
+        m = kip320.make_first_try_model(FIRST_TRY)
+        out[f"first-try/{name}"] = _violation(run(m), m)
+        m = _init_violation_model()
+        out[f"init/{name}"] = _violation(run(m), m)
+    return out
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("pipeline", PIPELINES)
+def test_a_run_repeats_the_parent_commits_records(tmp_path, pipeline,
+                                                  backend):
+    want = _golden()["runs"][f"{pipeline}/{backend}"]
+    got = json.loads(json.dumps(_observe(str(tmp_path), pipeline, backend)))
+    assert got["levels"] == want["levels"] and got["total"] == want["total"]
+    assert got["chain"] == want["chain"]
+    for depth, (a, b) in enumerate(zip(got["records"], want["records"]), 1):
+        assert a == b, f"level record {depth}"
+    assert len(got["records"]) == len(want["records"])
+    assert got["spans"] == want["spans"]
+
+
+def test_a_finished_run_is_freed_without_the_collector(tmp_path):
+    """The run object holds the visited set, the frontier and the trace
+    store: nothing it owns may point back at it (the pipeline's
+    `on_degrade_chunk`, a checkpoint validator, a writer's callback), or a
+    served job's device memory waits for the cyclic collector (on the chip
+    `peak_hbm_MiB` read 485 for 293 and 653 for 307 with such a cycle)."""
+    from kafka_specification_tpu.engine.run import Run
+
+    gc.collect()
+    gc.disable()
+    try:
+        for kw in (dict(), dict(checkpoint_dir=str(tmp_path / "ck")),
+                   dict(pipeline="device", store="disk",
+                        mem_budget="64K", spill_dir=str(tmp_path / "sp"))):
+            # (runs of earlier tests in this process may be held by a
+            # traceback some test keeps: only this call's run counts)
+            old = {id(o) for o in gc.get_objects() if isinstance(o, Run)}
+            assert check(kip320.make_model(CFG), **KW, **kw).total == 277
+            for _ in range(50):  # (a worker thread may be on its way out)
+                alive = [o for o in gc.get_objects()
+                         if isinstance(o, Run) and id(o) not in old]
+                if not alive:
+                    break
+                time.sleep(0.1)
+            assert not alive, (kw, [type(x).__name__ for x in
+                                    gc.get_referrers(alive[0])])
+    finally:
+        gc.enable()
+
+
+@pytest.fixture(scope="module")
+def verdicts():
+    return json.loads(json.dumps(_verdicts()))
+
+
+@pytest.mark.parametrize("job", ["first-try", "init"])
+@pytest.mark.parametrize("engine", ["single", "sharded-2"])
+def test_each_engine_returns_the_parent_commits_violation(verdicts, job,
+                                                          engine):
+    """Both engines build their Violation through `engine.bfs`'s
+    `decode_packed`, `init_violation_result` and `build_violation`."""
+    got, want = verdicts[f"{job}/{engine}"], _golden()["verdicts"]
+    assert got == want[f"{job}/{engine}"]
+    # the two engines agree on what a user reads first; which violating
+    # state of the level each meets first is its own discovery order's
+    other = verdicts[f"{job}/single"]
+    for key in ("levels", "total", "invariant", "depth"):
+        assert got[key] == other[key], key
+    assert len(got["trace"]) == len(other["trace"]) == got["depth"] + 1
+    if job == "init":
+        assert got == other and got["trace"] == [["<init>", "0"]]
+
+
+if __name__ == "__main__":
+    if sys.argv[1:] != ["--write"]:
+        raise SystemExit("usage: python tests/test_run_phases.py --write")
+    import tempfile
+
+    runs = {}
+    for p in PIPELINES:
+        for b in BACKENDS:
+            with tempfile.TemporaryDirectory() as tmp:
+                runs[f"{p}/{b}"] = _observe(tmp, p, b)
+    with open(GOLDEN, "w") as f:
+        json.dump({"runs": runs, "verdicts": _verdicts()}, f, indent=0,
+                  sort_keys=False)
+        f.write("\n")
+    print(f"wrote {GOLDEN}")

@@ -50,9 +50,23 @@ BUILD_EVENTS = (
 
 @pytest.fixture(scope="module")
 def events():
-    """Every event name JAX reports while this file's tests run."""
+    """Every event name JAX reports while this file's tests run.
+
+    The file starts from empty JAX caches, whatever its worker ran before
+    it.  JAX reports `jaxpr_trace_duration` whenever a jitted call misses
+    the C++ fast path, also where its trace is cached and nothing is
+    built; and an eager primitive whose FIRST call with some signature ran
+    inside an eager transformation (`tests/test_differential_walk.py`
+    runs the kernels under an eager `jax.vmap`: the trace state is not
+    clean, so JAX binds instead of compiling and hands the C++ cache no
+    executable) stays off the fast path for that signature for the life
+    of the process.  The sharded level loop's one eager slice a level
+    (scalar `convert_element_type`) then reported eleven "traces" in every
+    later call of a four-device run: the nine cases that were red in
+    whole runs since PR 37 and green alone (PR 43)."""
     from jax._src import monitoring as m
 
+    jax.clear_caches()
     seen = []
 
     def on_duration(name, secs, **kw):
