@@ -25,6 +25,7 @@ is run with TLC's deadlock check disabled for the same reason).
 
 from __future__ import annotations
 
+import collections
 import os
 import time
 from dataclasses import dataclass, field
@@ -284,6 +285,12 @@ class CheckResult:
 
 class _Step:
     """Builds and caches the jitted level step for one model."""
+
+    # pieces of a long invariant pass uploaded and launched ahead of the
+    # one whose verdict is read (`first_violation`): enough to keep upload,
+    # program and read-back overlapped, bounded so that a frontier of any
+    # length holds a few pieces on the device, not all of itself
+    PIECES_AHEAD = 4
 
     def __init__(self, model: Model):
         self.model = model
@@ -559,27 +566,51 @@ class _Step:
         program per padded row count `N` (pipeline.invariant_rows_program),
         kept with the level programs under (*key_head, N, inv_sig) — so
         it costs a launch, not an eager dispatch per operation of every
-        predicate.  `key_head`: the cache tag, then whatever else shapes
-        the program (the sharded engine's mesh); `where`: that engine's
-        placement, handed to ``io.put``."""
+        predicate.  Rows beyond `N` go through in pieces of `N`, a launch
+        each, `PIECES_AHEAD` of them uploaded and launched ahead of the
+        verdict being read (the frontier a depth cut leaves is a level's
+        worth: as ONE padded launch, 1,075,905 rows of 15 lanes held the
+        device idle for 0.35-0.8 s of a 1.5-2.0 s pass while the host
+        padded and uploaded 126 MB, and the level after it unpacks to
+        more than the device holds).  `key_head`: the cache tag, then
+        whatever else shapes the program (the sharded engine's mesh);
+        `where`: that engine's placement, handed to ``io.put``."""
         tag = key_head[0]
-        fn = self.cached(
-            (*key_head, N, self.inv_sig(True)),
-            lambda: invariant_rows_program(self.model, N),
-            program=tag, bucket=N,
-        )
-        # (a span and a profiler annotation, not a level's dispatch: it
-        # runs before the first level and after the last)
-        launch = obs_.dispatch(tag, bucket=N)
-        any_bad, first = fn(
-            io.put(_pad_rows(rows, N), *where), np.int32(rows.shape[0])
-        )
-        any_bad = io.fetch(any_bad)
-        launch.finish()
-        if not any_bad.any():
+        key = (*key_head, N, self.inv_sig(True))
+        # (spans and profiler annotations, not a level's dispatches: they
+        # run before the first level and after the last)
+        lowest = {}  # invariant -> its lowest violating row (pieces ascend)
+
+        def read(start, launch, any_bad, first):
+            any_bad = io.fetch(any_bad)
+            launch.finish()
+            if any_bad.any():
+                first = io.fetch(first)
+                for i in np.flatnonzero(any_bad):
+                    lowest.setdefault(int(i), start + int(first[i]))
+
+        ahead = collections.deque()  # launched, verdict not yet read
+        for start in range(0, max(rows.shape[0], 1), N):
+            piece = rows[start:start + N]
+            # (looked up a piece: a fresh entry is its `compile` span's
+            # wrapper for its first call only)
+            fn = self.cached(
+                key, lambda: invariant_rows_program(self.model, N),
+                program=tag, bucket=N,
+            )
+            launch = obs_.dispatch(tag, bucket=N)
+            ahead.append((start, launch, *fn(
+                io.put(_pad_rows(piece, N), *where),
+                np.int32(piece.shape[0]),
+            )))
+            if len(ahead) > self.PIECES_AHEAD:
+                read(*ahead.popleft())
+        while ahead:
+            read(*ahead.popleft())
+        if not lowest:
             return None
-        i = int(np.argmax(any_bad))
-        return self.model.invariants[i], int(io.fetch(first)[i])
+        i = min(lowest)
+        return self.model.invariants[i], lowest[i]
 
     def init_rows(self, io: HostIO, obs_: RunObserver):
         """The model's distinct initial states -> (rows u32[n0, K], hi,
@@ -829,6 +860,25 @@ class PreparedKernels:
         # reason the capacity is: the records are counts, not shapes,
         # so a seeded run reports what the run that seeded it did.
         self.level_high_waters: dict = {}  # depth -> record
+        self._visited0 = None  # (key, (vhi, vlo)): `initial_visited`
+
+    def initial_visited(self, vcap: int, hi: np.ndarray, lo: np.ndarray):
+        """The sorted visited set a run opens with, on the host: `vcap`
+        sentinel pairs, the initial states' sorted fingerprints first ->
+        (vhi, vlo), read-only.  The jobs of a shape open at one capacity
+        with the same initial states, so the image is built once and
+        uploaded by each (the same bytes a run without prepared kernels
+        fills afresh).  At 16,777,216 slots it is 128 MiB, and filling it
+        anew each pass faulted its pages in for 8 ms in one process and
+        67 ms in the next (huge pages or not: the allocator's luck), a
+        process-to-process step of 4% in a 1.5 s pass."""
+        key = (vcap, hi.tobytes(), lo.tobytes())
+        if self._visited0 is None or self._visited0[0] != key:
+            pair = sentinel_set(vcap, hi, lo)
+            for a in pair:
+                a.setflags(write=False)
+            self._visited0 = (key, pair)
+        return self._visited0[1]
 
     def note_result(self, res: "CheckResult") -> None:
         """Feed a finished run's sizing back: the final visited capacity
@@ -931,6 +981,16 @@ def prepare(model: Model) -> PreparedKernels:
     for `model` — the explicit warm entry point ``check(...,
     prepared=...)`` consumes."""
     return PreparedKernels(model)
+
+
+def sentinel_set(vcap: int, hi: np.ndarray, lo: np.ndarray):
+    """A sorted pair set of capacity `vcap` holding the sorted pairs
+    (`hi`, `lo`), on the host: sentinel pairs (all ones) sort last."""
+    vhi = np.full(vcap, 0xFFFFFFFF, np.uint32)
+    vlo = np.full(vcap, 0xFFFFFFFF, np.uint32)
+    vhi[:hi.shape[0]] = hi
+    vlo[:lo.shape[0]] = lo
+    return vhi, vlo
 
 
 def _pad_rows(arr: np.ndarray, n: int, fill=0):

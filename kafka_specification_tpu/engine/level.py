@@ -172,6 +172,7 @@ def commit_wait(r: Run, st):
     r.lvl.ahead += was_ahead
     r.lvl.rows_in += fp_n
     r.lvl.lanes += outs[16]
+    r.lvl.guard += st[2] * r.C
     # frontier-level verdicts (states being expanded = level `depth`)
     if r.check_invariants:
         viol_any_np = r.io.fetch(viol_any)
@@ -398,6 +399,7 @@ def commit_device_level(r: Run, fin, dispatch_s: float, t_dispatch: float,
     r.lvl.chunks += ran
     r.lvl.rows_in += min(ran * plan[0], plan[2])
     r.lvl.lanes += ran * out["lanes"]
+    r.lvl.guard += ran * plan[0] * r.C
     step_s = dispatch_s + wait_s
     r.lvl.step_s += step_s
     launches = out["launches"]
@@ -909,6 +911,7 @@ def _cut_level(r: Run, f_total: int, t_level: float) -> None:
             # of the committed ones (`chunks_committed`)
             chunks_ahead=r.lvl.ahead,
             dedup_lanes=r.lvl.lanes,
+            guard_lanes=r.lvl.guard,
             level_ms=round((time.perf_counter() - t_level) * 1e3, 1),
             step_ms=round(r.lvl.step_s * 1e3, 1),
             host_ms=round(r.lvl.host_s * 1e3, 1),
@@ -1031,6 +1034,10 @@ def _end_level(r: Run, f_total: int, t_level: float, lvl_io0,
                 "chunks_ahead": r.lvl.ahead,
                 # the lanes their dedup sides were handed
                 "dedup_lanes": r.lvl.lanes,
+                # the lanes their guard sides evaluated: padded rows
+                # x static fanout (rows handed where no width is
+                # padded: `frontier` x `fanout`)
+                "guard_lanes": r.lvl.guard,
                 **work_record(r.lvl.work),
                 # what the host launched, moved and stored this
                 # level (engine/hostio.py; docs/observability.md)

@@ -36,7 +36,7 @@ from . import bfs as _bfs
 from .bfs import (
     AdaptiveCompact, CheckResult, Violation, _f_all, _f_row, _f_rows,
     _next_pow2, _Step, build_violation, chain_stamp, decode_packed,
-    init_violation_result, readback_chain, u64,
+    init_violation_result, readback_chain, sentinel_set, u64,
 )
 from .hostio import HostIO
 from .pipeline import make_pipeline, resolve_pipeline, work_width
@@ -65,6 +65,9 @@ class Level:
         # (`chunks_ahead`), and the width their dedup sides were handed,
         # summed: the lanes every sort, probe and compaction ran
         self.chunks = self.rows_in = self.ahead = self.lanes = 0
+        # the lanes their guard sides evaluated (`guard_lanes`): a chunk's
+        # padded rows x the model's static fanout
+        self.guard = 0
         self.discarded = 0  # chunks dispatched and dropped at a verdict
         self.step_s = self.host_s = 0.0  # `step_ms`, `host_ms`
 
@@ -140,11 +143,13 @@ def _drop_ephemeral_spill(r: Run) -> None:
 
 def first_violation(r: Run, rows: np.ndarray):
     """The invariant pass over host-held rows (the initial states; the
-    frontier a cut left unexpanded): one launch of a cached program
-    per power-of-two row bucket -> (invariant, row index) or None."""
+    frontier a cut left unexpanded): launches of a cached program per
+    power-of-two row bucket, a chunk of the level loop's size at most a
+    launch -> (invariant, row index) or None."""
     sp_ = r.obs.open_span("host-invariants", rows=rows.shape[0])
     bad = r.step_builder.first_violation(
-        ("hinv",), _next_pow2(max(rows.shape[0], r.min_bucket)),
+        ("hinv",),
+        _next_pow2(max(min(rows.shape[0], r.chunk_size), r.min_bucket)),
         rows, r.io, r.obs,
     )
     sp_.finish()
@@ -214,6 +219,7 @@ def open_run(r: Run) -> Optional[CheckResult]:
     r.obs = RunObserver(r.run, r.stats_path, engine="bfs",
                        annotate=jax.profiler.TraceAnnotation)
     r.obs.check_begin(r.t_check, model=r.model.name)
+    r.obs.shape(r.model, r.C, r.K)
     r.io = HostIO(r.obs)  # counted transfers + named dispatches
 
     from ..storage import resolve_store
@@ -493,11 +499,11 @@ def _open_visited(r: Run, hi0, lo0, n0: int,
                 else 0,
             )
         )
-        r.vhi = np.full(r.vcap, 0xFFFFFFFF, np.uint32)
-        r.vlo = np.full(r.vcap, 0xFFFFFFFF, np.uint32)
-        r.vhi[:n0] = np.asarray(hi0)[order]
-        r.vlo[:n0] = np.asarray(lo0)[order]
-        r.vhi, r.vlo = r.io.put(r.vhi), r.io.put(r.vlo)
+        # (prepared kernels keep the image across the jobs of a shape)
+        image = (sentinel_set if r.prepared is None
+                 else r.prepared.initial_visited)(
+            r.vcap, np.asarray(hi0)[order], np.asarray(lo0)[order])
+        r.vhi, r.vlo = r.io.put(image[0]), r.io.put(image[1])
         r.vn = jnp.int32(n0)
 
 

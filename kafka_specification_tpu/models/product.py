@@ -28,7 +28,8 @@ def product_model(base: Model, k: int, name: str | None = None) -> Model:
     return product_models(
         [base] * k,
         name=name or f"{base.name} x{k}partitions",
-        meta={**base.meta, "partitions": k, "base": base.name},
+        meta={**base.meta, "partitions": k, "base": base.name,
+              "base_fanout": base.total_fanout},
     )
 
 
@@ -156,6 +157,7 @@ def product_models(bases, name: str | None = None, meta: dict | None = None) -> 
             **bases[0].meta,
             "partitions": k,
             "base": [b.name for b in bases],
+            "base_fanout": [b.total_fanout for b in bases],
         },
     )
 

@@ -94,6 +94,7 @@ class RunObserver:
         self._annotate = annotate
         self._check = None  # the open root span of this engine call
         self._phase = None  # the open check-open / check-close span
+        self._phase_attrs = {}  # what that span's end carries
         self._level = None  # the open level span
         self._t_begin = now()
         self._last_snapshot = 0.0
@@ -114,6 +115,19 @@ class RunObserver:
     def config(self, **fields) -> None:
         if self.run is not None:
             self.run.record_config(engine=self.engine, **fields)
+
+    def shape(self, model, fanout: int, lanes: int) -> None:
+        """What the engine was handed, on the manifest's `config` and on
+        the end of `check-open`: the static fanout and the packed lanes,
+        and of a product (models/product.py) the partitions and one
+        partition's fanout (a list where the partitions differ)."""
+        fields = dict(
+            fanout=fanout, lanes=lanes,
+            partitions=model.meta.get("partitions", 1),
+            base_fanout=model.meta.get("base_fanout", fanout),
+        )
+        self._phase_attrs = fields
+        self.config(**fields)
 
     # --- spans -------------------------------------------------------------
     def _open(self, kind: str, name: Optional[str], marker: bool,
@@ -154,8 +168,8 @@ class RunObserver:
 
     def _end_phase(self) -> None:
         if self._phase is not None:
-            self._phase.finish()
-            self._phase = None
+            self._phase.finish(**self._phase_attrs)
+            self._phase, self._phase_attrs = None, {}
 
     def open_span(self, kind: str, t0=None, **attrs) -> _Both:
         """A span closed by hand (``.finish(**attrs)``) that is the parent

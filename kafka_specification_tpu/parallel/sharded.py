@@ -1703,6 +1703,7 @@ def check_sharded(
     expander = _Step(model)
     C = expander.C
     K = spec.num_lanes
+    obs_.shape(model, C, K)
     # explicit per-tensor mesh layouts (mesh_layouts; asserted in
     # tests/test_sharded_device.py)
     layouts = mesh_layouts(mesh)
@@ -2720,7 +2721,7 @@ def check_sharded(
             # chunks committed, and the width their dedup sides were handed
             # (`R` a shard a chunk), summed over chunks and shards: the
             # single-device record's `chunks` and `dedup_lanes`
-            lvl_chunks = lvl_lanes = 0
+            lvl_chunks = lvl_lanes = lvl_guard = 0
             lvl_exch_bytes = lvl_exch_raw_bytes = 0
             # dispatched collective-bearing programs this level — one
             # launch PER SHARD each (the kspec_shard_launches_level
@@ -2967,12 +2968,13 @@ def check_sharded(
                 nonlocal verdict, lvl_act_en, lvl_new_per_shard
                 nonlocal lvl_en_per_shard, lvl_recv_per_shard
                 nonlocal shard_visited, lvl_exch_bytes, lvl_exch_raw_bytes
-                nonlocal lvl_chunks, lvl_lanes
+                nonlocal lvl_chunks, lvl_lanes, lvl_guard
                 ctx, outs, meta = st
                 bucket, frontier, took, chunk_off, _fv, t_chunk, _n = ctx
                 _attempt, _wt, _ca, T, W, R, compress, _launch = meta
                 lvl_chunks += 1
                 lvl_lanes += D * R
+                lvl_guard += D * bucket * C
                 (
                     out, out_parent, out_act, new_n, _vh, _vl, _vn,
                     viol_any, viol_idx, dl_any, dl_idx, act_en,
@@ -3153,6 +3155,7 @@ def check_sharded(
                 nonlocal lvl_exch_bytes, lvl_exch_raw_bytes
                 nonlocal lvl_dispatches, lvl_probe_ms
                 nonlocal prof_step, prof_host_s, lvl_chunks, lvl_lanes
+                nonlocal lvl_guard
                 lens = [p.shape[0] for p in pending]
                 plan = sdev.plan_level(lens, chunk, min_bucket)
                 if plan is None:
@@ -3297,6 +3300,7 @@ def check_sharded(
                 t_commit = time.perf_counter()
                 lvl_chunks += nc
                 lvl_lanes += nc * D * R
+                lvl_guard += nc * D * B * C
                 if not host_mode:
                     dev_vhi, dev_vlo, dev_vn = outs[4], outs[5], outs[6]
                 counts = io.fetch(outs[i_cnt]).astype(np.int64)  # [D]
@@ -3632,6 +3636,9 @@ def check_sharded(
                     "shard_launches": int(lvl_dispatches),
                     "chunks": lvl_chunks,
                     "dedup_lanes": lvl_lanes,
+                    # the lanes the guard sides evaluated: every shard's
+                    # padded rows x the static fanout
+                    "guard_lanes": lvl_guard,
                     **work_record(lvl_work),
                     # the single-device engine's host/device split and
                     # what the host launched and moved this level

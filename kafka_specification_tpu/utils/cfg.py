@@ -76,6 +76,10 @@ def parse_cfg(path_or_text) -> TlcConfig:
         text = path_or_text.read_text()
     elif "\n" not in str(path_or_text) and Path(str(path_or_text)).exists():
         text = Path(str(path_or_text)).read_text()
+    elif "\n" not in str(path_or_text) and str(path_or_text).endswith(".cfg"):
+        # one line that ends in `.cfg` is a path, never a cfg's text: say
+        # so, and not `KeyError: 'Replicas'` from whoever builds the model
+        raise FileNotFoundError(f"no cfg file at {str(path_or_text)!r}")
     else:
         text = str(path_or_text)
     cfg = TlcConfig()

@@ -38,6 +38,21 @@ CHECK_DEADLOCK FALSE
     assert cfg.check_deadlock is False
 
 
+@pytest.mark.parametrize("path", [
+    "configs/NoSuchJob.cfg", "Kip320FiveBrokerThreePartitions.cfg"])
+def test_parse_cfg_names_a_cfg_path_that_is_no_file(path):
+    """One line ending in `.cfg` is a path a user mistyped, not a cfg's
+    text: the error names it (it was `KeyError: 'Replicas'` from
+    `build_model`, one call later)."""
+    with pytest.raises(FileNotFoundError, match=path.replace(".", r"\.")):
+        parse_cfg(path)
+
+
+def test_parse_cfg_still_takes_one_line_of_text():
+    assert parse_cfg("INVARIANTS TypeOk").invariants == ["TypeOk"]
+    assert parse_cfg("INVARIANTS TypeOk\n").invariants == ["TypeOk"]
+
+
 def test_build_model_registry_covers_all_modules():
     import pathlib
 

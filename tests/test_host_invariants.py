@@ -141,6 +141,48 @@ def reachable():
     return model, rows, fine, empty
 
 
+@pytest.mark.parametrize("case", ["clean", "order", "lowest", "last-piece"])
+def test_rows_beyond_the_bucket_go_through_in_pieces(reachable, case):
+    """(e) PR 44: rows longer than the bucket are launched a piece of
+    `bucket` rows at a time (a depth cut leaves a level's worth of rows:
+    1,075,905 of them in the 3-partition cell) and the verdict is the
+    one launch's: the first invariant in declaration order that ANY piece
+    violates, and its lowest row over all pieces."""
+    model, rows, fine, empty = reachable
+    preds = {i.name: i for i in model.invariants}
+    states = _decoded(model, rows)
+    long_log = next(i for i, s in enumerate(states)
+                    if any(len(log) >= L for log in s))
+    assert [i.name for i in model.invariants] == ["ShortLogs", "SomeLog"]
+    # six pieces, the last of five rows: more than `PIECES_AHEAD`, so
+    # verdicts are read while later pieces are still being launched
+    n = 5 * MIN_BUCKET + 5
+    assert n // MIN_BUCKET + 1 > _Step.PIECES_AHEAD + 1
+    pick = (fine * 6)[:n]
+    want = None
+    if case == "order":
+        # SomeLog falls in piece 0, ShortLogs in piece 2: declaration order
+        pick[3], pick[2 * MIN_BUCKET + 7] = empty, long_log
+        want = ("ShortLogs", 2 * MIN_BUCKET + 7)
+    elif case == "lowest":
+        pick[MIN_BUCKET + 9] = pick[2 * MIN_BUCKET + 1] = empty
+        want = ("SomeLog", MIN_BUCKET + 9)
+    elif case == "last-piece":
+        pick[n - 1] = empty
+        want = ("SomeLog", n - 1)
+    io = HostIO()
+    got = _Step(model).first_violation(
+        ("hinv",), MIN_BUCKET, rows[pick], io,
+        RunObserver(None, None, engine="bfs"))
+    assert (got and (got[0].name, got[1])) == want
+    if want:
+        assert got[0] is preds[want[0]]
+    # six uploads of one bucket each, never the whole padded length
+    taken = io.take()
+    assert taken["h2d_puts"] == 6
+    assert taken["h2d_bytes"] == 6 * MIN_BUCKET * rows.shape[1] * 4
+
+
 @pytest.mark.parametrize("place", ["absent", "last"])
 @pytest.mark.parametrize("n", [1, MIN_BUCKET, MIN_BUCKET + 1])
 def test_row_counts_at_the_bucket_edges(reachable, n, place):

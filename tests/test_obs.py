@@ -195,7 +195,8 @@ def test_stats_shim_record_for_record_identical(tmp_path):
          if k not in ("successor_launches", "launches_per_chunk_max",
                       "io_hidden_ms", "io_exposed_ms",
                       "overlap_efficiency", "host_probe_ms",
-                      "store_ms", "chunks", "chunks_ahead", "dedup_lanes")
+                      "store_ms", "chunks", "chunks_ahead", "dedup_lanes",
+                      "guard_lanes")
          + WORK_FIELDS + LEVEL_COUNTERS}
         for r in r1.stats["levels"]
     ] == recs_bare
@@ -453,7 +454,7 @@ def test_sharded_per_shard_breakdowns_and_imbalance(tmp_path):
          if k not in ("exch_bytes", "exch_raw_bytes", "io_hidden_ms",
                       "io_exposed_ms", "shard_launches",
                       "host_probe_ms", "step_ms", "host_ms", "chunks",
-                      "dedup_lanes") + WORK_FIELDS
+                      "dedup_lanes", "guard_lanes") + WORK_FIELDS
          + LEVEL_COUNTERS}
         for r in res.stats["levels"]
     ] == recs

@@ -1,11 +1,14 @@
 """Product-space combinator (the multi-partition stretch definition)."""
 
+import functools
+
 import pytest
 
 from kafka_specification_tpu.engine.bfs import check
 from kafka_specification_tpu.models import id_sequence, kip320
 from kafka_specification_tpu.models.kafka_replication import Config
 from kafka_specification_tpu.models.product import product_model, product_oracle
+from kafka_specification_tpu.oracle.interp import oracle_bfs
 
 from helpers import assert_matches_oracle
 
@@ -21,13 +24,38 @@ def test_product_idsequence_matches_generic_oracle():
     assert res.total == 4**k  # |base|^k reachable product states
 
 
-def test_product_kip320_two_partitions_smoke():
+def convolve(levels, k, depth):
+    """Levels of the k-fold product of independent partitions with one
+    initial state each: a product state's depth is the sum of its parts',
+    so level d is the sum over d1 + ... + dk = d of the parts' levels."""
+    out = [1]
+    for _ in range(k):
+        out = [sum(out[i] * levels[d - i] for i in range(d + 1)
+                   if i < len(out) and d - i < len(levels))
+               for d in range(depth + 1)]
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _two_partitions_to_depth_three():
     base = kip320.make_model(Config(2, 2, 1, 1), invariants=("TypeOk",))
-    model = product_model(base, 2)
-    res = check(model, max_depth=3, min_bucket=64)
+    return check(product_model(base, 2), max_depth=3, min_bucket=64)
+
+
+@pytest.mark.parametrize("held_to", ["count", "convolution"])
+def test_product_kip320_two_partitions_smoke(held_to):
+    res = _two_partitions_to_depth_three()
     assert res.ok
-    # level 1 of the product = 2 x level 1 of the base (one partition steps)
-    assert res.levels[1] == 2 * 4
+    if held_to == "count":
+        # level 1 of the product = 2 x level 1 of the base (one partition
+        # steps)
+        assert res.levels[1] == 2 * 4
+    else:
+        one = oracle_bfs(
+            kip320.make_oracle(Config(2, 2, 1, 1), invariants=("TypeOk",)),
+            max_depth=3, keep_level_sets=False).levels
+        assert one == [1, 4, 12, 18]
+        assert res.levels == convolve(one, 2, 3) == [1, 8, 40, 132]
 
 
 @pytest.mark.slow

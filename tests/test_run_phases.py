@@ -11,8 +11,11 @@ backend: the level counts, every level record with its host timings dropped
 the engine thread's spans in the order they ended.  It also holds the
 rendered counterexample of each engine.  Written by
 `python tests/test_run_phases.py --write` on the parent's tree; never by a
-test run.  A statement of the commit path that moves across another shows
-here as a counter, a key or a span out of place."""
+test run.  PR 44 wrote it again for the one key it added to every level
+record, `guard_lanes` (nothing else differs from the parent's file, key
+for key), and holds that key to the `step` spans' buckets here.  A
+statement of the commit path that moves across another shows here as a
+counter, a key or a span out of place."""
 
 import dataclasses
 import gc
@@ -82,6 +85,13 @@ def _observe(tmp, pipeline, backend):
                 pipeline=pipeline, visited_backend=backend, **KW)
     with np.load(os.path.join(ck, "bfs_checkpoint.npz")) as z:
         chain = np.array(z["digest_chain"]).tolist()
+    # what `guard_lanes` (PR 44) must read: every `step` span's padded
+    # rows (a whole-level program: its chunks' too) x the static fanout
+    guard = {}
+    for r in read_jsonl_tolerant(os.path.join(run_dir, "spans.jsonl")):
+        if r.get("span") == "step" and r.get("ph") == "E":
+            guard[r["depth"] + 1] = guard.get(r["depth"] + 1, 0) + (
+                r["bucket"] * r.get("chunks", 1) * model.total_fanout)
     return {
         "levels": res.levels,
         "total": res.total,
@@ -89,6 +99,8 @@ def _observe(tmp, pipeline, backend):
                     for rec in res.stats["levels"]],
         "chain": chain,
         "spans": _span_names(run_dir),
+        "guard_lanes_of_spans": [guard[rec["depth"]]
+                                 for rec in res.stats["levels"]],
     }
 
 
@@ -138,6 +150,8 @@ def test_a_run_repeats_the_parent_commits_records(tmp_path, pipeline,
         assert a == b, f"level record {depth}"
     assert len(got["records"]) == len(want["records"])
     assert got["spans"] == want["spans"]
+    assert [dict(rec)["guard_lanes"] for rec in got["records"]] == got[
+        "guard_lanes_of_spans"]
 
 
 def test_a_finished_run_is_freed_without_the_collector(tmp_path):

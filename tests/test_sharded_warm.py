@@ -214,7 +214,7 @@ def test_level_records_and_spans_of_a_sharded_run(tmp_path, pipeline):
     for rec in recs:
         for key in ("step_ms", "host_ms", "level_ms", "shard_new",
                     "exch_bytes", "shard_launches", "chunks",
-                    "dedup_lanes") + LEVEL_COUNTERS:
+                    "dedup_lanes", "guard_lanes") + LEVEL_COUNTERS:
             assert key in rec, key
         # the lanes the shards' dedup sides ran hold every candidate, and
         # the host's blocked time is part of the level's

@@ -226,9 +226,10 @@ def test_level_records_carry_repeatable_counters(tmp_path, pipeline):
                     run=RunContext(str(tmp_path / f"run{i}")), **KW)
         runs.append(res.stats["levels"])
     exact = [k for k in LEVEL_COUNTERS if k not in LEVEL_TIMINGS] + [
-        "chunks", "dedup_lanes"]
+        "chunks", "dedup_lanes", "guard_lanes"]
     for rec in runs[0]:
-        assert set(LEVEL_COUNTERS) | {"store_ms", "dedup_lanes"} <= set(rec)
+        assert set(LEVEL_COUNTERS) | {"store_ms", "dedup_lanes",
+                                      "guard_lanes"} <= set(rec)
         # every candidate a level enabled lay in a lane its dedup sides
         # were handed; the host's blocked time is part of the level's
         assert rec["dedup_lanes"] >= rec["enabled_candidates"]
@@ -256,7 +257,7 @@ def test_level_records_carry_repeatable_counters(tmp_path, pipeline):
     emitted = read_jsonl_tolerant(str(tmp_path / "run0" / "stats.jsonl"))
     assert emitted and not any(
         set(LEVEL_COUNTERS) & set(r) or "store_ms" in r
-        or "dedup_lanes" in r for r in emitted)
+        or "dedup_lanes" in r or "guard_lanes" in r for r in emitted)
 
 
 # --- (d) a discarded dispatch is counted and marked ------------------------
