@@ -26,7 +26,9 @@ from ..resilience import integrity as _integ
 from ..resilience.integrity import IntegrityError
 from ..resilience.resources import ResourceExhausted, is_disk_full
 from .bfs import _f_all, _f_chunks, _f_rows, _hash_insert, _next_pow2, u64
-from .pipeline import grow_visited as _grow_visited, split_counts, work_record
+from .pipeline import (
+    grow_visited as _grow_visited, probes_run, split_counts, work_record,
+)
 from .run import (
     Level, Run, ckpt_poll, final_save, reclaim, save_checkpoint, violation_at,
 )
@@ -157,8 +159,8 @@ def commit_wait(r: Run, st):
     so the commit's fetches do not wait behind that launch (and a
     chunk that holds the verdict is never sliced, as in serial
     order)."""
-    start, fp_n, finalize, t_staged, was_ahead = (
-        st[0], st[1], st[3], st[7], st[11])
+    start, fp_n, finalize, t_staged, pre_vcap, was_ahead, set_n = (
+        st[0], st[1], st[3], st[7], st[9], st[11], st[12])
     queued_s = time.perf_counter() - t_staged
     t_wait = time.perf_counter()
     outs = finalize()
@@ -168,6 +170,9 @@ def commit_wait(r: Run, st):
     # holds the verdict ran its probe and merge like any other
     act_en_np, work = split_counts(r.io.fetch(counts, np.int64), r.n_work)
     r.lvl.work[:] += work
+    # every probe of a chunk's programs searches the visited set, at the
+    # capacity and from the length the chunk was dispatched with
+    r.lvl.probed(probes_run(work, pre_vcap), pre_vcap, set_n)
     r.lvl.chunks += 1
     r.lvl.ahead += was_ahead
     r.lvl.rows_in += fp_n
@@ -201,7 +206,7 @@ def commit_chunk(r: Run, st, waited=None) -> bool:
     thread; returns True when a verdict fired (the level stops and
     any younger staged chunk is discarded uncommitted)."""
     (start, fp_n, bucket, _finalize, pre_v, shadow, dispatch_s,
-     _t_staged, piece, pre_vcap, t_dispatch, was_ahead) = st
+     _t_staged, piece, pre_vcap, t_dispatch, was_ahead, _set_n) = st
     (outs, act_en_np, queued_s, wait_s, nn, rows
      ) = waited if waited is not None else commit_wait(r, st)
     (out, out_parent, out_act, new_n, _vh, _vl, _vn, viol_any,
@@ -400,6 +405,8 @@ def commit_device_level(r: Run, fin, dispatch_s: float, t_dispatch: float,
     r.lvl.rows_in += min(ran * plan[0], plan[2])
     r.lvl.lanes += ran * out["lanes"]
     r.lvl.guard += ran * plan[0] * r.C
+    for cap, set_n, a_chunk, a_level in out["probed"]:
+        r.lvl.probed(ran * a_chunk + a_level, cap, set_n)
     step_s = dispatch_s + wait_s
     r.lvl.step_s += step_s
     launches = out["launches"]
@@ -773,6 +780,7 @@ def _chunk_loop(r: Run, tail_chunks, dev_handled: int) -> None:
         mine, ahead = ahead, None
         from_ahead = mine is not None
         waited = None
+        set_n = 0  # the sorted visited set's length at the dispatch
         if r.visited_backend == "device":
             if staged is not None:
                 # the loop's one blocking wait on a successor
@@ -791,7 +799,8 @@ def _chunk_loop(r: Run, tail_chunks, dev_handled: int) -> None:
                         mine.drop()
                         r.lvl.discarded = 1
                     break
-            need = int(r.io.fetch(r.vn)) + M
+            set_n = int(r.io.fetch(r.vn))
+            need = set_n + M
             if need > r.vcap:
                 # one shared growth policy with the device level
                 # path (pipeline.grow_visited); growth is
@@ -856,6 +865,7 @@ def _chunk_loop(r: Run, tail_chunks, dev_handled: int) -> None:
             # the committed attempt's guard launch went out
             # before the previous chunk's successor launch
             int(from_ahead and getattr(finalize, "ahead", False)),
+            set_n,
         )
         if r.overlap_on:
             r.overlap_staged_peak = max(
@@ -912,6 +922,8 @@ def _cut_level(r: Run, f_total: int, t_level: float) -> None:
             chunks_ahead=r.lvl.ahead,
             dedup_lanes=r.lvl.lanes,
             guard_lanes=r.lvl.guard,
+            probes=r.lvl.probes,
+            probes_windowed=r.lvl.probes_windowed,
             level_ms=round((time.perf_counter() - t_level) * 1e3, 1),
             step_ms=round(r.lvl.step_s * 1e3, 1),
             host_ms=round(r.lvl.host_s * 1e3, 1),
@@ -1038,6 +1050,11 @@ def _end_level(r: Run, f_total: int, t_level: float, lvl_io0,
                 # x static fanout (rows handed where no width is
                 # padded: `frontier` x `fanout`)
                 "guard_lanes": r.lvl.guard,
+                # the sorted-set probes they ran, and those of them that
+                # searched the window of a capacity above it
+                # (`dedup.PROBE_WINDOW`)
+                "probes": r.lvl.probes,
+                "probes_windowed": r.lvl.probes_windowed,
                 **work_record(r.lvl.work),
                 # what the host launched, moved and stored this
                 # level (engine/hostio.py; docs/observability.md)

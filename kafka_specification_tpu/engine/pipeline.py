@@ -441,6 +441,14 @@ def work_record(work):
     return dict(zip(WORK_FIELDS + CANON_FIELDS, (int(x) for x in work)))
 
 
+def probes_run(work, cap: int) -> int:
+    """How many sorted-set probes a dispatch ran whose probes all searched
+    a capacity of `cap`, from its summed :func:`work_counts`: each probe
+    adds ``cap.bit_length()`` to ``probe_rounds_plain``."""
+    return (int(work[WORK_FIELDS.index("probe_rounds_plain")])
+            // max(1, cap.bit_length()))
+
+
 #: The most rows one iteration of :func:`novel_stage`'s loops moves (the
 #: block is a shape, :func:`novel_block`; how many blocks run is a device
 #: value).
@@ -1969,6 +1977,7 @@ class DevicePipeline:
             (6, 10, 11) if self.host_mode else (7, 11, 13)
         )
         attempt = 0
+        set_n = 0  # the visited set's length at the level's start
         while True:
             launch = None
             try:
@@ -1976,7 +1985,8 @@ class DevicePipeline:
                 if injected is not None:
                     raise injected
                 if not self.host_mode:
-                    need = int(io.fetch(vn)) + min(NCp * T, LN + T)
+                    set_n = int(io.fetch(vn))
+                    need = set_n + min(NCp * T, LN + T)
                     if need > vcap:
                         # eviction of the outgrown capacity's programs
                         # is DEFERRED until this level dispatches
@@ -2063,7 +2073,7 @@ class DevicePipeline:
         self.device_levels += 1
         if self.host_mode:
 
-            def finalize(outs=outs, dispatched=dispatched, T=T):
+            def finalize(outs=outs, dispatched=dispatched, T=T, LN=LN):
                 on = int(io.fetch(outs[5]))
                 vk = int(io.fetch(outs[6]))
                 verdict = None
@@ -2089,13 +2099,16 @@ class DevicePipeline:
                     digest=None,  # host folds the probe survivors
                     launches=dispatched,
                     lanes=T,
+                    # the one set the program probes, once a chunk
+                    probed=((LN, on, 1, 0),),
                 )
 
             # visited refs unchanged: the host set is the visited state
             return vhi, vlo, vn, vcap, finalize
         new_vhi, new_vlo, new_vn = outs[4], outs[5], outs[6]
 
-        def finalize(outs=outs, dispatched=dispatched, T=T):
+        def finalize(outs=outs, dispatched=dispatched, T=T, LN=LN,
+                     vcap=vcap, set_n=set_n):
             on = int(io.fetch(outs[3]))
             vk = int(io.fetch(outs[7]))
             verdict = None
@@ -2117,6 +2130,12 @@ class DevicePipeline:
                 ),
                 launches=dispatched,
                 lanes=T,
+                # the sets the program probes, (capacity, length, probes a
+                # chunk, probes a level) each: the level-new set, which
+                # only grows, at its last length; the visited set, which
+                # the level reads and never writes, for every chunk and
+                # once more for the level's one merge
+                probed=((LN, on, 1, 0), (vcap, set_n, 1, 1)),
             )
 
         return new_vhi, new_vlo, new_vn, vcap, finalize

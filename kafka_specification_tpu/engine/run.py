@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.observer import RunObserver
-from ..ops import hashset
+from ..ops import dedup, hashset
 from ..resilience import integrity as _integ
 from ..resilience.checkpoints import CheckpointStore
 from ..resilience.faults import FaultPlan
@@ -68,8 +68,18 @@ class Level:
         # the lanes their guard sides evaluated (`guard_lanes`): a chunk's
         # padded rows x the model's static fanout
         self.guard = 0
+        # the sorted-set probes they ran (`probes`), and those of them
+        # that searched the window of a capacity above it
+        self.probes = self.probes_windowed = 0
         self.discarded = 0  # chunks dispatched and dropped at a verdict
         self.step_s = self.host_s = 0.0  # `step_ms`, `host_ms`
+
+    def probed(self, n: int, cap: int, set_n: int) -> None:
+        """`n` probes of a sorted set of `set_n` entries in `cap` slots
+        (what the host held when it dispatched them: no fetch of its
+        own)."""
+        self.probes += n
+        self.probes_windowed += n * dedup.windowed(cap, set_n)
 
 
 class Run:

@@ -11,7 +11,10 @@ rounds as the fullest bucket needs (a device value: 5-7 rounds on hashed
 fingerprints where the pinned capacity alone would ask for 22; the bit
 length of the set where every entry shares its top bits, and the search is
 then the plain one).  Both lanes of the set ride one ``[2, cap]`` buffer,
-so a round is one gather.  Directory and buffer are rebuilt inside every
+so a round is one gather; where the pinned capacity is above
+``PROBE_WINDOW`` the buffer holds the set's first ``PROBE_WINDOW`` slots
+for as long as the set fits them, so a probe costs what the set holds and
+not what its capacity pins.  Directory and buffer are rebuilt inside every
 probe from the set it is handed: no carried state, exact for any data.
 The queries are searched in blocks, and only the blocks of their live
 prefix: a caller whose query list is sorted, sentinel pairs last (every
@@ -81,6 +84,27 @@ def rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo):
 #: in ``S(1)`` wherever the search reads it; from 32,768 the compiler
 #: places it otherwise.
 PROBE_BLOCK = 8192
+
+#: The most slots of the sorted set one probe's search reads where the set
+#: fits them: a pinned capacity above it is searched through its first
+#: ``PROBE_WINDOW`` slots while ``set_n`` is at most that (a device value;
+#: :func:`_rank_sorted`), and whole once the set has outgrown them.
+#: The largest capacity whose ``[2, cap]`` buffer the compiler keeps in
+#: ``S(1)`` (64 MiB; at 16,777,216 and above every round's gather reads it
+#: from the default memory space).  Timed on a TPU v5e, the function alone
+#: (PERF.md section 6, PR 45), a set of 1,189,826 and 1,203,489 query lanes
+#: 62% live, ms at capacities 8,388,608 / 16,777,216 / 33,554,432: the
+#: whole capacity searched 41.4 / 122.7 / 122.9, its first 8,388,608 slots
+#: 41.3 / 41.5 / 41.7, its first 4,194,304 slots 41.4 / 41.4 / 41.3.
+PROBE_WINDOW = 8_388_608
+
+
+def windowed(cap: int, set_n: int) -> bool:
+    """Whether a probe of a set of `set_n` entries in a capacity of `cap`
+    searches the window and not the capacity: what :func:`_rank_sorted`
+    decides from the shape and on the device, for a host that holds both
+    numbers (the level record's ``probes_windowed``)."""
+    return cap > PROBE_WINDOW and set_n <= PROBE_WINDOW
 
 
 def even_block(n: int, most: int) -> int:
@@ -160,13 +184,36 @@ def _search(pairs, lo_i, hi_i, q_hi, q_lo, rounds):
 
 def _rank_sorted(set_hi, set_lo, set_n, q_hi, q_lo, q_n=None):
     """probe_sorted's body, in no stage scope of its own: -> (found, rank,
-    rounds run over the query lanes, query lanes searched)."""
-    cap, T = set_hi.shape[0], q_hi.shape[0]
+    rounds run over the query lanes, query lanes searched).
+
+    The search reads ONE ``[2, n]`` buffer of the set's two lanes: the
+    whole capacity where that is at most :data:`PROBE_WINDOW` (a shape:
+    nothing else is traced), else its first ``PROBE_WINDOW`` slots while
+    the set fits them (``set_n``, a device value: one ``lax.cond``, both
+    branches :func:`_rank_pairs`).  Same answers to the last bit for any
+    data: no rank exceeds ``set_n``, and the one slot a clamp can read at
+    or past the live prefix is read only under ``rank < set_n``."""
+    cap = set_hi.shape[0]
     set_n = jnp.asarray(set_n, jnp.int32)
-    # both lanes in ONE buffer, read by one gather a round: on the chip a
-    # gather out of one [2, cap] operand costs a fifth of two gathers out
-    # of the two [cap] lanes (PERF.md section 6, PR 31)
-    pairs = jnp.stack([set_hi, set_lo])
+
+    def search(hi, lo):
+        # both lanes in ONE buffer, read by one gather a round: on the chip
+        # a gather out of one [2, n] operand costs a fifth of two gathers
+        # out of the two [n] lanes (PERF.md section 6, PR 31)
+        return _rank_pairs(jnp.stack([hi, lo]), set_n, q_hi, q_lo, q_n)
+
+    if cap <= PROBE_WINDOW:
+        return search(set_hi, set_lo)
+    return jax.lax.cond(
+        set_n <= PROBE_WINDOW,
+        lambda: search(set_hi[:PROBE_WINDOW], set_lo[:PROBE_WINDOW]),
+        lambda: search(set_hi, set_lo))
+
+
+def _rank_pairs(pairs, set_n, q_hi, q_lo, q_n):
+    """:func:`_rank_sorted` over `pairs` (uint32[2, n]: the first n slots
+    of the set's hi lanes and lo lanes, ``set_n <= n``)."""
+    cap, T = pairs.shape[1], q_hi.shape[0]
     k = directory_bits(T)
     nb = 1 << k
     # start[b] = lower bound of (b << (32 - k), 0) in the live prefix,

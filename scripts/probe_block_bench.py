@@ -1,14 +1,22 @@
 """`dedup.probe_sorted` alone on the chip: the blocked live-prefix search
-against the full-width form (PERF.md section 6, PR 41).
+against the full-width form (PERF.md section 6, PR 41), and the search of a
+bounded window of the set against the search of its whole pinned capacity
+(`--table window`; PERF.md section 6, PR 45).
 
     chiprun -- python scripts/probe_block_bench.py            # time on the chip
+    chiprun -- python scripts/probe_block_bench.py --table window
     JAX_PLATFORMS=cpu python scripts/probe_block_bench.py --hlo  # compile only
 
-For every (capacity, set size, query lanes, live share) it times the present
-form (`q_n=None`, every lane) and the blocked form at each block size, and
-prints where the optimised HLO places the `u32[2, cap]` pairs buffer
-(`S(1)` or not).  `--hlo` compiles for a described v5e and runs nothing: no
-time comes out of it.  Writes `chiprun_out/probe_block_bench/table.json`.
+`--table block`: for every (capacity, set size, query lanes, live share) it
+times the present form (`q_n=None`, every lane) and the blocked form at each
+block size.  `--table window`: the product cell's widest chunk (a set of
+1,189,826 and 1,203,489 query lanes, 62% live) at capacities 2^23, 2^24 and
+2^25, the whole capacity searched (`whole`) and its first 2^23 / 2^22 slots
+(`window <n>`: `dedup.PROBE_WINDOW`), and `merge_counted` of a tenth of the
+live lanes at the same capacities.  Both print where the optimised HLO
+places the `u32[2, n]` pairs buffers (`S(1)` or not).  `--hlo` compiles for
+a described v5e and runs nothing: no time comes out of it.  Writes
+`chiprun_out/probe_block_bench/<table>.json` (`hlo_<table>.json`).
 """
 
 from __future__ import annotations
@@ -41,6 +49,14 @@ CASES = (
 LIVE = (0.31, 0.44, 1.0)
 NARROW = ((4_194_304, 1_200_000, 8_192), (4_194_304, 1_200_000, 19_661))
 BLOCKS = (2_048, 4_096, 8_192, 16_384, 32_768, 65_536)
+#: `kip320-5b-3p-notrace`'s level 5: the set at the end of the pass, a
+#: fused chunk's pooled width (147 blocks of 8,187), `probe_live_share`
+WINDOW_CASES = tuple((cap, 1_189_826, 1_203_489)
+                     for cap in (8_388_608, 16_777_216, 33_554_432))
+WINDOW_LIVE = 0.62
+WINDOWS = (8_388_608, 4_194_304)
+#: no capacity reaches it: the search of the whole capacity
+NO_WINDOW = 1 << 31
 
 
 def make(cap, set_n, T, live, seed):
@@ -117,9 +133,33 @@ def variants():
     return out
 
 
-def pairs_layouts(text, cap):
-    """The layouts the optimised HLO gives `u32[2, cap]`, with counts."""
-    found = re.findall(r"u32\[2,%d\]\{[^}]*\}" % cap, text)
+def window_variants():
+    """name -> (function of the six arrays, `dedup.PROBE_WINDOW`)."""
+    out = {"whole": (dedup.probe_sorted, NO_WINDOW)}
+    for W in WINDOWS:
+        out[f"window {W}"] = (dedup.probe_sorted, W)
+
+    def merge(sh, sl, sn, qh, ql, qn):
+        # a tenth of the live lanes as new entries, at ranks spread over
+        # the set (the probe's own answer is left out of the time)
+        M = qh.shape[0]
+        new_n = qn // 10
+        rank = (jnp.arange(M, dtype=jnp.float32)
+                * (sn.astype(jnp.float32) / M)).astype(jnp.int32)
+        return dedup.merge_counted(sh, sl, sn, qh, ql, rank, new_n,
+                                   sh.shape[0])
+
+    out["merge"] = (merge, NO_WINDOW)
+    return out
+
+
+def pairs_layouts(text, *sizes):
+    """The layouts the optimised HLO gives `u32[2, n]` (the probe's pairs
+    buffers) and `u32[n]` / `s32[n]` (the merge's outputs, histogram and
+    prefix sum), with counts."""
+    found = [x for n in dict.fromkeys(sizes)
+             for x in re.findall(r"(?:u32\[2,|[us]32\[)%d\]\{[^}]*\}" % n,
+                                 text)]
     return {x: found.count(x) for x in sorted(set(found))}
 
 
@@ -127,6 +167,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--hlo", action="store_true",
                     help="compile for a described v5e, run nothing")
+    ap.add_argument("--table", choices=("block", "window"), default="block")
     ap.add_argument("--reps", type=int, default=15)
     ap.add_argument("--seed", type=int, default=2147486101)
     args = ap.parse_args()
@@ -141,21 +182,27 @@ def main():
         print("device", dev.platform, dev.device_kind, flush=True)
         assert dev.platform == "tpu", "a time comes from the chip alone"
     rows = []
-    todo = [(c, live) for c in CASES for live in LIVE]
-    todo += [(c, 1.0) for c in NARROW]
+    window = args.table == "window"
+    if window:
+        todo = [(c, WINDOW_LIVE) for c in WINDOW_CASES]
+    else:
+        todo = [(c, live) for c in CASES for live in LIVE]
+        todo += [(c, 1.0) for c in NARROW]
     for (cap, set_n, T), live in todo:
-        names = variants()
-        if T < 100_000:  # one or three blocks: the shell's cost alone
+        names = window_variants() if window else variants()
+        if not window and T < 100_000:  # one or three blocks: the shell's cost alone
             names = {k: v for k, v in names.items()
                      if k in ("present", "block 4096", "block 8192")}
         data = None if args.hlo else [
             jax.device_put(x) for x in make(cap, set_n, T, live, args.seed)]
         want = None
         for name, (fn, B) in names.items():
-            if B is not None:
+            if window:
+                dedup.PROBE_WINDOW = B
+            elif B is not None:
                 dedup.PROBE_BLOCK = B
             # a function object of its own: jit keys its trace on the
-            # function, and PROBE_BLOCK is read while tracing
+            # function, and the two constants are read while tracing
             jitted = jax.jit(lambda *a, fn=fn: fn(*a))
             row = {"cap": cap, "set_n": set_n, "T": T, "live": live,
                    "variant": name}
@@ -175,7 +222,7 @@ def main():
                 if want is None:
                     want = got
                     row["found"] = int(got[0].sum())
-                else:  # every variant answers as the present form does
+                elif name != "merge":  # every probe answers as the first
                     assert (got[0] == want[0]).all(), name
                     assert (got[1] == want[1]).all(), name
                     assert not np.asarray(out[0])[n:].any(), name
@@ -186,12 +233,13 @@ def main():
                     ms.append((time.perf_counter() - t0) * 1e3)
                 row["ms_median"] = statistics.median(ms)
                 row["ms_min"] = min(ms)
-                row["work"] = [int(x) for x in np.atleast_1d(out[2])]
-            row["pairs"] = pairs_layouts(text, cap)
+                row["work"] = [int(x) for x in np.atleast_1d(out[-1])]
+            row["pairs"] = pairs_layouts(text, cap,
+                                         *([min(cap, B)] if window else []))
             rows.append(row)
             print(json.dumps(row), flush=True)
     os.makedirs("chiprun_out/probe_block_bench", exist_ok=True)
-    name = "hlo.json" if args.hlo else "table.json"
+    name = ("hlo_" if args.hlo else "") + args.table + ".json"
     with open(f"chiprun_out/probe_block_bench/{name}", "w") as f:
         json.dump(rows, f, indent=1)
 

@@ -31,7 +31,7 @@ TINY = Config(2, 2, 1, 1)
 KIP101_LEVELS = [1, 4, 14, 44, 100, 166, 268, 456, 684, 976, 1292, 1486]
 CUT_KEYS = {"depth", "frontier", "rows_committed", "chunks_committed",
             "chunks_discarded", "chunks", "chunks_ahead", "dedup_lanes",
-            "guard_lanes", "level_ms",
+            "guard_lanes", "probes", "probes_windowed", "level_ms",
             "step_ms", "host_ms",
             "successor_launches"} | set(WORK_FIELDS) | set(hostio.LEVEL_COUNTERS)
 # the fused path from 64 rows up, so a small chunk leaves launch 2 in flight

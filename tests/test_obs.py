@@ -196,7 +196,7 @@ def test_stats_shim_record_for_record_identical(tmp_path):
                       "io_hidden_ms", "io_exposed_ms",
                       "overlap_efficiency", "host_probe_ms",
                       "store_ms", "chunks", "chunks_ahead", "dedup_lanes",
-                      "guard_lanes")
+                      "guard_lanes", "probes", "probes_windowed")
          + WORK_FIELDS + LEVEL_COUNTERS}
         for r in r1.stats["levels"]
     ] == recs_bare

@@ -13,7 +13,8 @@ replayed through the oracle twin); and what PR 44 added to the records: the
 `check-open` span's and the manifest's `partitions` / `base_fanout`, and
 every level record's `guard_lanes` (under each pipeline and visited
 backend: tests/test_run_phases.py; here the cell's, a streamed level's and
-the mesh's).
+the mesh's); and what PR 45 added: `probes` / `probes_windowed`, with
+`dedup.PROBE_WINDOW` patched under the job's capacity and at its default.
 
 The violating job is KafkaTruncateToHighWatermark at 2 brokers x 2
 partitions (WeakIsr at depth 8, 15,997 product states at most), not
@@ -133,8 +134,7 @@ def test_a_heterogeneous_product_says_each_partitions_fanout():
 
 # --- the cell's job through the harness's door, and its warm protocol --------
 
-@pytest.fixture(scope="module")
-def served(tmp_path_factory):
+def _serve(base):
     """The cell's configuration at depth 3, as `perfbench/run.py` `set_up`
     drives it: a cold pass, `after_setup_pass` (`note_result` + `rewarm`),
     two passes at the capacity fixed point.  -> (job, [pass records],
@@ -147,7 +147,6 @@ def served(tmp_path_factory):
         config = json.load(fh)
     assert config["cfg"] == CELL_CFG and config["options"] == {}
     job = adapter.Job(config, ROOT)
-    base = tmp_path_factory.mktemp("served")
     opts = {"store_trace": False, "max_depth": DEPTH}
     passes, records, rewarmed = [], [], None
     for tag in ("cold", "warm1", "warm2"):
@@ -156,6 +155,29 @@ def served(tmp_path_factory):
         if tag == "cold":
             rewarmed = job.after_setup_pass()
     return job, passes, records, rewarmed
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    return _serve(tmp_path_factory.mktemp("served"))
+
+
+# under the capacity the job pins at depth 3 on the CPU (524,288 slots) and
+# over the 9,311 states its set ever holds: what 8,388,608 is to the cell's
+# 16,777,216 slots and 1,189,826 states on the chip
+SMALL_WINDOW = 65_536
+
+
+@pytest.fixture(scope="module")
+def served_windowed(tmp_path_factory):
+    """:func:`served` with `dedup.PROBE_WINDOW` under the job's capacity
+    (the model, its step cache and every program are this job's own, traced
+    while the patch holds)."""
+    from kafka_specification_tpu.ops import dedup
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(dedup, "PROBE_WINDOW", SMALL_WINDOW)
+        return _serve(tmp_path_factory.mktemp("served_windowed"))
 
 
 @functools.lru_cache(maxsize=None)
@@ -281,6 +303,57 @@ def test_warm_passes_upload_one_host_image_of_the_visited_set(served):
     assert records[1][0]["h2d_bytes"] == warm > 2 * 4 * vcap
     assert warm - 2 * 4 * vcap < 1 << 20  # the rest: a state and a bucket
     assert prepare(job.model)._visited0 is None
+
+
+# --- the probe's window (ISSUE 45) --------------------------------------------
+
+def test_every_probe_of_a_pinned_pass_searches_the_window(served_windowed):
+    """With the window under the pinned capacity and over the set, every
+    sorted-set probe of the two passes at the fixed point is one the host
+    booked to the window (`probes_windowed == probes > 0` a level); every
+    level is golden (the counters: the test below)."""
+    job, passes, records, _ = served_windowed
+    assert job.prepared.capacity_hint > SMALL_WINDOW > passes[2]["total"]
+    for rec in passes:
+        assert rec["levels"] == CELL_LEVELS[:DEPTH + 1]
+        assert rec["total"] == 9311 and rec["violation"] is None
+        assert not rec["stats"].get("degradations")
+    # (the cold pass too: its growth ladder starts at 131,072 slots, level
+    # 1's bucket of 256 rows x 339, already above the window)
+    for recs in records:
+        assert [r["probes_windowed"] for r in recs] == [
+            r["probes"] for r in recs]
+        # one `step` chunk a level, one probe a chunk
+        assert [r["probes"] for r in recs] == [1] * DEPTH
+    # the harness's records keep both fields
+    assert [(r["probes_windowed"], r["probes"])
+            for r in passes[2]["level_records"]] == [(1, 1)] * DEPTH
+
+
+def test_the_window_changes_no_count(served, served_windowed):
+    keys = ("frontier", "enabled_candidates", "new", "duplicates", "chunks",
+            "successor_launches", "dedup_lanes", "guard_lanes", "probes",
+            "probe_rounds", "probe_rounds_plain", "probe_lanes",
+            "probe_lanes_plain", "merge_slots", "merge_slots_plain",
+            "novel_rows", "novel_rows_plain", "d2h_bytes", "h2d_bytes",
+            "d2h_fetches", "action_enablement")
+    for a, b in zip(served[2], served_windowed[2]):
+        assert [[lv[k] for k in keys] for lv in a] == [
+            [lv[k] for k in keys] for lv in b]
+    assert [p["stats"]["visited_capacity"] for p in served[1]] == [
+        p["stats"]["visited_capacity"] for p in served_windowed[1]]
+
+
+def test_no_probe_is_windowed_at_the_default_window(served):
+    """The CPU's capacities are under `dedup.PROBE_WINDOW`: the probe is
+    the parent's program and the counter says so."""
+    from kafka_specification_tpu.ops import dedup
+
+    job, passes, records, _ = served
+    assert job.prepared.capacity_hint <= dedup.PROBE_WINDOW
+    for recs in records:
+        assert [r["probes_windowed"] for r in recs] == [0] * DEPTH
+        assert [r["probes"] for r in recs] == [1] * DEPTH
 
 
 # --- two partitions, a level in several chunks -------------------------------

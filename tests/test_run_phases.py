@@ -13,7 +13,9 @@ rendered counterexample of each engine.  Written by
 `python tests/test_run_phases.py --write` on the parent's tree; never by a
 test run.  PR 44 wrote it again for the one key it added to every level
 record, `guard_lanes` (nothing else differs from the parent's file, key
-for key), and holds that key to the `step` spans' buckets here.  A
+for key), and holds that key to the `step` spans' buckets here; PR 45
+wrote it once more for `probes` and `probes_windowed` behind it (nothing else
+differs, key for key) and holds them to the chunks here.  A
 statement of the commit path that moves across another shows here as a
 counter, a key or a span out of place."""
 
@@ -152,6 +154,18 @@ def test_a_run_repeats_the_parent_commits_records(tmp_path, pipeline,
     assert got["spans"] == want["spans"]
     assert [dict(rec)["guard_lanes"] for rec in got["records"]] == got[
         "guard_lanes_of_spans"]
+    # the sorted-set probes of a level (PR 45): a chunk's program probes
+    # the visited set once where that lives sorted on the device; a
+    # whole-level program probes its level-new set once a chunk and, on
+    # that backend, the visited set once a chunk and once for its merge
+    # (the `device-hash` backend leaves that pipeline for the chunk loop);
+    # no capacity here is above `dedup.PROBE_WINDOW`
+    a_chunk, a_level = {
+        ("device", "device"): (2, 1), ("device", "host"): (1, 0),
+    }.get((pipeline, backend), (int(backend == "device"), 0))
+    for rec in map(dict, got["records"]):
+        assert rec["probes"] == a_chunk * rec["chunks"] + a_level
+        assert rec["probes_windowed"] == 0
 
 
 def test_a_finished_run_is_freed_without_the_collector(tmp_path):
