@@ -275,10 +275,10 @@ def commit_chunk(r: Run, st, waited=None) -> bool:
             r.lvl.new += w
             if r.chain is not None and w:
                 # arena rows are the committed novel states;
-                # the numpy twin recomputes their fps (the C
-                # pass hands back rows, not fingerprints)
-                r.chain.fold(
-                    _integ.fingerprint_rows(
+                # the host twin digests them (the C pass hands
+                # back rows, not fingerprints)
+                r.chain.fold_digest(
+                    *_integ.digest_rows(
                         r.a_rows[r.a_w - w : r.a_w], r.spec.exact64
                     )
                 )
@@ -459,8 +459,8 @@ def commit_device_level(r: Run, fin, dispatch_s: float, t_dispatch: float,
                     r.a_act[r.a_w:],
                 )
                 if r.chain is not None and committed:
-                    r.chain.fold(
-                        _integ.fingerprint_rows(
+                    r.chain.fold_digest(
+                        *_integ.digest_rows(
                             r.a_rows[r.a_w: r.a_w + committed],
                             r.spec.exact64,
                         )
@@ -644,11 +644,17 @@ def _boundary(r: Run):
             # counts still chain, the rows are not re-read)
             if not r.symmetric:
                 _integ.count_check()
-                r.chain.verify_level(
-                    r.depth,
-                    _integ.fingerprint_rows(
-                        r.frontier_np, r.spec.exact64),
+                sp_ = r.obs.open_span(
+                    "frontier-verify", rows=r.frontier_np.shape[0],
+                    lanes=r.K, native=_integ.native_twin(r.spec.exact64),
                 )
+                try:
+                    r.chain.verify_level(
+                        r.depth,
+                        _integ.digest_rows(r.frontier_np, r.spec.exact64),
+                    )
+                finally:
+                    sp_.finish()
         elif sp and r.frontier_np.paths():
             # disk-spilled frontier: the flip lands in a segment
             # FILE (there is no long-lived host buffer to flip);

@@ -6,9 +6,12 @@ sorted set (ops/dedup.py) is the fast path while fingerprints fit in HBM;
 this is the TLC-FPSet-equivalent for runs that outgrow it, and the backend
 of engine.check(..., visited_backend="host").
 
+`rows_digest` — the host's one-pass fingerprint-and-digest twin of the
+device's hashed fingerprint (resilience/integrity.py calls it).
+
 The shared library is compiled on first use with g++ -O2 (cached next to the
 source); environments without a toolchain fall back to a numpy-based set
-with the same interface.
+with the same interface, and to integrity.py's numpy twin.
 """
 
 from __future__ import annotations
@@ -36,11 +39,22 @@ def _load():
             if (not os.path.exists(_SO)) or os.path.getmtime(_SO) < os.path.getmtime(
                 _SRC
             ):
-                subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-o", _SO, _SRC],
-                    check=True,
-                    capture_output=True,
-                )
+                # built beside the source under a name of this process's
+                # own and renamed into place: several processes of a fresh
+                # checkout (test workers) build at once, and none may load
+                # a library another is still writing
+                tmp = f"{_SO}.{os.getpid()}.tmp"
+                try:
+                    subprocess.run(
+                        ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, _SRC],
+                        check=True,
+                        capture_output=True,
+                    )
+                    # kspec: allow(durable-io) a build cache, not run state
+                    os.replace(tmp, _SO)
+                finally:
+                    if os.path.exists(tmp):
+                        os.unlink(tmp)
             lib = ctypes.CDLL(_SO)
             lib.fpset_create.restype = ctypes.c_void_p
             lib.fpset_create.argtypes = [ctypes.c_uint64]
@@ -83,6 +97,16 @@ def _load():
                 ctypes.POINTER(ctypes.c_uint64),
                 ctypes.c_uint64,
             ]
+            lib.rows_digest.restype = None
+            lib.rows_digest.argtypes = [
+                ctypes.POINTER(ctypes.c_uint32),
+                ctypes.c_uint64,
+                ctypes.c_uint64,
+                ctypes.c_uint32,
+                ctypes.c_uint32,
+                ctypes.POINTER(ctypes.c_uint64),
+                ctypes.POINTER(ctypes.c_uint64),
+            ]
             _lib = lib
         except Exception as e:  # no toolchain -> numpy fallback
             _build_error = e
@@ -91,6 +115,36 @@ def _load():
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def rows_digest(rows: np.ndarray, seed_hi: int, seed_lo: int,
+                want_fps: bool):
+    """One native pass over C-contiguous ``uint32[n, K]`` packed states in
+    hashed mode (fpset.cpp ``rows_digest``) -> ``(fps, (count, xor, sum))``
+    with ``fps`` the ``uint64[n]`` fingerprints, or None where they were not
+    asked for.  Returns None where the library did not load: the caller
+    (resilience/integrity.py, which also holds the seeds) then runs its
+    numpy twin."""
+    lib = _load()
+    if lib is None:
+        return None
+    if (rows.dtype != np.uint32 or rows.ndim != 2
+            or not rows.flags.c_contiguous):
+        raise ValueError("rows_digest wants C-contiguous uint32[n, K]")
+    n, k = rows.shape
+    fps = np.empty(n, np.uint64) if want_fps else None
+    digest = np.zeros(3, np.uint64)
+    u64p = ctypes.POINTER(ctypes.c_uint64)
+    lib.rows_digest(
+        rows.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+        n,
+        k,
+        seed_hi,
+        seed_lo,
+        fps.ctypes.data_as(u64p) if want_fps else None,
+        digest.ctypes.data_as(u64p),
+    )
+    return fps, tuple(int(v) for v in digest)
 
 
 class FpSet:

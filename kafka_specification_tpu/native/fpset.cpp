@@ -13,6 +13,9 @@
 // fingerprints ARE packed states, so value 0 is a real state and must not
 // be conflated with any other). Batch insert returns a novelty mask so one
 // FFI crossing handles a whole BFS level.
+//
+// Beside the set, at the end: rows_digest, the host's one-pass twin of the
+// device's hashed fingerprint and of the level digest chain's fold.
 
 #include <cstdint>
 #include <cstdlib>
@@ -36,6 +39,16 @@ inline uint64_t mix(uint64_t x) {
   x *= 0x94d049bb133111ebULL;
   x ^= x >> 31;
   return x;
+}
+
+inline uint32_t rotl32(uint32_t x, int r) { return (x << r) | (x >> (32 - r)); }
+
+inline uint32_t fmix32(uint32_t h) {
+  h ^= h >> 16;
+  h *= 0x85EBCA6Bu;
+  h ^= h >> 13;
+  h *= 0xC2B2AE35u;
+  return h ^ (h >> 16);
 }
 
 bool grow(FpSet* s);
@@ -194,6 +207,41 @@ uint64_t fpset_dump(void* h, uint64_t* out, uint64_t max_n) {
     if (s->slots[i] != 0) out[w++] = s->slots[i];
   }
   return w;
+}
+
+// The host twin of ops/fingerprint.py's hashed mode, fused with the level
+// digest chain's fold (resilience/integrity.py): each uint32[k] row is read
+// once, hashed with both murmur3 seeds (the caller's: integrity.py holds
+// the host's one copy), the all-ones pair remapped as hash_pair does, and
+// folded into digest = {count, xor, wrapping sum}.  out_fps (n slots,
+// hi<<32|lo) may be null: a caller that only compares or folds a digest
+// never materialises the fingerprints.
+void rows_digest(const uint32_t* rows, uint64_t n, uint64_t k,
+                 uint32_t seed_hi, uint32_t seed_lo,
+                 uint64_t* out_fps, uint64_t* digest) {
+  const uint32_t c1 = 0xCC9E2D51u, c2 = 0x1B873593u;
+  const uint32_t tail = static_cast<uint32_t>(4 * k);
+  uint64_t x = 0, sum = 0;
+  for (uint64_t i = 0; i < n; i++) {
+    const uint32_t* row = rows + i * k;
+    uint32_t hi = seed_hi, lo = seed_lo;
+    for (uint64_t j = 0; j < k; j++) {
+      uint32_t kx = row[j] * c1;
+      kx = rotl32(kx, 15) * c2;
+      hi = rotl32(hi ^ kx, 13) * 5u + 0xE6546B64u;
+      lo = rotl32(lo ^ kx, 13) * 5u + 0xE6546B64u;
+    }
+    hi = fmix32(hi ^ tail);
+    lo = fmix32(lo ^ tail);
+    if (hi == 0xFFFFFFFFu && lo == 0xFFFFFFFFu) lo = 0xFFFFFFFEu;
+    uint64_t fp = (static_cast<uint64_t>(hi) << 32) | lo;
+    if (out_fps) out_fps[i] = fp;
+    x ^= fp;
+    sum += fp;
+  }
+  digest[0] = n;
+  digest[1] = x;
+  digest[2] = sum;
 }
 
 }  // extern "C"

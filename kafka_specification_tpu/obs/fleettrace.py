@@ -105,10 +105,11 @@ EVENT_KINDS = {
 #: half of the registry the lint holds against docs/observability.md.
 ENGINE_SPAN_KINDS = {
     "check", "check-open", "check-close", "run-open", "init-states",
-    "host-invariants", "level", "compile", "step", "dispatch",
-    "compact-host", "store", "shadow", "host-assembly", "host-probe",
-    "exchange", "exchange-level", "spill-run-write", "spill-merge",
-    "checkpoint-write", "checkpoint-verify", "counterexample",
+    "host-invariants", "frontier-verify", "level", "compile", "step",
+    "dispatch", "compact-host", "store", "shadow", "host-assembly",
+    "host-probe", "exchange", "exchange-level", "spill-run-write",
+    "spill-merge", "checkpoint-write", "checkpoint-verify",
+    "counterexample",
 }
 ENGINE_EVENT_KINDS = {
     "pipeline-fallback",

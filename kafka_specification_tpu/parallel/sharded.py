@@ -982,7 +982,7 @@ def _make_sharded_level_host(
       accumulators) so the host probe never recomputes them;
     - no in-jit digest folds — the chain's multiset is only known after
       the probe, so the host folds the survivors exactly as the
-      per-chunk host commit does (fingerprint_rows over the kept rows).
+      per-chunk host commit does (digest_rows over the kept rows).
 
     The exchange (+ codec) still runs inside the loop, and the framing
     digests still accumulate — fabric integrity is independent of where
@@ -2682,18 +2682,23 @@ def check_sharded(
                 # frontier verify: the pending shards' combined multiset
                 # must digest to the entry sealed at discovery (the
                 # per-shard split is layout; the multiset is the search)
-                parts = [
-                    _integ.fingerprint_rows(p, spec.exact64)
-                    for p in pending
-                    if p.shape[0]
-                ]
                 _integ.count_check()
-                chain.verify_level(
-                    depth,
-                    np.concatenate(parts)
-                    if parts
-                    else np.empty(0, np.uint64),
+                sp_ = obs_.open_span(
+                    "frontier-verify",
+                    rows=int(sum(p.shape[0] for p in pending)), lanes=K,
+                    native=_integ.native_twin(spec.exact64),
                 )
+                try:
+                    chain.verify_level(
+                        depth,
+                        _integ.combine_digests(
+                            _integ.digest_rows(p, spec.exact64)
+                            for p in pending
+                            if p.shape[0]
+                        ),
+                    )
+                finally:
+                    sp_.finish()
             if max_depth is not None and depth >= max_depth:
                 cut = True
                 break
@@ -3101,13 +3106,13 @@ def check_sharded(
                     next_pending[d].append(rows)
                     if chain is not None:
                         # fold this shard's new states into the level
-                        # digest via the numpy fingerprint twin (rows are
+                        # digest via the host fingerprint twin (rows are
                         # what the host actually keeps — digesting them,
                         # then checking the chain against the device
                         # fingerprints at save time, cross-checks the
                         # two representations for free)
-                        chain.fold(
-                            _integ.fingerprint_rows(rows, spec.exact64)
+                        chain.fold_digest(
+                            *_integ.digest_rows(rows, spec.exact64)
                         )
                     if collect_trace:
                         # step parents are d_src*bucket + i within this padded
@@ -3438,7 +3443,7 @@ def check_sharded(
                             continue
                         next_pending[d].append(rows)
                         if chain is not None:
-                            # fold the probe SURVIVORS via the numpy
+                            # fold the probe SURVIVORS via the host
                             # fingerprint twin, deliberately NOT the
                             # device lanes in hi3/lo3: digesting the
                             # rows the host actually keeps, then
@@ -3446,10 +3451,8 @@ def check_sharded(
                             # fingerprints at save time, cross-checks
                             # the two representations for free (the
                             # per-chunk host commit's exact rationale)
-                            chain.fold(
-                                _integ.fingerprint_rows(
-                                    rows, spec.exact64
-                                )
+                            chain.fold_digest(
+                                *_integ.digest_rows(rows, spec.exact64)
                             )
                         if collect_trace:
                             pg = par3[d, :c][mask].astype(np.int64)

@@ -390,10 +390,9 @@ class StateSpaceCache:
             )
         boundary = self._read_boundary(d, art)
         depth = len(levels) - 1
-        c, x, s = _integ.digest_fps(
-            _integ.fingerprint_rows(boundary, bool(entry["exact64"]))
-        )
-        if (c, x, s) != tuple(chain.entries[depth][:3]):
+        if _integ.digest_rows(
+            boundary, bool(entry["exact64"])
+        ) != tuple(chain.entries[depth][:3]):
             raise VerifyFailed(
                 "artifact-corrupt: boundary frontier digest does not "
                 f"match the chain entry at depth {depth}"

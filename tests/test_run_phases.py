@@ -15,7 +15,10 @@ test run.  PR 44 wrote it again for the one key it added to every level
 record, `guard_lanes` (nothing else differs from the parent's file, key
 for key), and holds that key to the `step` spans' buckets here; PR 45
 wrote it once more for `probes` and `probes_windowed` behind it (nothing else
-differs, key for key) and holds them to the chunks here.  A
+differs, key for key) and holds them to the chunks here; PR 46 wrote it
+for the `frontier-verify` span each level boundary now ends (twelve a run;
+with that name taken out every list of spans, and everything else, is the
+file PR 45 left: the chain too, entry for entry).  A
 statement of the commit path that moves across another shows here as a
 counter, a key or a span out of place."""
 

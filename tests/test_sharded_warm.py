@@ -239,7 +239,8 @@ def test_level_records_and_spans_of_a_sharded_run(tmp_path, pipeline):
     for spans in (first, second):
         kinds = [s["span"] for s in spans]
         for kind in ("check", "check-open", "check-close", "dispatch",
-                     "init-states", "host-invariants", "level"):
+                     "init-states", "host-invariants", "frontier-verify",
+                     "level"):
             assert kind in kinds, kind
         assert kinds.count("check") == 1
         root = next(s for s in spans if s["span"] == "check")

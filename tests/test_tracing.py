@@ -187,8 +187,8 @@ def test_every_engine_span_reaches_check(tmp_path, pipeline):
         assert node is root, s
     kinds = {s["span"] for s in done.values()}
     assert {"check", "check-open", "check-close", "run-open", "init-states",
-            "host-invariants", "level", "dispatch", "step", "host-assembly",
-            "store"} <= kinds
+            "host-invariants", "frontier-verify", "level", "dispatch", "step",
+            "host-assembly", "store"} <= kinds
     if pipeline == "fused":
         assert "compact-host" in kinds
     # the blocked part of the two host spans between launches: the fetches
