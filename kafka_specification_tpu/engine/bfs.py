@@ -1024,7 +1024,7 @@ def _f_all(f) -> np.ndarray:
 
 
 def walk_trace(trace_store, actions, decode_row, inv_name, depth, idx,
-               obs=None, source="ram") -> Violation:
+               obs=None, source="ram", symmetry=None) -> Violation:
     """Parent-pointer counterexample reconstruction, shared by both engines.
 
     trace_store[level] = (rows, parent, act): the level's states in discovery
@@ -1035,10 +1035,14 @@ def walk_trace(trace_store, actions, decode_row, inv_name, depth, idx,
     With `obs` (a RunObserver) the walk is one `counterexample` span:
     `source` says where the store lives (`ram` | `disk`), `decode_ms` is the
     wall of the `decode_row` calls and `walk_ms` the rest (the pointer
-    reads).
+    reads).  Under the model's `symmetry` the span names its operator and
+    order: the rows walked were stored as found and keyed by their orbits,
+    so the trace is a behaviour of the unreduced spec.
     """
+    sym = {} if symmetry is None else {
+        "symmetry": symmetry.operator, "symmetry_order": symmetry.order}
     span = obs.open_span("counterexample", invariant=inv_name, depth=depth,
-                         source=source) if obs is not None else None
+                         source=source, **sym) if obs is not None else None
     t_walk = time.perf_counter()
     decode_s = 0.0
 
@@ -1094,6 +1098,7 @@ def build_violation(model: Model, trace_store, plog_view, inv_name, depth,
             return walk_trace(
                 store, model.actions, partial(decode_packed, model),
                 inv_name, depth, idx, obs=obs, source=source,
+                symmetry=model.symmetry,
             )
     return None
 

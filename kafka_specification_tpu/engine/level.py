@@ -189,10 +189,18 @@ def commit_wait(r: Run, st):
         r.verdict = ("deadlock", start + int(r.io.fetch(dl_idx)),
                      "Deadlock")
     nn, rows = 0, None
+    device_decides = r.host_set is None and r.ht_hi is None
     if r.verdict is None:
         nn = int(r.io.fetch(new_n))
-        if r.host_set is None and r.ht_hi is None:
+        if device_decides:
             rows = take_rows(r, outs, nn)
+    elif r.collect_stats and device_decides:
+        # the verdict's chunk is never sliced and commits no row, but the
+        # device expanded and deduplicated it like any other: where the
+        # device decides novelty its counts are the cut level's (one
+        # 4-byte fetch of a program long finished)
+        r.lvl.act_en += act_en_np
+        r.lvl.new += int(r.io.fetch(new_n))
     return (outs, act_en_np, queued_s, time.perf_counter() - t_wait,
             nn, rows)
 
@@ -433,6 +441,11 @@ def commit_device_level(r: Run, fin, dispatch_s: float, t_dispatch: float,
             if kind == "invariant"
             else "Deadlock",
         )
+        if r.collect_stats and r.host_set is None:
+            # the chunks before the verdict's: the program adds a chunk's
+            # counts only where it commits the chunk
+            r.lvl.act_en += act_en_np
+            r.lvl.new += out["new_n"]
         return True
     t_host = time.perf_counter()
     t_host_wall = _now()
@@ -917,9 +930,15 @@ def _cut_level(r: Run, f_total: int, t_level: float) -> None:
         # its own (never one of stats["levels"], whose length
         # is the number of committed levels) and its span ends
         # with cut=true; the counterexample is built after it
+        enabled = int(r.lvl.act_en.sum())
         cut = r.result_stats["cut_level"] = dict(
             depth=r.depth + 1,
             frontier=f_total,
+            # as a level record counts them, over the chunks whose counts
+            # the host holds (docs/observability.md says which)
+            enabled_candidates=enabled,
+            new=r.lvl.new,
+            duplicates=enabled - r.lvl.new,
             rows_committed=r.lvl.rows_in,
             chunks_committed=r.lvl.chunks,
             chunks_discarded=r.lvl.discarded,

@@ -210,7 +210,8 @@ class RunObserver:
         if self._level is not None:
             self._level.finish(
                 cut=True, **{k: record[k] for k in (
-                    "rows_committed", "chunks_committed", "chunks_discarded")})
+                    "rows_committed", "chunks_committed", "chunks_discarded",
+                    "enabled_candidates", "new", "duplicates")})
             self._level = None
 
     def level(self, **fields) -> dict:

@@ -29,7 +29,8 @@ How one image is formed (:class:`Canon`, tables built once per model from
 traced once.  The stage costs per LIVE candidate: the valid lanes are
 numbered, and a rolled loop over fixed blocks of them, whose trip count is
 a device value, gathers a block's rows, reduces its images and scatters
-the keys back (the idiom of ``pipeline.novel_stage``).
+the keys back (the idiom of ``pipeline.novel_stage``); whole WIDE blocks
+first where the width holds them (:data:`CANON_WIDE`).
 
 The oracle twin of this reduction (models/kafka_replication.py
 ``o_canonical``) is written on the TLA-level state and shares nothing with
@@ -58,10 +59,27 @@ SENT = 0xFFFFFFFF  # ops/dedup.SENT (the masked lanes' fingerprint pair)
 #: / 0.205 / 0.149.
 CANON_BLOCK = 8192
 
+#: A WIDE block is this many blocks' rows.  While a whole wide block of
+#: live rows is left the loop takes one; the blocks of :func:`canon_block`
+#: finish the rest, so a width that holds few live rows pays for a block
+#: and one that holds hundreds of thousands runs a quarter of the
+#: iterations: a block of B rows costs a + b B (a = ~0.37 ms, the 120
+#: launches of the group loop's operations; b = ~0.097 us a row: the stage
+#: alone at 3,702,784 lanes, 330,000 live: 48.2 ms in 41 blocks of 8,192,
+#: 37.1 ms in 11 of 32,768; PERF.md section 6, PR 47).  A width under two
+#: wide blocks compiles no wide loop and lowers as it did without one.
+CANON_WIDE = 4
+
 
 def canon_block(T: int) -> int:
     """The block of a width: ``dedup.even_block`` at :data:`CANON_BLOCK`."""
     return even_block(T, CANON_BLOCK)
+
+
+def canon_wide_block(T: int) -> int:
+    """The wide block of a width, 0 where the width holds fewer than two."""
+    W = CANON_WIDE * canon_block(T)
+    return W if CANON_WIDE > 1 and T >= 2 * W else 0
 
 
 class Canon:
@@ -212,35 +230,45 @@ class Canon:
         its orbit's size (0 elsewhere), and the rows whose images the stage
         formed (blocks run x block size: a device count)."""
         T, K = cand.shape
-        B = canon_block(T)
+        B, W = canon_block(T), canon_wide_block(T)
         sent = jnp.uint32(SENT)
         n_live = jnp.sum(valid, dtype=jnp.int32)
         pos = jnp.cumsum(valid, dtype=jnp.int32) - 1
         lane_of = jnp.zeros((T,), jnp.int32).at[
             jnp.where(valid, pos, T)].set(
                 jnp.arange(T, dtype=jnp.int32), mode="drop")
-        blocks = (n_live + (B - 1)) // B
 
-        def block(k, outs):
-            hi, lo, orbit = outs
-            s = jnp.minimum(k * B, T - B)
-            at = jax.lax.dynamic_slice(lane_of, (s,), (B,))
-            keep = (s + jnp.arange(B, dtype=jnp.int32)) < n_live
-            best, hits = self.least(cand[at])
-            b_hi, b_lo = fingerprint_lanes(best, self.spec.exact64)
-            # the overlap writes the same lanes again; dead rows drop
-            to = jnp.where(keep, at, T)
-            return (hi.at[to].set(b_hi, mode="drop"),
-                    lo.at[to].set(b_lo, mode="drop"),
-                    orbit.at[to].set(self.G // jnp.maximum(hits, 1),
-                                     mode="drop"))
+        def run(size, first, count, outs):
+            """`count` blocks of `size` live rows, from live row `first`
+            (None: from the first one)."""
 
-        hi, lo, orbit = jax.lax.fori_loop(
-            0, blocks, block,
-            (jnp.full((T,), sent), jnp.full((T,), sent),
-             jnp.zeros((T,), jnp.int32)),
-        )
-        return hi, lo, orbit, blocks * B
+            def block(k, outs):
+                hi, lo, orbit = outs
+                s = k * size if first is None else first + k * size
+                s = jnp.minimum(s, T - size)
+                at = jax.lax.dynamic_slice(lane_of, (s,), (size,))
+                keep = (s + jnp.arange(size, dtype=jnp.int32)) < n_live
+                best, hits = self.least(cand[at])
+                b_hi, b_lo = fingerprint_lanes(best, self.spec.exact64)
+                # the overlap writes the same lanes again; dead rows drop
+                to = jnp.where(keep, at, T)
+                return (hi.at[to].set(b_hi, mode="drop"),
+                        lo.at[to].set(b_lo, mode="drop"),
+                        orbit.at[to].set(self.G // jnp.maximum(hits, 1),
+                                         mode="drop"))
+
+            return jax.lax.fori_loop(0, count, block, outs)
+
+        # whole wide blocks first, then the rest in blocks of B
+        done = (n_live // W) * W if W else None
+        blocks = ((n_live if done is None else n_live - done) + (B - 1)) // B
+        outs = (jnp.full((T,), sent), jnp.full((T,), sent),
+                jnp.zeros((T,), jnp.int32))
+        if done is not None:
+            outs = run(W, None, done // W, outs)
+        hi, lo, orbit = run(B, done, blocks, outs)
+        rows = blocks * B
+        return hi, lo, orbit, rows if done is None else done + rows
 
 
 def canon_of(model) -> Canon:

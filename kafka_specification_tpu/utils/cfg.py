@@ -143,7 +143,8 @@ KAFKA_VARIANTS = ("KafkaTruncateToHighWatermark", "Kip101", "Kip279")
 CFG_MODULE_ALIASES = {"Kip320Stretch": "Kip320", "Kip320FiveBroker": "Kip320",
                       "AsyncIsrFourBroker": "AsyncIsr",
                       "MCKip320FiveBroker": "MCKip320",
-                      "Kip279FourBroker": "Kip279"}
+                      "Kip279FourBroker": "Kip279",
+                      "MCKip279FiveBroker": "MCKip279"}
 
 # The wrapper modules a TLC user writes to run a spec under SYMMETRY: the
 # corpus's modules define no symmetry set, so the conventional

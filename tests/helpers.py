@@ -24,16 +24,18 @@ def enumerate_states(model, max_depth=None, min_bucket=32):
         min_bucket=min_bucket,
         collect_levels=collected,
     )
-    levels = []
     unpack = jax.jit(jax.vmap(spec.unpack))
-    for packed in collected:
-        batch = {k: np.asarray(v) for k, v in unpack(packed).items()}
-        states = set()
-        for i in range(packed.shape[0]):
-            row = {k: v[i] for k, v in batch.items()}
-            states.add(model.decode(row))
-        levels.append(states)
-    return res, levels
+    return res, [set(decode_rows(model, packed, unpack))
+                 for packed in collected]
+
+
+def decode_rows(model, packed, unpack=None):
+    """Packed rows (a level as `collect_levels` hands it out) -> the
+    model's decoded canonical states, in the rows' order."""
+    unpack = unpack or jax.jit(jax.vmap(model.spec.unpack))
+    batch = {k: np.asarray(v) for k, v in unpack(packed).items()}
+    return [model.decode({k: v[i] for k, v in batch.items()})
+            for i in range(packed.shape[0])]
 
 
 def assert_matches_oracle(model, oracle, max_depth=None, min_bucket=32):

@@ -29,7 +29,8 @@ from test_oracle_replay import replay_through_oracle
 
 TINY = Config(2, 2, 1, 1)
 KIP101_LEVELS = [1, 4, 14, 44, 100, 166, 268, 456, 684, 976, 1292, 1486]
-CUT_KEYS = {"depth", "frontier", "rows_committed", "chunks_committed",
+CUT_KEYS = {"depth", "frontier", "enabled_candidates", "new", "duplicates",
+            "rows_committed", "chunks_committed",
             "chunks_discarded", "chunks", "chunks_ahead", "dedup_lanes",
             "guard_lanes", "probes", "probes_windowed", "level_ms",
             "step_ms", "host_ms",

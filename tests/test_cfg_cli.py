@@ -75,6 +75,26 @@ def test_cli_check_and_exit_codes(tmp_path, capsys):
     assert '"distinct_states": 12' in out
 
 
+def test_cli_check_of_the_five_broker_kip279_cfg_under_its_wrapper_module(
+        capsys):
+    """`configs/MCKip279FiveBroker.cfg` is no module's stem: the alias names
+    the wrapper module for whoever walks `configs/` (`cli analyze`, the
+    registry test above), `cli check` takes it as the header's line gives
+    it, and the stanza counts orbits (1, 2, 7, 36 for the unreduced 1, 10,
+    110, 1,220)."""
+    import json
+
+    assert CFG_MODULE_ALIASES["MCKip279FiveBroker"] == "MCKip279"
+    with open("configs/MCKip279FiveBroker.cfg") as fh:
+        assert ("check configs/MCKip279FiveBroker.cfg \\\n"
+                "\\*       --module MCKip279\n") in fh.read()
+    rc = cli_main(["check", "configs/MCKip279FiveBroker.cfg", "--module",
+                   "MCKip279", "--hand", "--max-depth", "3", "--json"])
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 0 and out["levels"] == [1, 2, 7, 36]
+    assert out["distinct_states"] == 46
+
+
 @needs_reference
 def test_cli_simulate_emitted(capsys):
     # random walks over the mechanically emitted IdSequence model; TypeOk

@@ -101,9 +101,9 @@ def test_chunks_ahead_counts_the_hidden_boundaries(pair):
             cut["chunks"], cut["discarded_dispatches"]) == (4, 1, 5, 0)
     assert cut["dispatches"] == 2 * 4 + 1
     assert off.stats["cut_level"]["chunks_ahead"] == 0
-    # the verdict's chunk is read exactly as in serial order (never sliced,
-    # `new_n` never fetched); the two fetches more are the dropped guard
-    # stage's counts and matrix
+    # the verdict's chunk is read exactly as in serial order (never sliced;
+    # `new_n` fetched as a count on both sides since PR 47); the two fetches
+    # more are the dropped guard stage's counts and matrix
     assert cut["d2h_fetches"] == off.stats["cut_level"]["d2h_fetches"] + 2
     assert res.stats["overlap"]["staged_chunks_peak"] == 2
     assert res.stats["overlap"]["guard_ahead_peak"] == 1
