@@ -29,7 +29,6 @@ import collections
 import os
 import time
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Optional
 
 import jax
@@ -1023,58 +1022,66 @@ def _f_all(f) -> np.ndarray:
     return f if isinstance(f, np.ndarray) else f.read_all()
 
 
-def walk_trace(trace_store, actions, decode_row, inv_name, depth, idx,
-               obs=None, source="ram", symmetry=None) -> Violation:
+def walk_trace(trace_store, model: Model, inv_name, depth, idx, obs=None,
+               source="ram") -> Violation:
     """Parent-pointer counterexample reconstruction, shared by both engines.
 
     trace_store[level] = (rows, parent, act): the level's states in discovery
     order, each new state's parent index into the previous level, and the
     action id that produced it.  Walks level `depth` index `idx` back to an
     init state and returns the Violation with the root->violation trace.
+    The walk collects the chain's packed rows first; they are decoded after
+    it in one `decode_rows` call, on the host.
 
     With `obs` (a RunObserver) the walk is one `counterexample` span:
-    `source` says where the store lives (`ram` | `disk`), `decode_ms` is the
-    wall of the `decode_row` calls and `walk_ms` the rest (the pointer
-    reads).  Under the model's `symmetry` the span names its operator and
+    `source` says where the store lives (`ram` | `disk`), `walk_ms` is the
+    pointer reads and `decode_ms` the `decode_rows` call (`decode="host"`:
+    numpy and the model's decoder, no device operation; `decoded_rows` its
+    batch).  Under the model's `symmetry` the span names its operator and
     order: the rows walked were stored as found and keyed by their orbits,
     so the trace is a behaviour of the unreduced spec.
     """
+    symmetry = model.symmetry
     sym = {} if symmetry is None else {
         "symmetry": symmetry.operator, "symmetry_order": symmetry.order}
     span = obs.open_span("counterexample", invariant=inv_name, depth=depth,
                          source=source, **sym) if obs is not None else None
     t_walk = time.perf_counter()
-    decode_s = 0.0
-
-    def decode(row):
-        nonlocal decode_s
-        t = time.perf_counter()
-        state = decode_row(row)
-        decode_s += time.perf_counter() - t
-        return state
-
-    chain = []
+    names, rows = [], []
     i = idx
     for d in range(depth, 0, -1):
-        rows, parent, act = trace_store[d]
-        chain.append((actions[int(act[i])].name, decode(rows[i])))
+        level_rows, parent, act = trace_store[d]
+        names.append(model.actions[int(act[i])].name)
+        rows.append(level_rows[i])
         i = int(parent[i])
-    rows0, _, _ = trace_store[0]
-    chain.append(("<init>", decode(rows0[i])))
-    chain.reverse()
+    names.append("<init>")
+    rows.append(trace_store[0][0][i])
+    t_decode = time.perf_counter()
+    states = decode_rows(model, np.stack(rows))
+    chain = list(zip(names, states))[::-1]
     if span is not None:
-        walk_s = time.perf_counter() - t_walk - decode_s
-        span.finish(trace_len=len(chain), walk_ms=round(walk_s * 1e3, 3),
-                    decode_ms=round(decode_s * 1e3, 3))
+        t_end = time.perf_counter()
+        span.finish(trace_len=len(chain), decode="host",
+                    decoded_rows=len(rows),
+                    walk_ms=round((t_decode - t_walk) * 1e3, 3),
+                    decode_ms=round((t_end - t_decode) * 1e3, 3))
     return Violation(invariant=inv_name, depth=depth, state=chain[-1][1], trace=chain)
 
 
+def decode_rows(model: Model, rows: np.ndarray) -> list:
+    """Packed rows on the host, `[n, num_lanes]` -> the model's decoded
+    canonical state of each (the field dict where the model has no
+    decoder), through `StateSpec.unpack_rows`: numpy alone."""
+    fields = model.spec.unpack_rows(rows)
+    states = [{k: v[j, ...] for k, v in fields.items()}
+              for j in range(len(rows))]
+    return [model.decode(s) for s in states] if model.decode else states
+
+
 def decode_packed(model: Model, row: np.ndarray):
-    """One packed row -> the model's decoded canonical state (the field
-    dict where the model has no decoder).  Both engines' verdict paths."""
-    s = {k: np.asarray(v)
-         for k, v in model.spec.unpack(jnp.asarray(row)).items()}
-    return model.decode(s) if model.decode else s
+    """One packed row -> its decoded state: the one-row case of
+    `decode_rows`.  Both engines' verdict paths."""
+    return decode_rows(model, np.asarray(row)[None])[0]
 
 
 def init_violation_result(model: Model, inv, row, levels, total,
@@ -1095,11 +1102,8 @@ def build_violation(model: Model, trace_store, plog_view, inv_name, depth,
     None, and the caller reports the violating state trace-less."""
     for store, source in ((trace_store, "ram"), (plog_view, "disk")):
         if store is not None:
-            return walk_trace(
-                store, model.actions, partial(decode_packed, model),
-                inv_name, depth, idx, obs=obs, source=source,
-                symmetry=model.symmetry,
-            )
+            return walk_trace(store, model, inv_name, depth, idx, obs=obs,
+                              source=source)
     return None
 
 

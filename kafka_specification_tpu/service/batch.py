@@ -62,6 +62,7 @@ from ..engine.bfs import (
     Violation,
     _next_pow2,
     check,
+    decode_packed,
     walk_trace,
 )
 
@@ -139,18 +140,12 @@ class SharedExploration:
         key = (name, depth, idx)
         if key not in self._viol:
             self._viol[key] = walk_trace(
-                self.trace, self.model.actions, self.decode, name, depth, idx
+                self.trace, self.model, name, depth, idx
             )
         return self._viol[key]
 
     def decode(self, packed_row: np.ndarray):
-        import jax.numpy as jnp
-
-        s = {
-            k: np.asarray(v)
-            for k, v in self.model.spec.unpack(jnp.asarray(packed_row)).items()
-        }
-        return self.model.decode(s) if self.model.decode else s
+        return decode_packed(self.model, packed_row)
 
 
 def shared_bounds(members: list) -> tuple:

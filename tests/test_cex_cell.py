@@ -254,6 +254,8 @@ def test_counterexample_span_and_its_split(warm):
     assert (cex["invariant"], cex["depth"], cex["trace_len"],
             cex["source"]) == ("WeakIsr", 11, 12, "ram")
     assert cex["walk_ms"] >= 0 and cex["decode_ms"] > 0
+    # every state of the trace decoded in one host call (PR 48)
+    assert (cex["decode"], cex["decoded_rows"]) == ("host", 12)
     assert cex["walk_ms"] + cex["decode_ms"] <= cex["ms"] + 0.01
 
 

@@ -1,4 +1,7 @@
-"""Codec round-trip and canonicality tests (SURVEY.md §7 step 3)."""
+"""Codec round-trip and canonicality tests (SURVEY.md §7 step 3), and the
+numpy twin of `unpack` the verdict path decodes with (PR 48)."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -8,6 +11,8 @@ import pytest
 from kafka_specification_tpu.ops.packing import Field, StateSpec
 from kafka_specification_tpu.ops.fingerprint import fingerprint_lanes
 from kafka_specification_tpu.ops import dedup
+from kafka_specification_tpu.models import id_sequence
+from kafka_specification_tpu.utils.cfg import build_model, parse_cfg
 
 
 def _random_state(spec, rng):
@@ -112,3 +117,100 @@ def test_member_sorted():
     )
     want = np.array([(int(a), int(b)) in member_keys for a, b in q])
     np.testing.assert_array_equal(got, want)
+
+
+# --- `unpack_rows`: `unpack` on the host -------------------------------------
+
+# the layouts the benchmark's configurations build (perfbench/configs/*.json:
+# `module`, `cfg`), 3 to 15 lanes
+CELL_LAYOUTS = {
+    "kip320-3b": ("Kip320", "configs/Kip320.cfg"),
+    "firsttry-3b": ("Kip320FirstTry", "configs/Kip320FirstTry.cfg"),
+    "kip279-4b": ("Kip279", "configs/Kip279FourBroker.cfg"),
+    "kip279-5b-symmetry": ("MCKip279", "configs/MCKip279FiveBroker.cfg"),
+    "asyncisr-4b": ("AsyncIsr", "configs/AsyncIsrFourBroker.cfg"),
+    "kip320-5b": ("Kip320", "configs/Kip320FiveBroker.cfg"),
+    "kip320-5b-3p": ("Kip320", "configs/Kip320Stretch.cfg"),
+}
+# every lane full, a 32-bit field with a negative bias (its all-ones pattern
+# wraps in int32, in both forms), hashed fingerprints
+HASHED = StateSpec(
+    [
+        Field("wide", (), -(2**31), 2**31 - 1),
+        Field("bytes", (2, 4), -128, 127),
+        Field("bit", (32,), 0, 1),
+    ],
+    force_hashed=True,
+)
+MOST_ROWS = 13  # the longest trace a cell walks
+
+
+def _reachable(model, n):
+    """The first `n` states of the model's search, packed, breadth-first
+    from its initial states (one jitted expansion of a row)."""
+    spec = model.spec
+
+    @jax.jit
+    def successors(row):
+        state = spec.unpack(row)
+        enabled, rows = [], []
+        for a in model.actions:
+            en, nxt = jax.vmap(lambda c: a.kernel(state, c))(
+                jnp.arange(a.n_choices, dtype=jnp.int32))
+            enabled.append(en)
+            rows.append(jax.vmap(spec.pack)(nxt))
+        return jnp.concatenate(enabled), jnp.concatenate(rows)
+
+    rows = [np.asarray(spec.pack(s)) for s in model.init_states()]
+    seen = {r.tobytes() for r in rows}
+    at = 0
+    while len(rows) < n:
+        enabled, nxt = map(np.asarray, successors(rows[at]))
+        at += 1
+        for r in nxt[enabled]:
+            if r.tobytes() not in seen:
+                seen.add(r.tobytes())
+                rows.append(r)
+    return np.stack(rows[:n])
+
+
+@functools.lru_cache(maxsize=None)
+def _layout(name):
+    """(spec, MOST_ROWS packed states the spec can hold)."""
+    if name == "hashed":
+        rng = np.random.default_rng(5)
+        states = [_random_state(HASHED, rng) for _ in range(MOST_ROWS)]
+        return HASHED, np.stack(
+            [np.asarray(HASHED.pack(s)) for s in states])
+    model = (id_sequence.make_model(MOST_ROWS) if name == "id_sequence"
+             else build_model(CELL_LAYOUTS[name][0],
+                              parse_cfg(CELL_LAYOUTS[name][1])))
+    return model.spec, _reachable(model, MOST_ROWS)
+
+
+@pytest.mark.parametrize("n", [0, 1, MOST_ROWS])
+@pytest.mark.parametrize("name", [*CELL_LAYOUTS, "id_sequence", "hashed"])
+def test_unpack_rows_is_unpack_on_the_host(name, n):
+    """The same integers as `jax.vmap(spec.unpack)`, field for field, in
+    value, shape and dtype: for any bit pattern (a reachable state or not:
+    pad bits set, a biased value past its range) and for packed states."""
+    spec, reachable = _layout(name)
+    assert reachable.shape == (MOST_ROWS, spec.num_lanes)
+    rng = np.random.default_rng(6)
+    noise = rng.integers(0, 2**32, size=(n, spec.num_lanes), dtype=np.uint32)
+    noise[:1] = 0xFFFFFFFF  # (where there is a row) every bit of every lane
+    for rows in (noise, reachable[:n]):
+        want = jax.vmap(spec.unpack)(jnp.asarray(rows))
+        got = spec.unpack_rows(rows)
+        # (in the spec's field order, as `unpack` gives them; `vmap` sorts)
+        assert list(got) == [f.name for f in spec.fields]
+        assert set(got) == set(want)
+        for f in spec.fields:
+            assert type(got[f.name]) is np.ndarray
+            assert got[f.name].dtype == want[f.name].dtype == np.int32
+            assert got[f.name].shape == want[f.name].shape == (n,) + f.shape
+            np.testing.assert_array_equal(got[f.name], want[f.name])
+    # the states are states: every field inside its declared range
+    fields = spec.unpack_rows(reachable[:n])
+    for f in spec.fields:
+        assert ((fields[f.name] >= f.lo) & (fields[f.name] <= f.hi)).all()

@@ -18,10 +18,15 @@ wrote it once more for `probes` and `probes_windowed` behind it (nothing else
 differs, key for key) and holds them to the chunks here; PR 46 wrote it
 for the `frontier-verify` span each level boundary now ends (twelve a run;
 with that name taken out every list of spans, and everything else, is the
-file PR 45 left: the chain too, entry for entry).  A
+file PR 45 left: the chain too, entry for entry).  PR 48 left it as it
+was and holds the verdict path to the host here: `build_violation` (both
+sources, a model with and without a decoder), `init_violation_result` and
+the trace-less decode run with `StateSpec.unpack` raising and every
+transfer disallowed.  A
 statement of the commit path that moves across another shows here as a
 counter, a key or a span out of place."""
 
+import contextlib
 import dataclasses
 import gc
 import json
@@ -39,13 +44,16 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-from kafka_specification_tpu.engine.bfs import check
+from kafka_specification_tpu.engine.bfs import (
+    build_violation, check, decode_packed, decode_rows, init_violation_result)
 from kafka_specification_tpu.engine.hostio import LEVEL_TIMINGS
 from kafka_specification_tpu.models import id_sequence, kip320
 from kafka_specification_tpu.models.base import Invariant
 from kafka_specification_tpu.models.kafka_replication import Config
 from kafka_specification_tpu.obs import RunContext, read_jsonl_tolerant
+from kafka_specification_tpu.ops.packing import StateSpec
 from kafka_specification_tpu.parallel.sharded import check_sharded
+from kafka_specification_tpu.storage.parent_log import ParentLog
 from kafka_specification_tpu.utils.pretty import render_trace
 
 GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
@@ -128,16 +136,18 @@ def _init_violation_model():
         Invariant("NotZero", lambda s: s["nextId"] != 0)])
 
 
-def _verdicts():
-    """The verdict of each engine on the violating jobs."""
+def _verdicts(store=None):
+    """The verdict of each engine on the violating jobs; `store` receives
+    the single engine's trace store of the first-try job."""
     mesh = Mesh(np.array(jax.devices()[:2]), ("d",))
     out = {}
     for name, run in (
-            ("single", lambda m: check(m, min_bucket=1024)),
+            ("single", lambda m, **kw: check(m, min_bucket=1024, **kw)),
             ("sharded-2", lambda m: check_sharded(m, mesh=mesh,
                                                   min_bucket=1024))):
         m = kip320.make_first_try_model(FIRST_TRY)
-        out[f"first-try/{name}"] = _violation(run(m), m)
+        kw = {"collect_trace": store} if name == "single" else {}
+        out[f"first-try/{name}"] = _violation(run(m, **kw), m)
         m = _init_violation_model()
         out[f"init/{name}"] = _violation(run(m), m)
     return out
@@ -202,8 +212,26 @@ def test_a_finished_run_is_freed_without_the_collector(tmp_path):
 
 
 @pytest.fixture(scope="module")
-def verdicts():
-    return json.loads(json.dumps(_verdicts()))
+def first_try_store():
+    """The trace store of the single engine's first-try run (`verdicts`
+    fills it: one run of the job serves both)."""
+    return []
+
+
+@pytest.fixture(scope="module")
+def verdicts(first_try_store):
+    return json.loads(json.dumps(_verdicts(first_try_store)))
+
+
+@pytest.fixture(scope="module")
+def first_try_idx(verdicts, first_try_store):
+    """The violating row's index in its level of the store: the one row
+    of the level that decodes to the verdict's state."""
+    want = verdicts["first-try/single"]
+    states = decode_rows(kip320.make_first_try_model(FIRST_TRY),
+                         first_try_store[want["depth"]][0])
+    (idx,) = [i for i, s in enumerate(states) if repr(s) == want["state"]]
+    return idx
 
 
 @pytest.mark.parametrize("job", ["first-try", "init"])
@@ -222,6 +250,126 @@ def test_each_engine_returns_the_parent_commits_violation(verdicts, job,
     assert len(got["trace"]) == len(other["trace"]) == got["depth"] + 1
     if job == "init":
         assert got == other and got["trace"] == [["<init>", "0"]]
+
+
+@contextlib.contextmanager
+def _no_device():
+    """Under it the verdict path can run no device operation: `unpack`,
+    the form the kernels trace, raises, and so does a transfer."""
+    def unpack(self, lanes):
+        raise AssertionError("StateSpec.unpack on the verdict path")
+
+    with pytest.MonkeyPatch.context() as mp, jax.transfer_guard("disallow"):
+        mp.setattr(StateSpec, "unpack", unpack)
+        yield
+
+
+class _Spans:
+    """An observer that keeps what each span it opened ended with."""
+
+    def __init__(self):
+        self.ended = []
+
+    def open_span(self, kind, **attrs):
+        return _Span(self.ended, {"span": kind, **attrs})
+
+
+class _Span:
+    def __init__(self, ended, attrs):
+        self.ended, self.attrs = ended, attrs
+
+    def finish(self, **attrs):
+        self.ended.append({**self.attrs, **attrs})
+
+
+def _eager(spec, row):
+    """What the verdict path did before PR 48: `unpack` op by op on the
+    device, then a fetch a field."""
+    return {k: np.asarray(v)
+            for k, v in spec.unpack(jax.numpy.asarray(row)).items()}
+
+
+def _same_fields(got, want):
+    assert list(got) == list(want)
+    for k in want:
+        assert type(got[k]) is np.ndarray
+        assert (got[k].dtype, got[k].shape) == (want[k].dtype, want[k].shape)
+        np.testing.assert_array_equal(got[k], want[k])
+
+
+@pytest.mark.parametrize("decoder", ["decoder", "no-decoder"])
+@pytest.mark.parametrize("source", ["ram", "disk"])
+def test_the_verdict_path_decodes_on_the_host(verdicts, first_try_store,
+                                              first_try_idx, tmp_path,
+                                              source, decoder):
+    """`build_violation` over the run's own trace store, and over a parent
+    log that holds the same levels, with no device under it: the golden's
+    trace to the byte where the model has a decoder, and the field dicts
+    the eager `unpack` gave where it has none (PR 48: a row that is on the
+    host is unpacked on the host)."""
+    want = verdicts["first-try/single"]
+    store, depth, idx = first_try_store, want["depth"], first_try_idx
+    model = kip320.make_first_try_model(FIRST_TRY)
+    stores = (store, None)
+    if source == "disk":
+        plog = ParentLog(str(tmp_path / "plog"), model.spec.num_lanes)
+        for d, (rows, parent, act) in enumerate(store):
+            plog.begin_level(d)
+            plog.append(rows, parent, act)
+            plog.end_level()
+        stores = (None, plog.view())
+    if decoder == "no-decoder":
+        model = dataclasses.replace(model, decode=None)
+        chain, i = [], idx
+        for d in range(depth, -1, -1):
+            chain.append(_eager(model.spec, store[d][0][i]))
+            i = int(store[d][1][i])
+        chain.reverse()
+    obs = _Spans()
+    with _no_device():
+        v = build_violation(model, *stores, want["invariant"], depth, idx,
+                            obs=obs)
+    assert (v.invariant, v.depth) == (want["invariant"], depth)
+    assert [a for a, _ in v.trace] == [a for a, _ in want["trace"]]
+    assert v.state is v.trace[-1][1]
+    if decoder == "decoder":
+        assert [repr(s) for _, s in v.trace] == [s for _, s in want["trace"]]
+        assert render_trace(model.meta, v.trace) == want["rendered"]
+    else:
+        for (_, got), ref in zip(v.trace, chain):
+            _same_fields(got, ref)
+    (cex,) = obs.ended
+    assert (cex["span"], cex["source"], cex["decode"], cex["decoded_rows"],
+            cex["trace_len"]) == ("counterexample", source, "host",
+                                  depth + 1, depth + 1)
+    assert cex["walk_ms"] >= 0 and cex["decode_ms"] >= 0
+
+
+@pytest.mark.parametrize("decoder", ["decoder", "no-decoder"])
+def test_a_violation_with_no_trace_decodes_on_the_host(decoder):
+    """The two verdicts that walk nothing: an initial state that breaks an
+    invariant (`init_violation_result`) and the trace-less `Violation` of
+    a job that keeps no store (`engine/run.py`: `decode_packed` of the
+    frontier's row), with no device under them."""
+    model = _init_violation_model()
+    if decoder == "no-decoder":
+        model = dataclasses.replace(model, decode=None)
+    (init,) = model.init_states()
+    row = np.asarray(model.spec.pack(init))
+    eager = _eager(model.spec, row)
+    with _no_device():
+        res = init_violation_result(model, model.invariants[0], row, [1], 1,
+                                    0.5)
+        state = decode_packed(model, row)
+    v = res.violation
+    assert (v.invariant, v.depth, v.trace) == ("NotZero", 0,
+                                               [("<init>", v.state)])
+    assert (res.levels, res.total, res.diameter) == ([1], 1, 0)
+    for got in (v.state, state):
+        if decoder == "decoder":
+            assert got == model.decode(eager) and repr(got) == "0"
+        else:
+            _same_fields(got, eager)  # `nextId`: a 0-d int32, as it was
 
 
 if __name__ == "__main__":
