@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.base import Model
+from ..obs.ledger import PROCESS as _LEDGER
 from ..obs.observer import RunObserver
 from ..obs.tracer import now as _now
 from ..ops import hashset
@@ -95,10 +96,17 @@ class _CompileOnFirstCall:
     the bare jitted function.  This is what lets a warm serving path PROVE
     its cache hits: a job that re-uses every step shows zero compile spans
     in its trace (service/kernel_cache, docs/service.md).  The span says
-    what the program is made of too: ``gathers`` / ``scatters``
-    (:func:`indexing_equations` of the jaxpr the call traced; read back
-    from jit's own trace cache, so the program is traced once).  With no
-    active tracer the wrapper costs one dict store and disappears."""
+    what the call was made of: ``trace_ms`` / ``lower_ms`` /
+    ``backend_ms`` / ``cache`` / ``retrieval_ms`` / ``rest_ms``, what JAX
+    itself reported on this thread while the call was open
+    (obs/ledger.py, which books the call whether or not a run is traced:
+    ``rewarm`` has none), and what the program is made of: ``gathers`` /
+    ``scatters`` (:func:`indexing_equations` of the jaxpr the call traced;
+    read back from jit's own trace cache, so the program is traced once,
+    and after the call is booked: the read-back fires a trace event of
+    its own).  The program is called as jit calls it, never through a
+    ``Compiled`` object: the level loop dispatches it 20-110 times a
+    level."""
 
     def __init__(self, fn, cache: dict, key, **attrs):
         self.fn = fn
@@ -109,16 +117,16 @@ class _CompileOnFirstCall:
     def __call__(self, *args):
         from ..obs import tracer as _tr
 
-        t0 = _tr.now()
-        out = self.fn(*args)
-        cur = _tr.current_tracer()
-        if cur is not None:
-            t1 = _tr.now()
-            gathers, scatters = indexing_equations(
-                self.fn.trace(*args).jaxpr.jaxpr
-            )
-            cur.emit_span("compile", t0, t1, gathers=gathers,
-                          scatters=scatters, **self._attrs)
+        with _LEDGER.first_call(**self._attrs) as call:
+            out = self.fn(*args)
+            parts = call.done()
+            cur = _tr.current_tracer()
+            if cur is not None:
+                gathers, scatters = indexing_equations(
+                    self.fn.trace(*args).jaxpr.jaxpr
+                )
+                cur.emit_span("compile", call.t0, call.t1, gathers=gathers,
+                              scatters=scatters, **self._attrs, **parts)
         # swap in the bare jitted fn iff this entry is still current (a
         # capacity-growth eviction may already have dropped the key)
         if self._cache.get(self._key) is self:
@@ -816,9 +824,7 @@ class PreparedKernels:
     artifact: ``prepare(model)`` once, then ``check(model,
     prepared=pk)`` any number of times — the second and every later check
     of the same schema shape re-uses every compiled step (zero ``compile``
-    spans in its trace, the daemon's warm-path proof).  ``warmup``
-    optionally pre-compiles the step for a given frontier bucket so even
-    the FIRST job of a shape pays no compile inside its latency budget.
+    spans in its trace, the daemon's warm-path proof).
 
     Two sizing facts of the last run ride along, each a fixed point of
     the warm protocol ``note_result`` -> ``rewarm`` -> ``check``: the
@@ -923,7 +929,15 @@ class PreparedKernels:
           differ from what the run itself compiled
           (pipeline.warm_seeded_levels).
 
-        Returns the number of programs compiled."""
+        Returns the number of programs compiled.  No caller has a run
+        open around this, so the programs it builds leave no ``compile``
+        span: the process ledger's `rewarm` and `programs` (`slowest`
+        marks them `during: rewarm`) are their record."""
+        with _LEDGER.rewarming() as booked:
+            booked.built = self._rewarm()
+        return booked.built
+
+    def _rewarm(self) -> int:
         from .pipeline import key_vcap, warm_key, warm_seeded_levels
 
         # non-device backends never evict on growth: no `cap`, no loop
@@ -946,40 +960,14 @@ class PreparedKernels:
                 self.step, self.model, self.level_high_waters, cap)
         return done
 
-    def warmup(
-        self,
-        bucket: int = 256,
-        vcap: int = 1 << 12,
-        check_invariants: bool = True,
-        with_merge: bool = True,
-        compact=None,
-        squeeze_full: bool = False,
-    ) -> None:
-        """Force trace + XLA compile of one step shape by running it on an
-        all-invalid frontier (fvalid all False: no successor is enabled, no
-        verdict can fire — pure compilation, results discarded)."""
-        bucket = _next_pow2(max(32, bucket))
-        vcap = _next_pow2(max(64, vcap))
-        step = self.step.get(
-            bucket, vcap, check_invariants, with_merge=with_merge,
-            compact=compact, squeeze_full=squeeze_full,
-        )
-        K = self.model.spec.num_lanes
-        out = step(
-            jnp.zeros((bucket, K), jnp.uint32),
-            jnp.zeros((bucket,), bool),
-            jnp.full(vcap, 0xFFFFFFFF, jnp.uint32),
-            jnp.full(vcap, 0xFFFFFFFF, jnp.uint32),
-            jnp.int32(0),
-        )
-        jax.block_until_ready(out)
-
 
 def prepare(model: Model) -> PreparedKernels:
     """Prepare (and cache on the model) the reusable jitted engine kernels
     for `model` — the explicit warm entry point ``check(...,
     prepared=...)`` consumes."""
-    return PreparedKernels(model)
+    _LEDGER.mark_backend()
+    with _LEDGER.model():
+        return PreparedKernels(model)
 
 
 def sentinel_set(vcap: int, hi: np.ndarray, lo: np.ndarray):
@@ -1277,6 +1265,7 @@ def check(
     """
     options = dict(locals())  # every parameter, under its own name
     t_check = _now()  # (the root span `check` starts here)
+    _LEDGER.mark_backend()
     from .level import run_levels
     from .run import Run, close_run, open_run
 

@@ -36,6 +36,7 @@ import time
 from typing import Optional
 
 from ..resilience.heartbeat import append_jsonl, heartbeat_record
+from .ledger import PROCESS as _LEDGER
 from .metrics import set_registry
 from .tracer import now, set_tracer
 
@@ -303,7 +304,10 @@ class RunObserver:
             self.run.finish(status, **detail)
 
     def finish(self, result) -> None:
-        """Fold the terminal CheckResult into metrics + manifest."""
+        """The engine call has its result: book it in the process ledger,
+        whose snapshot the result carries (``stats["process"]``), and fold
+        the result into metrics + manifest."""
+        result.stats["process"] = _LEDGER.check_done(self._t_begin)
         if self.run is None:
             return
         m = self.run.metrics
@@ -340,9 +344,11 @@ class RunObserver:
         # the run directory's own record of which path produced the
         # answer: whole-level programs run + why (if ever) the run left
         # them (`--pipeline device`); mesh size and what the exchange
-        # carried (sharded engine); the level a verdict cut
+        # carried (sharded engine); the level a verdict cut; what the
+        # process had paid in set-up when the check closed (`cli report`)
         for key in ("device", "devices", "exchange_compressed",
-                    "exchange_bytes_total", "cut_level", "symmetry"):
+                    "exchange_bytes_total", "cut_level", "symmetry",
+                    "process"):
             if key in s:
                 summary[key] = s[key]
         if result.violation is not None:

@@ -277,6 +277,57 @@ def _fmt_dur(s: Optional[float]) -> str:
     return f"{s / 3600:.1f}h"
 
 
+def _setup_table(p: Optional[dict]) -> list:
+    """The process ledger as the check that wrote this manifest left it
+    (obs/ledger.py; manifest `result.process`): what the process had paid
+    outside its searches, one line a part, then its longest first calls.
+    Every figure is wall seconds (a trace inside a trace counts once)."""
+    if not p:
+        return []
+    prog, helpers = p["programs"], p["helpers"]
+    rewarm, checks = p["rewarm"], p["checks"]
+
+    def since_start(unix):
+        return "?" if unix is None else f"{unix - p['start_unix']:.3f} s"
+
+    def both(key):
+        return (f"{prog[key] + helpers[key]:>9.3f} s  programs "
+                f"{prog[key]:.3f} + helpers {helpers[key]:.3f}")
+
+    out = ["", "Set-up of the process (ledger when this check closed):",
+           f"  start   {since_start(p['backend_ready_unix']):>11}  process "
+           f"start to backend ready (JAX imported at "
+           f"{since_start(p['jax_unix'])})",
+           f"  model   {p['model_s']:>9.3f} s  {p['models']} builds",
+           f"  trace   {both('trace_s')}",
+           f"  lower   {both('lower_s')}",
+           f"  backend {both('backend_s')}; cache "
+           f"{prog['cache_hits'] + helpers['cache_hits']} hits, "
+           f"{prog['cache_misses'] + helpers['cache_misses']} misses, "
+           f"{prog['retrieval_s'] + helpers['retrieval_s']:.3f} s of "
+           f"retrieval; {prog['built']} programs, {helpers['built']} helpers"
+           + (" (" + ", ".join(f"{n} x{c}" for n, c in
+                               helpers["by_name"].items()) + ")"
+              if helpers.get("by_name") else ""),
+           f"  rewarm  {rewarm['s']:>9.3f} s  {rewarm['calls']} calls, "
+           f"{rewarm['built']} programs",
+           f"  checks  {checks['s']:>9.3f} s  {checks['calls']} calls (this "
+           f"one {checks['last_s']:.3f} s); first calls "
+           f"{prog['call_s']:.3f} s of the process"]
+    if prog.get("slowest"):
+        out.append("  slowest first calls:")
+    for c in prog.get("slowest", ()):
+        what = " ".join(f"{k}={c[k]}" for k in c if k not in (
+            "ms", "trace_ms", "lower_ms", "backend_ms", "cache",
+            "retrieval_ms", "rest_ms", "during"))
+        out.append(
+            f"    {c['ms']:>10.1f} ms = trace {c['trace_ms']:.1f} + lower "
+            f"{c['lower_ms']:.1f} + backend {c['backend_ms']:.1f} "
+            f"({c['cache']}) + rest {c['rest_ms']:.1f}  [{c['during']}]  "
+            f"{what}")
+    return out
+
+
 def report_data(run_dir: str, now: Optional[float] = None) -> dict:
     """The machine-readable report (cli report --json)."""
     data = load_run(run_dir)
@@ -991,7 +1042,10 @@ def render_report(run_dir: str, now: Optional[float] = None,
         bits.append(f"restarts={restarts}")
     out.append("  " + "  ".join(bits))
     if v["detail"]:
-        out.append("  " + json.dumps(v["detail"], default=str))
+        # (the process's set-up has a table of its own below)
+        out.append("  " + json.dumps(
+            {k: d for k, d in v["detail"].items() if k != "process"},
+            default=str))
     if v["status"] == "resource-exhausted":
         # the verdict beat: this run did NOT crash — it checkpointed and
         # exited typed (exit code 75) because it ran out of something;
@@ -1111,6 +1165,7 @@ def render_report(run_dir: str, now: Optional[float] = None,
             f"  processes: {len(r['shard_procs'])}; last completed level "
             f"per process {depths}"
         )
+    out += _setup_table((man.get("result") or {}).get("process"))
     # --- levels table -----------------------------------------------------
     if levels:
         out.append("")

@@ -61,6 +61,7 @@ from ..ops import devlevel
 from ..pipeline_registry import resolve_pipeline
 from ..models.base import Model
 from ..obs import metrics as _met
+from ..obs.ledger import PROCESS as _LEDGER
 from ..obs.observer import RunObserver
 from ..obs.tracer import now as _now
 from ..ops import dedup, hashset
@@ -1661,6 +1662,7 @@ def check_sharded(
     elastic call may build programs the cache lacks.
     """
     t_check = _now()  # the root `check` span starts at the first line
+    _LEDGER.mark_backend()
     # encoding-soundness gate (analysis; KSPEC_ANALYZE=0 disables) —
     # same refusal contract as engine.check, memoized per model name
     from ..analysis import require_encoding_sound

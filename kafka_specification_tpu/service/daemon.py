@@ -44,6 +44,7 @@ from ..utils.platform_guard import device_label, device_stamp
 from ..engine.bfs import check
 from ..obs import RunContext, fleettrace
 from ..obs.atomicio import atomic_write_text
+from ..obs.ledger import PROCESS as _LEDGER
 from ..obs.metrics import MetricsRegistry
 from ..resilience.faults import FaultPlan, InjectedCrash, injected_skew_s
 from ..resilience.heartbeat import append_jsonl, heartbeat_record
@@ -423,6 +424,7 @@ class Daemon:
         # group is a singleton, so every job runs real solo semantics.
         solo = len(group) == 1
         t0 = time.perf_counter()
+        built0 = _LEDGER.build_s()  # (the job's compile / explore split)
         try:
             invs = (
                 job_invariants(leader_spec["module"], leader_cfg)
@@ -640,7 +642,7 @@ class Daemon:
                     os.environ["KSPEC_FAULT"] = old_fault
         n = self._publish_group(
             group, members, specs, leader_spec, leader_ctx,
-            solo, solo_res if solo else None, shared, t0,
+            solo, solo_res if solo else None, shared, t0, built0,
             seed_depth=seed_depth, cache_entry=entry,
         )
         if solo and self.state_cache is not None and not fault:
@@ -682,7 +684,7 @@ class Daemon:
         return n
 
     def _publish_group(self, group, members, specs, leader_spec,
-                       leader_ctx, solo, solo_res, shared, t0,
+                       leader_ctx, solo, solo_res, shared, t0, built0,
                        seed_depth=None, cache_entry=None) -> int:
         """Derive + publish every member's verdict.  Runs with
         ``_busy_jobs`` still set (cleared by drain_once): derive_member
@@ -702,10 +704,11 @@ class Daemon:
         # and the engine's perf_counter duration agree
         t_run_end = fleettrace.now()
         cache_hit = bool(cache_entry.get("hit")) if cache_entry else None
-        compile_ms = (
-            0.0 if cache_entry is None or cache_hit
-            else round(float(cache_entry.get("build_s") or 0.0) * 1e3, 1)
-        )
+        # what this run spent building, wherever it happened: the model
+        # and its prepared kernels (a kernel-cache miss), and every
+        # program the engine traced, compiled or loaded inside `check`
+        # (a cold shape's minutes), by the process ledger's growth
+        compile_ms = round((_LEDGER.build_s() - built0) * 1e3, 1)
         if compile_ms:
             self.metrics.observe("kspec_svc_stage_compile_ms", compile_ms)
         self.metrics.observe(
@@ -742,8 +745,13 @@ class Daemon:
                     # the engine's RunObserver already finished the
                     # manifest with the SHARED result; overwrite the
                     # summary with the member's own derived verdict +
-                    # service metadata
-                    leader_ctx.finish(rec["status"], **_summary(rec))
+                    # service metadata (the engine's record of the
+                    # process's set-up stays: `cli report` prints it)
+                    process = (leader_ctx.manifest.get("result")
+                               or {}).get("process")
+                    leader_ctx.finish(
+                        rec["status"], **_summary(rec),
+                        **({"process": process} if process else {}))
                     run_dir = leader_ctx.dir
                 else:
                     ctx = RunContext(

@@ -49,6 +49,7 @@ from __future__ import annotations
 import time
 from typing import Optional
 
+from ..obs.ledger import PROCESS as _LEDGER
 from ..utils.cfg import (
     TlcConfig,
     build_model,
@@ -154,7 +155,7 @@ class KernelCache:
     def __init__(self, max_entries: int = 32):
         self.max_entries = max_entries
         self._entries: dict = {}  # overlay key -> entry dict
-        self._models: dict = {}  # model key -> {model, names, build_s}
+        self._models: dict = {}  # model key -> {key, model, names}
         self.hits = 0
         self.misses = 0
         self.evictions = 0
@@ -173,7 +174,6 @@ class KernelCache:
         if base is not None and set(invariants) <= set(base["names"]):
             return base
         union = sorted(set(invariants) | set(base["names"] if base else ()))
-        t0 = time.perf_counter()
         build_cfg = TlcConfig(
             constants=dict(cfg.constants),
             invariants=list(union),
@@ -200,7 +200,6 @@ class KernelCache:
             # the names actually RESOLVED into the model (builders may
             # apply defaults), so coverage checks match reality
             "names": tuple(i.name for i in model.invariants),
-            "build_s": round(time.perf_counter() - t0, 3),
         }
         self._models[bkey] = base
         return base
@@ -222,17 +221,21 @@ class KernelCache:
             return {**entry, "hit": True}
         self.misses += 1
         t0 = time.perf_counter()
-        prior = self._models.get(model_key(module, cfg, emitted))
-        base = self._base(module, cfg, emitted, invariants)
-        overlay = prior is not None and prior is base  # warm base, no build
-        model = _overlay_model(base["model"], tuple(invariants))
-        if model is not base["model"]:
-            self.overlay_derives += 1
+        # (one build in the process ledger's `model_s`, the overlay's
+        # derivation included: `build_model` and `prepare` nest in it)
+        with _LEDGER.model():
+            prior = self._models.get(model_key(module, cfg, emitted))
+            base = self._base(module, cfg, emitted, invariants)
+            overlay = prior is not None and prior is base  # warm base
+            model = _overlay_model(base["model"], tuple(invariants))
+            if model is not base["model"]:
+                self.overlay_derives += 1
+            prepared = prepare(model)
         entry = {
             "key": key,
             "base_key": base["key"],
             "model": model,
-            "prepared": prepare(model),
+            "prepared": prepared,
             "build_s": round(time.perf_counter() - t0, 3),
             "overlay": bool(overlay),
             "last_used": time.time(),

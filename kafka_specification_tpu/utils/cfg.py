@@ -244,6 +244,17 @@ def build_model(
     on leader # None, AsyncIsr TypeOk admitting pendingVersion = Nil); the
     literal reference predicates — False at Init — remain available as
     LeaderInIsrLiteral / TypeOkLiteral (PARITY.md)."""
+    from ..obs.ledger import PROCESS
+
+    # (runs before any run context is open, in the CLI, the daemon and the
+    # benchmark alike: the process ledger's `model_s` is its record)
+    PROCESS.mark_backend(start=False)
+    with PROCESS.model():
+        return _build_model(module, cfg, oracle, emitted, reference,
+                            analysis_gate)
+
+
+def _build_model(module, cfg, oracle, emitted, reference, analysis_gate):
     if emitted and oracle:
         raise ValueError("emitted models have no oracle twin (the oracle IS "
                          "an independent path; use oracle=False)")

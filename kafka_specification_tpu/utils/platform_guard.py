@@ -57,6 +57,9 @@ def enable_compile_cache(min_compile_secs: float = 0.5) -> None:
     """
     import jax
 
+    from ..obs.ledger import PROCESS
+
+    PROCESS.install()  # "JAX imported", and its build events from here on
     cache_dir = compile_cache_dir()
     if cache_dir is not None:
         jax.config.update("jax_compilation_cache_dir", cache_dir)

@@ -363,6 +363,42 @@ def test_daemon_end_to_end_and_warm_second_job(tmp_path):
     assert d.cache.stats()["hits"] == 1
 
 
+def test_compile_explore_split_reads_the_process_ledger(tmp_path):
+    """`compile_ms` of a job's run is what the PROCESS spent building over
+    it, by the process ledger's growth (obs/ledger.py): the model and its
+    prepared kernels AND every program the engine traced, compiled or
+    loaded inside `check`, which the kernel cache's `build_s` never held
+    (a cold shape's compiles were booked to `explore`).  Cold job of a
+    shape: compile > 0 and more than the model build; warm job of the
+    same shape: exactly 0.  Same names and units: `svc-run.compile_ms`,
+    `kspec_svc_stage_compile_ms`, `cli trace`'s stage table."""
+    from kafka_specification_tpu.obs import fleettrace
+
+    svc = tmp_path / "svc"
+    q = JobQueue(str(svc))
+    d = _daemon(svc)
+    cfg = ID_CFG.replace("MaxId = 6", "MaxId = 11")  # a shape of its own
+    runs = []
+    for _ in range(2):
+        jid = q.submit(cfg, "IdSequence", kernel_source="hand")["job_id"]
+        assert d.drain_once() == 1
+        (run,) = [s for s in fleettrace.load_trace([str(svc)], jid)
+                  if s.get("span") == "svc-run"]
+        stages = fleettrace.stage_decomposition(
+            fleettrace.assemble(fleettrace.load_trace([str(svc)], jid),
+                                jid)["spans"])
+        assert stages["compile"] == run["compile_ms"]
+        runs.append((run, _compile_spans(q, jid)))
+    (cold, cold_spans), (warm, warm_spans) = runs
+    assert cold["cache_hit"] is False and warm["cache_hit"] is True
+    first_calls_ms = sum(s["ms"] for s in cold_spans)
+    assert cold_spans and cold["compile_ms"] >= first_calls_ms > 0
+    assert cold["compile_ms"] <= cold["ms"]  # explore = run - compile >= 0
+    assert warm_spans == [] and warm["compile_ms"] == 0.0
+    hist = d.metrics.snapshot()["histograms"]["kspec_svc_stage_compile_ms"]
+    assert hist["count"] == 1  # the warm job observed no compile stage
+
+
 def _compile_spans(q: JobQueue, jid: str) -> list:
     path = os.path.join(q.run_dir(jid), "spans.jsonl")
     with open(path) as fh:
